@@ -129,11 +129,12 @@ def probe_block_dots(data_perm: jax.Array, queries: jax.Array,
         j = pl.program_id(1)
         qv = q_ref[pl.ds(q, 1), :]                    # (1, D)
         if int_path:
-            # native s8 x s8 -> s32 MXU contraction: pass the int8 refs
-            # directly (an explicit int32 upcast would 4x the VMEM copy and
-            # skip the int8 systolic path)
+            # native s8 x s8 -> s32 MXU contraction.  The query row was
+            # widened to int32 for the dynamic row load (see below);
+            # narrowing it back is exact, and the (P, D) block — the
+            # operand that carries the bytes — stays int8 end to end
             dot = jax.lax.dot_general(
-                qv, blk_ref[0],
+                qv.astype(jnp.int8), blk_ref[0],
                 dimension_numbers=(((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.int32)
         else:
@@ -148,6 +149,13 @@ def probe_block_dots(data_perm: jax.Array, queries: jax.Array,
         out_ref[0, pl.ds(j, 1), :] = dot
 
     out_dt = jnp.int32 if int_path else jnp.float32
+    if int_path:
+        # Mosaic loads a dynamic single row only from a 32-bit ref: int8
+        # rows pack four to a sublane, and the v5e compiler refuses
+        # `q_ref[pl.ds(q, 1)]` on them ("cannot statically prove that
+        # index in dimension 0 is a multiple of 8").  So the resident
+        # query matrix is int32 — the same VMEM footprint as the f32 path
+        queries = queries.astype(jnp.int32)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((Q, nprobe, P), out_dt),

@@ -1,0 +1,333 @@
+"""The served path's device programs, compiled for a DESCRIBED TPU v5e.
+
+Interpret-mode and CPU tests cannot see what the chip's compiler refuses
+(an unaligned int8 row load got through every one of them), so the
+programs `chip_smoke.py` runs are compiled here at its real widths
+against a `v5e:2x2` topology that is described, not attached.  Nothing
+runs: a pass says "the compiler accepts it", never "it is right" or
+"it is fast".
+
+The topology is described only inside the module-scoped fixture below
+(never at import: under pytest-xdist every worker imports this file, and
+only the one that runs it may load libtpu), and every compile happens in
+this process with the persistent compile cache off.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from sptag_tpu.core.types import DistCalcMethod
+
+L2 = int(DistCalcMethod.L2)
+COS = int(DistCalcMethod.Cosine)
+
+# chip_smoke.py's shapes: 200k x 128 f32 L2 and 200k x 384 int8 cosine BKT
+# (DenseClusterSize 256 -> ~1024 blocks of 256 rows, MaxCheck 2048 ->
+# nprobe 8), FLAT at 1M x 128, k = 10
+N_BKT, C_BLK, P_BLK, NPROBE, K = 200_000, 1024, 256, 8, 10
+DTYPES = {"f32": jnp.float32, "int8": jnp.int8}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                              # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-device executable is written to the persistent cache
+    # but can never be read back without the chip
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    from sptag_tpu.parallel.sharded import SHARD_AXIS
+
+    assert len(topo.devices) == 4
+    return Mesh(np.asarray(topo.devices), (SHARD_AXIS,))
+
+
+def _on(sharding, tree):
+    """Shapes of `tree` (ShapeDtypeStructs / eval_shape output) placed on
+    `sharding`; None leaves (optional kernel operands) stay None."""
+    return jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        tree)
+
+
+def _s(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _has_mosaic_kernel(compiled) -> bool:
+    return "tpu_custom_call" in compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
+# Pallas kernels
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("dtype,D,Q,nprobe", [
+    ("f32", 128, 256, 8), ("f32", 128, 1024, 64), ("f32", 768, 256, 8),
+    ("int8", 128, 256, 8), ("int8", 384, 1024, 8), ("int8", 128, 1024, 64),
+])
+def test_probe_block_dots_compiles(one_chip, dtype, D, Q, nprobe):
+    from sptag_tpu.ops import pallas_kernels
+
+    dt = DTYPES[dtype]
+    compiled = pallas_kernels.probe_block_dots.lower(
+        _s(one_chip, (C_BLK, P_BLK, D), dt), _s(one_chip, (Q, D), dt),
+        _s(one_chip, (Q, nprobe), jnp.int32)).compile()
+    assert _has_mosaic_kernel(compiled)
+
+
+@pytest.mark.parametrize("dtype,D,G", [
+    ("f32", 128, 8), ("f32", 128, 32), ("int8", 128, 32), ("int8", 384, 32),
+])
+def test_group_block_dots_compiles(one_chip, dtype, D, G):
+    from sptag_tpu.ops import pallas_kernels
+
+    dt = DTYPES[dtype]
+    Q, U = 256, 2 * NPROBE
+    compiled = pallas_kernels.group_block_dots.lower(
+        _s(one_chip, (C_BLK, P_BLK, D), dt), _s(one_chip, (Q, D), dt),
+        _s(one_chip, (Q // G, U), jnp.int32)).compile()
+    assert _has_mosaic_kernel(compiled)
+
+
+# ---------------------------------------------------------------------------
+# dense tree-partition search (SearchMode=dense), Pallas route
+# ---------------------------------------------------------------------------
+
+def _dense_args(sh, dtype, D, Q):
+    dt = DTYPES[dtype]
+    return (_s(sh, (C_BLK, P_BLK, D), dt),             # data_perm
+            _s(sh, (C_BLK, P_BLK), jnp.int32),         # member_ids
+            _s(sh, (C_BLK, P_BLK), jnp.float32),       # member_sq
+            _s(sh, (C_BLK, D), jnp.float32),           # centroids
+            _s(sh, (C_BLK,), jnp.float32),             # cent_sq
+            _s(sh, (N_BKT,), jnp.bool_),               # deleted
+            _s(sh, (Q, D), dt))                        # queries
+
+
+@pytest.mark.parametrize("dtype,D,metric,base", [
+    ("f32", 128, L2, 1), ("int8", 384, COS, 127),
+])
+def test_dense_search_kernel_compiles_with_pallas(one_chip, dtype, D,
+                                                  metric, base):
+    from sptag_tpu.algo.dense import _dense_search_kernel
+
+    compiled = _dense_search_kernel.lower(
+        *_dense_args(one_chip, dtype, D, 256), k=K, nprobe=NPROBE,
+        metric=metric, base=base, use_pallas=True,
+        interpret=False).compile()
+    assert _has_mosaic_kernel(compiled)
+
+
+@pytest.mark.parametrize("dtype,D,metric,base,G", [
+    ("f32", 128, L2, 1, 8), ("int8", 384, COS, 127, 32),
+])
+def test_dense_grouped_kernel_compiles_with_pallas(one_chip, dtype, D,
+                                                   metric, base, G):
+    from sptag_tpu.algo.dense import _dense_search_grouped_kernel
+
+    compiled = _dense_search_grouped_kernel.lower(
+        *_dense_args(one_chip, dtype, D, 256),
+        _s(one_chip, (), jnp.int32), k=K, nprobe=NPROBE, U=2 * NPROBE, G=G,
+        metric=metric, base=base, use_pallas=True,
+        interpret=False).compile()
+    assert _has_mosaic_kernel(compiled)
+
+
+# ---------------------------------------------------------------------------
+# FLAT exact scan at SIFT1M's shape
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("Q", [1, 512])
+def test_flat_search_kernel_compiles_1m(one_chip, Q):
+    from sptag_tpu.algo.flat import _flat_search_kernel
+
+    n, D = 1_000_064, 128           # 1M rows padded to the 128-row bucket
+    compiled = _flat_search_kernel.lower(
+        _s(one_chip, (n, D), jnp.float32), _s(one_chip, (n,), jnp.float32),
+        _s(one_chip, (n,), jnp.bool_), _s(one_chip, (Q, D), jnp.float32),
+        k=K, metric=L2, base=1).compile()
+    mem = compiled.memory_analysis()
+    # corpus + the (Q, N) distance matrix + top-k scratch must fit 16 GB
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes) < 15 * 2 ** 30
+
+
+# ---------------------------------------------------------------------------
+# beam walk (SearchMode=beam) with the bf16 scoring corpus the engine
+# picks only when it sees a TPU — a branch no CPU test takes
+# ---------------------------------------------------------------------------
+
+def _beam_plan(max_check=2048):
+    from sptag_tpu.algo import engine
+
+    L = engine.beam_pool_size(K, max_check, N_BKT)
+    B = engine.beam_width_for(16, max_check, L)
+    limit = max(3, (max_check // 64) // B, 1)
+    return L, B, limit
+
+
+def _engine_arrays(sh, n, D, m=32, pivots=8192):
+    from sptag_tpu.algo.engine import _num_words
+
+    return dict(
+        data=_s(sh, (n, D), jnp.float32),
+        data_score=_s(sh, (n, D), jnp.bfloat16),
+        sqnorm=_s(sh, (n,), jnp.float32),
+        graph=_s(sh, (n, m), jnp.int32),
+        deleted=_s(sh, (n,), jnp.bool_),
+        pivot_ids=_s(sh, (pivots,), jnp.int32),
+        pivot_vecs=_s(sh, (pivots, D), jnp.float32),
+        pivot_mask=_s(sh, (_num_words(n),), jnp.int32))
+
+
+def test_beam_trio_compiles_bf16_scoring(one_chip):
+    from sptag_tpu.algo import engine
+
+    Q, D = 256, 128
+    L, B, limit = _beam_plan()
+    a = _engine_arrays(one_chip, N_BKT, D)
+    q = _s(one_chip, (Q, D), jnp.float32)
+
+    seed = engine._beam_seed_kernel.lower(
+        a["pivot_ids"], a["pivot_vecs"], a["pivot_mask"], q, L=L,
+        metric=L2, seed_keep=0)
+    seed.compile()
+    cand_ids, cand_d, visited, spare_ids, spare_d = _on(
+        one_chip, seed.out_info)
+    state = _on(one_chip, jax.eval_shape(engine._init_walk_state, cand_ids,
+                                         cand_d, visited))
+
+    seg = engine._beam_segment_kernel.lower(
+        a["data"], a["sqnorm"], a["graph"], q,
+        _s(one_chip, (Q,), jnp.int32), *state, k=K, L=L, B=B, S=4,
+        metric=L2, base=1, nbp_limit=limit, inject=4, spare_ids=spare_ids,
+        spare_d=spare_d, data_score=a["data_score"]).compile()
+    assert "bf16" in seg.as_text()
+
+    engine._beam_finalize_kernel.lower(
+        a["data"], a["sqnorm"], a["deleted"], q, state[0], state[1],
+        k_eff=K, metric=L2, base=1, rerank=True).compile()
+
+
+def test_beam_monolithic_walk_compiles_bf16_scoring(one_chip):
+    """The program `GraphSearchEngine.search` dispatches by default
+    (seed + walk + finalize fused)."""
+    from sptag_tpu.algo import engine
+
+    Q, D = 256, 128
+    L, B, limit = _beam_plan()
+    a = _engine_arrays(one_chip, N_BKT, D)
+    engine._beam_search_kernel.lower(
+        a["data"], a["sqnorm"], a["graph"], a["deleted"], a["pivot_ids"],
+        a["pivot_vecs"], a["pivot_mask"], _s(one_chip, (Q, D), jnp.float32),
+        _s(one_chip, (Q,), jnp.int32), k=K, L=L, B=B, metric=L2, base=1,
+        nbp_limit=limit, inject=4, data_score=a["data_score"]).compile()
+
+
+# ---------------------------------------------------------------------------
+# four chips: one program across a (4,) mesh of the described devices
+# ---------------------------------------------------------------------------
+
+def _assert_per_device(compiled, at_most_bytes):
+    mem = compiled.memory_analysis()
+    per_dev = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+               + mem.output_size_in_bytes)
+    assert per_dev < at_most_bytes, per_dev
+
+
+def test_sharded_flat_kernel_compiles_4m_on_four(mesh4):
+    """`chip_smoke.py --chips 4`'s flat phase: 4M x 128 f32, a quarter
+    (512 MB) per chip, per-shard top-k merged by an all-gather."""
+    from sptag_tpu.parallel.sharded import (SHARD_AXIS,
+                                            _sharded_search_kernel)
+
+    n, D, Q = 4_000_000, 128, 64
+    rows = NamedSharding(mesh4, P(SHARD_AXIS, None))
+    vec = NamedSharding(mesh4, P(SHARD_AXIS))
+    rep = NamedSharding(mesh4, P(None, None))
+    compiled = _sharded_search_kernel.lower(
+        _s(rows, (n, D), jnp.float32), _s(vec, (n,), jnp.float32),
+        _s(vec, (n,), jnp.bool_), _s(rep, (Q, D), jnp.float32),
+        k_local=K, k_final=K, metric=L2, base=1, mesh=mesh4).compile()
+    assert "all-gather" in compiled.as_text()
+    # per-device bytes: a quarter of the 2 GB corpus plus the (Q, N/4)
+    # scores — a program that gathered the corpus onto one chip would
+    # show the whole 2 GB here
+    _assert_per_device(compiled, 2 ** 30)
+
+
+def test_sharded_beam_kernel_compiles_on_four(mesh4):
+    from sptag_tpu.algo.engine import _num_words
+    from sptag_tpu.parallel.sharded import SHARD_AXIS, _sharded_beam_kernel
+
+    n_dev, n_local, D, Q, m, pv = 4, N_BKT // 4, 128, 256, 32, 2048
+    L, B, limit = _beam_plan()
+    rows = NamedSharding(mesh4, P(SHARD_AXIS, None))
+    vec = NamedSharding(mesh4, P(SHARD_AXIS))
+    rows3 = NamedSharding(mesh4, P(SHARD_AXIS, None, None))
+    rep = NamedSharding(mesh4, P(None, None))
+    compiled = _sharded_beam_kernel.lower(
+        _s(rows, (n_dev * n_local, D), jnp.float32),
+        _s(vec, (n_dev * n_local,), jnp.float32),
+        _s(rows, (n_dev * n_local, m), jnp.int32),
+        _s(vec, (n_dev * n_local,), jnp.bool_),
+        _s(rows, (n_dev, pv), jnp.int32),
+        _s(rows3, (n_dev, pv, D), jnp.float32),
+        _s(rows, (n_dev, _num_words(n_local)), jnp.int32),
+        _s(rep, (Q, D), jnp.float32),
+        k_local=K, k_final=K, L=L, B=B, T=-(-2048 // B), metric=L2, base=1,
+        nbp_limit=limit, mesh=mesh4).compile()
+    assert "all-gather" in compiled.as_text()
+    _assert_per_device(compiled, 2 ** 30)
+
+
+def test_sharded_dense_kernel_compiles_on_four(mesh4):
+    from sptag_tpu.parallel.sharded import SHARD_AXIS, _sharded_dense_kernel
+
+    n_dev, n_local, D, Q, C = 4, N_BKT // 4, 128, 256, C_BLK // 4
+    s4 = NamedSharding(mesh4, P(SHARD_AXIS, None, None, None))
+    s3 = NamedSharding(mesh4, P(SHARD_AXIS, None, None))
+    s2 = NamedSharding(mesh4, P(SHARD_AXIS, None))
+    vec = NamedSharding(mesh4, P(SHARD_AXIS))
+    rep = NamedSharding(mesh4, P(None, None))
+    compiled = _sharded_dense_kernel.lower(
+        _s(s4, (n_dev, C, P_BLK, D), jnp.float32),
+        _s(s3, (n_dev, C, P_BLK), jnp.int32),
+        _s(s3, (n_dev, C, P_BLK), jnp.float32),
+        _s(s3, (n_dev, C, D), jnp.float32),
+        _s(s2, (n_dev, C), jnp.float32),
+        _s(s2, (n_dev, C), jnp.bool_),
+        _s(vec, (n_dev * n_local,), jnp.bool_),
+        _s(rep, (Q, D), jnp.float32),
+        k_local=K, k_final=K, nprobe=NPROBE, metric=L2, base=1,
+        dedup=False, mesh=mesh4).compile()
+    assert "all-gather" in compiled.as_text()
+    _assert_per_device(compiled, 2 ** 30)
