@@ -825,6 +825,7 @@ class DenseTreeSearcher:
             deleted = np.zeros(self.n, bool)
         self.deleted = jnp.asarray(deleted[:self.n])
         self.last_effective_group = 0     # set by search(); diagnostic only
+        self.last_use_pallas = False      # likewise: the last search's route
         self._demotions = set()
         self.register_devmem()
 
@@ -956,11 +957,10 @@ class DenseTreeSearcher:
             # top-U cut (see _dense_search_grouped_kernel)
             G = min(G, 1 << (U.bit_length() - 1))
             # dtype tile floor: the Pallas (G, D) query block needs the
-            # sublane minimum ((8,128) f32 / (32,128) int8); below it, fall
-            # back to the UNGROUPED kernel rather than compile an illegal
-            # block (which would trip the except-handler and disable the
-            # working per-query Pallas kernel process-wide).  Applied on
-            # every platform so CPU and TPU return the same results
+            # sublane minimum ((8,128) f32 / (32,128) int8); below it, use
+            # the UNGROUPED kernel rather than compile an illegal block.
+            # Applied on every platform so CPU and TPU return the same
+            # results
             if G < self._group_floor():
                 G = 0
             # only G*nprobe distinct blocks can exist in a group's union —
@@ -1009,47 +1009,13 @@ class DenseTreeSearcher:
         use_pallas = pallas_kernels.supported(self.data_perm) and (
             self.data_perm.dtype != np.dtype(np.int8)
             or queries.dtype == np.dtype(np.int8))
-        try:
-            return self._search_impl(queries, nq, k, k_eff, nprobe, chunk,
-                                     D, use_pallas, G, U, bins)
-        except Exception as e:                         # noqa: BLE001
-            # a pallas_call that fails to COMPILE on this backend (Mosaic
-            # lowering gap) must degrade gracefully, not take search
-            # availability down.  Graduated ladder, semantics first: a
-            # failure with grouping active retries the SAME grouped search
-            # through XLA (only the new grouped Pallas kernel may be at
-            # fault — the caller's requested union semantics are kept) and
-            # pins grouped searches to XLA for the process; only a
-            # per-query Pallas failure with a successful XLA retry
-            # justifies process-wide Pallas disablement
-            if not use_pallas:
-                raise
-            if G and not pallas_kernels.grouped_disabled():
-                try:
-                    out = self._search_impl(queries, nq, k, k_eff, nprobe,
-                                            chunk, D, use_pallas=False,
-                                            G=G, U=U, bins=bins)
-                    pallas_kernels.disable_grouped(repr(e)[:200])
-                    return out
-                except Exception:                      # noqa: BLE001
-                    pass                # grouped itself at fault: ungroup
-            self.last_effective_group = 0
-            out = self._search_impl(queries, nq, k,
-                                    min(k_eff, nprobe * P), nprobe, chunk,
-                                    D, use_pallas=False, G=0, U=0,
-                                    bins=topk_bins.resolve_bins(
-                                        binned, min(k_eff, nprobe * P),
-                                        nprobe * P, recall_target))
-            # the ungrouped XLA retry SUCCEEDED, so the failure was not
-            # transient.  Scope the disablement to what actually failed:
-            # with grouping active, BOTH grouped paths failed but the
-            # per-query Pallas kernel never ran — disabling it would
-            # punish an innocent fast path
-            if G:
-                pallas_kernels.disable_grouped(repr(e)[:200])
-            else:
-                pallas_kernels.disable(repr(e)[:200])
-            return out
+        # diagnostic, like last_effective_group: which route the LAST
+        # search took.  The route is decided here, before the call; a
+        # Pallas kernel that then fails raises — nothing retries through
+        # XLA behind the caller's back
+        self.last_use_pallas = use_pallas
+        return self._search_impl(queries, nq, k, k_eff, nprobe, chunk, D,
+                                 use_pallas, G, U, bins)
 
     def _search_impl(self, queries, nq, k, k_eff, nprobe, chunk, D,
                      use_pallas, G=0, U=0, bins=0):
@@ -1073,11 +1039,7 @@ class DenseTreeSearcher:
                     self.data_perm, self.member_ids, self.member_sq,
                     self.centroids, self.cent_sq, self.deleted,
                     jnp.asarray(q), jnp.int32(nq), k_eff, nprobe, U, g_eff,
-                    int(self.metric), self.base,
-                    # a grouped-Pallas compile failure pins grouped
-                    # searches to XLA; the per-query kernel keeps Pallas
-                    use_pallas=use_pallas
-                    and not pallas_kernels.grouped_disabled(),
+                    int(self.metric), self.base, use_pallas=use_pallas,
                     interpret=interp, dedup=dedup, binned_bins=bins)
             else:
                 d, ids = _dense_search_kernel(
@@ -1107,9 +1069,7 @@ class DenseTreeSearcher:
                 jnp.asarray(q.reshape(m, chunk, D)),
                 jnp.asarray(valid3, np.int32),
                 k_eff, nprobe, U, min(G, chunk), int(self.metric),
-                self.base,
-                use_pallas=use_pallas
-                and not pallas_kernels.grouped_disabled(),
+                self.base, use_pallas=use_pallas,
                 interpret=interp, dedup=dedup, binned_bins=bins)
         else:
             d, ids = _dense_search_chunked(

@@ -1056,11 +1056,8 @@ class GraphSearchEngine:
         # returned distances are exact either way; int corpora ignore this
         # (int8 gathers are already 4x smaller than f32).
         if score_dtype == "auto":
-            try:
-                score_dtype = ("bf16" if jax.devices()[0].platform == "tpu"
-                               else "f32")
-            except Exception:                           # noqa: BLE001
-                score_dtype = "f32"
+            score_dtype = ("bf16" if jax.devices()[0].platform == "tpu"
+                           else "f32")
         if self.cascade and self.corpus_tier == "device":
             # device-tier cascade: the int8 quantization replaces the
             # bf16 shadow as the in-loop scoring corpus (half its bytes

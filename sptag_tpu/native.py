@@ -6,13 +6,21 @@ ingestion, wire codec).  Built on demand with g++ (this toolchain has no
 pybind11 — plain C ABI + ctypes), cached next to the source, and every
 caller degrades gracefully to the pure-Python implementation when the
 library is unavailable.
+
+The binary is compiled with -march=native, so it is only ever valid on the
+machine that built it.  A stamp file next to it records what it was built
+from (source, flags, this machine's CPU); a binary whose stamp is missing
+or differs — a checkout copied from another machine, an edited source — is
+rebuilt, never loaded.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
+import platform
 import subprocess
 import threading
 from typing import Optional
@@ -22,19 +30,65 @@ log = logging.getLogger(__name__)
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _SRC = os.path.join(_REPO_ROOT, "native", "sptag_host.cpp")
 _LIB = os.path.join(_REPO_ROOT, "native", "libsptag_host.so")
+_STAMP = _LIB + ".stamp"
+_FLAGS = ["-O3", "-march=native", "-std=c++17", "-shared", "-fPIC"]
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _build() -> bool:
-    cmd = ["g++", "-O3", "-march=native", "-std=c++17", "-shared", "-fPIC",
-           "-o", _LIB, _SRC, "-lpthread"]
+def _machine() -> str:
+    """What -march=native resolved against: architecture plus the CPU's
+    model and feature flags."""
+    lines = [platform.machine()]
+    try:
+        with open("/proc/cpuinfo") as f:
+            seen = set()
+            for ln in f:
+                key = ln.split(":", 1)[0].strip()
+                if key in ("model name", "flags", "Features") \
+                        and key not in seen:
+                    seen.add(key)
+                    lines.append(ln.strip())
+    except OSError:
+        pass
+    return "\n".join(lines)
+
+
+def _expected_stamp() -> str:
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update(" ".join(_FLAGS).encode())
+    h.update(_machine().encode())
+    return h.hexdigest()
+
+
+def _stamp_matches(stamp: str) -> bool:
+    try:
+        with open(_STAMP) as f:
+            return os.path.exists(_LIB) and f.read().strip() == stamp
+    except OSError:
+        return False
+
+
+def _build(stamp: str) -> bool:
+    """Compile to a private name, then rename into place and stamp —
+    concurrent builders (pytest workers) never see a half-written file."""
+    tmp = f"{_LIB}.{os.getpid()}.tmp"
+    cmd = ["g++", *_FLAGS, "-o", tmp, _SRC, "-lpthread"]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        # stamp last: a binary without its stamp is rebuilt, not trusted
+        if os.path.exists(_STAMP):
+            os.remove(_STAMP)
+        os.replace(tmp, _LIB)
+        with open(tmp, "w") as f:
+            f.write(stamp + "\n")
+        os.replace(tmp, _STAMP)
         return True
-    except (subprocess.SubprocessError, FileNotFoundError) as e:
+    except (subprocess.SubprocessError, OSError) as e:
         log.info("native host library build skipped: %s", e)
         return False
 
@@ -51,10 +105,9 @@ def load() -> Optional[ctypes.CDLL]:
         _tried = True
         if not os.path.exists(_SRC):
             return None
-        if not os.path.exists(_LIB) or (os.path.getmtime(_LIB)
-                                        < os.path.getmtime(_SRC)):
-            if not _build():
-                return None
+        stamp = _expected_stamp()
+        if not _stamp_matches(stamp) and not _build(stamp):
+            return None
         try:
             lib = ctypes.CDLL(_LIB)
         except OSError as e:

@@ -30,8 +30,6 @@ import jax.numpy as jnp
 from sptag_tpu.utils import costmodel
 
 _INTERPRET = False        # tests may flip this to run on CPU
-_DISABLED = False         # set when a kernel fails to compile on the backend
-_GROUP_DISABLED = False   # grouped kernel only (per-query kernel stays live)
 
 
 def set_interpret(value: bool) -> None:
@@ -40,43 +38,17 @@ def set_interpret(value: bool) -> None:
     _INTERPRET = value
 
 
-def disable(reason: str = "") -> None:
-    """Disable the Pallas path for this process (callers fall back to the
-    XLA kernels).  Used when a pallas_call fails to compile on the live
-    backend — e.g. a Mosaic lowering gap for a dtype — so one bad kernel
-    degrades throughput instead of availability."""
-    global _DISABLED
-    _DISABLED = True
-    import logging
-
-    logging.getLogger(__name__).warning(
-        "pallas kernels disabled for this process: %s", reason)
-
-
-def disable_grouped(reason: str = "") -> None:
-    """Disable only the grouped probe kernel (callers fall back to the
-    per-query Pallas kernel, which stays live)."""
-    global _GROUP_DISABLED
-    _GROUP_DISABLED = True
-    import logging
-
-    logging.getLogger(__name__).warning(
-        "grouped pallas kernel disabled for this process: %s", reason)
-
-
-def grouped_disabled() -> bool:
-    return _GROUP_DISABLED
-
-
 def interpret() -> bool:
     return _INTERPRET
 
 
 def supported(data_perm) -> bool:
-    """Pallas path gate: TPU (or interpret mode) + f32/int8 data +
-    MXU-friendly block shape."""
-    if _DISABLED:
-        return False
+    """Pallas path gate, decided BEFORE the call from what can be
+    observed: TPU (or interpret mode) + f32/int8 data + MXU-friendly
+    block shape.  There is no switch that turns the route off after a
+    failure: a kernel that passes this gate and then fails to compile or
+    run raises to the caller (tests/test_chip_compile.py holds the
+    compiles the gate promises)."""
     if data_perm.dtype not in (jnp.float32, jnp.dtype(jnp.int8)):
         return False
     C, P, D = data_perm.shape
@@ -86,10 +58,7 @@ def supported(data_perm) -> bool:
         return False
     if _INTERPRET:
         return True
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:                                   # noqa: BLE001
-        return False
+    return jax.devices()[0].platform == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -357,6 +357,14 @@ class PipelinedAnnClient:
         with self._wlock:
             sock, self._sock = self._sock, None
         if sock is not None:
+            # shutdown first: close() alone neither wakes the reader
+            # thread blocked in recv() on this socket nor sends the FIN
+            # while that recv holds the descriptor — the server would
+            # keep the connection (and its handler task) forever
+            try:
+                sock.shutdown(socket.SHUT_RDWR)
+            except OSError:
+                pass                        # already reset by the peer
             sock.close()
         self._fail_pending()
 

@@ -12,10 +12,11 @@ import os
 # the suite's subprocess tests (bench children, multihost, servers) and
 # the main process sharing one cache dir, XLA's executable
 # serialization segfaulted the whole pytest process twice — once reading
-# an entry, once writing one (stacks in reports/ROUND4.md).  In-process
-# jit caching still dedupes within the run; tests must be correct
-# without cross-run executable reuse anyway.
-os.environ.setdefault("SPTAG_TPU_COMPILE_CACHE", "")
+# an entry, once writing one.  jax's own switch, set in the environment
+# before jax is imported so child processes inherit it.  In-process jit
+# caching still dedupes within the run; tests must be correct without
+# cross-run executable reuse anyway.
+os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")
 
 # Run the whole suite under the lock sanitizer (utils/locksan.py): every
 # lock the framework creates during tests records into the process-wide

@@ -103,3 +103,66 @@ def test_native_header_codec_cross_validates_python(lib):
         lib.sptag_unpack_header(buf, t, s, b, c, r)
         assert (t.value, s.value, b.value, c.value, r.value) == (
             int(ptype), int(status), blen, cid, rid)
+
+
+# ---- the stamp rule: a binary not built here from this source is rebuilt,
+# ---- never loaded (a chip run copies the checkout from another machine)
+
+@pytest.fixture
+def planted(lib, tmp_path, monkeypatch):
+    """A dummy `.so` in a private directory, the loader pointed at it and
+    reset; records the first bytes of whatever `ctypes.CDLL` is given."""
+    import ctypes
+
+    so = tmp_path / "libsptag_host.so"
+    so.write_bytes(b"built on another machine")
+    monkeypatch.setattr(native, "_LIB", str(so))
+    monkeypatch.setattr(native, "_STAMP", str(so) + ".stamp")
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_tried", False)
+    loaded = []
+    real_cdll = ctypes.CDLL
+
+    def cdll(path, *args, **kwargs):
+        with open(path, "rb") as f:
+            loaded.append(f.read(4))
+        return real_cdll(path, *args, **kwargs)
+
+    monkeypatch.setattr(native.ctypes, "CDLL", cdll)
+    return so, loaded
+
+
+@pytest.mark.parametrize("stamp", ["0" * 64, None],
+                         ids=["wrong-stamp", "no-stamp"])
+def test_stamp_mismatch_rebuilds_and_never_loads_the_stale_binary(
+        planted, stamp):
+    so, loaded = planted
+    if stamp is not None:
+        (so.parent / (so.name + ".stamp")).write_text(stamp + "\n")
+    got = native.load()
+    assert got is not None
+    assert loaded == [b"\x7fELF"]          # the rebuilt file, loaded once
+    assert got.sptag_count_lines(b"a\t1\n", 4) == 1
+    assert (so.parent / (so.name + ".stamp")).read_text().strip() \
+        == native._expected_stamp()
+
+
+def test_stamp_mismatch_with_failing_build_loads_nothing(planted,
+                                                         monkeypatch):
+    so, loaded = planted
+    (so.parent / (so.name + ".stamp")).write_text("0" * 64 + "\n")
+    monkeypatch.setattr(native, "_FLAGS", ["--no-such-flag"])
+    assert native.load() is None
+    assert loaded == []
+    assert so.read_bytes() == b"built on another machine"
+
+
+def test_matching_stamp_loads_without_rebuild(planted, monkeypatch):
+    so, loaded = planted
+    assert native.load() is not None       # builds + stamps
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_tried", False)
+    monkeypatch.setattr(native, "_build", lambda stamp: pytest.fail(
+        "rebuilt a binary whose stamp matches"))
+    assert native.load() is not None
+    assert loaded == [b"\x7fELF", b"\x7fELF"]
