@@ -539,8 +539,8 @@ class BKTIndex(VectorIndex):
                 # starvation check at the SOURCE (round 5, measured at
                 # 10M: budget 256 over ~5,700 clusters probes nprobe=1 —
                 # one cluster — and the refine pass replaced TPT edges
-                # with near-random results, recall 0.589 -> 0.469;
-                # reports/SCALE.md).  Warn when the refine budget covers
+                # with near-random results, recall 0.589 -> 0.469).
+                # Warn when the refine budget covers
                 # fewer than two probes of the partition it searches.
                 # the search closure below runs max_check=max(budget, 2k)
                 # with k=cef+1, so judge the EFFECTIVE budget (the final
@@ -552,7 +552,7 @@ class BKTIndex(VectorIndex):
                         "dense refine budget MaxCheckForRefineGraph=%d "
                         "(effective %d) probes only %d of %d clusters "
                         "(cluster size %d) — refine at this coverage can "
-                        "DEGRADE the graph (reports/SCALE.md round-5); "
+                        "DEGRADE the graph (measured at 10M, round 5); "
                         "raise the budget or set RefineIterations=0",
                         budget, eff, nprobe_est, searcher.num_clusters,
                         searcher.cluster_size)
@@ -594,9 +594,9 @@ class BKTIndex(VectorIndex):
     def resolve_search_mode(self, mode: str, max_check: int) -> str:
         """Resolve "auto" to a concrete engine: beam below the
         AutoModeThreshold budget, dense at or above it — the measured
-        crossover (reports/TPU_PERF.md: beam holds recall at small
-        MaxCheck where the dense scan collapses, dense wins both QPS and
-        recall at large budgets).  A dense-only index (BuildGraph=0) has
+        crossover (beam holds recall at small MaxCheck where the dense
+        scan collapses, dense wins both QPS and recall at large budgets;
+        round-3 chip sessions, not measured on this code).  A dense-only index (BuildGraph=0) has
         no walk to fall back to, so auto always resolves to dense there."""
         if mode != "auto":
             return mode

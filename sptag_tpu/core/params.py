@@ -104,7 +104,7 @@ _GRAPH_SPECS = [
     _spec("max_check_for_refine_graph", int, 8192, "MaxCheckForRefineGraph"),
     # TPU-side addition (no reference counterpart): roll back a refine
     # pass that lowers sampled graph accuracy by > 0.02 — measured at 10M
-    # (reports/SCALE.md round-5): a budget-starved refine pass replaces
+    # (round 5): a budget-starved refine pass replaces
     # TPT candidate edges with near-random search results
     _spec("refine_accuracy_guard", int, 1, "RefineAccuracyGuard"),
     # catastrophic absolute floor for the guard's rollback: a pass must
@@ -117,7 +117,7 @@ _GRAPH_SPECS = [
     # TPU-side addition: the shared seed-pivot pool scales as n/THIS
     # (capped 16,384) — seed coverage, not search budget, is the beam
     # walk's recall ceiling at scale (measured 250k: 0.45 -> 0.78 recall
-    # from this alone; reports/SCALE.md round-5).  0 disables the
+    # from this alone, round 5).  0 disables the
     # auto-scale and restores the NumberOfInitialDynamicPivots*32 pool
     # for operators trading recall for seed-matmul cost.
     _spec("seed_pivot_auto_scale", int, 24, "SeedPivotAutoScale"),
@@ -320,9 +320,9 @@ class BKTParams(ParamSet):
             _spec("beam_packed_neighbors", int, 0, "BeamPackedNeighbors"),
             # SearchMode=auto: per-request engine pick by budget — beam
             # below this MaxCheck threshold, dense at or above it (the
-            # measured crossover on the 200k corpus is ~1024:
-            # reports/TPU_PERF.md — beam wins recall at small budgets,
-            # dense wins QPS+recall at large ones)
+            # crossover measured on the 200k corpus in round 3 was
+            # ~1024 — beam wins recall at small budgets, dense wins
+            # QPS+recall at large ones; not measured on this code)
             _spec("auto_mode_threshold", int, 1024, "AutoModeThreshold"),
             _spec("dense_cluster_size", int, 256, "DenseClusterSize"),
             # 0 = dense-only build (framework extension): skip the RNG

@@ -145,7 +145,7 @@ class RelativeNeighborhoodGraph:
         # Accuracy guard (round 5, measured at 10M: a refine pass whose
         # search budget is starved — nprobe=1 over the shard's partition —
         # REPLACES good TPT candidate edges with near-random results,
-        # taking recall@2048 from 0.589 to 0.469; reports/SCALE.md).  The
+        # taking recall@2048 from 0.589 to 0.469).  The
         # estimator's sample is seeded, so pre/post is a PAIRED
         # comparison on the same 100 nodes.  A pass that both drops the
         # paired estimate and lands below a catastrophic absolute floor

@@ -38,6 +38,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from sptag_tpu.algo.engine import (
@@ -54,7 +55,6 @@ from sptag_tpu.algo.engine import (
     beam_width_for,
 )
 from sptag_tpu.ops import topk_bins
-from sptag_tpu.parallel._compat import shard_map
 from sptag_tpu.utils import costmodel, recompile_guard, roofline
 
 SHARD_AXIS = "shard"

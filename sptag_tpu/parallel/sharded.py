@@ -25,13 +25,13 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from sptag_tpu.core.index import MAX_DIST
 from sptag_tpu.core.types import DistCalcMethod
 from sptag_tpu.ops import distance as dist_ops
 from sptag_tpu.ops import topk_bins
-from sptag_tpu.parallel._compat import shard_map
 from sptag_tpu.utils import costmodel, devmem, locksan, metrics, round_up
 
 SHARD_AXIS = "shard"

@@ -10,8 +10,7 @@ plus the capability-registry peaks the percentages are stated against.
     python -m sptag_tpu.tools.perf_report            # newest BENCH_*.json
     python -m sptag_tpu.tools.perf_report --probe    # this machine's caps
 
-The table is plain GitHub markdown so it pastes straight into
-reports/TPU_PERF.md.
+The table is plain GitHub markdown so it pastes straight into PERF.md.
 """
 
 from __future__ import annotations

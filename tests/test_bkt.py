@@ -323,7 +323,7 @@ def test_beam_packed_neighbors_matches_row_gather():
 
 
 def test_starved_refine_budget_warns(caplog):
-    """Round-5 guardrail (reports/SCALE.md): a dense refine whose budget
+    """Round-5 guardrail: a dense refine whose budget
     probes <2 clusters of its partition must say so — at 10M that
     configuration silently replaced TPT edges with near-random results."""
     import logging

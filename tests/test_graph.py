@@ -140,7 +140,7 @@ def test_full_build_accuracy():
 
 
 def test_refine_accuracy_guard_rolls_back_degrading_pass(caplog):
-    """Round-5 guardrail (measured at 10M, reports/SCALE.md): a refine
+    """Round-5 guardrail (measured at 10M): a refine
     pass whose search returns garbage must be rolled back instead of
     replacing the TPT candidate edges."""
     import logging

@@ -4,7 +4,7 @@ Small corpora on 2-4 virtual CPU devices (the conftest `host_mesh`
 helper; the suite boots with 8 forced host devices) so the mesh serve
 spine is exercised in tier-1 instead of living behind `slow` markers:
 
-* the shard_map compat shim (jax.shard_map vs the experimental module);
+* the mesh programs go through the installed `jax.shard_map`;
 * the merge contract: the in-mesh path returns the SAME ids as the
   socket fan-out aggregator + host merge over identical shard contents,
   across k / MaxCheck / deleted-mask cases;
@@ -73,15 +73,17 @@ def mesh_built(tmp_path_factory):
     return data, index, folder
 
 
-# ------------------------------------------------------------- compat shim
+# ------------------------------------------------------------- shard_map
 
-def test_shard_map_compat_shim(host_mesh):
-    """parallel/_compat.py resolves a working shard_map on this JAX
-    (the removed-`jax.shard_map` pre-existing failure class), and a
-    sharded search actually runs through it."""
-    from sptag_tpu.parallel import _compat
+def test_mesh_programs_use_the_installed_shard_map(host_mesh):
+    """One installation: the mesh modules call `jax.shard_map` itself (no
+    version shim in between), and a sharded search runs through it."""
+    import jax
 
-    assert callable(_compat.shard_map)
+    from sptag_tpu.parallel import mesh_engine, sharded
+
+    assert sharded.shard_map is jax.shard_map
+    assert mesh_engine.shard_map is jax.shard_map
     data = _corpus(n=96, d=8, seed=1)
     idx = ShardedFlatIndex(data, DistCalcMethod.L2, base=1,
                            mesh=host_mesh(2))

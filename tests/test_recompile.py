@@ -146,3 +146,58 @@ def test_warmup_then_guard_helper():
     d, ids = rg.warmup_then_guard(idx.search_batch, data[:8], 5,
                                   label="helper", repeats=2)
     assert ids.shape == (8, 5)
+
+
+# ---- where the persistent compile cache goes (utils.enable_compile_cache):
+# ---- placed from outside by JAX_COMPILATION_CACHE_DIR, else one fixed
+# ---- directory in the checkout
+
+@pytest.fixture
+def fresh_cache_switch(monkeypatch):
+    import jax
+
+    from sptag_tpu import utils
+
+    before = jax.config.jax_compilation_cache_dir
+    min_secs = jax.config.jax_persistent_cache_min_compile_time_secs
+    monkeypatch.setattr(utils, "_cache_enabled", False)
+    yield utils
+    jax.config.update("jax_compilation_cache_dir", before)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", min_secs)
+
+
+def test_compile_cache_dir_from_environment_is_left_alone(
+        fresh_cache_switch, monkeypatch, tmp_path):
+    import jax
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "mine"))
+    jax.config.update("jax_compilation_cache_dir", "sentinel-untouched")
+    fresh_cache_switch.enable_compile_cache()
+    assert jax.config.jax_compilation_cache_dir == "sentinel-untouched"
+
+
+def test_compile_cache_dir_defaults_to_the_checkout(fresh_cache_switch,
+                                                    monkeypatch):
+    import os
+
+    import jax
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    fresh_cache_switch.enable_compile_cache()
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert jax.config.jax_compilation_cache_dir \
+        == os.path.join(repo, ".jax_cache")
+    # fixed: no salt, pid or time — a second process computes the same
+    assert fresh_cache_switch.COMPILE_CACHE_DIR \
+        == os.path.join(repo, ".jax_cache")
+
+
+def test_suite_turns_the_cache_off_with_jaxs_own_switch():
+    """conftest.py exports JAX_ENABLE_COMPILATION_CACHE=false before jax
+    is imported, so this process and every child it starts run uncached."""
+    import os
+
+    import jax
+
+    assert os.environ["JAX_ENABLE_COMPILATION_CACHE"] == "false"
+    assert jax.config.jax_enable_compilation_cache is False

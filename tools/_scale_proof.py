@@ -1,6 +1,6 @@
 """One-off scale proof: 500k-row BKT build + search end-to-end on the CPU
-backend (the TPU compile service was down when this ran; the CPU backend
-executes the identical programs).  Results recorded in reports/SCALE.md.
+backend (which executes the identical programs).  Not measured on the
+chip on this code.
 
 Run from the repo root: `python tools/_scale_proof.py`
 """

@@ -41,7 +41,6 @@ from bench import (  # noqa: E402
     build_or_load,
     exact_topk,
     make_dataset,
-    probe_accelerator,
 )
 
 
@@ -219,19 +218,17 @@ def main():
     ap.add_argument("--build-only", action="store_true")
     ap.add_argument("--configs", default="1,2,4")
     ap.add_argument("--cpu", action="store_true",
-                    help="force the CPU backend (skip the TPU probe)")
+                    help="run on the CPU backend (otherwise the device "
+                    "must be a TPU)")
     args = ap.parse_args()
 
+    import jax
     if args.cpu:
-        import jax
         jax.config.update("jax_platforms", "cpu")
-        platform = "cpu"
-    else:
-        platform, err, _, _cached = probe_accelerator(budget_s=600)
-        if platform is None:
-            import jax
-            jax.config.update("jax_platforms", "cpu")
-            platform = "cpu"
+    platform = jax.devices()[0].platform
+    if platform != ("cpu" if args.cpu else "tpu"):
+        raise SystemExit(f"device platform is {platform!r}; pass --cpu "
+                         "for an explicit CPU run")
 
     results = []
     for key in args.configs.split(","):

@@ -134,7 +134,7 @@ def main():
     # layout too, so the quality ladder below can measure BOTH modes:
     # beam (the reference-parity walk) and dense (the TPU flagship —
     # measured at 250k it responds to budget all the way up where the
-    # walk's recall is seed-coverage-bound; reports/SCALE.md round-5).
+    # walk's recall is seed-coverage-bound; round 5).
     # RSS caveat: the dense pack allocates a padded second corpus copy
     # AFTER the build's resume checkpoints retire (~4 GB host-side at
     # 10M x d96) — on a memory-tight box set SCALE10M_DENSE=0 or a
