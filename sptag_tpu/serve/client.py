@@ -27,6 +27,7 @@ Three client shapes, smallest first:
 from __future__ import annotations
 
 import concurrent.futures
+import contextlib
 import random
 import socket
 import threading
@@ -361,10 +362,8 @@ class PipelinedAnnClient:
             # thread blocked in recv() on this socket nor sends the FIN
             # while that recv holds the descriptor — the server would
             # keep the connection (and its handler task) forever
-            try:
+            with contextlib.suppress(OSError):  # already reset by peer
                 sock.shutdown(socket.SHUT_RDWR)
-            except OSError:
-                pass                        # already reset by the peer
             sock.close()
         self._fail_pending()
 

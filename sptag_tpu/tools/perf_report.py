@@ -1,6 +1,6 @@
 """Roofline perf report — `python -m sptag_tpu.tools.perf_report`.
 
-Renders the TPU_PERF.md-style roofline table (VERDICT §"Next round"
+Renders the roofline table (VERDICT §"Next round"
 item 5) from a bench artifact's ledger-derived roofline block: one row
 per measured kernel family (flat / dense / beam / int8) with achieved
 GFLOP/s, achieved GB/s, %-of-peak on both axes and the binding resource,

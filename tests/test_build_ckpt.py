@@ -66,7 +66,7 @@ def test_interrupted_build_resumes_completed_stages(tmp_path, monkeypatch):
 
     def dying_refine(self, *a, **kw):
         calls["n"] += 1
-        raise RuntimeError("tunnel died")
+        raise RuntimeError("backend died")
 
     monkeypatch.setattr(RelativeNeighborhoodGraph, "refine_once",
                         dying_refine)
@@ -131,7 +131,7 @@ def test_kdt_interrupted_build_resumes(tmp_path, monkeypatch):
     real_refine = RelativeNeighborhoodGraph.refine_once
 
     def dying_refine(self, *a, **kw):
-        raise RuntimeError("tunnel died")
+        raise RuntimeError("backend died")
 
     monkeypatch.setattr(RelativeNeighborhoodGraph, "refine_once",
                         dying_refine)
