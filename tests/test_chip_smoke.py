@@ -124,7 +124,8 @@ def test_rehearsal_prints_phase_lines_and_never_ok(tmp_path):
     assert set(last["device"]) == {"platform", "kind", "count"}
     assert last["device"]["platform"] == "cpu"
     phases = {ln["phase"]: ln for ln in lines[:-1]}
-    assert list(phases) == ["device", "compile_cache", "flat_1m"]
+    assert list(phases) == ["device", "compile_cache", "flat.build",
+                            "flat_1m"]
     flat = phases["flat_1m"]
     assert (flat["d"], flat["k"], flat["metric"]) == (128, 10, "L2")
     assert flat["ids_identical"] + flat["ids_tie_resolved"] \
