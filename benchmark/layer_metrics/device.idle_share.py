@@ -1,0 +1,6 @@
+"""1 - (union of device-operation intervals / traced slice), in %."""
+
+
+def read(run):
+    t = run["trace"]
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"]) if t else None
