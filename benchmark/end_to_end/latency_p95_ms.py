@@ -1,10 +1,7 @@
 """`latency_p95_ms`: as latency_p50_ms, the 95th percentile."""
 
-import numpy as np
+from benchmark.harness.reduce import latency_percentile_ms
 
 
 def read(run):
-    r = run["requests"]
-    ok = r["status"] == r["success_status"]
-    return float(np.percentile(r["latency"][ok], 95)) * 1e3 if ok.any() \
-        else None
+    return latency_percentile_ms(run, 95)

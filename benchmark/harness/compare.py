@@ -19,6 +19,16 @@ def number(name: str, value, limit, better: str) -> dict:
             "better": better, "ok": ok}
 
 
+def answers_as_window(ids, dists) -> dict:
+    """Bare answers (one per query, in query order) in the shape of a
+    window's record — how a control is put in the program's place."""
+    n = len(ids)
+    return {"query": np.arange(n), "status": np.zeros(n, np.int64),
+            "success_status": np.int64(0),
+            "ids": np.asarray(ids, np.int64),
+            "dists": np.asarray(dists, np.float32)}
+
+
 def distinct_answers(record: dict, sample: np.ndarray):
     """Every Success answer of the window to a query of `sample`, reduced
     to the distinct (query, ids, distances) triples -> (query index (m,),

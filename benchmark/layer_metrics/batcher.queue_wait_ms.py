@@ -1,6 +1,7 @@
 """Span `server.queue_wait` (enqueued -> its batch assembled), mean."""
 
+from benchmark.harness.reduce import span_mean_ms
+
 
 def read(run):
-    s = run["spans"].get("server.queue_wait")
-    return 1e3 * s["total_s"] / s["count"] if s else None
+    return span_mean_ms(run, "server.queue_wait")

@@ -1,7 +1,8 @@
 """Span `server.execute_batch` (parse, grouping, dispatch, device program
 and readback of one batch, timed from outside), mean."""
 
+from benchmark.harness.reduce import span_mean_ms
+
 
 def read(run):
-    s = run["spans"].get("server.execute_batch")
-    return 1e3 * s["total_s"] / s["count"] if s else None
+    return span_mean_ms(run, "server.execute_batch")

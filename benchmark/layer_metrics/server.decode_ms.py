@@ -1,7 +1,8 @@
 """Span `server.decode` (wire body -> RemoteQuery), total / count over
 the window."""
 
+from benchmark.harness.reduce import span_mean_ms
+
 
 def read(run):
-    s = run["spans"].get("server.decode")
-    return 1e3 * s["total_s"] / s["count"] if s else None
+    return span_mean_ms(run, "server.decode")

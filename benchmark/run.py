@@ -43,13 +43,13 @@ if ROOT not in sys.path:
 
 import numpy as np                     # noqa: E402
 
-from benchmark.harness import serving, tracered          # noqa: E402
+from benchmark.harness import compare, serving, tracered  # noqa: E402
 from benchmark.harness.serving import HarnessError, require  # noqa: E402
 from benchmark.loadgen import load_by_name               # noqa: E402
 
 CACHE = os.path.join(HERE, ".cache")
 WORK = os.path.join(HERE, ".work")
-INDEX_CACHE_KEEP = 4        # cached indexes kept per checkout (~70 MB each)
+INDEX_CACHE_KEEP = 8        # cached indexes kept per checkout (~70 MB each)
 TRACE_START_S, TRACE_LENGTH_S = 2.0, 3.0
 
 
@@ -59,16 +59,16 @@ def load_json(*parts):
 
 
 def find_cell(workload: str) -> tuple:
-    """-> (benchmark, cell, config, traffic) by the names in
-    BENCHMARK.json."""
+    """-> (benchmark, cell, config, its file's path, traffic) by the names
+    in BENCHMARK.json."""
     bench = load_json(ROOT, "BENCHMARK.json")
     cells = {w["name"]: w for w in bench["workloads"]}
     require(workload in cells, f"no workload {workload!r} in BENCHMARK.json")
     cell = cells[workload]
     entry = {c["name"]: c for c in bench["configs"]}[cell["config"]]
-    config = load_json(ROOT, entry["file"])
+    config_path = os.path.join(ROOT, entry["file"])
     traffic = load_json(HERE, "traffic", cell["traffic"] + ".json")
-    return bench, cell, config, traffic
+    return bench, cell, load_json(config_path), config_path, traffic
 
 
 def metrics_of(bench: dict, group: str, workload: str) -> list:
@@ -93,8 +93,7 @@ def source_hash(config_path: str) -> str:
     return h.hexdigest()[:16]
 
 
-def build_or_load(name, config, config_path, seed, data, workdir,
-                  variant: str) -> tuple:
+def build_or_load(name, config, config_path, seed, data, workdir) -> tuple:
     """-> (index folder, seconds of index_builder.main that made it,
     whether this run built it).  A configuration with `index_cache` keeps
     the saved folder in benchmark/.cache/index/ by seed and source hash
@@ -105,7 +104,7 @@ def build_or_load(name, config, config_path, seed, data, workdir,
                                            config), True
     home = os.path.join(CACHE, "index")
     entry = os.path.join(
-        home, f"{name}-s{seed}{variant}-{source_hash(config_path)}")
+        home, f"{name}-s{seed}-{source_hash(config_path)}")
     note = os.path.join(entry, "build.json")
     folder = os.path.join(entry, "index")
     if os.path.exists(os.path.join(folder, "indexloader.ini")) \
@@ -226,10 +225,9 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
     `rehearse` (tests only): sizes that fit a CPU — skips the look for a
     chip, and the numbers it reads are never printed as metrics.
     `control` (the on-chip control only): the float precision the program
-    is switched to before it builds and serves."""
-    bench, cell, config, traffic = find_cell(workload)
-    entry = {c["name"]: c for c in bench["configs"]}[cell["config"]]
-    config_path = os.path.join(ROOT, entry["file"])
+    is switched to before it serves (one to a process: a program traced
+    at one precision is not traced again)."""
+    bench, cell, config, config_path, traffic = find_cell(workload)
     if rehearse:
         config = {**config, **rehearse.get("config", {})}
         traffic = {**traffic, **rehearse.get("traffic", {})}
@@ -252,8 +250,6 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
     from sptag_tpu.utils import recompile_guard
     from sptag_tpu.utils import trace as program_trace
 
-    if control:
-        program_distance.set_float_precision(control)
     workdir = os.path.join(WORK, workload)
     shutil.rmtree(workdir, ignore_errors=True)
     os.makedirs(workdir)
@@ -263,8 +259,10 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
     data, queries = dataset.make(seed, config["rows"], config["dim"],
                                  traffic["distinct_queries"])
     folder, build_seconds, built = build_or_load(
-        name, config, config_path, seed, data, workdir,
-        f"-{control}" if control else "")
+        name, config, config_path, seed, data, workdir)
+    if control:
+        # the index is the sound one; what it is served with is not
+        program_distance.set_float_precision(control)
     texts = [serving.query_text(name, k, q) for q in queries]
     texts_path = os.path.join(workdir, "texts.json")
     with open(texts_path, "w") as f:
@@ -322,14 +320,11 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
     status = record["status"]
     success = status == record["success_status"]
     attempted, failed = int(len(status)), int((~success).sum())
-    numbers = [{"name": "answers_received", "value": float(success.sum()),
-                "limit": 1.0, "better": "higher",
-                "ok": bool(success.any())},
-               {"name": "requests_failed", "value": float(failed),
-                "limit": 0.0, "better": "lower", "ok": failed == 0},
-               {"name": "serve_errors", "value": float(sum(errors.values())),
-                "limit": 0.0, "better": "lower",
-                "ok": not any(errors.values())}]
+    numbers = [compare.number("answers_received", success.sum(), 1,
+                              "higher"),
+               compare.number("requests_failed", failed, 0, "lower"),
+               compare.number("serve_errors", sum(errors.values()), 0,
+                              "lower")]
     if success.any():
         answered = np.unique(record["query"][success])
         rng = np.random.default_rng(seed)
@@ -344,13 +339,17 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
               f"{'ok' if n['ok'] else 'NOT OK'}", flush=True)
     reference_s = time.perf_counter() - t_ref
 
-    group = "per_layer" if traced else "end_to_end"
-    folder_of = {"per_layer": "layer_metrics", "end_to_end": "end_to_end"}
+    group, readers = ("per_layer", "layer_metrics") if traced \
+        else ("end_to_end", "end_to_end")
     values = {}
     for m in metrics_of(bench, group, workload):
-        value = load_by_name(folder_of[group], m["name"]).read(run)
+        value = load_by_name(readers, m["name"]).read(run)
         if value is not None:
             values[m["name"]] = {"value": float(value), "unit": m["unit"]}
+    done = (record["t_send"] + record["latency"])[success]
+    quarters = [float(((done >= i * seconds / 4)
+                       & (done < (i + 1) * seconds / 4)).sum())
+                / (seconds / 4) for i in range(4)]
     result = {"correct": all(n["ok"] for n in numbers),
               "attempted": attempted, "failed": failed,
               "metrics": values, "device": device,
@@ -359,6 +358,7 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
                        "build_seconds": build_seconds,
                        "built_this_run": built, "warm_passes": warm_passes,
                        "compiles_in_window": compiles.count,
+                       "qps_by_quarter": quarters,
                        "serve_errors": errors,
                        **(run["check"] or {}).get("seen", {})}}
     if control:
