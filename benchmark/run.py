@@ -310,10 +310,6 @@ def run_cell(workload: str, seed: int, seconds: float, traced: bool,
         if raw["devices"] or not rehearse:
             run["trace"] = tracered.reduce_trace(raw, cell["chips"])
             run["trace"]["lines"] = raw["lines"]
-        keep = os.environ.get("BENCHMARK_KEEP_TRACE")
-        if keep:
-            os.makedirs(keep, exist_ok=True)
-            shutil.copy(tracered.find_xplane(trace_dir), keep)
 
     # the reference, after the window: not the system's set-up
     t_ref = time.perf_counter()

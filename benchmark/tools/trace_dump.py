@@ -3,6 +3,9 @@ the first events and the heaviest names of each line — as JSON, for
 reading one trace by hand before trusting the reduction.
 
     python3 -m benchmark.tools.trace_dump <dir or .xplane.pb> <out.json>
+
+A traced run leaves its trace in benchmark/.work/<cell>/trace until the
+cell's next run.
 """
 
 import json
