@@ -785,28 +785,33 @@ class BKTIndex(VectorIndex):
             raise ValueError(
                 f"query dim {queries.shape[1]} != index dim "
                 f"{self.feature_dim}")
-        queries = self._prepare_query(queries)
         from concurrent.futures import Future
 
         from sptag_tpu.algo.scheduler import pad_result_row
 
-        # delta union for the streaming path: the shard is scanned ONCE
-        # for the whole batch up front (fresh rows must be visible to
-        # streamed results exactly like whole-batch ones), and each
-        # retiring query merges its row in its resolve callback.  The
-        # scheduler walks the engine snapshot pinned at submit, so the
-        # two tiers stay disjoint even if a swap lands mid-flight.
-        delta = self._delta
-        delta_res = None
-        if delta is not None and delta.count:
-            from sptag_tpu.core.delta import merge_topk
+        # this path bypasses search_batch: the span holds preparation, the
+        # delta scan and the hand-over to the scheduler; the walk itself
+        # runs on the scheduler's thread and is waited for by the caller
+        with trace.span("index.search"):
+            queries = self._prepare_query(queries)
+            # delta union for the streaming path: the shard is scanned
+            # ONCE for the whole batch up front (fresh rows must be
+            # visible to streamed results exactly like whole-batch ones),
+            # and each retiring query merges its row in its resolve
+            # callback.  The scheduler walks the engine snapshot pinned at
+            # submit, so the two tiers stay disjoint even if a swap lands
+            # mid-flight.
+            delta = self._delta
+            delta_res = None
+            if delta is not None and delta.count:
+                from sptag_tpu.core.delta import merge_topk
 
-            delta_res = delta.search(queries, min(k, delta.count),
-                                     self._tombstone_mask())
+                delta_res = delta.search(queries, min(k, delta.count),
+                                         self._tombstone_mask())
+            inners = self._scheduler_submit(queries, min(k, self._n), mc,
+                                            rids=rids)
         out = []
-        for row, inner in enumerate(
-                self._scheduler_submit(queries, min(k, self._n), mc,
-                                       rids=rids)):
+        for row, inner in enumerate(inners):
             outer: Future = Future()
 
             def _pad(f, outer=outer, row=row):

@@ -33,11 +33,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from sptag_tpu.core.types import DistCalcMethod
+from sptag_tpu.core.types import DeviceTopK, DistCalcMethod
 from sptag_tpu.ops import distance as dist_ops
 from sptag_tpu.ops import pallas_kernels
 from sptag_tpu.ops import topk_bins
-from sptag_tpu.utils import costmodel, devmem, query_bucket, round_up
+from sptag_tpu.utils import (costmodel, devmem, metrics, query_bucket,
+                             round_up, trace)
 
 MAX_DIST = np.float32(3.4e38)   # plain scalar: module import must NOT init a backend
 
@@ -252,26 +253,29 @@ def _finalize_topk(nd, ids, deleted, dedup: bool, k: int, extra_dead=None,
     recipe's answer to the scan's sort bottleneck; callers size bins via
     the recall-target math so returned-set recall meets the configured
     ApproxRecallTarget."""
-    dead = deleted[jnp.maximum(ids, 0)] | (ids < 0)
-    if extra_dead is not None:
-        dead = dead | extra_dead
-    nd = jnp.where(dead, MAX_DIST, nd)
-    if dedup:
-        # closure-assigned replicas: the same row can appear in several
-        # probed blocks with identical distances — keep one occurrence
-        from sptag_tpu.algo.engine import _sorted_dup_mask
+    with jax.named_scope("dense.mask"):
+        dead = deleted[jnp.maximum(ids, 0)] | (ids < 0)
+        if extra_dead is not None:
+            dead = dead | extra_dead
+        nd = jnp.where(dead, MAX_DIST, nd)
+        if dedup:
+            # closure-assigned replicas: the same row can appear in
+            # several probed blocks with identical distances — keep one
+            # occurrence
+            from sptag_tpu.algo.engine import _sorted_dup_mask
 
-        nd = jnp.where(_sorted_dup_mask(jnp.where(ids >= 0, ids, -1)) &
-                       (ids >= 0), MAX_DIST, nd)
-    k_eff = min(k, nd.shape[1])
-    if binned_bins:
-        out_d, pos = topk_bins.binned_topk(nd, k_eff, binned_bins)
-    else:
-        neg, pos = jax.lax.top_k(-nd, k_eff)
-        out_d = -neg
-    out_ids = jnp.take_along_axis(ids, pos, axis=1)
-    out_ids = jnp.where(out_d < MAX_DIST, out_ids, -1)
-    return out_d, out_ids.astype(jnp.int32)
+            nd = jnp.where(_sorted_dup_mask(jnp.where(ids >= 0, ids, -1)) &
+                           (ids >= 0), MAX_DIST, nd)
+    with jax.named_scope("dense.topk"):
+        k_eff = min(k, nd.shape[1])
+        if binned_bins:
+            out_d, pos = topk_bins.binned_topk(nd, k_eff, binned_bins)
+        else:
+            neg, pos = jax.lax.top_k(-nd, k_eff)
+            out_d = -neg
+        out_ids = jnp.take_along_axis(ids, pos, axis=1)
+        out_ids = jnp.where(out_d < MAX_DIST, out_ids, -1)
+        return out_d, out_ids.astype(jnp.int32)
 
 
 @functools.partial(jax.jit,
@@ -296,33 +300,41 @@ def _dense_search_kernel(data_perm, member_ids, member_sq, centroids,
     # them with float queries (int8/int16 values are exact in f32; the
     # integer dot branch would truncate the means to int32 and mis-rank
     # blocks against the float cent_sq term)
-    d0 = dist_ops.pairwise_distance(queries.astype(jnp.float32), centroids,
-                                    DistCalcMethod(metric), x_sqnorm=cent_sq)
-    _, topc = jax.lax.top_k(-d0, nprobe)                     # (Q, nprobe)
-    ids = member_ids[topc].reshape(Q, nprobe * P)
-    sq = member_sq[topc].reshape(Q, nprobe * P)
-    if use_pallas:
-        from sptag_tpu.ops import pallas_kernels
+    # the scope names (dense.centroids / gather / probe, and dense.mask /
+    # dense.topk in _finalize_topk) are what a profiler trace calls the
+    # stages; the grouped twin uses the same ones: kernel PRs keep them
+    with jax.named_scope("dense.centroids"):
+        d0 = dist_ops.pairwise_distance(
+            queries.astype(jnp.float32), centroids, DistCalcMethod(metric),
+            x_sqnorm=cent_sq)
+        _, topc = jax.lax.top_k(-d0, nprobe)                 # (Q, nprobe)
+    with jax.named_scope("dense.gather"):
+        ids = member_ids[topc].reshape(Q, nprobe * P)
+        sq = member_sq[topc].reshape(Q, nprobe * P)
+    with jax.named_scope("dense.probe"):
+        if use_pallas:
+            from sptag_tpu.ops import pallas_kernels
 
-        # int8 blocks contract int8 queries with exact int32 accumulation
-        # in-kernel; float blocks take float queries
-        q_in = queries if data_perm.dtype == jnp.dtype(jnp.int8) \
-            else queries.astype(jnp.float32)
-        dot = pallas_kernels.probe_block_dots(
-            data_perm, q_in, topc.astype(jnp.int32),
-            interpret=interpret).reshape(Q, nprobe * P).astype(jnp.float32)
-        if int(metric) == int(DistCalcMethod.Cosine):
-            nd = float(base) * float(base) - dot
+            # int8 blocks contract int8 queries with exact int32
+            # accumulation in-kernel; float blocks take float queries
+            q_in = queries if data_perm.dtype == jnp.dtype(jnp.int8) \
+                else queries.astype(jnp.float32)
+            dot = pallas_kernels.probe_block_dots(
+                data_perm, q_in, topc.astype(jnp.int32),
+                interpret=interpret
+            ).reshape(Q, nprobe * P).astype(jnp.float32)
+            if int(metric) == int(DistCalcMethod.Cosine):
+                nd = float(base) * float(base) - dot
+            else:
+                qf = queries.astype(jnp.float32)
+                qn = jnp.sum(qf * qf, axis=-1)[:, None]
+                nd = jnp.maximum(qn + sq - 2.0 * dot, 0.0)
         else:
-            qf = queries.astype(jnp.float32)
-            qn = jnp.sum(qf * qf, axis=-1)[:, None]
-            nd = jnp.maximum(qn + sq - 2.0 * dot, 0.0)
-    else:
-        vecs = data_perm[topc].reshape(Q, nprobe * P, D)
-        nd = dist_ops.batched_gathered_distance(
-            queries, vecs, DistCalcMethod(metric), base, sq)
-    return _finalize_topk(nd, ids, deleted, dedup, k,
-                          binned_bins=binned_bins)
+            vecs = data_perm[topc].reshape(Q, nprobe * P, D)
+            nd = dist_ops.batched_gathered_distance(
+                queries, vecs, DistCalcMethod(metric), base, sq)
+    return DeviceTopK(*_finalize_topk(nd, ids, deleted, dedup, k,
+                                      binned_bins=binned_bins))
 
 
 def _segmented_min(vals, first):
@@ -370,87 +382,91 @@ def _dense_search_grouped_kernel(data_perm, member_ids, member_sq, centroids,
     C, P, D = data_perm.shape
     NG = Q // G
     qf = queries.astype(jnp.float32)
-    d0 = dist_ops.pairwise_distance(qf, centroids, DistCalcMethod(metric),
-                                    x_sqnorm=cent_sq)            # (Q, C)
-    nd0, topc = jax.lax.top_k(-d0, nprobe)                   # (Q, nprobe)
-    valid = jnp.arange(Q, dtype=jnp.int32) < nq_valid        # (Q,)
+    with jax.named_scope("dense.centroids"):
+        d0 = dist_ops.pairwise_distance(
+            qf, centroids, DistCalcMethod(metric),
+            x_sqnorm=cent_sq)                                    # (Q, C)
+        nd0, topc = jax.lax.top_k(-d0, nprobe)                   # (Q, nprobe)
+        valid = jnp.arange(Q, dtype=jnp.int32) < nq_valid        # (Q,)
 
-    # sort queries by their best block id so groups share probed blocks;
-    # padding sorts to the back (key C) so it doesn't split real groups.
-    # The inverse permutation comes from a SCATTER of the forward one —
-    # the same trick as engine._sorted_dedup; the old back-to-back
-    # argsort+argsort paid a second full sort for what one O(Q) scatter
-    # computes
-    order = jnp.argsort(jnp.where(valid, topc[:, 0], C))
-    inv = jnp.zeros_like(order).at[order].set(
-        jnp.arange(order.shape[0], dtype=order.dtype))
-    qs = queries[order]
-    qsf = qf[order]
-    topc_s = topc[order].reshape(NG, G * nprobe)
-    # union-ranking score: probe RANK first, center distance as tie-break.
-    # Ranking by raw distance lets a tight query's far probes crowd out a
-    # loose query's top-1 block — every query's rank-r block must outrank
-    # ALL rank-r+1 blocks or per-query recall collapses for batch outliers.
-    # The tie-break is the distance's position within the query's own probe
-    # SPREAD (shift- and scale-invariant, in [0, 0.999]): raw distances can
-    # be uniformly huge (int cosine ~ base^2 - dot) or uniformly tiny, and
-    # any absolute squash would collapse to a constant and leave block-id
-    # ordering as the de-facto tie-break
-    dc = -nd0                                 # ascending per query (top_k)
-    rel = dc - dc[:, :1]
-    tie = rel / (rel[:, -1:] + 1e-20) * 0.999
-    comp = (jnp.arange(nprobe, dtype=jnp.float32)[None, :]
-            + tie)                                           # (Q, nprobe)
-    # padding queries' probes never evict a real query's blocks
-    comp = jnp.where(valid[:, None], comp, MAX_DIST)
-    topd_s = comp[order].reshape(NG, G * nprobe)
+        # sort queries by their best block id so groups share probed
+        # blocks; padding sorts to the back (key C) so it doesn't split
+        # real groups.  The inverse permutation comes from a SCATTER of
+        # the forward one — the same trick as engine._sorted_dedup; the
+        # old back-to-back argsort+argsort paid a second full sort for
+        # what one O(Q) scatter computes
+        order = jnp.argsort(jnp.where(valid, topc[:, 0], C))
+        inv = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype))
+        qs = queries[order]
+        qsf = qf[order]
+        topc_s = topc[order].reshape(NG, G * nprobe)
+        # union-ranking score: probe RANK first, center distance as tie-break.
+        # Ranking by raw distance lets a tight query's far probes crowd out a
+        # loose query's top-1 block — every query's rank-r block must outrank
+        # ALL rank-r+1 blocks or per-query recall collapses for batch outliers.
+        # The tie-break is the distance's position within the query's own probe
+        # SPREAD (shift- and scale-invariant, in [0, 0.999]): raw distances can
+        # be uniformly huge (int cosine ~ base^2 - dot) or uniformly tiny, and
+        # any absolute squash would collapse to a constant and leave block-id
+        # ordering as the de-facto tie-break
+        dc = -nd0                                 # ascending per query (top_k)
+        rel = dc - dc[:, :1]
+        tie = rel / (rel[:, -1:] + 1e-20) * 0.999
+        comp = (jnp.arange(nprobe, dtype=jnp.float32)[None, :]
+                + tie)                                           # (Q, nprobe)
+        # padding queries' probes never evict a real query's blocks
+        comp = jnp.where(valid[:, None], comp, MAX_DIST)
+        topd_s = comp[order].reshape(NG, G * nprobe)
 
-    # distinct union blocks per group, ranked by best (min) score:
-    # sort by block id, segmented-min over runs, keep each run's last
-    o2 = jnp.argsort(topc_s, axis=1)
-    bid = jnp.take_along_axis(topc_s, o2, axis=1)
-    bd = jnp.take_along_axis(topd_s, o2, axis=1)
-    first = jnp.concatenate(
-        [jnp.ones((NG, 1), bool), bid[:, 1:] != bid[:, :-1]], axis=1)
-    mn = _segmented_min(bd, first)
-    last = jnp.concatenate(
-        [bid[:, 1:] != bid[:, :-1], jnp.ones((NG, 1), bool)], axis=1)
-    rank_d = jnp.where(last, mn, MAX_DIST)
-    negu, upos = jax.lax.top_k(-rank_d, U)                   # (NG, U)
-    union = jnp.where(-negu < MAX_DIST,
-                      jnp.take_along_axis(bid, upos, axis=1), -1)
-    union_safe = jnp.maximum(union, 0).astype(jnp.int32)
+        # distinct union blocks per group, ranked by best (min) score:
+        # sort by block id, segmented-min over runs, keep each run's last
+        o2 = jnp.argsort(topc_s, axis=1)
+        bid = jnp.take_along_axis(topc_s, o2, axis=1)
+        bd = jnp.take_along_axis(topd_s, o2, axis=1)
+        first = jnp.concatenate(
+            [jnp.ones((NG, 1), bool), bid[:, 1:] != bid[:, :-1]], axis=1)
+        mn = _segmented_min(bd, first)
+        last = jnp.concatenate(
+            [bid[:, 1:] != bid[:, :-1], jnp.ones((NG, 1), bool)], axis=1)
+        rank_d = jnp.where(last, mn, MAX_DIST)
+        negu, upos = jax.lax.top_k(-rank_d, U)                   # (NG, U)
+        union = jnp.where(-negu < MAX_DIST,
+                          jnp.take_along_axis(bid, upos, axis=1), -1)
+        union_safe = jnp.maximum(union, 0).astype(jnp.int32)
 
-    ids_u = member_ids[union_safe]                           # (NG, U, P)
-    sq_u = member_sq[union_safe]                             # (NG, U, P)
-    if use_pallas:
-        q_in = qs if data_perm.dtype == jnp.dtype(jnp.int8) else qsf
-        dot = pallas_kernels.group_block_dots(
-            data_perm, q_in, union_safe,
-            interpret=interpret).astype(jnp.float32)         # (NG, U, G, P)
-        dot = dot.transpose(0, 2, 1, 3)                      # (NG, G, U, P)
-    else:
-        vecs = data_perm[union_safe]                         # (NG, U, P, D)
-        if dist_ops.exact_int_dot(queries.dtype):
-            # exact integer dot (reference int convention, DistanceUtils.h:
-            # 452): int32 accumulation, then float for the metric algebra.
-            # int16 falls through to the float32 branch — int32 overflows
-            # on raw int16 data (ops/distance.py pairwise_dot)
-            dot = jnp.einsum(
-                "gqd,gupd->gqup", qs.reshape(NG, G, D).astype(jnp.int32),
-                vecs.astype(jnp.int32),
-                preferred_element_type=jnp.int32).astype(jnp.float32)
+    with jax.named_scope("dense.gather"):
+        ids_u = member_ids[union_safe]                           # (NG, U, P)
+        sq_u = member_sq[union_safe]                             # (NG, U, P)
+    with jax.named_scope("dense.probe"):
+        if use_pallas:
+            q_in = qs if data_perm.dtype == jnp.dtype(jnp.int8) else qsf
+            dot = pallas_kernels.group_block_dots(
+                data_perm, q_in, union_safe,
+                interpret=interpret).astype(jnp.float32)     # (NG, U, G, P)
+            dot = dot.transpose(0, 2, 1, 3)                  # (NG, G, U, P)
         else:
-            dot = jnp.einsum(
-                "gqd,gupd->gqup", qsf.reshape(NG, G, D),
-                vecs.astype(jnp.float32),
-                precision=dist_ops.float_precision(),
-                preferred_element_type=jnp.float32)
-    if int(metric) == int(DistCalcMethod.Cosine):
-        nd = float(base) * float(base) - dot
-    else:
-        qn = jnp.sum(qsf * qsf, axis=-1).reshape(NG, G, 1, 1)
-        nd = jnp.maximum(qn + sq_u[:, None, :, :] - 2.0 * dot, 0.0)
+            vecs = data_perm[union_safe]                     # (NG, U, P, D)
+            if dist_ops.exact_int_dot(queries.dtype):
+                # exact integer dot (reference int convention, DistanceUtils.h:
+                # 452): int32 accumulation, then float for the metric algebra.
+                # int16 falls through to the float32 branch — int32 overflows
+                # on raw int16 data (ops/distance.py pairwise_dot)
+                dot = jnp.einsum(
+                    "gqd,gupd->gqup", qs.reshape(NG, G, D).astype(jnp.int32),
+                    vecs.astype(jnp.int32),
+                    preferred_element_type=jnp.int32).astype(jnp.float32)
+            else:
+                dot = jnp.einsum(
+                    "gqd,gupd->gqup", qsf.reshape(NG, G, D),
+                    vecs.astype(jnp.float32),
+                    precision=dist_ops.float_precision(),
+                    preferred_element_type=jnp.float32)
+        if int(metric) == int(DistCalcMethod.Cosine):
+            nd = float(base) * float(base) - dot
+        else:
+            qn = jnp.sum(qsf * qsf, axis=-1).reshape(NG, G, 1, 1)
+            nd = jnp.maximum(qn + sq_u[:, None, :, :] - 2.0 * dot, 0.0)
 
     ids = jnp.broadcast_to(ids_u[:, None, :, :],
                            (NG, G, U, P)).reshape(Q, U * P)
@@ -461,7 +477,7 @@ def _dense_search_grouped_kernel(data_perm, member_ids, member_sq, centroids,
                                     extra_dead=pad_blocks,
                                     binned_bins=binned_bins)
     # un-sort back to the caller's query order
-    return out_d[inv], out_ids[inv]
+    return DeviceTopK(out_d[inv], out_ids[inv])
 
 
 @functools.partial(jax.jit,
@@ -1017,6 +1033,15 @@ class DenseTreeSearcher:
         return self._search_impl(queries, nq, k, k_eff, nprobe, chunk, D,
                                  use_pallas, G, U, bins)
 
+    def _publish_scored(self, blocks: int) -> None:
+        """What one query of the search now running scores: the whole
+        centroid table and the rows of the `blocks` blocks it probes.
+        A roofline is computed from these, not from MaxCheck taken on
+        trust (benchmark kernel.dense_scan_roofline).  Gauges of the most
+        recent search: totals would mix in the build's own searches."""
+        metrics.set_gauge("dense.centroids_per_query", self.num_clusters)
+        metrics.set_gauge("dense.rows_per_query", blocks * self.cluster_size)
+
     def _search_impl(self, queries, nq, k, k_eff, nprobe, chunk, D,
                      use_pallas, G=0, U=0, bins=0):
         out_d = np.full((nq, k), np.float32(MAX_DIST), np.float32)
@@ -1030,6 +1055,7 @@ class DenseTreeSearcher:
                 g_eff = 0                         # tile floor (see search)
             if g_eff != G:
                 self.last_effective_group = g_eff
+            self._publish_scored(U if g_eff > 1 else nprobe)
             q = queries
             if q_pad != nq:
                 q = np.concatenate(
@@ -1048,13 +1074,17 @@ class DenseTreeSearcher:
                     jnp.asarray(q), k_eff, nprobe, int(self.metric),
                     self.base, use_pallas=use_pallas, interpret=interp,
                     dedup=dedup, binned_bins=bins)
-            out_d[:, :d.shape[1]] = np.asarray(d)[:nq]
-            out_i[:, :ids.shape[1]] = np.asarray(ids)[:nq]
+            with trace.span("index.readback"):
+                # the host blocks here until the program has run
+                d, ids = np.asarray(d), np.asarray(ids)
+            out_d[:, :d.shape[1]] = d[:nq]
+            out_i[:, :ids.shape[1]] = ids[:nq]
             return out_d, out_i
         # multi-chunk: ONE device program (lax.map over chunks) — a Python
         # chunk loop would pay a synced host round trip per chunk; this
         # costs ~2 round trips total for any batch size
         m = -(-nq // chunk)
+        self._publish_scored(U if G > 1 else nprobe)
         q = queries
         if m * chunk != nq:
             q = np.concatenate(
@@ -1079,8 +1109,10 @@ class DenseTreeSearcher:
                 k_eff, nprobe, int(self.metric), self.base,
                 use_pallas=use_pallas, interpret=interp, dedup=dedup,
                 binned_bins=bins)
-        d = np.asarray(d).reshape(m * chunk, -1)
-        ids = np.asarray(ids).reshape(m * chunk, -1)
+        with trace.span("index.readback"):
+            d, ids = np.asarray(d), np.asarray(ids)
+        d = d.reshape(m * chunk, -1)
+        ids = ids.reshape(m * chunk, -1)
         out_d[:, :d.shape[1]] = d[:nq]
         out_i[:, :ids.shape[1]] = ids[:nq]
         return out_d, out_i

@@ -43,7 +43,7 @@ from sptag_tpu.core.types import (
 from sptag_tpu.core.vectorset import MetadataSet, VectorSet, metas_for
 from sptag_tpu.io import atomic, wal
 from sptag_tpu.ops import distance as dist_ops
-from sptag_tpu.utils import faultinject, locksan, metrics
+from sptag_tpu.utils import faultinject, locksan, metrics, trace
 from sptag_tpu.utils.ini import IniReader
 
 log = logging.getLogger(__name__)
@@ -410,13 +410,17 @@ class VectorIndex(abc.ABC):
         if queries.shape[1] != self.feature_dim:
             raise ValueError(
                 f"query dim {queries.shape[1]} != index dim {self.feature_dim}")
-        queries = self._prepare_query(queries)
-        # delta/main union (ISSUE 9): the main tier covers its frozen
-        # snapshot; fresh rows ride the FLAT-scanned delta shard and the
-        # two top-k lists merge here — one flag test when no delta
-        return self._merge_delta(
-            queries, k, self._search_batch(queries, k, max_check,
-                                           search_mode))
+        # the seam every synchronous search of FLAT, BKT and KDT crosses:
+        # query preparation, padding, dispatch, device wait, readback
+        # (`index.readback` inside it) and the delta merge
+        with trace.span("index.search"):
+            queries = self._prepare_query(queries)
+            # delta/main union (ISSUE 9): the main tier covers its frozen
+            # snapshot; fresh rows ride the FLAT-scanned delta shard and
+            # the two top-k lists merge here — one flag test when no delta
+            return self._merge_delta(
+                queries, k, self._search_batch(queries, k, max_check,
+                                               search_mode))
 
     def submit_batch(self, queries: np.ndarray, k: int = 10,
                      max_check: Optional[int] = None,

@@ -12,6 +12,7 @@ are persisted in `indexloader.ini` and parsed back by
 from __future__ import annotations
 
 import enum
+from typing import Any, NamedTuple
 
 import numpy as np
 
@@ -153,3 +154,16 @@ def convert_string_to(text: str, py_type):
     if py_type is str:
         return text
     raise TypeError(f"unsupported conversion target {py_type}")
+
+
+class DeviceTopK(NamedTuple):
+    """What the jitted search programs return: (Q, k) distances and row
+    ids, unpacked as a pair.  The field names reach the program's
+    StableHLO (`jax.result_info`) and change no operation.  They are also
+    what gave the programs a compile-cache key of their own when their
+    stages were named (`jax.named_scope`, PR 25): jax keys its persistent
+    cache on the program with locations stripped, so scope names alone are
+    served whatever executable the cache already holds, under its names."""
+
+    dists: Any
+    ids: Any

@@ -32,7 +32,8 @@ from sptag_tpu.core.index import MAX_DIST
 from sptag_tpu.core.types import DistCalcMethod
 from sptag_tpu.ops import distance as dist_ops
 from sptag_tpu.ops import topk_bins
-from sptag_tpu.utils import costmodel, devmem, locksan, metrics, round_up
+from sptag_tpu.utils import (costmodel, devmem, locksan, metrics, round_up,
+                             trace)
 
 SHARD_AXIS = "shard"
 
@@ -479,7 +480,10 @@ class ServingAdapter:
         mode = self._resolve_mode(search_mode, max_check, impl=impl)
         sub = getattr(impl, "submit_batch", None)
         if self._mesh_serve and mode == "beam" and sub is not None:
-            return sub(queries, k, max_check=max_check, rids=rids)
+            # the hand-over to the mesh scheduler; the walk is waited for
+            # by the caller
+            with trace.span("index.search"):
+                return sub(queries, k, max_check=max_check, rids=rids)
         return resolved_futures(
             lambda: self.search_batch(queries, k, max_check=max_check,
                                       search_mode=search_mode),
@@ -502,11 +506,13 @@ class ServingAdapter:
         a query that the configured mode could serve."""
         impl = self._impl                      # epoch pin (swap_impl)
         mode = self._resolve_mode(search_mode, max_check, impl=impl)
-        if mode == "dense":
-            return impl.search_dense(np.asarray(queries), k=k,
-                                     max_check=max_check)
-        return impl.search(np.asarray(queries), k=k,
-                           max_check=max_check)
+        # the adapter is no VectorIndex: its own seam, the same name
+        with trace.span("index.search"):
+            if mode == "dense":
+                return impl.search_dense(np.asarray(queries), k=k,
+                                         max_check=max_check)
+            return impl.search(np.asarray(queries), k=k,
+                               max_check=max_check)
 
     def _resolve_mode(self, search_mode: Optional[str],
                       max_check: Optional[int], impl=None) -> str:

@@ -35,6 +35,26 @@ from sptag_tpu.utils import (faultinject, flightrec, hostprof, locksan,
 log = logging.getLogger(__name__)
 
 
+def _count_batch(size: int) -> None:
+    """One count per gathered batch, under the rung of the shared
+    query-count padding ladder (utils.QUERY_BUCKETS) its size falls on:
+    which compiled program the batch runs and, as rates, how batch sizes
+    are distributed.  (FLAT's own ladder pads 129..512 queries to 512.)
+    Literal names, one call each: the registry never expires a name."""
+    if size <= 1:
+        metrics.inc("server.batches_q1")
+    elif size <= 8:
+        metrics.inc("server.batches_q8")
+    elif size <= 32:
+        metrics.inc("server.batches_q32")
+    elif size <= 128:
+        metrics.inc("server.batches_q128")
+    elif size <= 256:
+        metrics.inc("server.batches_q256")
+    else:
+        metrics.inc("server.batches_q1024")
+
+
 #: body-size ceiling, shared with every framing reader (see wire.py)
 MAX_BODY_LENGTH = wire.MAX_BODY_LENGTH
 
@@ -547,103 +567,10 @@ class SearchServer:
                                      header.resource_id)
             await self._send(cid, resp.pack())
         elif t == wire.PacketType.SearchRequest:
-            metrics.inc("server.requests")
-            rec = flightrec.enabled()
-            degraded = False
-            if self.admission is not None:
-                # canary isolation (ISSUE 15): admission runs pre-decode
-                # keyed by connection, so canary connections are marked
-                # at their first probe's decode (below) and exempted
-                # from fair-share accounting from then on
-                decision = self.admission.admit(
-                    str(cid), canary=cid in self._canary_cids)
-                if decision == admission_mod.SHED:
-                    # reject at the socket edge with a DISTINCT status
-                    # BEFORE decode cost is paid — under overload, body
-                    # decode is the attack surface (the body bytes were
-                    # already read to keep the stream aligned, but never
-                    # parsed)
-                    metrics.inc("server.admission_sheds")
-                    if rec:
-                        flightrec.record(self.flight_tier, "shed")
-                    shed = wire.RemoteSearchResult(
-                        wire.ResultStatus.Overloaded, []).pack()
-                    resp = wire.PacketHeader(
-                        wire.PacketType.SearchResponse,
-                        wire.PacketProcessStatus.Dropped, len(shed),
-                        cid, header.resource_id)
-                    await self._send(cid, resp.pack() + shed)
-                    return
-                degraded = decision == admission_mod.DEGRADE
-            t_dec0 = time.monotonic_ns() if rec else 0
-            hp = hostprof.armed()
-            if hp:
-                # serve-stage pin (utils/hostprof.py, ISSUE 10): samples
-                # landing on the loop thread during decode fold under
-                # stage:decode (the rid is unknown until unpack returns)
-                hostprof.set_stage("decode")
-            with trace.span("server.decode"):
-                query = wire.RemoteQuery.unpack(body)
-            if query is None:
-                # a SearchRequest whose body does not decode still gets a
-                # FailedExecute answer downstream, but must be countable
-                metrics.inc("server.malformed_packets")
-            elif not query.request_id:
-                # text-protocol id channel (reference clients can't set
-                # the wire field); stays empty if neither is present
-                query.request_id = protocol.request_id_of(query.query) or ""
-            else:
-                # the wire field is attacker-sized (up to the body cap);
-                # it rides into every log line and response — bound it
-                # like the text channel does
-                query.request_id = query.request_id[:64]
-            if query is not None and query.request_id \
-                    and canary_mod.is_canary_rid(query.request_id) \
-                    and cid not in self._canary_cids:
-                self._canary_cids.add(cid)
-            if rec:
-                flightrec.record(
-                    self.flight_tier, "decode",
-                    query.request_id if query is not None else "",
-                    dur_ns=time.monotonic_ns() - t_dec0)
-            if hp:
-                hostprof.clear_stage()
-            # deadline resolution (ISSUE 8): the wire trailer wins, the
-            # $deadlinems text option covers reference clients, then the
-            # operator's [Service] DeadlineMs default.  The value is a
-            # RELATIVE budget anchored at THIS arrival (clocks across
-            # machines are not assumed synchronized).
-            deadline_mono = None
-            if query is not None:
-                dl = query.deadline_ms \
-                    or (protocol.deadline_of(query.query) or 0.0)
-                if dl <= 0:
-                    dl = self.deadline_ms
-                if dl > 0:
-                    deadline_mono = time.perf_counter() + dl / 1000.0
-            try:
-                self._queue.put_nowait((cid, header, query,
-                                        time.perf_counter(),
-                                        deadline_mono, degraded))
-                metrics.set_gauge("server.queue_depth", self._queue.qsize())
-                if rec:
-                    flightrec.record(
-                        self.flight_tier, "enqueue",
-                        query.request_id if query is not None else "",
-                        payload={"depth": self._queue.qsize()})
-            except asyncio.QueueFull:
-                # shed load at the edge rather than buffering unboundedly;
-                # the client sees a definitive, well-formed FailedExecute
-                # for THIS request (a body-less Dropped header would break
-                # result unpacking on the other side)
-                metrics.inc("server.queue_full")
-                shed = wire.RemoteSearchResult(
-                    wire.ResultStatus.FailedExecute, [],
-                    query.request_id if query is not None else "").pack()
-                resp = wire.PacketHeader(wire.PacketType.SearchResponse,
-                                         wire.PacketProcessStatus.Dropped,
-                                         len(shed), cid, header.resource_id)
-                await self._send(cid, resp.pack() + shed)
+            with trace.annotate("server.dispatch"):
+                shed = self._admit_and_enqueue(cid, header, body)
+            if shed is not None:
+                await self._send(cid, shed)
         elif wire.is_request(t):
             # HandleNoHandlerResponse (Connection.cpp:374-398)
             resp = wire.PacketHeader(wire.response_type(t),
@@ -651,11 +578,116 @@ class SearchServer:
                                      cid, header.resource_id)
             await self._send(cid, resp.pack())
 
+    def _admit_and_enqueue(self, cid: int, header: wire.PacketHeader,
+                           body: bytes) -> Optional[bytes]:
+        """The synchronous part of a SearchRequest: admission, decode,
+        deadline, enqueue.  Returns the response to send at once where
+        the request is shed (admission, or a full queue), else None."""
+        metrics.inc("server.requests")
+        rec = flightrec.enabled()
+        degraded = False
+        if self.admission is not None:
+            # canary isolation (ISSUE 15): admission runs pre-decode
+            # keyed by connection, so canary connections are marked
+            # at their first probe's decode (below) and exempted
+            # from fair-share accounting from then on
+            decision = self.admission.admit(
+                str(cid), canary=cid in self._canary_cids)
+            if decision == admission_mod.SHED:
+                # reject at the socket edge with a DISTINCT status
+                # BEFORE decode cost is paid — under overload, body
+                # decode is the attack surface (the body bytes were
+                # already read to keep the stream aligned, but never
+                # parsed)
+                metrics.inc("server.admission_sheds")
+                if rec:
+                    flightrec.record(self.flight_tier, "shed")
+                shed = wire.RemoteSearchResult(
+                    wire.ResultStatus.Overloaded, []).pack()
+                resp = wire.PacketHeader(
+                    wire.PacketType.SearchResponse,
+                    wire.PacketProcessStatus.Dropped, len(shed),
+                    cid, header.resource_id)
+                return resp.pack() + shed
+            degraded = decision == admission_mod.DEGRADE
+        t_dec0 = time.monotonic_ns() if rec else 0
+        hp = hostprof.armed()
+        if hp:
+            # serve-stage pin (utils/hostprof.py, ISSUE 10): samples
+            # landing on the loop thread during decode fold under
+            # stage:decode (the rid is unknown until unpack returns)
+            hostprof.set_stage("decode")
+        with trace.span("server.decode"):
+            query = wire.RemoteQuery.unpack(body)
+        if query is None:
+            # a SearchRequest whose body does not decode still gets a
+            # FailedExecute answer downstream, but must be countable
+            metrics.inc("server.malformed_packets")
+        elif not query.request_id:
+            # text-protocol id channel (reference clients can't set
+            # the wire field); stays empty if neither is present
+            query.request_id = protocol.request_id_of(query.query) or ""
+        else:
+            # the wire field is attacker-sized (up to the body cap);
+            # it rides into every log line and response — bound it
+            # like the text channel does
+            query.request_id = query.request_id[:64]
+        if query is not None and query.request_id \
+                and canary_mod.is_canary_rid(query.request_id) \
+                and cid not in self._canary_cids:
+            self._canary_cids.add(cid)
+        if rec:
+            flightrec.record(
+                self.flight_tier, "decode",
+                query.request_id if query is not None else "",
+                dur_ns=time.monotonic_ns() - t_dec0)
+        if hp:
+            hostprof.clear_stage()
+        # deadline resolution (ISSUE 8): the wire trailer wins, the
+        # $deadlinems text option covers reference clients, then the
+        # operator's [Service] DeadlineMs default.  The value is a
+        # RELATIVE budget anchored at THIS arrival (clocks across
+        # machines are not assumed synchronized).
+        deadline_mono = None
+        if query is not None:
+            dl = query.deadline_ms \
+                or (protocol.deadline_of(query.query) or 0.0)
+            if dl <= 0:
+                dl = self.deadline_ms
+            if dl > 0:
+                deadline_mono = time.perf_counter() + dl / 1000.0
+        try:
+            self._queue.put_nowait((cid, header, query,
+                                    time.perf_counter(),
+                                    deadline_mono, degraded))
+            metrics.set_gauge("server.queue_depth", self._queue.qsize())
+            if rec:
+                flightrec.record(
+                    self.flight_tier, "enqueue",
+                    query.request_id if query is not None else "",
+                    payload={"depth": self._queue.qsize()})
+        except asyncio.QueueFull:
+            # shed load at the edge rather than buffering unboundedly;
+            # the client sees a definitive, well-formed FailedExecute
+            # for THIS request (a body-less Dropped header would break
+            # result unpacking on the other side)
+            metrics.inc("server.queue_full")
+            shed = wire.RemoteSearchResult(
+                wire.ResultStatus.FailedExecute, [],
+                query.request_id if query is not None else "").pack()
+            resp = wire.PacketHeader(wire.PacketType.SearchResponse,
+                                     wire.PacketProcessStatus.Dropped,
+                                     len(shed), cid, header.resource_id)
+            return resp.pack() + shed
+        return None
+
     # --------------------------------------------------------- batched serve
 
     async def _batcher(self) -> None:
+        t_prev = None                # the previous batch's t_assembled
         while True:
             first = await self._queue.get()
+            t_first = time.perf_counter()
             batch = [first]
             deadline = asyncio.get_event_loop().time() + self.batch_window
             while len(batch) < self.max_batch:
@@ -667,12 +699,20 @@ class SearchServer:
                         self._queue.get(), timeout))
                 except asyncio.TimeoutError:
                     break
-            await self._serve_batch(batch)
+            t_prev = await self._serve_batch(batch, t_first, t_prev)
 
-    async def _serve_batch(self, batch) -> None:
+    async def _serve_batch(self, batch, t_first: float,
+                           t_prev: Optional[float]) -> float:
+        """Execute one gathered batch and hand its responses off; returns
+        the instant the batch was assembled (the next one's `t_prev`)."""
         t_assembled = time.perf_counter()
+        # the batcher's cycle, from timestamps on either side of awaits
+        # (an annotation here would name waiting as if it were work)
+        trace.record("server.batch_gather", t_assembled - t_first)
+        if t_prev is not None:
+            trace.record("server.batch_cycle", t_assembled - t_prev)
         metrics.set_gauge("server.queue_depth", self._queue.qsize())
-        metrics.set_gauge("server.last_batch_size", len(batch))
+        _count_batch(len(batch))
         rec = flightrec.enabled()
         # deadline enforcement at the execute boundary (ISSUE 8): a
         # query whose budget ran out while queued gets a Timeout answer
@@ -694,7 +734,7 @@ class SearchServer:
             await self._spawn_response_task(
                 self._respond_expired(expired, t_assembled))
             if not batch:
-                return
+                return t_assembled
         texts = []
         rids = []
         for cid, header, query, t_enq, _deadline, _deg in batch:
@@ -723,6 +763,7 @@ class SearchServer:
         deg_floor = (self.admission.config.degrade_max_check_floor
                      if self.admission is not None and any(deg_flags)
                      else None)
+        t_returned = None
         try:
             def run_batch():
                 if hostprof.armed():
@@ -736,19 +777,25 @@ class SearchServer:
                         "execute", live[0] if len(live) == 1 else "")
                 try:
                     with trace.span("server.execute_batch"):
-                        return self.executor.execute_batch(
+                        out = self.executor.execute_batch(
                             texts, on_ready=on_ready, rids=rids,
                             degraded=deg_flags if deg_floor else None,
                             degrade_floor=deg_floor)
                 finally:
                     hostprof.clear_stage()
-            results = await loop.run_in_executor(None, run_batch)
+                return out, time.perf_counter()
+            results, t_returned = await loop.run_in_executor(None,
+                                                             run_batch)
         except Exception:
             metrics.inc("server.batch_failures")
             log.exception("batch execution failed")
             results = [wire.RemoteSearchResult(
                 wire.ResultStatus.FailedExecute, [])] * len(batch)
         t_executed = time.perf_counter()
+        if t_returned is not None:
+            # how long the finished batch waited for the event loop: its
+            # wake-up queues behind the on_ready callbacks it posted
+            trace.record("server.batch_resume", t_executed - t_returned)
         if rec:
             flightrec.record(
                 self.flight_tier, "execute",
@@ -764,6 +811,7 @@ class SearchServer:
         await self._spawn_response_task(
             self._respond_batch(batch, results, streamed, t_assembled,
                                 t_executed))
+        return t_assembled
 
     def _stream_response(self, entry, result, t_assembled: float,
                          streamed: set, i: int) -> None:
@@ -771,15 +819,16 @@ class SearchServer:
         delivered and send its response in its own (tracked) task.
         NOT marking it (over the task cap) is always safe — the batch
         tail sends whatever was not streamed."""
-        if len(self._response_tasks) >= self._max_stream_tasks:
-            metrics.inc("server.stream_overflows")
-            return
-        streamed.add(i)
-        metrics.inc("server.streamed_responses")
-        task = asyncio.ensure_future(
-            self._respond_one(entry, result, t_assembled,
-                              time.perf_counter()))
-        self._track_response_task(task)
+        with trace.annotate("server.stream_response"):
+            if len(self._response_tasks) >= self._max_stream_tasks:
+                metrics.inc("server.stream_overflows")
+                return
+            streamed.add(i)
+            metrics.inc("server.streamed_responses")
+            task = asyncio.ensure_future(
+                self._respond_one(entry, result, t_assembled,
+                                  time.perf_counter()))
+            self._track_response_task(task)
 
     async def _spawn_response_task(self, coro) -> None:
         await self._response_sem.acquire()

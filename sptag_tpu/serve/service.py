@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import dataclasses
 import logging
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -33,7 +33,7 @@ from sptag_tpu.serve.wire import (
     RemoteSearchResult,
     ResultStatus,
 )
-from sptag_tpu.utils import metrics
+from sptag_tpu.utils import metrics, trace
 from sptag_tpu.utils.ini import IniReader
 
 log = logging.getLogger(__name__)
@@ -860,23 +860,12 @@ class SearchExecutor:
         import concurrent.futures as cf
 
         index = self.context.indexes[name]
-        vecs = []
-        ok: List[int] = []
-        for i in idxs:
-            v = parsed[i].extract_vector(
-                parsed[i].data_type or index.value_type,
-                self.context.settings.vector_separator)
-            if v is None or v.shape[-1] != index.feature_dim:
-                results[i] = RemoteSearchResult(
-                    ResultStatus.FailedExecute, [])
-            else:
-                vecs.append(v)
-                ok.append(i)
+        queries, ok = self._parse_vectors(parsed, results, index, idxs)
         if not ok:
             return
         try:
             futs = index.submit_batch(
-                np.stack(vecs), k, max_check=max_check,
+                queries, k, max_check=max_check,
                 search_mode=self._sanitize_search_mode(parsed[ok[0]],
                                                        index),
                 rids=[rids[i] if rids else "" for i in ok])
@@ -888,27 +877,53 @@ class SearchExecutor:
                     ResultStatus.FailedExecute, [])
             return
         by_fut = {f: i for f, i in zip(futs, ok)}
-        for f in cf.as_completed(futs):
-            i = by_fut[f]
-            e = f.exception()
-            if e is not None:
-                metrics.inc("service.search_errors")
-                log.error("streamed search failed on index %s: %r",
-                          name, e)
-                results[i] = RemoteSearchResult(
-                    ResultStatus.FailedExecute, [])
-                continue
-            dists, ids = f.result()
-            metas = (metas_for(index.metadata, ids) if with_meta else None)
-            r = RemoteSearchResult(ResultStatus.Success, [IndexSearchResult(
-                name, [int(v) for v in ids], [float(d) for d in dists],
-                metas)])
-            results[i] = r
-            metrics.inc("service.streamed_results")
-            try:
-                on_ready(i, r)
-            except Exception:                            # noqa: BLE001
-                log.exception("on_ready callback failed")
+        # with a scheduler-backed index this span also holds the wait for
+        # each query to retire; with resolved futures it is result
+        # building and the on_ready hand-offs alone
+        with trace.span("service.results"):
+            for f in cf.as_completed(futs):
+                i = by_fut[f]
+                e = f.exception()
+                if e is not None:
+                    metrics.inc("service.search_errors")
+                    log.error("streamed search failed on index %s: %r",
+                              name, e)
+                    results[i] = RemoteSearchResult(
+                        ResultStatus.FailedExecute, [])
+                    continue
+                dists, ids = f.result()
+                metas = (metas_for(index.metadata, ids)
+                         if with_meta else None)
+                r = RemoteSearchResult(
+                    ResultStatus.Success, [IndexSearchResult(
+                        name, [int(v) for v in ids],
+                        [float(d) for d in dists], metas)])
+                results[i] = r
+                metrics.inc("service.streamed_results")
+                try:
+                    on_ready(i, r)
+                except Exception:                        # noqa: BLE001
+                    log.exception("on_ready callback failed")
+
+    def _parse_vectors(self, parsed, results, index, idxs: List[int]
+                       ) -> Tuple[Optional[np.ndarray], List[int]]:
+        """The group's query texts -> one (Q, D) array and the batch
+        positions it holds; a query whose vector does not parse or has
+        the wrong width is answered FailedExecute here."""
+        vecs = []
+        ok: List[int] = []
+        with trace.span("service.parse"):
+            for i in idxs:
+                v = parsed[i].extract_vector(
+                    parsed[i].data_type or index.value_type,
+                    self.context.settings.vector_separator)
+                if v is None or v.shape[-1] != index.feature_dim:
+                    results[i] = RemoteSearchResult(
+                        ResultStatus.FailedExecute, [])
+                else:
+                    vecs.append(v)
+                    ok.append(i)
+            return (np.stack(vecs) if ok else None), ok
 
     def _degrade_max_check(self, mc: Optional[int],
                            sel: tuple, floor: int) -> int:
@@ -951,7 +966,8 @@ class SearchExecutor:
         their MaxCheck clamped toward the floor and oversized k toward
         the service default before grouping, so an overloaded server
         spends a bounded amount of device time per admitted query."""
-        parsed = [parse_query(t) for t in query_texts]
+        with trace.span("service.parse"):
+            parsed = [parse_query(t) for t in query_texts]
         results: List[Optional[RemoteSearchResult]] = [None] * len(parsed)
         groups: Dict[tuple, List[int]] = {}
         for i, p in enumerate(parsed):
@@ -988,23 +1004,13 @@ class SearchExecutor:
                 continue
             for name in sel:
                 index = self.context.indexes[name]
-                vecs = []
-                ok: List[int] = []
-                for i in idxs:
-                    v = parsed[i].extract_vector(
-                        parsed[i].data_type or index.value_type,
-                        self.context.settings.vector_separator)
-                    if v is None or v.shape[-1] != index.feature_dim:
-                        results[i] = RemoteSearchResult(
-                            ResultStatus.FailedExecute, [])
-                    else:
-                        vecs.append(v)
-                        ok.append(i)
+                queries, ok = self._parse_vectors(parsed, results, index,
+                                                  idxs)
                 if not ok:
                     continue
                 try:
                     dists, ids = index.search_batch(
-                        np.stack(vecs), k, max_check=max_check,
+                        queries, k, max_check=max_check,
                         search_mode=self._sanitize_search_mode(
                             parsed[ok[0]], index))
                 except Exception:
@@ -1014,15 +1020,16 @@ class SearchExecutor:
                         results[i] = RemoteSearchResult(
                             ResultStatus.FailedExecute, [])
                     continue
-                for row, i in enumerate(ok):
-                    metas = (metas_for(index.metadata, ids[row])
-                             if with_meta else None)
-                    if results[i] is None:
-                        results[i] = RemoteSearchResult(
-                            ResultStatus.Success, [])
-                    results[i].results.append(IndexSearchResult(
-                        name, [int(v) for v in ids[row]],
-                        [float(d) for d in dists[row]], metas))
+                with trace.span("service.results"):
+                    for row, i in enumerate(ok):
+                        metas = (metas_for(index.metadata, ids[row])
+                                 if with_meta else None)
+                        if results[i] is None:
+                            results[i] = RemoteSearchResult(
+                                ResultStatus.Success, [])
+                        results[i].results.append(IndexSearchResult(
+                            name, [int(v) for v in ids[row]],
+                            [float(d) for d in dists[row]], metas))
         return [r if r is not None
                 else RemoteSearchResult(ResultStatus.FailedExecute, [])
                 for r in results]

@@ -16,8 +16,12 @@ framework.  Two cooperating layers:
   when a jax profiler trace is active, so host spans line up with device
   timelines in TensorBoard/Perfetto; `start_trace(logdir)` / `stop_trace()`
   wrap `jax.profiler` for callers that should not import jax eagerly.
+  `annotate("name")` is the annotation alone, for per-request sites where
+  a registry record thousands of times a second would cost the event loop
+  the time being measured.
 
-Used by bench.py and the server batch path.
+Spans are per BATCH on the served path (serve/server.py, serve/service.py,
+core/index.py, algo/flat.py, algo/dense.py); docs/TELEMETRY.md lists them.
 """
 
 from __future__ import annotations
@@ -31,7 +35,11 @@ from sptag_tpu.utils import metrics
 
 _lock = threading.Lock()
 _spans: Dict[str, list] = {}      # name -> [count, total_s, max_s]
+# True while a profiler trace is live: span() and annotate() then emit
+# TraceAnnotations.  start_trace/stop_trace set it; a caller that starts
+# jax.profiler itself (benchmark/run.py) sets it by hand.
 _trace_active = False
+_NO_ANNOTATION = contextlib.nullcontext()
 
 
 @contextlib.contextmanager
@@ -50,6 +58,16 @@ def span(name: str) -> Iterator[None]:
         if ann is not None:
             ann.__exit__(None, None, None)
         record(name, dt)
+
+
+def annotate(name: str):
+    """A `TraceAnnotation` when a profiler trace is live, else one shared
+    no-op context manager: nothing is recorded in the registry or the
+    histograms, so the cost with no trace live is this call."""
+    if _trace_active:
+        import jax.profiler
+        return jax.profiler.TraceAnnotation(name)
+    return _NO_ANNOTATION
 
 
 def record(name: str, seconds: float) -> None:
@@ -93,12 +111,16 @@ def reset() -> None:
         _spans.clear()
 
 
-def start_trace(logdir: str) -> None:
+def start_trace(logdir: str, python_tracer: bool = False) -> None:
     """Begin a jax profiler trace (XLA device timeline + host annotations).
-    View with TensorBoard's profile plugin or Perfetto."""
+    View with TensorBoard's profile plugin or Perfetto.  The python tracer
+    is off unless asked for: it slows the server's event loop, which a
+    trace of a live server is there to see."""
     global _trace_active
     import jax.profiler
-    jax.profiler.start_trace(logdir)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 1 if python_tracer else 0
+    jax.profiler.start_trace(logdir, profiler_options=options)
     _trace_active = True
 
 
