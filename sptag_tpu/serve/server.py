@@ -750,7 +750,9 @@ class SearchServer:
         # on_ready from ITS thread as individual queries finish; each
         # marshals onto the loop and sends immediately — a fast query's
         # response leaves while stragglers are still walking, instead of
-        # at whole-batch granularity.  Every on_ready lands on the loop
+        # at whole-batch granularity.  A group whose futures were all
+        # resolved at submit makes no on_ready call: its answers leave in
+        # _respond_batch's joined writes.  Every on_ready lands on the loop
         # BEFORE run_in_executor's completion wakes this coroutine
         # (call_soon_threadsafe is FIFO), so `streamed` is complete when
         # the batch tail below reads it.
@@ -794,7 +796,7 @@ class SearchServer:
         t_executed = time.perf_counter()
         if t_returned is not None:
             # how long the finished batch waited for the event loop: its
-            # wake-up queues behind the on_ready callbacks it posted
+            # wake-up queues behind any on_ready callbacks it posted
             trace.record("server.batch_resume", t_executed - t_returned)
         if rec:
             flightrec.record(
@@ -852,10 +854,35 @@ class SearchServer:
 
     async def _respond_batch(self, batch, results, streamed: set,
                              t_assembled: float, t_executed: float) -> None:
-        for i, (entry, result) in enumerate(zip(batch, results)):
-            if i in streamed:
-                continue           # already sent by the streaming path
-            await self._respond_one(entry, result, t_assembled, t_executed)
+        """Send what the streaming path did not: answers that arrived as
+        a batch leave as one — every reply encoded, then ONE locked
+        write + drain per connection of that connection's packets joined
+        in batch order (the client matches by resource id).  Each packet
+        is `_respond_one`'s, byte for byte, and every per-request duty
+        (`_after_response`) stays per request."""
+        todo = [(entry, result)
+                for i, (entry, result) in enumerate(zip(batch, results))
+                if i not in streamed]
+        if self._fault.enabled:
+            # injected faults delay, garble or cut SINGLE responses
+            for entry, result in todo:
+                await self._respond_one(entry, result, t_assembled,
+                                        t_executed)
+            return
+        by_cid: Dict[int, list] = {}
+        with trace.span("server.encode"):
+            for entry, result in todo:
+                result, payload = self._encode_response(entry, result)
+                by_cid.setdefault(entry[0], []).append(
+                    (entry, result, payload))
+        for cid, sent in by_cid.items():
+            t_send0 = time.perf_counter()
+            with trace.span("server.drain"):
+                await self._send(cid, b"".join(p for _e, _r, p in sent))
+            now = time.perf_counter()
+            for entry, result, _payload in sent:
+                self._after_response(entry, result, t_assembled,
+                                     t_executed, t_send0, now)
 
     async def _respond_expired(self, entries, t_assembled: float) -> None:
         """Answer deadline-expired queries with Timeout — cheap, honest,
@@ -896,9 +923,11 @@ class SearchServer:
             return None
         return None                                       # "drop"
 
-    async def _respond_one(self, entry, result, t_assembled: float,
-                           t_executed: float) -> None:
-        cid, header, query, t_enq, _deadline, degraded = entry
+    def _encode_response(self, entry, result) -> tuple:
+        """One response's packet: -> (the result as answered, header +
+        body bytes).  Echoes the request id, marks a degraded Success;
+        synchronous, so a caller's `server.encode` span covers it."""
+        cid, header, query, _t_enq, _deadline, degraded = entry
         if query is None or result is None:
             result = wire.RemoteSearchResult(
                 wire.ResultStatus.FailedExecute, [])
@@ -921,8 +950,7 @@ class SearchServer:
             # per-query encode runs whole on the loop thread between
             # awaits, so the rid pin is exact here
             hostprof.set_stage("encode", rid)
-        with trace.span("server.encode"):
-            body = result.pack()
+        body = result.pack()
         if hp:
             hostprof.clear_stage()
         if rec:
@@ -932,7 +960,13 @@ class SearchServer:
             wire.PacketType.SearchResponse,
             wire.PacketProcessStatus.Ok, len(body), cid,
             header.resource_id)
-        payload = resp.pack() + body
+        return result, resp.pack() + body
+
+    async def _respond_one(self, entry, result, t_assembled: float,
+                           t_executed: float) -> None:
+        cid = entry[0]
+        with trace.span("server.encode"):
+            result, payload = self._encode_response(entry, result)
         if self._fault.enabled:
             fault = self._fault.decide("server.respond")
             if fault is not None:
@@ -942,8 +976,20 @@ class SearchServer:
         t_send0 = time.perf_counter()
         with trace.span("server.drain"):
             await self._send(cid, payload)
+        self._after_response(entry, result, t_assembled, t_executed,
+                             t_send0, time.perf_counter())
+
+    def _after_response(self, entry, result, t_assembled: float,
+                        t_executed: float, t_send0: float,
+                        now: float) -> None:
+        """What every answered request is owed once its bytes are with
+        the socket (its own write, or its connection's joined one):
+        the response count, the `server.request` record, flight records,
+        the slow-query log, the quality sample."""
+        _cid, _header, query, t_enq, _deadline, _degraded = entry
+        rid = query.request_id if query is not None else ""
+        rec = flightrec.enabled()
         metrics.inc("server.responses")
-        now = time.perf_counter()
         total = now - t_enq
         trace.record("server.request", total)
         if rec:

@@ -849,14 +849,22 @@ class SearchExecutor:
                              idxs: List[int], on_ready,
                              rids: Optional[List[str]] = None) -> None:
         """Single-index group via per-query futures (VectorIndex
-        .submit_batch): each query's result is built and handed to
-        `on_ready(i, result)` AS ITS FUTURE RESOLVES — with a continuous-
-        batching index that is per-query retire order from the slot
-        scheduler, so the caller streams responses while stragglers are
-        still walking.  Indexes without a scheduler resolve everything at
-        once (base submit_batch) and on_ready degrades to batch
-        granularity.  `on_ready` runs on THIS thread; failures are not
-        streamed (they ride the returned results list)."""
+        .submit_batch).  The granularity at which the group's answers
+        leave follows what `submit_batch` returns:
+
+        * every future already resolved (an index without a slot
+          scheduler ran the whole block at once): the answers arrived as
+          a batch and leave as one — built in bulk, `on_ready` called for
+          none, the caller sends the returned list (`service.batched_
+          results` counts them);
+        * any future pending (ContinuousBatching=1, MeshServe): each
+          query's result is built and handed to `on_ready(i, result)` AS
+          ITS FUTURE RESOLVES — per-query retire order from the slot
+          scheduler, so the caller streams responses while stragglers
+          are still walking (`service.streamed_results`).
+
+        `on_ready` runs on THIS thread; failures are not streamed (they
+        ride the returned results list)."""
         import concurrent.futures as cf
 
         index = self.context.indexes[name]
@@ -876,10 +884,28 @@ class SearchExecutor:
                 results[i] = RemoteSearchResult(
                     ResultStatus.FailedExecute, [])
             return
+        if all(f.done() for f in futs):
+            with trace.span("service.results"):
+                good: List[int] = []
+                d_rows, i_rows = [], []
+                for f, i in zip(futs, ok):
+                    e = f.exception()
+                    if e is not None:
+                        metrics.inc("service.search_errors")
+                        log.error("batched search failed on index %s: %r",
+                                  name, e)
+                        results[i] = RemoteSearchResult(
+                            ResultStatus.FailedExecute, [])
+                    else:
+                        dists, ids = f.result()
+                        good.append(i)
+                        d_rows.append(dists)
+                        i_rows.append(ids)
+                self._append_rows(results, name, index, good, d_rows,
+                                  i_rows, with_meta)
+            return
         by_fut = {f: i for f, i in zip(futs, ok)}
-        # with a scheduler-backed index this span also holds the wait for
-        # each query to retire; with resolved futures it is result
-        # building and the on_ready hand-offs alone
+        # the span also holds the wait for each query to retire
         with trace.span("service.results"):
             for f in cf.as_completed(futs):
                 i = by_fut[f]
@@ -904,6 +930,27 @@ class SearchExecutor:
                     on_ready(i, r)
                 except Exception:                        # noqa: BLE001
                     log.exception("on_ready callback failed")
+
+    @staticmethod
+    def _append_rows(results, name: str, index, ok: List[int], dists, ids,
+                     with_meta: bool) -> None:
+        """A whole group's answers from one index, in bulk: row `r` of
+        `dists` / `ids` (a (Q, k) block or a list of (k,) rows) becomes
+        an IndexSearchResult on `results[ok[r]]`.  `.tolist()` yields the
+        Python ints and floats `int()` / `float()` would, element for
+        element, so the packed bytes are the per-element loop's.
+        `service.batched_results` counts each query once, at the first
+        index that answers it."""
+        fresh = 0
+        for row, i in enumerate(ok):
+            metas = (metas_for(index.metadata, ids[row])
+                     if with_meta else None)
+            if results[i] is None:
+                results[i] = RemoteSearchResult(ResultStatus.Success, [])
+                fresh += 1
+            results[i].results.append(IndexSearchResult(
+                name, ids[row].tolist(), dists[row].tolist(), metas))
+        metrics.inc("service.batched_results", fresh)
 
     def _parse_vectors(self, parsed, results, index, idxs: List[int]
                        ) -> Tuple[Optional[np.ndarray], List[int]]:
@@ -995,8 +1042,8 @@ class SearchExecutor:
                                 "submit_batch")):
                 # every serving surface exposes submit_batch — indexes
                 # without a scheduler (and mesh adapters with MeshServe
-                # off) return pre-resolved futures, so streaming
-                # degrades to batch granularity with identical bytes
+                # off) return pre-resolved futures, and the group is
+                # then answered in bulk with no on_ready call
                 self._run_group_streaming(parsed, results, sel[0], k,
                                           with_meta, max_check,
                                           search_mode, idxs, on_ready,
@@ -1021,15 +1068,8 @@ class SearchExecutor:
                             ResultStatus.FailedExecute, [])
                     continue
                 with trace.span("service.results"):
-                    for row, i in enumerate(ok):
-                        metas = (metas_for(index.metadata, ids[row])
-                                 if with_meta else None)
-                        if results[i] is None:
-                            results[i] = RemoteSearchResult(
-                                ResultStatus.Success, [])
-                        results[i].results.append(IndexSearchResult(
-                            name, [int(v) for v in ids[row]],
-                            [float(d) for d in dists[row]], metas))
+                    self._append_rows(results, name, index, ok, dists, ids,
+                                      with_meta)
         return [r if r is not None
                 else RemoteSearchResult(ResultStatus.FailedExecute, [])
                 for r in results]

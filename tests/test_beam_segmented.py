@@ -240,34 +240,64 @@ def test_scheduler_stop_fails_pending(bkt_setup):
 
 # ---- serve-tier streaming -------------------------------------------------
 
-def test_execute_batch_on_ready_streams_per_query():
-    """SearchExecutor.execute_batch(on_ready=...) delivers every
-    successful single-index result through the callback, identical to the
-    returned list — the surface server._serve_batch streams from."""
+@pytest.mark.parametrize("backing", ["resolved", "scheduler"])
+def test_execute_batch_on_ready_streams_per_query(bkt_setup, backing):
+    """SearchExecutor.execute_batch(on_ready=...) answers a single-index
+    group at the granularity its futures resolve at.  `resolved` (FLAT:
+    submit_batch returns finished futures): nothing is streamed, the
+    returned list is complete and equal to the plain call's.  `scheduler`
+    (BKT beam, ContinuousBatching=1: more queries than slots, so futures
+    are pending when submit_batch returns): every successful result goes
+    through the callback exactly once, identical to the returned list —
+    the surface server._serve_batch streams from."""
     from sptag_tpu.serve.service import SearchExecutor, ServiceContext
+    from sptag_tpu.utils import metrics
 
-    rng = np.random.default_rng(3)
-    data = rng.standard_normal((64, 8)).astype(np.float32)
-    flat = sp.create_instance("FLAT", "Float")
-    flat.set_parameter("DistCalcMethod", "L2")
-    assert flat.build(data) == sp.ErrorCode.Success
+    if backing == "resolved":
+        rng = np.random.default_rng(3)
+        data = rng.standard_normal((64, 8)).astype(np.float32)
+        index = sp.create_instance("FLAT", "Float")
+        index.set_parameter("DistCalcMethod", "L2")
+        assert index.build(data) == sp.ErrorCode.Success
+        rows = 5
+    else:
+        index, data, _ = bkt_setup
+        for name, value in [("ContinuousBatching", "1"), ("BeamSlots", "8"),
+                            ("BeamSegmentIters", "2")]:
+            assert index.set_parameter(name, value)
+        rows = 20
     ctx = ServiceContext()
-    ctx.add_index("t", flat)
+    ctx.add_index("t", index)
     ex = SearchExecutor(ctx)
-    texts = ["|".join(str(x) for x in data[i][:8]) for i in range(5)]
+    texts = ["|".join(str(x) for x in data[i]) for i in range(rows)]
     texts.append("1|2")                       # dim mismatch -> failure row
-    plain = ex.execute_batch(texts)
     got = {}
 
     def on_ready(i, result):
         assert i not in got, "double delivery"
         got[i] = result
-    streamed = ex.execute_batch(texts, on_ready=on_ready)
-    assert sorted(got) == [0, 1, 2, 3, 4]     # failures are not streamed
-    for i, r in got.items():
-        assert streamed[i] is r
-        assert r.results[0].ids == plain[i].results[0].ids
-    assert streamed[5].status == plain[5].status   # failure still returned
+    try:
+        plain = ex.execute_batch(texts)
+        metrics.reset()
+        streamed = ex.execute_batch(texts, on_ready=on_ready)
+    finally:
+        if backing == "scheduler":
+            index.set_parameter("ContinuousBatching", "0")
+    if backing == "resolved":
+        assert got == {}
+        assert metrics.counter_value("service.batched_results") == rows
+        assert metrics.counter_value("service.streamed_results") == 0
+    else:
+        assert sorted(got) == list(range(rows))   # failures: not streamed
+        assert metrics.counter_value("service.streamed_results") == rows
+        assert metrics.counter_value("service.batched_results") == 0
+        for i, r in got.items():
+            assert streamed[i] is r
+    for i in range(rows):
+        assert streamed[i].status == plain[i].status
+        assert streamed[i].results[0].ids == plain[i].results[0].ids
+        assert streamed[i].results[0].ids[0] == i
+    assert streamed[rows].status == plain[rows].status   # failure returned
 
 
 def test_kdt_scheduler_parity():

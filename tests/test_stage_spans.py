@@ -89,8 +89,8 @@ def served():
             for b in range(BATCHES):
                 rows = list(range(b * N, (b + 1) * N))
                 answers = _burst(sock, data, rows)
-                # streamed: in the order the futures complete
-                assert sorted(a.results[0].ids[0] for a in answers) == rows
+                # resolved futures: one joined write, in batch order
+                assert [a.results[0].ids[0] for a in answers] == rows
     finally:
         thread.stop()
     return {"spans": trace.report(),
