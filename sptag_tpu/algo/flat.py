@@ -51,27 +51,28 @@ def _query_bucket(q: int) -> int:
     return round_up(q, _QUERY_BUCKETS[-1])
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("k", "metric", "base", "approx",
-                                    "recall_target", "binned_bins"))
-def _flat_search_kernel(data, sqnorm, invalid, queries, k: int,
-                        metric: int, base: int, approx: bool = False,
-                        recall_target: float = 0.99,
-                        binned_bins: int = 0):
-    """One fused program: distance matrix -> mask -> top-k.
+def pad_to_bucket(queries: np.ndarray) -> np.ndarray:
+    """`queries` with zero rows appended up to its rung of the query
+    ladder: every served batch size runs one of a few compiled programs
+    (the caller slices the answers back to its own row count)."""
+    q = queries.shape[0]
+    q_pad = _query_bucket(q)
+    if q_pad == q:
+        return queries
+    return np.concatenate(
+        [queries, np.zeros((q_pad - q, queries.shape[1]), queries.dtype)],
+        axis=0)
 
-    `approx=True` selects `lax.approx_max_k` — the TPU's hardware-
-    accelerated partial-reduction top-k (the peak-FLOP/s KNN recipe of
-    arXiv:2206.14286, PAPERS.md): the (Q, N) selection stops being the
-    bottleneck of the exact scan at large N.  Per-op `recall_target`
-    (the ApproxRecallTarget parameter — previously a hard-coded 0.99);
-    the handful of true neighbors it may miss are beyond the exactness
-    contract the `ApproxTopK` parameter explicitly trades away.
 
-    `binned_bins` > 0 selects the portable bin-reduction top-k instead
-    (ops/topk_bins.py, BinnedTopK): same coarse-select shape, but it
-    accelerates every backend — `approx_max_k` lowers to a full sort
-    off-TPU.  When both are set, binned wins (it subsumes the recipe)."""
+def scan_topk(data, sqnorm, invalid, queries, k: int, metric: int,
+              base: int, approx: bool = False, recall_target: float = 0.99,
+              binned_bins: int = 0):
+    """Distance matrix -> mask -> top-k of one resident block of rows:
+    THE scan body, traced into the one-chip program
+    (`_flat_search_kernel`) and, per shard, into the mesh program
+    (parallel/sharded.py `_sharded_search_kernel`), so a distance or
+    top-k change reaches both.  -> ((Q, k) float32 distances, (Q, k)
+    int32 row ids of THIS block; -1 where only masked rows were left)."""
     # the scope names are what a profiler trace calls the two stages
     # (benchmark kernel.topk_ms_per_batch reads `flat.topk`): kernel PRs
     # keep them
@@ -93,7 +94,32 @@ def _flat_search_kernel(data, sqnorm, invalid, queries, k: int,
             dists = -neg
         ids = jnp.where(dists >= jnp.float32(MAX_DIST), -1,
                         idx).astype(jnp.int32)
-    return DeviceTopK(dists, ids)
+    return dists, ids
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("k", "metric", "base", "approx",
+                                    "recall_target", "binned_bins"))
+def _flat_search_kernel(data, sqnorm, invalid, queries, k: int,
+                        metric: int, base: int, approx: bool = False,
+                        recall_target: float = 0.99,
+                        binned_bins: int = 0):
+    """One fused program: distance matrix -> mask -> top-k (`scan_topk`).
+
+    `approx=True` selects `lax.approx_max_k` — the TPU's hardware-
+    accelerated partial-reduction top-k (the peak-FLOP/s KNN recipe of
+    arXiv:2206.14286, PAPERS.md): the (Q, N) selection stops being the
+    bottleneck of the exact scan at large N.  Per-op `recall_target`
+    (the ApproxRecallTarget parameter — previously a hard-coded 0.99);
+    the handful of true neighbors it may miss are beyond the exactness
+    contract the `ApproxTopK` parameter explicitly trades away.
+
+    `binned_bins` > 0 selects the portable bin-reduction top-k instead
+    (ops/topk_bins.py, BinnedTopK): same coarse-select shape, but it
+    accelerates every backend — `approx_max_k` lowers to a full sort
+    off-TPU.  When both are set, binned wins (it subsumes the recipe)."""
+    return DeviceTopK(*scan_topk(data, sqnorm, invalid, queries, k, metric,
+                                 base, approx, recall_target, binned_bins))
 
 
 def exact_device_scan(data_d, sqnorm_d, invalid_d, queries: np.ndarray,
@@ -108,15 +134,10 @@ def exact_device_scan(data_d, sqnorm_d, invalid_d, queries: np.ndarray,
     supposed to measure would be no oracle at all.  Rides the
     registered `flat.scan` cost-ledger family (no new jit site)."""
     q = queries.shape[0]
-    q_pad = _query_bucket(q)
-    if q_pad != q:
-        queries = np.concatenate(
-            [queries, np.zeros((q_pad - q, queries.shape[1]),
-                               queries.dtype)], axis=0)
     k_eff = min(k, data_d.shape[0])
     dists, ids = _flat_search_kernel(
-        data_d, sqnorm_d, invalid_d, jnp.asarray(queries), k_eff,
-        metric, base, approx=False)
+        data_d, sqnorm_d, invalid_d, jnp.asarray(pad_to_bucket(queries)),
+        k_eff, metric, base, approx=False)
     return np.asarray(dists)[:q], np.asarray(ids)[:q]
 
 
@@ -517,11 +538,7 @@ class FlatIndex(VectorIndex):
             raise RuntimeError("index is empty")
         del max_check, search_mode      # exact scan: no budget, no modes
         q = queries.shape[0]
-        q_pad = _query_bucket(q)
-        if q_pad != q:
-            queries = np.concatenate(
-                [queries, np.zeros((q_pad - q, queries.shape[1]),
-                                   queries.dtype)], axis=0)
+        queries = pad_to_bucket(queries)
         if self._cascade_active():
             # tiered cascade (ops/cascade.py, ISSUE 14): sketch Hamming
             # scan -> int8 re-rank -> fp exact re-rank, per-tier
@@ -655,14 +672,8 @@ class FlatIndex(VectorIndex):
             st = self._cascade_state()
             if st.fp_host is not None:
                 q = queries.shape[0]
-                q_pad = _query_bucket(q)
-                if q_pad != q:
-                    queries = np.concatenate(
-                        [queries,
-                         np.zeros((q_pad - q, queries.shape[1]),
-                                  queries.dtype)], axis=0)
                 d, ids = cascade.host_exact_scan(
-                    st.fp_host, st.invalid_host, queries,
+                    st.fp_host, st.invalid_host, pad_to_bucket(queries),
                     min(k, st.n_pad), int(self.dist_calc_method),
                     self.base)
                 return d[:q], ids[:q]

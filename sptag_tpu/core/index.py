@@ -1274,15 +1274,18 @@ def load_index(folder: str, lazy_metadata: bool = False) -> VectorIndex:
 
     Mesh folders (ISSUE 11): a folder carrying a ``sharded.json``
     manifest is a persisted mesh index (one reference-format sub-folder
-    per shard, ShardedBKTIndex.build(save_to=...)); it loads as a
+    per shard; the builder CLI with ``Index.MeshShardAxis=N`` or
+    ShardedBKTIndex.build(save_to=...) writes one).  The manifest's
+    ``algo`` names the shards' family (FLAT -> `ShardedFlatIndex`,
+    BKT / KDT or absent -> `ShardedBKTIndex`); either loads as a
     `ServingAdapter` over the reassembled mesh placement, so a
     ``[Index_<name>] IndexFolder=<mesh folder>`` ini line deploys
     in-mesh serving through the same config surface as any index."""
     if os.path.exists(os.path.join(folder, "sharded.json")):
         from sptag_tpu.parallel.sharded import ServingAdapter, \
-            ShardedBKTIndex
+            load_mesh_index
 
-        sharded = ShardedBKTIndex.load(folder)
+        sharded = load_mesh_index(folder)
         return ServingAdapter(
             sharded, feature_dim=int(sharded.data.shape[1]))
     _recover_interrupted_save(folder)

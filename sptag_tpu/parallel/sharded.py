@@ -19,8 +19,9 @@ nothing in this module changes.
 from __future__ import annotations
 
 import functools
+import json
 import os
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -28,6 +29,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from sptag_tpu.algo.flat import pad_to_bucket, scan_topk
 from sptag_tpu.core.index import MAX_DIST
 from sptag_tpu.core.types import DistCalcMethod
 from sptag_tpu.ops import distance as dist_ops
@@ -66,34 +68,38 @@ def _gather_merge(d, gids, k_final: int):
     return gd, jnp.where(gd >= jnp.float32(MAX_DIST), -1, gi)
 
 
+class MeshTopK(NamedTuple):
+    """What the mesh FLAT program returns: (Q, k) distances and GLOBAL row
+    ids, replicated.  Field names of its own, as `DeviceTopK`'s are the
+    one-chip programs': they reach the StableHLO and so the persistent
+    compile cache's key, which the scope names alone do not
+    (core/types.py `DeviceTopK`)."""
+
+    merged_dists: jax.Array
+    global_ids: jax.Array
+
+
 @functools.partial(jax.jit,
                    static_argnames=("k_local", "k_final", "metric", "base",
                                     "mesh"))
 def _sharded_search_kernel(data, sqnorm, invalid, queries, k_local: int,
                            k_final: int, metric: int, base: int, mesh: Mesh):
-    """One program: per-shard distances + local top-k_local, ICI all-gather
-    of the (dist, global-id) candidates, global top-k_final re-rank."""
+    """One program: per shard the one-chip scan body (`algo/flat.py
+    scan_topk`: distances, mask, local top-k_local), then under scope
+    `mesh.merge` the ICI all-gather of the (dist, global-id) candidates
+    and the global top-k_final re-rank."""
 
     def local_search(data_s, sqnorm_s, invalid_s, q_s):
-        n_local = data_s.shape[0]
-        shard = jax.lax.axis_index(SHARD_AXIS)
-        if metric == int(DistCalcMethod.L2):
-            d = dist_ops.pairwise_l2(q_s, data_s, sqnorm_s)
-        else:
-            d = dist_ops.pairwise_cosine(q_s, data_s, base)
-        d = jnp.where(invalid_s[None, :], jnp.float32(MAX_DIST), d)
-        neg, idx = jax.lax.top_k(-d, k_local)               # (Q, kl) local
-        gids = idx.astype(jnp.int32) + shard * n_local      # global ids
-        # Fan-in over ICI: every shard contributes its k_local candidates.
-        all_d = jax.lax.all_gather(-neg, SHARD_AXIS, axis=1, tiled=True)
-        all_i = jax.lax.all_gather(gids, SHARD_AXIS, axis=1, tiled=True)
-        gneg, gpos = jax.lax.top_k(-all_d, k_final)         # (Q, kf) global
-        gd = -gneg
-        gi = jnp.take_along_axis(all_i, gpos, axis=1)
-        gi = jnp.where(gd >= jnp.float32(MAX_DIST), -1, gi)
-        return gd, gi
+        d, ids = scan_topk(data_s, sqnorm_s, invalid_s, q_s, k_local,
+                           metric, base)
+        # the merge is what a trace calls the mesh's own stage (benchmark
+        # kernel.mesh_merge_ms_per_batch reads `mesh.merge`)
+        with jax.named_scope("mesh.merge"):
+            shard = jax.lax.axis_index(SHARD_AXIS)
+            gids = jnp.where(ids >= 0, ids + shard * data_s.shape[0], -1)
+            return _gather_merge(d, gids, k_final)
 
-    return shard_map(
+    return MeshTopK(*shard_map(
         local_search,
         mesh=mesh,
         in_specs=(P(SHARD_AXIS, None), P(SHARD_AXIS), P(SHARD_AXIS),
@@ -102,7 +108,93 @@ def _sharded_search_kernel(data, sqnorm, invalid, queries, k_local: int,
         # outputs are replicated by construction (all_gather + identical
         # top_k on every shard); the static VMA check can't see that
         check_vma=False,
-    )(data, sqnorm, invalid, queries)
+    )(data, sqnorm, invalid, queries))
+
+
+MANIFEST = "sharded.json"
+
+
+def read_manifest(folder: str) -> dict:
+    with open(os.path.join(folder, MANIFEST)) as f:
+        return json.load(f)
+
+
+def write_manifest(folder: str, manifest: dict, metadata=None) -> None:
+    """Commit a mesh folder whose shard sub-folders are already saved:
+    the frontend metadata (global-id keyed, reference metadata.bin /
+    metadataIndex.bin format, top level), then the manifest.
+
+    The manifest is written atomically and LAST: the per-shard saves are
+    crash-safe (staged swap in save_index) and the manifest is the commit
+    point, so everything it vouches for must already be durable.  A
+    rebuild without metadata removes stale files so a load can't serve
+    the previous corpus's payloads."""
+    manifest_path = os.path.join(folder, MANIFEST)
+    staged = f".tmp.{os.getpid()}"
+    with open(manifest_path + staged, "w") as f:
+        json.dump(manifest, f)
+    mpath = os.path.join(folder, "metadata.bin")
+    ipath = os.path.join(folder, "metadataIndex.bin")
+    if metadata is not None:
+        metadata.save(mpath + staged, ipath + staged)
+        os.replace(mpath + staged, mpath)
+        os.replace(ipath + staged, ipath)
+    else:
+        for p in (mpath, ipath):
+            try:
+                os.remove(p)
+            except OSError:
+                pass
+    os.replace(manifest_path + staged, manifest_path)
+
+
+def _folder_metadata(folder: str):
+    """The frontend metadata a mesh folder carries at its top level, lazy
+    and file-backed (a LAION-class blob is not pulled resident), or
+    None."""
+    mpath = os.path.join(folder, "metadata.bin")
+    ipath = os.path.join(folder, "metadataIndex.bin")
+    if os.path.exists(mpath) and os.path.exists(ipath):
+        from sptag_tpu.core.vectorset import FileMetadataSet
+        return FileMetadataSet(mpath, ipath)
+    return None
+
+
+def _mesh_for(n_shards: int, mesh: Optional[Mesh]) -> Mesh:
+    """The mesh a saved folder of `n_shards` loads onto.  The default is
+    sized from the manifest: a 2-shard save loads onto the first 2 local
+    devices of an 8-device host.  An EXPLICIT mesh must match exactly —
+    placement is the caller's statement of intent."""
+    if mesh is None:
+        devs = jax.devices()
+        if len(devs) < n_shards:
+            raise ValueError(
+                f"saved index has {n_shards} shards but the "
+                f"host exposes only {len(devs)} devices")
+        mesh = make_mesh(devs[:n_shards])
+    if mesh.devices.size != n_shards:
+        raise ValueError(
+            f"mesh has {mesh.devices.size} devices but the saved index "
+            f"has {n_shards} shards")
+    return mesh
+
+
+def load_mesh_index(folder: str, mesh: Optional[Mesh] = None):
+    """A persisted mesh folder -> the sharded index of the family its
+    manifest names (`algo`; absent = BKT, what every folder saved before
+    the key existed holds)."""
+    algo = str(read_manifest(folder).get("algo", "BKT")).upper()
+    cls = ShardedFlatIndex if algo == "FLAT" else ShardedBKTIndex
+    return cls.load(folder, mesh=mesh)
+
+
+def _publish_placement(n_shards: int, rows_per_shard: int) -> None:
+    """Gauges of the most recent mesh placement: how many devices hold a
+    share of the corpus, and how many row slots (padding included) each
+    holds — what the benchmark's sharded-scan roofline counts bytes
+    from."""
+    metrics.set_gauge("mesh.shards", n_shards)
+    metrics.set_gauge("mesh.rows_per_shard", rows_per_shard)
 
 
 class ShardedFlatIndex:
@@ -120,14 +212,17 @@ class ShardedFlatIndex:
         self.metric = DistCalcMethod(metric)
         self.base = base
         self.n = data.shape[0]
+        self.metadata = None
         n_dev = self.mesh.devices.size
 
         if self.metric == DistCalcMethod.Cosine and not normalized:
             data = dist_ops.normalize(data, base)
 
-        n_pad = round_up(max(self.n, n_dev), n_dev * 8)
-        padded = np.zeros((n_pad, data.shape[1]), data.dtype)
-        padded[:self.n] = data
+        n_pad = self.rows_per_shard(self.n, n_dev) * n_dev
+        padded = data
+        if n_pad != self.n:
+            padded = np.zeros((n_pad, data.shape[1]), data.dtype)
+            padded[:self.n] = data
         invalid = np.ones(n_pad, dtype=bool)
         invalid[:self.n] = (deleted[:self.n] if deleted is not None
                             else np.zeros(self.n, bool))
@@ -148,6 +243,76 @@ class ShardedFlatIndex:
         devmem.track("shard_blocks", self,
                      self.data.nbytes + self.sqnorm.nbytes
                      + self.invalid.nbytes)
+        _publish_placement(n_dev, n_pad // n_dev)
+
+    @staticmethod
+    def rows_per_shard(n: int, n_shards: int) -> int:
+        """Row slots each of `n_shards` contiguous equal blocks holds
+        for an `n`-row corpus (the last block's tail is padding)."""
+        return round_up(max(n, n_shards), n_shards * 8) // n_shards
+
+    @classmethod
+    def save_shards(cls, data: np.ndarray, folder: str, n_shards: int,
+                    value_type, params=(), metadata=None) -> None:
+        """Persist `data` as a mesh FLAT folder without touching a device:
+        `n_shards` contiguous blocks of `rows_per_shard` rows (the last
+        one shorter — its padding exists only on the device), each a
+        reference-format FLAT folder `shard_NNN` exactly as a reference
+        Server persists its partition, plus the manifest.  `params` are
+        (name, value) pairs set on every shard index (DistCalcMethod
+        among them), so they persist in each shard's indexloader.ini."""
+        from sptag_tpu.core.index import create_instance
+        from sptag_tpu.core.types import ErrorCode
+
+        n, n_local = data.shape[0], cls.rows_per_shard(data.shape[0],
+                                                       n_shards)
+        if n <= (n_shards - 1) * n_local:
+            raise ValueError(
+                f"corpus ({n} rows) leaves one of {n_shards} shards empty")
+        os.makedirs(folder, exist_ok=True)
+        for s in range(n_shards):
+            sub = create_instance("FLAT", value_type)
+            for name, value in params:
+                sub.set_parameter(name, str(value))
+            code = sub.build(data[s * n_local:(s + 1) * n_local])
+            if code == ErrorCode.Success:
+                code = sub.save_index(
+                    os.path.join(folder, f"shard_{s:03d}"))
+            if code != ErrorCode.Success:
+                raise RuntimeError(f"shard {s}: {code}")
+        write_manifest(folder, {
+            "n_shards": n_shards, "n": n, "dim": int(data.shape[1]),
+            "metric": int(sub.dist_calc_method),
+            "value_type": int(sub.value_type), "algo": "FLAT"}, metadata)
+
+    @classmethod
+    def load(cls, folder: str,
+             mesh: Optional[Mesh] = None) -> "ShardedFlatIndex":
+        """Load a folder `save_shards` wrote: the shard folders are read
+        in order, their rows and tombstones laid end to end (cosine rows
+        were normalized at ingest) and placed over the mesh."""
+        from sptag_tpu.core.index import load_index
+
+        meta = read_manifest(folder)
+        mesh = _mesh_for(meta["n_shards"], mesh)
+        n_local = cls.rows_per_shard(meta["n"], meta["n_shards"])
+        subs = [load_index(os.path.join(folder, f"shard_{s:03d}"))
+                for s in range(meta["n_shards"])]
+        rows = [sub.num_samples for sub in subs]
+        want = [min(n_local, meta["n"] - s * n_local)
+                for s in range(meta["n_shards"])]
+        if rows != want:
+            raise ValueError(
+                f"shards of {folder} hold {rows} rows, the manifest's "
+                f"partition of {meta['n']} rows gives them {want}")
+        self = cls(np.concatenate([sub._host[:r]
+                                   for sub, r in zip(subs, rows)]),
+                   DistCalcMethod(meta["metric"]), subs[0].base, mesh=mesh,
+                   deleted=np.concatenate([sub._deleted[:r]
+                                           for sub, r in zip(subs, rows)]),
+                   normalized=True)
+        self.metadata = _folder_metadata(folder)
+        return self
 
     def search(self, queries: np.ndarray,
                k: int = 10, normalized: bool = False,
@@ -157,8 +322,13 @@ class ShardedFlatIndex:
         # the flat mesh index serves behind ServingAdapter, whose wire
         # surface forwards the $maxcheck option to every index type
         del max_check
+        queries = np.asarray(queries)
         if self.metric == DistCalcMethod.Cosine and not normalized:
-            queries = dist_ops.normalize(np.asarray(queries), self.base)
+            queries = dist_ops.normalize(queries, self.base)
+        # the one-chip scan's ladder: a served window forms batches of
+        # every size, and each size would be a program of its own
+        q = queries.shape[0]
+        queries = pad_to_bucket(queries)
         n_dev = self.mesh.devices.size
         n_local = self.data.shape[0] // n_dev
         k_local = min(k, n_local)
@@ -166,7 +336,11 @@ class ShardedFlatIndex:
         dists, ids = _sharded_search_kernel(
             self.data, self.sqnorm, self.invalid, jnp.asarray(queries),
             k_local, k_final, int(self.metric), self.base, self.mesh)
-        return _pad_to_k(np.asarray(dists), np.asarray(ids), k, k_final)
+        with trace.span("index.readback"):
+            # the host blocks here until the program has run
+            dists = np.asarray(dists)[:q]
+            ids = np.asarray(ids)[:q]
+        return _pad_to_k(dists, ids, k, k_final)
 
 
 # --------------------------------------------------------------------------
@@ -761,40 +935,18 @@ class ShardedBKTIndex:
         one reference-format sub-index folder per shard (`shard_000`,
         `shard_001`, ...), exactly how each reference Server persists its
         own partition.  The mesh size must match the shard count."""
-        import json
-
         from sptag_tpu.core.index import load_index
 
-        with open(os.path.join(folder, "sharded.json")) as f:
-            meta = json.load(f)
-        if mesh is None:
-            # size the default mesh from the manifest: a 2-shard save
-            # loads onto the first 2 local devices of an 8-device host
-            # (an EXPLICIT mesh must still match exactly — placement is
-            # the caller's statement of intent)
-            devs = jax.devices()
-            if len(devs) < meta["n_shards"]:
-                raise ValueError(
-                    f"saved index has {meta['n_shards']} shards but the "
-                    f"host exposes only {len(devs)} devices")
-            mesh = make_mesh(devs[:meta["n_shards"]])
-        if mesh.devices.size != meta["n_shards"]:
-            raise ValueError(
-                f"mesh has {mesh.devices.size} devices but the saved index "
-                f"has {meta['n_shards']} shards")
+        meta = read_manifest(folder)
+        mesh = _mesh_for(meta["n_shards"], mesh)
         subs = [load_index(os.path.join(folder, f"shard_{s:03d}"))
                 for s in range(meta["n_shards"])]
         self = cls._assemble(subs, meta["n"], meta["dim"],
                              DistCalcMethod(meta["metric"]), mesh,
                              meta.get("empty_shards", []), dense)
         # frontend metadata (global-id keyed), persisted at the mesh-folder
-        # top level by build(..., metadata=...); lazy file-backed so a
-        # LAION-class blob is not pulled resident
-        mpath = os.path.join(folder, "metadata.bin")
-        ipath = os.path.join(folder, "metadataIndex.bin")
-        if os.path.exists(mpath) and os.path.exists(ipath):
-            from sptag_tpu.core.vectorset import FileMetadataSet
-            self.metadata = FileMetadataSet(mpath, ipath)
+        # top level by build(..., metadata=...)
+        self.metadata = _folder_metadata(folder)
         return self
 
     def save(self, folder: str) -> None:
@@ -899,40 +1051,13 @@ class ShardedBKTIndex:
                 ck.clear()
                 sub.last_checkpoint = None
         if save_to is not None:
-            import json
-
             os.makedirs(save_to, exist_ok=True)
             for s, sub in enumerate(shard_indexes):
                 sub.save_index(os.path.join(save_to, f"shard_{s:03d}"))
-            # atomic manifest write: the per-shard saves are crash-safe
-            # (staged swap in save_index) — a torn manifest must not be
-            # the one thing that makes a good checkpoint unloadable
-            manifest = os.path.join(save_to, "sharded.json")
-            tmp = manifest + f".tmp.{os.getpid()}"
-            with open(tmp, "w") as f:
-                json.dump({"n_shards": n_dev, "n": n,
-                           "dim": int(data.shape[1]),
-                           "metric": int(metric),
-                           "empty_shards": empty_shards}, f)
-            # metadata is staged (tmp + rename) BEFORE the manifest
-            # replace — the manifest is the commit point, so everything it
-            # vouches for must already be durable; a rebuild without
-            # metadata removes stale files so load() can't serve the
-            # previous corpus's payloads
-            mpath = os.path.join(save_to, "metadata.bin")
-            ipath = os.path.join(save_to, "metadataIndex.bin")
-            if metadata is not None:
-                metadata.save(mpath + f".tmp.{os.getpid()}",
-                              ipath + f".tmp.{os.getpid()}")
-                os.replace(mpath + f".tmp.{os.getpid()}", mpath)
-                os.replace(ipath + f".tmp.{os.getpid()}", ipath)
-            else:
-                for p in (mpath, ipath):
-                    try:
-                        os.remove(p)
-                    except OSError:
-                        pass
-            os.replace(tmp, manifest)
+            write_manifest(save_to, {
+                "n_shards": n_dev, "n": n, "dim": int(data.shape[1]),
+                "metric": int(metric), "empty_shards": empty_shards,
+                "algo": str(algo).upper()}, metadata)
         self = cls._assemble(shard_indexes, n, int(data.shape[1]), metric,
                              mesh, empty_shards, dense)
         self.metadata = metadata
@@ -1102,7 +1227,9 @@ class ShardedBKTIndex:
             binned_bins=topk_bins.resolve_bins(
                 self._binned_mode(), k_local,
                 nprobe * self.dense_cluster_size, self._recall_target()))
-        return _pad_to_k(np.asarray(d), np.asarray(ids), k, k_final)
+        with trace.span("index.readback"):
+            d, ids = np.asarray(d), np.asarray(ids)
+        return _pad_to_k(d, ids, k, k_final)
 
     def _place(self, data, graph, deleted, pivot_ids, pivot_vecs,
                pivot_mask) -> None:
@@ -1154,6 +1281,7 @@ class ShardedBKTIndex:
                      + self.pivot_mask.nbytes)
         if self.data_score is not None:
             devmem.track("int8_blocks", self, self.data_score.nbytes)
+        _publish_placement(mesh.devices.size, self.n_local)
 
     # ---- per-shard budget policy (VERDICT r3 item 8) ---------------------
 
@@ -1303,4 +1431,6 @@ class ShardedBKTIndex:
             merge_bins=mb, finalize_bins=fb, seed_keep=sk,
             score_scale=getattr(self, "score_scale", 0.0),
             data_score=getattr(self, "data_score", None))
-        return _pad_to_k(np.asarray(d), np.asarray(ids), k, k_final)
+        with trace.span("index.readback"):
+            d, ids = np.asarray(d), np.asarray(ids)
+        return _pad_to_k(d, ids, k, k_final)

@@ -21,7 +21,8 @@ import time
 from typing import List
 
 from sptag_tpu.core.index import create_instance
-from sptag_tpu.core.types import ErrorCode, enum_from_string, VectorValueType
+from sptag_tpu.core.types import (DistCalcMethod, ErrorCode, VectorValueType,
+                                  enum_from_string)
 from sptag_tpu.io.reader import ReaderOptions, load_vectors
 from sptag_tpu.utils import pin_platform
 
@@ -40,6 +41,33 @@ def split_passthrough(args: List[str]):
         else:
             rest.append(a)
     return params, rest
+
+
+def build_mesh_folder(args, value_type, vectors, metadata, params,
+                      n_shards: int) -> None:
+    """`n_shards` contiguous partitions of `vectors`, each saved as a
+    reference-format sub-index under `args.outputfolder`, plus the
+    `sharded.json` manifest `load_index` recognizes
+    (parallel/sharded.py).  FLAT partitions are written without a device;
+    BKT / KDT shards are built over the first `n_shards` local devices
+    (ShardedBKTIndex.build reads MeshShardAxis off `params`)."""
+    from sptag_tpu.parallel.sharded import ShardedBKTIndex, ShardedFlatIndex
+
+    data = vectors.data
+    named = [("NumberOfThreads", str(args.thread))] + list(params)
+    if args.algo.upper() == "FLAT":
+        ShardedFlatIndex.save_shards(
+            data, args.outputfolder, n_shards, value_type,
+            params=[(n, v) for n, v in named
+                    if n.lower() != "meshshardaxis"],
+            metadata=metadata)
+        return
+    metric = dict((n.lower(), v) for n, v in named).get(
+        "distcalcmethod", "Cosine")
+    ShardedBKTIndex.build(
+        data, metric=enum_from_string(DistCalcMethod, metric),
+        value_type=value_type, params=dict(named), algo=args.algo,
+        save_to=args.outputfolder, metadata=metadata)
 
 
 def main(argv=None) -> int:
@@ -89,24 +117,34 @@ def main(argv=None) -> int:
                   vectors.dimension, args.dimension)
         return 1
 
-    index = create_instance(args.algo, value_type)
-    index.set_parameter("NumberOfThreads", str(args.thread))
-    for name, value in params:
-        if not index.set_parameter(name, value):
-            log.warning("unknown parameter %s", name)
-
+    # Index.MeshShardAxis=N (N > 0): a mesh folder of N partitions, one
+    # reference-format sub-index per device, instead of one index
+    n_shards = next((int(v) for name, v in reversed(params)
+                     if name.lower() == "meshshardaxis"), 0)
     t0 = time.perf_counter()
-    code = index.build(vectors, metadata,
-                       with_meta_index=metadata is not None)
-    if code != ErrorCode.Success:
-        log.error("build failed: %s", code)
-        return 1
-    log.info("built index in %.1fs", time.perf_counter() - t0)
+    if n_shards > 0:
+        build_mesh_folder(args, value_type, vectors, metadata, params,
+                          n_shards)
+        log.info("built and saved %d-shard mesh index in %.1fs",
+                 n_shards, time.perf_counter() - t0)
+    else:
+        index = create_instance(args.algo, value_type)
+        index.set_parameter("NumberOfThreads", str(args.thread))
+        for name, value in params:
+            if not index.set_parameter(name, value):
+                log.warning("unknown parameter %s", name)
 
-    code = index.save_index(args.outputfolder)
-    if code != ErrorCode.Success:
-        log.error("save failed: %s", code)
-        return 1
+        code = index.build(vectors, metadata,
+                           with_meta_index=metadata is not None)
+        if code != ErrorCode.Success:
+            log.error("build failed: %s", code)
+            return 1
+        log.info("built index in %.1fs", time.perf_counter() - t0)
+
+        code = index.save_index(args.outputfolder)
+        if code != ErrorCode.Success:
+            log.error("save failed: %s", code)
+            return 1
     log.info("saved index to %s", args.outputfolder)
     if args.trace_report:
         import json

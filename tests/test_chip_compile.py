@@ -284,6 +284,32 @@ def test_sharded_flat_kernel_compiles_4m_on_four(mesh4):
     _assert_per_device(compiled, 2 ** 30)
 
 
+@pytest.mark.parametrize("Q", [1, 8, 32, 128])
+def test_sharded_flat_kernel_compiles_deep10m_on_four(mesh4, Q):
+    """The benchmark's `sharded_deep10m.saturate`: 10M x 96 f32, 2.5M rows
+    (960 MB) a chip, at every rung of the query ladder the cell warms."""
+    from sptag_tpu.parallel.sharded import (SHARD_AXIS,
+                                            _sharded_search_kernel)
+
+    n, D = 10_000_000, 96
+    rows = NamedSharding(mesh4, P(SHARD_AXIS, None))
+    vec = NamedSharding(mesh4, P(SHARD_AXIS))
+    rep = NamedSharding(mesh4, P(None, None))
+    compiled = _sharded_search_kernel.lower(
+        _s(rows, (n, D), jnp.float32), _s(vec, (n,), jnp.float32),
+        _s(vec, (n,), jnp.bool_), _s(rep, (Q, D), jnp.float32),
+        k_local=K, k_final=K, metric=L2, base=1, mesh=mesh4).compile()
+    text = compiled.as_text()
+    # at one query the compiler gathers by all-reduce of a padded slice
+    assert "all-gather" in text or "all-reduce" in text
+    # the stage names reach the compiled program's metadata
+    for scope in ("flat.distance", "flat.topk", "mesh.merge"):
+        assert scope in text, scope
+    # a chip's share of the corpus, its (Q, 2.5M) scores and the top-k's
+    # workspace: well inside 16 GB
+    _assert_per_device(compiled, 6 * 2 ** 30)
+
+
 def test_sharded_beam_kernel_compiles_on_four(mesh4):
     from sptag_tpu.algo.engine import _num_words
     from sptag_tpu.parallel.sharded import SHARD_AXIS, _sharded_beam_kernel
