@@ -38,10 +38,15 @@ from sptag_tpu.io import format as fmt
 from sptag_tpu.ops import cascade
 from sptag_tpu.ops import distance as dist_ops
 from sptag_tpu.ops import topk_bins
-from sptag_tpu.utils import costmodel, devmem, round_up, trace
+from sptag_tpu.utils import costmodel, devmem, metrics, round_up, trace
 
 _ROW_PAD = 128      # pad corpus rows to multiples of this (TPU lane width)
 _QUERY_BUCKETS = (1, 8, 32, 128, 512)
+
+
+def pad_rows(n: int) -> int:
+    """Row slots of the device block that holds `n` corpus rows."""
+    return max(_ROW_PAD, round_up(n, _ROW_PAD))
 
 
 def _query_bucket(q: int) -> int:
@@ -62,6 +67,97 @@ def pad_to_bucket(queries: np.ndarray) -> np.ndarray:
     return np.concatenate(
         [queries, np.zeros((q_pad - q, queries.shape[1]), queries.dtype)],
         axis=0)
+
+
+_GROUP = 128        # columns a group of the two-stage select holds: a lane tile
+# When the two-stage select pays, as the chip showed it (PERF.md section
+# 6, PR 28; v5e, whole scan programs).  Rows at least this many times
+# k*_GROUP wide: below, one top-k over the row is no slower (1M x 128 at
+# 128 queries, k=10: 0.71 against 0.83 ms at 131,072 columns, 2.58 against
+# 6.22 at 1M).
+_TWO_STAGE_MIN_WIDTH = 100
+# Query blocks that fill the 128 lanes or fit one 8-row sublane tile: in
+# between, the transposed scores pad every row to 128 lanes, the passes
+# cost what 128 queries cost, and the N-wide top-k, which shrinks with
+# the queries, stays cheaper (32 queries: 3.22 against 2.11 ms).
+_SUBLANES, _LANES = 8, 128
+# A chosen group is fetched for all Q queries at once, Q*Q*k*512 bytes of
+# slabs: 1.3 GB at 512 queries and k=10 (13.99 against 22.78 ms), four
+# times that at the next rung of the query ladder.
+_TWO_STAGE_MAX_QUERIES = 512
+
+
+def select_stages(q: int, n: int, k: int) -> int:
+    """How many stages the exact selection of the `k` smallest of `q`
+    rows `n` wide takes: 2 (`exact_topk`'s group minima) where the chip
+    showed them to pay, else 1 (one `lax.top_k`: delta-row scans, small
+    corpora, `k` near `n`).  A function of the traced shape alone — the
+    host counters `flat.select_two_stage` / `flat.select_single` ask it
+    what the program they dispatch was traced as."""
+    wide = n >= _TWO_STAGE_MIN_WIDTH * k * _GROUP
+    block = q <= _SUBLANES or _LANES <= q <= _TWO_STAGE_MAX_QUERIES
+    return 2 if wide and block else 1
+
+
+def count_select(q: int, n: int, k: int) -> None:
+    """One dispatched exact scan of `q` rows `n` wide, counted by the
+    stage count its program was traced with."""
+    if select_stages(q, n, k) == 2:
+        metrics.inc("flat.select_two_stage")
+    else:
+        metrics.inc("flat.select_single")
+
+
+def exact_topk(d, k: int):
+    """The `k` smallest of every row of `d` (Q, N), ascending, with their
+    columns: `lax.top_k(-d, k)`'s answer BIT FOR BIT (values, columns,
+    lowest column first among equals), without sorting N-wide rows.
+
+    Wide rows take two stages.  (1) The minimum of every group of
+    `_GROUP` consecutive columns, one pass over `d`.  (2) `lax.top_k` over
+    the (Q, N/_GROUP) minima picks the k groups with the smallest minima,
+    lowest group first among equals.  (3) Those groups' columns — and the
+    tail past the last whole group, a group of its own that is always
+    kept — meet in ascending column order in one `lax.top_k` over
+    k*_GROUP (+ tail) candidates.
+
+    Exact by construction: a column x of an unchosen group g cannot be
+    among the k smallest, because each of the k chosen groups h holds a
+    column <= min(g) <= x, and where it is equal h < g, so that column
+    lies left of x: k columns precede x in (value, column) order.  The
+    candidates meet in column order, so the last top-k breaks ties as the
+    N-wide one would.
+
+    Everything reads `d` through its transpose, the query as the minor
+    dimension: there a group is _GROUP whole rows, its minimum an
+    elementwise one, and a chosen group is fetched as the (_GROUP, Q) slab
+    that holds it for every query — one contiguous piece — of which the
+    query's own lane is kept.  (Laid out query-major, the chip's compiler
+    transposes all of `d` for the reduction and once more for the gather;
+    a corpus placed in whole groups, as the snapshots and the mesh shards
+    are, has no tail to slice the groups off.)"""
+    q, n = d.shape
+    if select_stages(q, n, k) == 1:
+        neg, idx = jax.lax.top_k(-d, k)
+        return -neg, idx
+    groups, tail = divmod(n, _GROUP)
+    dt = d.T
+    grouped = (dt[:groups * _GROUP] if tail else dt).reshape(
+        groups, _GROUP, q)
+    _, chosen = jax.lax.top_k(-grouped.min(axis=1).T, k)
+    chosen = jnp.sort(chosen, axis=1)                   # column order
+    slabs = jnp.take(grouped, chosen.reshape(-1), axis=0, mode="clip")
+    own = jnp.eye(q, dtype=bool)[:, None, None, :]
+    cand = jnp.where(own, slabs.reshape(q, k, _GROUP, q),
+                     jnp.float32(np.inf)).min(axis=3).reshape(q, k * _GROUP)
+    if tail:
+        cand = jnp.concatenate([cand, dt[groups * _GROUP:].T], axis=1)
+    neg, pos = jax.lax.top_k(-cand, k)
+    group = jnp.take_along_axis(chosen, jnp.minimum(pos // _GROUP, k - 1),
+                                axis=1)
+    cols = jnp.where(pos < k * _GROUP, group * _GROUP + pos % _GROUP,
+                     pos + (groups - k) * _GROUP)
+    return -neg, cols
 
 
 def scan_topk(data, sqnorm, invalid, queries, k: int, metric: int,
@@ -90,8 +186,7 @@ def scan_topk(data, sqnorm, invalid, queries, k: int, metric: int,
                                             recall_target=recall_target)
             dists = -neg
         else:
-            neg, idx = jax.lax.top_k(-d, k)
-            dists = -neg
+            dists, idx = exact_topk(d, k)
         ids = jnp.where(dists >= jnp.float32(MAX_DIST), -1,
                         idx).astype(jnp.int32)
     return dists, ids
@@ -225,18 +320,42 @@ def _flat_scan_cost(Q, N, D, k, itemsize=4, binned_bins=0, **_):
     (mask + the min/argmin reduction reads), plus the shortlist select
     (ops/topk_bins.binned_select_cost) — the win is the SORT the exact
     top-k would add on top, which the exact branch's topk term carries
-    implicitly in XLA's numbers, not in this formula."""
+    implicitly in XLA's numbers, not in this formula.  A row wide enough
+    for the exact branch's two stages (`select_stages`) is costed by
+    `_two_stage_select_cost` instead of the N-wide top-k."""
     flops = (costmodel.matmul_flops(Q, N, D) + 2.0 * D * (Q + N)
              + 2.0 * Q * N)
+    nbytes = N * D * itemsize + Q * D * itemsize + N * 4 + N + Q * k * 8
     if binned_bins:
         sel_f, sel_b = topk_bins.binned_select_cost(Q, N, k, binned_bins)
-        nbytes = (N * D * itemsize + Q * D * itemsize + N * 4 + N
-                  + Q * k * 8
-                  + costmodel.SCAN_MATRIX_TRAFFIC * Q * N * 4
-                  + sel_b)
-        return flops + sel_f, nbytes
-    nbytes = (N * D * itemsize + Q * D * itemsize + N * 4 + N + Q * k * 8
-              + costmodel.SCAN_MATRIX_TRAFFIC * Q * N * 4)
+        return (flops + sel_f,
+                nbytes + costmodel.SCAN_MATRIX_TRAFFIC * Q * N * 4 + sel_b)
+    if select_stages(Q, N, k) == 2:
+        sel_f, sel_b = _two_stage_select_cost(Q, N, k)
+        return flops + sel_f, nbytes + sel_b
+    return flops, nbytes + costmodel.SCAN_MATRIX_TRAFFIC * Q * N * 4
+
+
+#: cost-analysis traversals of the (Q, N) score matrix when the exact
+#: selection takes two stages (written and masked through its transpose,
+#: read by the group minima) — fitted 5.1-5.3 against this container's XLA
+_TWO_STAGE_MATRIX_TRAFFIC = 5.2
+
+
+def _two_stage_select_cost(Q, N, k):
+    """`exact_topk`'s two stages over (Q, N) scores: the group-minimum
+    pass (with the transpose's elementwise ops, fitted 3 an element), the
+    top-k over the (Q, N/_GROUP) minima, the slabs — k groups a query,
+    each fetched for all Q queries, written and read back by the
+    select-reduce that keeps the query's own lane — and the top-k
+    over the k*_GROUP (+ tail) candidates.  A tail costs one more
+    traversal (the whole groups are sliced off it)."""
+    groups, tail = divmod(N, _GROUP)
+    slab = Q * k * _GROUP * Q
+    flops = (3.0 * Q * N + costmodel.topk_flops(Q, groups) + 3.0 * slab
+             + costmodel.topk_flops(Q, k * _GROUP + tail))
+    nbytes = ((_TWO_STAGE_MATRIX_TRAFFIC + (1.0 if tail else 0.0))
+              * Q * N * 4 + 2.0 * slab * 4)
     return flops, nbytes
 
 
@@ -418,7 +537,7 @@ class FlatIndex(VectorIndex):
             # snapshot coverage stops at the delta base: rows beyond it
             # are served by the FLAT-scanned delta shard until absorbed
             n = self._main_rows()
-            n_pad = max(_ROW_PAD, round_up(n, _ROW_PAD))
+            n_pad = pad_rows(n)
             dt = dtype_of(self.value_type)
             data = np.zeros((n_pad, self.feature_dim), dtype=dt)
             data[:n] = self._host[:n]
@@ -590,10 +709,12 @@ class FlatIndex(VectorIndex):
             bins = topk_bins.resolve_bins(
                 str(getattr(self.params, "binned_topk", "off")), k_eff,
                 data_d.shape[0], rt)
+            approx = bool(getattr(self.params, "approx_topk", False))
+            if not (approx or bins):
+                count_select(queries.shape[0], data_d.shape[0], k_eff)
             dists, ids = _flat_search_kernel(
                 data_d, sqnorm_d, invalid_d, jnp.asarray(queries), k_eff,
-                int(self.dist_calc_method), self.base,
-                approx=bool(getattr(self.params, "approx_topk", False)),
+                int(self.dist_calc_method), self.base, approx=approx,
                 recall_target=rt, binned_bins=bins)
         with trace.span("index.readback"):
             # the host blocks here until the program has run

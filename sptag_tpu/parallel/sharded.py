@@ -29,7 +29,8 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from sptag_tpu.algo.flat import pad_to_bucket, scan_topk
+from sptag_tpu.algo.flat import (count_select, pad_rows, pad_to_bucket,
+                                 scan_topk)
 from sptag_tpu.core.index import MAX_DIST
 from sptag_tpu.core.types import DistCalcMethod
 from sptag_tpu.ops import distance as dist_ops
@@ -81,13 +82,16 @@ class MeshTopK(NamedTuple):
 
 @functools.partial(jax.jit,
                    static_argnames=("k_local", "k_final", "metric", "base",
-                                    "mesh"))
+                                    "mesh", "row_stride"))
 def _sharded_search_kernel(data, sqnorm, invalid, queries, k_local: int,
-                           k_final: int, metric: int, base: int, mesh: Mesh):
+                           k_final: int, metric: int, base: int, mesh: Mesh,
+                           row_stride: Optional[int] = None):
     """One program: per shard the one-chip scan body (`algo/flat.py
     scan_topk`: distances, mask, local top-k_local), then under scope
     `mesh.merge` the ICI all-gather of the (dist, global-id) candidates
-    and the global top-k_final re-rank."""
+    and the global top-k_final re-rank.  `row_stride`: the corpus rows a
+    shard stands for, where its device block is padded beyond them
+    (absent: the block's own row count)."""
 
     def local_search(data_s, sqnorm_s, invalid_s, q_s):
         d, ids = scan_topk(data_s, sqnorm_s, invalid_s, q_s, k_local,
@@ -96,7 +100,8 @@ def _sharded_search_kernel(data, sqnorm, invalid, queries, k_local: int,
         # kernel.mesh_merge_ms_per_batch reads `mesh.merge`)
         with jax.named_scope("mesh.merge"):
             shard = jax.lax.axis_index(SHARD_AXIS)
-            gids = jnp.where(ids >= 0, ids + shard * data_s.shape[0], -1)
+            gids = jnp.where(
+                ids >= 0, ids + shard * (row_stride or data_s.shape[0]), -1)
             return _gather_merge(d, gids, k_final)
 
     return MeshTopK(*shard_map(
@@ -218,19 +223,31 @@ class ShardedFlatIndex:
         if self.metric == DistCalcMethod.Cosine and not normalized:
             data = dist_ops.normalize(data, base)
 
-        n_pad = self.rows_per_shard(self.n, n_dev) * n_dev
-        padded = data
-        if n_pad != self.n:
-            padded = np.zeros((n_pad, data.shape[1]), data.dtype)
-            padded[:self.n] = data
-        invalid = np.ones(n_pad, dtype=bool)
-        invalid[:self.n] = (deleted[:self.n] if deleted is not None
-                            else np.zeros(self.n, bool))
+        # a shard stands for `row_stride` consecutive corpus rows (the
+        # last one for what is left) and holds them in a device block
+        # padded as the one-chip snapshot is, under the `invalid` mask
+        self.row_stride = stride = self.rows_per_shard(self.n, n_dev)
+        n_slot = pad_rows(stride)
+        if deleted is None:
+            deleted = np.zeros(self.n, bool)
+
+        def blocks_of(source, fill):
+            def block(index):
+                s = (index[0].start or 0) // n_slot
+                rows = source[s * stride:(s + 1) * stride]
+                out = np.empty((n_slot,) + source.shape[1:], source.dtype)
+                out[:len(rows)] = rows
+                out[len(rows):] = fill
+                return out
+            return block
 
         row_sharding = NamedSharding(self.mesh, P(SHARD_AXIS, None))
         vec_sharding = NamedSharding(self.mesh, P(SHARD_AXIS))
-        self.data = jax.device_put(padded, row_sharding)
-        self.invalid = jax.device_put(invalid, vec_sharding)
+        n_pad = n_slot * n_dev
+        self.data = jax.make_array_from_callback(
+            (n_pad, data.shape[1]), row_sharding, blocks_of(data, 0))
+        self.invalid = jax.make_array_from_callback(
+            (n_pad,), vec_sharding, blocks_of(deleted[:self.n], True))
         if self.metric == DistCalcMethod.L2:
             self.sqnorm = jax.jit(
                 dist_ops.row_sqnorms,
@@ -243,7 +260,7 @@ class ShardedFlatIndex:
         devmem.track("shard_blocks", self,
                      self.data.nbytes + self.sqnorm.nbytes
                      + self.invalid.nbytes)
-        _publish_placement(n_dev, n_pad // n_dev)
+        _publish_placement(n_dev, stride)
 
     @staticmethod
     def rows_per_shard(n: int, n_shards: int) -> int:
@@ -333,9 +350,11 @@ class ShardedFlatIndex:
         n_local = self.data.shape[0] // n_dev
         k_local = min(k, n_local)
         k_final = min(k, k_local * n_dev)
+        count_select(queries.shape[0], n_local, k_local)
         dists, ids = _sharded_search_kernel(
             self.data, self.sqnorm, self.invalid, jnp.asarray(queries),
-            k_local, k_final, int(self.metric), self.base, self.mesh)
+            k_local, k_final, int(self.metric), self.base, self.mesh,
+            row_stride=self.row_stride)
         with trace.span("index.readback"):
             # the host blocks here until the program has run
             dists = np.asarray(dists)[:q]

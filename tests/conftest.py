@@ -50,6 +50,21 @@ jax.config.update("jax_platforms", "cpu")
 import pytest  # noqa: E402
 
 
+@pytest.fixture(scope="session")
+def bench_reference():
+    """benchmark/harness/reference.py, loaded by its path: the plain
+    numpy exact scan the benchmark holds the FLAT cells to; it imports
+    nothing of the program."""
+    import importlib.util
+
+    path = os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
+                        "harness", "reference.py")
+    spec = importlib.util.spec_from_file_location("bench_reference", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
 @pytest.fixture
 def host_mesh():
     """N-device host mesh over the forced CPU devices (ISSUE 11

@@ -14,6 +14,7 @@ this process with the persistent compile cache off.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -83,6 +84,16 @@ def _s(sharding, shape, dtype):
 
 def _has_mosaic_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
+
+
+def _row_wide_selections(compiled, n):
+    """Operations of the compiled program that sort or top-k an operand
+    `n` columns (or rows) wide: the N-wide selection the FLAT scan's
+    two-stage select (ISSUE 28) exists to avoid."""
+    wide = re.compile(rf"[\[,]{n}[\],]")
+    return [line.strip()[:160] for line in compiled.as_text().splitlines()
+            if ('custom_call_target="TopK"' in line or " sort(" in line
+                or "/top_k" in line) and wide.search(line)]
 
 
 # ---------------------------------------------------------------------------
@@ -165,7 +176,7 @@ def test_dense_grouped_kernel_compiles_with_pallas(one_chip, dtype, D,
 # FLAT exact scan at SIFT1M's shape
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("Q", [1, 512])
+@pytest.mark.parametrize("Q", [1, 128, 512])
 def test_flat_search_kernel_compiles_1m(one_chip, Q):
     from sptag_tpu.algo.flat import _flat_search_kernel
 
@@ -178,6 +189,18 @@ def test_flat_search_kernel_compiles_1m(one_chip, Q):
     # corpus + the (Q, N) distance matrix + top-k scratch must fit 16 GB
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes) < 15 * 2 ** 30
+    text = compiled.as_text()
+    for scope in ("flat.distance", "flat.topk"):
+        assert scope in text, scope
+    if Q > 1:
+        # two stages: nothing sorts the 1M-wide rows, and the scores are
+        # written once, in the layout both stages read (the parent's
+        # temporaries were the (Q, N) scores alone: 512.0 MB at Q=128);
+        # at 512 queries the slabs of the chosen groups, Q*Q*k*512 bytes,
+        # no longer stay out of device memory
+        assert not _row_wide_selections(compiled, n)
+        slabs = Q * Q * K * 128 * 4 if Q > 128 else 0
+        assert mem.temp_size_in_bytes < 1.01 * (Q * n * 4 + slabs)
 
 
 # ---------------------------------------------------------------------------
@@ -291,20 +314,34 @@ def test_sharded_flat_kernel_compiles_deep10m_on_four(mesh4, Q):
     from sptag_tpu.parallel.sharded import (SHARD_AXIS,
                                             _sharded_search_kernel)
 
-    n, D = 10_000_000, 96
+    from sptag_tpu.algo.flat import pad_rows
+    from sptag_tpu.parallel.sharded import ShardedFlatIndex
+
+    # as `ShardedFlatIndex` places it: a shard stands for 2,500,000 rows
+    # in a device block padded to 2,500,096
+    D, stride = 96, ShardedFlatIndex.rows_per_shard(10_000_000, 4)
+    n_slot = pad_rows(stride)
+    n = 4 * n_slot
     rows = NamedSharding(mesh4, P(SHARD_AXIS, None))
     vec = NamedSharding(mesh4, P(SHARD_AXIS))
     rep = NamedSharding(mesh4, P(None, None))
     compiled = _sharded_search_kernel.lower(
         _s(rows, (n, D), jnp.float32), _s(vec, (n,), jnp.float32),
         _s(vec, (n,), jnp.bool_), _s(rep, (Q, D), jnp.float32),
-        k_local=K, k_final=K, metric=L2, base=1, mesh=mesh4).compile()
+        k_local=K, k_final=K, metric=L2, base=1, mesh=mesh4,
+        row_stride=stride).compile()
     text = compiled.as_text()
     # at one query the compiler gathers by all-reduce of a padded slice
     assert "all-gather" in text or "all-reduce" in text
     # the stage names reach the compiled program's metadata
     for scope in ("flat.distance", "flat.topk", "mesh.merge"):
         assert scope in text, scope
+    if Q == 128:
+        # two stages a shard: nothing sorts its 2.5M-wide rows, and the
+        # temporaries are the (Q, 2.5M) scores as before (1,280.1 MB)
+        assert not _row_wide_selections(compiled, n_slot)
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            < 1.01 * Q * n_slot * 4
     # a chip's share of the corpus, its (Q, 2.5M) scores and the top-k's
     # workspace: well inside 16 GB
     _assert_per_device(compiled, 6 * 2 ** 30)

@@ -81,7 +81,11 @@ def _assert_close(family, compiled, **shape):
     assert metrics.counter_value("costmodel.xla_mismatch") == before
 
 
-@pytest.mark.parametrize("Q,N,D,k", [(32, 1024, 64, 10), (8, 512, 32, 5)])
+@pytest.mark.parametrize("Q,N,D,k", [
+    (32, 1024, 64, 10), (8, 512, 32, 5),
+    # rows the exact selection takes in two stages (ISSUE 28); the last
+    # leaves a tail past its last whole group
+    (8, 40960, 32, 1), (128, 65536, 32, 2), (128, 40000, 64, 1)])
 def test_crosscheck_flat_scan(Q, N, D, k):
     from sptag_tpu.algo.flat import _flat_search_kernel
 

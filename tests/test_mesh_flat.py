@@ -367,6 +367,42 @@ def test_mesh_program_is_the_one_chip_scan_plus_a_named_merge(host_mesh):
     assert "merged_dists" in text and "global_ids" in text
 
 
+def test_a_wide_shard_selects_in_two_stages_and_is_exact(host_mesh,
+                                                         bench_reference):
+    """ISSUE 28: a shard wide enough for the two-stage select (its device
+    block padded past the rows it stands for, as the one-chip snapshot
+    is), deleted rows among the true neighbours, global ids by the rows a
+    shard stands for — against the benchmark's plain numpy scan."""
+    from sptag_tpu.algo import flat
+    from sptag_tpu.parallel.sharded import ShardedFlatIndex
+
+    rng = np.random.default_rng(28)
+    n, dim = 2 * 128_100 + 11, 8
+    data = rng.standard_normal((n, dim)).astype(np.float32)
+    queries = data[rng.integers(0, n, 6)] + np.float32(0.02)
+    nearest, _ = bench_reference.exact_topk(data, queries, K)
+    deleted = np.zeros(n, bool)
+    deleted[nearest[:, 0]] = True
+    deleted[n - 1] = True
+    kept = np.flatnonzero(~deleted)
+    want_ids, want_d = bench_reference.exact_topk(data[kept], queries, K)
+
+    index = ShardedFlatIndex(data, DistCalcMethod.L2, base=1,
+                             mesh=host_mesh(2), deleted=deleted)
+    stride = rows_per_shard(n, 2)
+    assert index.row_stride == stride and stride % 128
+    n_slot = index.data.shape[0] // 2
+    assert n_slot == -(-stride // 128) * 128
+    assert flat.select_stages(8, n_slot, K) == 2
+    assert metrics.gauge_value("mesh.rows_per_shard") == stride
+    before = metrics.counter_value("flat.select_two_stage")
+    dists, ids = index.search(queries, K)
+    assert metrics.counter_value("flat.select_two_stage") == before + 1
+    assert np.array_equal(ids, kept[want_ids])
+    assert set(ids.ravel() // stride) == {0, 1}
+    np.testing.assert_allclose(dists, want_d, rtol=1e-4, atol=1e-3)
+
+
 # ---------------------------------------------------------------- (f) ----
 
 def golden_rows(n=300, d=24):

@@ -207,7 +207,7 @@ def test_merge_contract_deleted_mask(host_mesh):
     mesh = host_mesh(2)
     idx = ShardedFlatIndex(data, DistCalcMethod.L2, base=1, mesh=mesh,
                            deleted=deleted)
-    n_local = idx.data.shape[0] // 2
+    n_local = idx.row_stride        # the rows a shard stands for
     # fan-out baseline WITHOUT sockets: per-shard single-chip FLAT
     # indexes with the same rows deleted, host-merged like the
     # aggregator's client-side merge (the socket path itself is covered
