@@ -1,16 +1,22 @@
 #!/usr/bin/env python3
-"""chip_smoke.py — the served search path, once, on the real chip.
+"""chip_smoke.py — the served paths no benchmark cell covers yet, once, on
+the real chip.
 
-    python chip_smoke.py               # one TPU chip (what the driver runs)
-    python chip_smoke.py --chips 4     # the sharded path only, four chips
+    python chip_smoke.py               # one TPU chip
+    python chip_smoke.py --chips 4     # the sharded BKT path only, four chips
     python chip_smoke.py --rehearse    # tiny, any backend, never "ok": true
 
-Drives build -> save -> load -> SearchServer over a socket -> AnnClient
-through the functions the CLIs call, at sizes SPTAG's users call real
-(FLAT 1M x 128 = SIFT1M's shape; BKT 200k x 128 f32 L2 in both search
-modes; BKT 200k x 384 int8 cosine), and checks every answer against an
-exact numpy scan written here, independent of sptag_tpu.  Data comes from
---seed and nothing on disk.
+The repo is measured by `python3 -m benchmark.run` (BENCHMARK.json); its
+cells check FLAT over the socket, the BKT dense scan and the sharded FLAT
+mesh more strictly than this script did, so those phases are gone.  What
+is left has no cell yet (ROADMAP B1 / B3 / B2): the BKT beam walk (200k x
+128 f32 L2), BKT int8 cosine (d=384) and the sharded BKT mesh, each
+through build -> save -> load -> SearchServer over a socket -> AnnClient
+and checked against an exact numpy scan that imports nothing of
+sptag_tpu.  A phase goes when its cell lands.  Serving, the f32 data and
+the L2 reference are the benchmark's own (benchmark/harness/,
+benchmark/datasets/); only what the harness lacks is written here.  Data
+comes from --seed and nothing on disk.
 
 ONE process owns the chip from start to end: the script builds, serves
 (server on its own thread + event loop) and queries (clients on other
@@ -24,29 +30,35 @@ with "ok": true, which needs a TPU.
 from __future__ import annotations
 
 import argparse
-import asyncio
 import contextlib
 import json
 import os
 import shutil
 import sys
 import tempfile
-import threading
 import time
 import traceback
 
 import numpy as np
 
+# numpy only: the harness imports the program inside its functions
+from benchmark.datasets import clustered_f32
+from benchmark.harness import reference, serving
+from benchmark.harness.serving import require
+
 K = 10
 MAX_CHECK = 2048
-MAX_CLUSTERS = 256
-#: bench.py's _GRAPH_PARAMS / _bkt_params — the knobs every recorded BKT
-#: number of this repo was taken with
-BKT_PARAMS = [("BKTNumber", "1"), ("BKTKmeansK", "32"), ("TPTNumber", "8"),
-              ("TPTLeafSize", "1000"), ("NeighborhoodSize", "32"),
-              ("CEF", "256"), ("MaxCheckForRefineGraph", "512"),
-              ("RefineIterations", "2"), ("MaxCheck", str(MAX_CHECK)),
-              ("RefineQueryGroup", "32"), ("FinalRefineSearchMode", "same")]
+#: the knobs the builders' chip runs of BKT were taken with (the same as
+#: benchmark/configs/bkt_100k_f32_l2_dense.json's, less its SearchMode)
+BKT_PARAMS = {"BKTNumber": "1", "BKTKmeansK": "32", "TPTNumber": "8",
+              "TPTLeafSize": "1000", "NeighborhoodSize": "32",
+              "CEF": "256", "MaxCheckForRefineGraph": "512",
+              "RefineIterations": "2", "MaxCheck": str(MAX_CHECK),
+              "RefineQueryGroup": "32", "FinalRefineSearchMode": "same"}
+#: configurations in the shape benchmark/harness/serving.py takes
+BKT_F32 = {"algo": "BKT", "value_type": "Float", "metric": "L2", "k": K,
+           "index_params": BKT_PARAMS}
+BKT_INT8 = {**BKT_F32, "value_type": "Int8", "metric": "Cosine"}
 #: rows ISSUE 22 asks of both BKT phases; a smaller `REAL` value is a cut
 #: and is printed as one
 ASKED_BKT_N = 200_000
@@ -54,21 +66,12 @@ ASKED_BKT_N = 200_000
 #: never cut — only row counts)
 #: int8_n is CUT to 100k: at 200k the whole script took 1084 s of its
 #: 1200 s on the chip (int8 build alone 566 s; my chip run C, PR 22)
-REAL = dict(flat_n=1_000_000, bkt_n=200_000, int8_n=100_000,
-            shard_flat_n=4_000_000, fresh=1000, selfq=256, singles=64,
-            burst=256)
-TINY = dict(flat_n=20_000, bkt_n=4_000, int8_n=4_000, shard_flat_n=40_000,
-            fresh=64, selfq=32, singles=4, burst=32)
+REAL = dict(bkt_n=200_000, int8_n=100_000, fresh=1000, selfq=256)
+#: TINY's 256 BKT rows are one dense block: from 512 rows up the CPU build
+#: with BKT_PARAMS takes a minute and more (tier-1 runs this rehearsal)
+TINY = dict(bkt_n=256, int8_n=4_000, fresh=64, selfq=32)
 RECALL_BAR = {"dense": 0.90, "beam": 0.80, "int8": 0.90}
-
-
-class SmokeFailure(AssertionError):
-    """A check of this script did not hold."""
-
-
-def require(cond, message: str) -> None:
-    if not cond:
-        raise SmokeFailure(message)
+ALL_PHASES = "bkt,int8"
 
 
 def emit(obj: dict) -> None:
@@ -76,44 +79,18 @@ def emit(obj: dict) -> None:
 
 
 # ---------------------------------------------------------------------------
-# data from --seed, in bulk
+# what the harness lacks: int8 rows and SPTAG's integer cosine
 # ---------------------------------------------------------------------------
 
-def make_clustered(seed: int, n: int, d: int, nq: int, dtype=np.float32):
-    """(n, d) corpus + (nq, d) fresh queries: Gaussian clusters
-    (SIFT-like structure rather than pure noise) — 256 of them at every
-    real size, fewer only where a rehearsal's corpus would leave a
-    cluster smaller than one 256-row block.  int8: rows scaled to unit
-    norm x 127 and rounded, the way int8 cosine embeddings ship."""
-    rng = np.random.default_rng(seed)
-    clusters = max(4, min(MAX_CLUSTERS, n // 512))
-    centers = rng.standard_normal((clusters, d), dtype=np.float32) * 4.0
+def make_int8(seed: int, n: int, d: int, nq: int):
+    """The benchmark's Gaussian clusters scaled to unit norm x 127 and
+    rounded, the way int8 cosine embeddings ship."""
+    def to_int8(x):
+        x = x / np.maximum(np.linalg.norm(x, axis=1, keepdims=True), 1e-9)
+        return np.clip(np.round(x * 127.0), -128, 127).astype(np.int8)
+    data, queries = clustered_f32.make(seed, n, d, nq)
+    return to_int8(data), to_int8(queries)
 
-    def draw(rows):
-        x = rng.standard_normal((rows, d), dtype=np.float32)
-        x += centers[rng.integers(0, clusters, rows)]
-        return x
-
-    data, queries = draw(n), draw(nq)
-    if np.dtype(dtype) == np.int8:
-        def to_int8(x):
-            x = x / np.maximum(np.linalg.norm(x, axis=1, keepdims=True),
-                               1e-9)
-            return np.clip(np.round(x * 127.0), -128, 127).astype(np.int8)
-        return to_int8(data), to_int8(queries)
-    return data, queries
-
-
-def write_bin(path: str, array: np.ndarray) -> None:
-    """The reference's vectors.bin layout: int32 rows, int32 cols, rows."""
-    with open(path, "wb") as f:
-        f.write(np.asarray(array.shape, "<i4").tobytes())
-        array.tofile(f)
-
-
-# ---------------------------------------------------------------------------
-# the exact reference: plain numpy, no sptag_tpu
-# ---------------------------------------------------------------------------
 
 def normalize_int8(x: np.ndarray) -> np.ndarray:
     """SPTAG's ingest rule for integer cosine (Utils::Normalize,
@@ -124,152 +101,34 @@ def normalize_int8(x: np.ndarray) -> np.ndarray:
     return np.trunc(f).astype(np.int8)
 
 
-def exact_topk(data: np.ndarray, queries: np.ndarray, k: int, metric: str,
-               block: int = 131_072, slack: int = 22):
-    """Exact top-k by brute force -> ((Q, k) ids, (Q, k) float64 scores,
-    ascending = nearest first).
-
-    L2 (float rows): squared distance.  Cosine (int8 rows): SPTAG's
-    integer convention, 127^2 - dot of the `normalize_int8` rows
-    (DistanceUtils.h:452) — a float cosine of the rows as given ranks
-    quantization near-ties differently and is not what an int8 index
-    promises.
-
-    Each corpus block is ranked with one float32 GEMM, its best k+slack
-    rows are kept, and the survivors are re-scored in float64 — so float32
-    rounding can only reorder rows inside the slack, never decide the
-    answer (int8 dots are exact in float32 anyway: |dot| <= 127^2)."""
-    if metric == "Cosine":
-        data, queries = normalize_int8(data), normalize_int8(queries)
-    q32 = queries.astype(np.float32)
-    keep = k + slack
-    cand = []
-    for lo in range(0, data.shape[0], block):
-        x32 = data[lo:lo + block].astype(np.float32)
-        rank = -(q32 @ x32.T)
-        if metric == "L2":
-            rank = (x32 * x32).sum(1)[None, :] + 2.0 * rank
-        kk = min(keep, rank.shape[1])
-        part = np.argpartition(rank, kk - 1, axis=1)[:, :kk]
-        cand.append(part + lo)
-    cand = np.concatenate(cand, axis=1)
-    scores = exact_scores(data, queries, cand, metric)
+def exact_topk_int8_cosine(data: np.ndarray, queries: np.ndarray, k: int,
+                            block: int = 131_072):
+    """Exact top-k in SPTAG's integer cosine convention -> ((Q, k) ids,
+    (Q, k) float64 scores, ascending = nearest first): 127^2 - dot of the
+    `normalize_int8` rows (DistanceUtils.h:452).  A float cosine of the
+    rows as given ranks quantization near-ties differently and is not what
+    an int8 index promises (the harness's reference is L2 over floats).
+    The float32 GEMM is exact: rows no longer than 127 keep every partial
+    sum of integers within 127^2."""
+    x, q = normalize_int8(data), normalize_int8(queries).astype(np.float32)
+    ids, scores = [], []
+    for lo in range(0, len(x), block):
+        s = 127.0 ** 2 - (q @ x[lo:lo + block].astype(np.float32).T
+                          ).astype(np.float64)
+        kk = min(k, s.shape[1])
+        part = np.argpartition(s, kk - 1, axis=1)[:, :kk]
+        ids.append(part + lo)
+        scores.append(np.take_along_axis(s, part, axis=1))
+    ids, scores = np.concatenate(ids, axis=1), np.concatenate(scores, axis=1)
     order = np.argsort(scores, axis=1, kind="stable")[:, :k]
-    return (np.take_along_axis(cand, order, axis=1),
+    return (np.take_along_axis(ids, order, axis=1),
             np.take_along_axis(scores, order, axis=1))
 
 
-def exact_scores(data, queries, ids, metric: str) -> np.ndarray:
-    """float64 score of each (query, id) pair (for Cosine: of rows already
-    through `normalize_int8`)."""
-    x = data[ids].astype(np.float64)                         # (Q, m, d)
-    q = queries.astype(np.float64)[:, None, :]
-    if metric == "L2":
-        return ((x - q) ** 2).sum(-1)
-    return 127.0 ** 2 - (x * q).sum(-1)
-
-
-def compare_exact(data, queries, got_ids, ref_ids, ref_scores, metric):
-    """Exact-search verdict per query: the id list equals the reference's,
-    or differs only where float32 cannot tell the rows apart (the true
-    scores of the returned ids equal the reference's within float32
-    rounding of the terms they were computed from).  Returns (identical,
-    tie_resolved) counts; raises on any other difference."""
-    got_ids = np.asarray(got_ids)
-    same = (got_ids == ref_ids).all(axis=1)
-    bad = np.flatnonzero(~same)
-    if bad.size:
-        ids = got_ids[bad]
-        require((ids >= 0).all() and (ids < len(data)).all(),
-                f"exact search returned invalid ids for queries {bad[:5]}")
-        require(all(len(set(r)) == len(r) for r in ids.tolist()),
-                f"exact search returned duplicate ids for queries {bad[:5]}")
-        got = np.sort(exact_scores(data, queries[bad], ids, metric), axis=1)
-        # float32 rounding of |q|^2 + |x|^2 - 2 q.x, a few ulps
-        scale = ((queries[bad].astype(np.float64) ** 2).sum(1)
-                 + float((data[:4096].astype(np.float64) ** 2).sum(1).max()))
-        tol = 8.0 * np.finfo(np.float32).eps * scale[:, None]
-        worst = np.abs(got - ref_scores[bad]) - tol
-        require((worst <= 0).all(),
-                f"exact search differs from the reference beyond float32 "
-                f"ties: query {bad[int(np.argmax(worst.max(1)))]}")
-    return int(same.sum()), int(bad.size)
-
-
-def recall_at_k(got_ids, ref_ids, k: int) -> float:
-    return float(np.mean([len(set(g[:k]) & set(r[:k])) / k
-                          for g, r in zip(np.asarray(got_ids).tolist(),
-                                          np.asarray(ref_ids).tolist())]))
-
-
 # ---------------------------------------------------------------------------
-# serving: ini -> ServiceContext.from_ini -> SearchServer <- AnnClient
+# clients and counters (the harness's generator is a child process; here
+# every client is a thread of the process that owns the chip)
 # ---------------------------------------------------------------------------
-
-class ServerThread(threading.Thread):
-    """An asyncio SearchServer on its own thread and loop (the boot-task
-    reference is kept on purpose: see tests/conftest.py::ServerThread)."""
-
-    def __init__(self, server):
-        super().__init__(daemon=True, name="chip-smoke-server")
-        self.server = server
-        self.addr = None
-        self.loop = None
-        self._ready = threading.Event()
-
-    def run(self):
-        self.loop = asyncio.new_event_loop()
-        asyncio.set_event_loop(self.loop)
-
-        async def boot():
-            self.addr = await self.server.start("127.0.0.1", 0)
-            self._ready.set()
-
-        self._boot_task = self.loop.create_task(boot())
-        self.loop.run_forever()
-
-    def wait_ready(self, timeout=60):
-        require(self._ready.wait(timeout), "server did not start listening")
-        return self.addr
-
-    def stop(self):
-        asyncio.run_coroutine_threadsafe(self.server.stop(),
-                                         self.loop).result(timeout=60)
-        self.loop.call_soon_threadsafe(self.loop.stop)
-        self.join(timeout=60)
-
-
-@contextlib.contextmanager
-def served(workdir: str, name: str, folder: str):
-    """Serve one saved index the way `python -m sptag_tpu.serve.server`
-    does; yields (context, (host, port))."""
-    from sptag_tpu.serve.server import SearchServer
-    from sptag_tpu.serve.service import ServiceContext
-
-    ini = os.path.join(workdir, f"{name}.ini")
-    with open(ini, "w") as f:
-        f.write("[Service]\nListenAddr=127.0.0.1\nListenPort=0\n"
-                f"[QueryConfig]\nDefaultMaxResultNumber={K}\n"
-                f"[Index]\nList={name}\n"
-                f"[Index_{name}]\nIndexFolder={folder}\n")
-    ctx = ServiceContext.from_ini(ini)
-    # from_ini logs and skips an index that fails to load
-    require(name in ctx.indexes, f"server did not load index {name!r}")
-    thread = ServerThread(SearchServer(ctx))
-    thread.start()
-    try:
-        yield ctx, thread.wait_ready()
-    finally:
-        thread.stop()
-
-
-def query_text(name: str, vec: np.ndarray) -> str:
-    if vec.dtype.kind == "f":
-        body = "|".join(repr(float(x)) for x in vec)   # exact round trip
-    else:
-        body = "|".join(str(int(x)) for x in vec)
-    return f"$resultnum:{K} $indexname:{name} {body}"
-
 
 def _ids_of(res, name: str) -> list:
     from sptag_tpu.serve import wire
@@ -281,25 +140,12 @@ def _ids_of(res, name: str) -> list:
     return list(res.results[0].ids)
 
 
-def ask_single(addr, name: str, queries: np.ndarray) -> np.ndarray:
-    """One AnnClient, one request in flight at a time."""
-    from sptag_tpu.serve.client import AnnClient
-
-    client = AnnClient(addr[0], addr[1], timeout_s=900.0)
-    client.connect()
-    try:
-        return np.asarray([_ids_of(client.search(query_text(name, q)), name)
-                           for q in queries], np.int64)
-    finally:
-        client.close()
-
-
 def ask_burst(addr, name: str, queries: np.ndarray) -> np.ndarray:
     """Every query in flight at once (pipelined connections), so the
     server's batcher forms real batches."""
     from sptag_tpu.serve.client import AnnClientPool
 
-    texts = [query_text(name, q) for q in queries]
+    texts = [serving.query_text(name, K, q) for q in queries]
     with AnnClientPool(addr[0], addr[1], connections=4, timeout_s=900.0,
                        max_workers=min(len(texts), 256)) as pool:
         futs = [pool.search_async(t) for t in texts]
@@ -307,25 +153,14 @@ def ask_burst(addr, name: str, queries: np.ndarray) -> np.ndarray:
                           np.int64)
 
 
-def build_index(workdir: str, name: str, data: np.ndarray, algo: str,
-                value_type: str, metric: str, params, compile_log) -> tuple:
-    """BIN file -> the builder CLI's main() -> saved folder.  Returns
-    (folder, build+save seconds), and prints a line of its own first, so
-    that a later failed check cannot lose the build's numbers."""
-    from sptag_tpu.tools import index_builder
-
-    bin_path = os.path.join(workdir, f"{name}.bin")
+def build_index(workdir: str, name: str, data: np.ndarray, config: dict,
+                compile_log) -> tuple:
+    """The harness's build (BIN file -> the builder CLI's main() -> saved
+    folder).  Returns (folder, build+save seconds), and prints a line of
+    its own first, so that a later failed check cannot lose the build's
+    numbers."""
     folder = os.path.join(workdir, f"{name}_index")
-    write_bin(bin_path, data)
-    t0 = time.perf_counter()
-    rc = index_builder.main(
-        ["-d", str(data.shape[1]), "-v", value_type, "-i",
-         f"BIN:{bin_path}", "-o", folder, "-a", algo,
-         f"Index.DistCalcMethod={metric}"]
-        + [f"Index.{k}={v}" for k, v in params])
-    require(rc == 0, f"index_builder exited {rc} for {name}")
-    seconds = time.perf_counter() - t0
-    os.remove(bin_path)
+    seconds = serving.build_index(workdir, folder, data, config)
     emit({"phase": f"{name}.build", "n": len(data), "build_seconds": seconds,
           "compiles": compile_log.count,
           "compile_seconds": compile_log.total_s})
@@ -368,11 +203,8 @@ class Counters:
 def no_serve_errors(where: str) -> None:
     """The server answers a failed search with a status, and counts it;
     both must be clean."""
-    from sptag_tpu.utils import metrics
-
-    for name in ("service.search_errors", "server.batch_failures"):
-        require(metrics.counter_value(name) == 0,
-                f"{name} = {metrics.counter_value(name)} after {where}")
+    bad = {n: v for n, v in serving.serve_error_counts().items() if v}
+    require(not bad, f"{bad} after {where}")
 
 
 # ---------------------------------------------------------------------------
@@ -384,49 +216,22 @@ def phase_device(rehearse: bool, chips: int) -> dict:
 
     from sptag_tpu.utils import roofline
 
-    devs = jax.devices()
-    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
-              "count": len(devs)}
-    out = {"phase": "device", **device}
-    if not rehearse:
-        require(device["platform"] == "tpu",
-                f"no TPU: jax.devices()[0].platform = {device['platform']!r}")
-        cap = roofline.capability()
-        require(cap.source == "table",
-                f"device kind {device['kind']!r} is not in "
-                "utils/roofline.py's table")
-        out["capability"] = {
-            "source": cap.source, "peak_flops_bf16": cap.peak_flops_bf16,
-            "peak_flops_int8": cap.peak_flops_int8,
-            "hbm_gbps": cap.hbm_gbps}
-    require(len(devs) >= chips, f"{chips} chips asked, {len(devs)} found")
-    emit(out)
+    if rehearse:                 # the harness's check refuses a CPU
+        devs = jax.devices()
+        device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+                  "count": len(devs)}
+        require(len(devs) >= chips, f"{chips} chips asked, {len(devs)} found")
+        emit({"phase": "device", **device})
+        return device
+    device = serving.check_device(chips)
+    cap = roofline.capability()
+    require(cap.source == "table",
+            f"device kind {device['kind']!r} is not in "
+            "utils/roofline.py's table")
+    emit({"phase": "device", **device, "capability": {
+        "source": cap.source, "peak_flops_bf16": cap.peak_flops_bf16,
+        "peak_flops_int8": cap.peak_flops_int8, "hbm_gbps": cap.hbm_gbps}})
     return device
-
-
-def phase_flat(workdir, seed, size, counters) -> None:
-    out = {"phase": "flat_1m", "n": size["flat_n"], "d": 128,
-           "dtype": "float32", "metric": "L2", "k": K}
-    with counters.phase(out) as compile_log:
-        nq = size["singles"] + size["burst"]
-        data, queries = make_clustered(seed, size["flat_n"], 128, nq)
-        folder, out["build_seconds"] = build_index(
-            workdir, "flat", data, "FLAT", "Float", "L2", [], compile_log)
-        t0 = time.perf_counter()
-        ref_ids, ref_scores = exact_topk(data, queries, K, "L2")
-        out["reference_seconds"] = time.perf_counter() - t0
-        with served(workdir, "flat", folder) as (_, addr):
-            t0 = time.perf_counter()
-            got = [ask_single(addr, "flat", queries[:size["singles"]])]
-            out["singles_seconds"] = time.perf_counter() - t0
-            t0 = time.perf_counter()
-            got.append(ask_burst(addr, "flat", queries[size["singles"]:]))
-            out["burst_seconds"] = time.perf_counter() - t0
-        no_serve_errors("flat_1m")
-        out["queries"] = nq
-        out["ids_identical"], out["ids_tie_resolved"] = compare_exact(
-            data, queries, np.concatenate(got), ref_ids, ref_scores, "L2")
-    emit(out)
 
 
 def _dense_route(index) -> dict:
@@ -440,22 +245,6 @@ def _dense_route(index) -> dict:
                 dense.data_perm)),
             "pallas_ran": bool(dense.last_use_pallas),
             "blocks": list(dense.data_perm.shape)}
-
-
-def _own_block_ranks(dense, data, rows) -> list:
-    """For corpus rows that did not find themselves in dense mode: how
-    many block centroids are nearer to the row than its own block's, by
-    an exact float64 computation over the searcher's layout.  A rank at
-    or past nprobe means the search never looked into that block — the
-    dense scan's documented approximation, not a device fault."""
-    member_ids = np.asarray(dense.member_ids)
-    centroids = np.asarray(dense.centroids, np.float64)
-    ranks = []
-    for r in rows:
-        own = np.unique(np.argwhere(member_ids == r)[:, 0])
-        d = ((centroids - data[r].astype(np.float64)) ** 2).sum(1)
-        ranks.append(int((d < d[own].min()).sum()))
-    return ranks
 
 
 def _beam_miss_diagnosis(index, addr, data, missed) -> dict:
@@ -480,74 +269,55 @@ def _beam_miss_diagnosis(index, addr, data, missed) -> dict:
                 (again[:, 0] == missed).sum())}
 
 
-def check_self_queries(mode: str, asked: int, missed: int, bar: float,
-                       dense_ranks=None, nprobe=None) -> None:
+def check_self_queries(mode: str, asked: int, missed: int, bar: float
+                       ) -> None:
     """A corpus row queried as itself comes back first — as often as the
     mode's recall bar says, not always.  Both modes are approximate: dense
-    scores only the MaxCheck/256 blocks with the nearest mean centroids,
-    and a row of a block packed from several subtrees can lie far from its
-    block's mean; the beam walk stops after a few iterations without
-    improvement and never visits a row few edges lead to (chip runs of
-    PR 22: dense 252 of 256, beam 233 of 256).  What IS exact is checked
-    exactly: a dense miss must have its own block at or past nprobe by
-    exact centroid distance — else the device disagreed with the
-    algorithm."""
+    scores only the MaxCheck/256 blocks with the nearest mean centroids;
+    the beam walk stops after a few iterations without improvement and
+    never visits a row few edges lead to (chip runs of PR 22: dense 252
+    of 256, beam 233 of 256)."""
     require(asked - missed >= bar * asked,
             f"{mode}: {missed} of {asked} corpus rows did not find "
             "themselves first")
-    if dense_ranks:
-        require(min(dense_ranks) >= nprobe,
-                f"dense: a self-query missed though its own block ranks "
-                f"{min(dense_ranks)} < nprobe {nprobe} by centroid distance")
 
 
-def phase_bkt(workdir, seed, size, counters, need_pallas: bool) -> None:
+def phase_bkt(workdir, seed, size, counters) -> None:
+    """The beam walk (the dense scan of the same index is the cell
+    `bkt_100k.saturate`)."""
     n = size["bkt_n"]
     out = {"phase": "bkt_200k", "n": n, "d": 128, "dtype": "float32",
            "metric": "L2", "k": K, "max_check": MAX_CHECK}
     if n != ASKED_BKT_N:
         out["cut"] = f"n {ASKED_BKT_N} -> {n}"
     with counters.phase(out) as compile_log:
-        data, fresh = make_clustered(seed + 1, n, 128, size["fresh"])
+        data, fresh = clustered_f32.make(seed + 1, n, 128, size["fresh"])
         folder, out["build_seconds"] = build_index(
-            workdir, "bkt", data, "BKT", "Float", "L2", BKT_PARAMS,
-            compile_log)
+            workdir, "bkt", data, BKT_F32, compile_log)
         self_rows = np.random.default_rng(seed).choice(
             n, size["selfq"], replace=False)
-        ref_ids, _ = exact_topk(data, fresh, K, "L2")
-        with served(workdir, "bkt", folder) as (ctx, addr):
-            index = ctx.indexes["bkt"]
-            for mode in ("dense", "beam"):
-                # one build, one loaded index: the mode is a parameter
-                require(index.set_parameter("SearchMode", mode),
-                        f"SearchMode={mode} was refused")
-                t0 = time.perf_counter()
-                own = ask_burst(addr, "bkt", data[self_rows])
-                missed = self_rows[own[:, 0] != self_rows]
-                out[f"{mode}_self_first"] = len(self_rows) - len(missed)
-                ranks = None
-                if mode == "dense" and len(missed):
-                    dense = index._get_dense()
-                    ranks = _own_block_ranks(dense, data, missed)
-                    out["dense_nprobe"] = -(-MAX_CHECK // dense.cluster_size)
-                    out["dense_self_miss_block_rank"] = ranks
-                if mode == "beam" and len(missed):
-                    out.update(_beam_miss_diagnosis(index, addr, data,
-                                                    missed))
-                check_self_queries(mode, len(self_rows), len(missed),
-                                   RECALL_BAR[mode], ranks,
-                                   out.get("dense_nprobe"))
-                got = ask_burst(addr, "bkt", fresh)
-                out[f"{mode}_seconds"] = time.perf_counter() - t0
-                out[f"{mode}_recall_at_10"] = recall_at_k(got, ref_ids, K)
-                require(out[f"{mode}_recall_at_10"] >= RECALL_BAR[mode],
-                        f"{mode} recall@10 {out[f'{mode}_recall_at_10']:.4f}"
-                        f" < {RECALL_BAR[mode]}")
-                if mode == "dense":
-                    out.update(_dense_route(index))
-                    require(out["pallas_ran"] or not need_pallas,
-                            "f32 dense search did not take the Pallas route")
-                no_serve_errors(f"bkt_200k {mode}")
+        ref_ids, _ = reference.exact_topk(data, fresh, K)
+        with serving.served(workdir, "bkt", folder, BKT_F32) as (server,
+                                                                 addr):
+            index = server.context.indexes["bkt"]
+            # the mode is a parameter of the loaded index: no rebuild
+            require(index.set_parameter("SearchMode", "beam"),
+                    "SearchMode=beam was refused")
+            t0 = time.perf_counter()
+            own = ask_burst(addr, "bkt", data[self_rows])
+            missed = self_rows[own[:, 0] != self_rows]
+            out["beam_self_first"] = len(self_rows) - len(missed)
+            if len(missed):
+                out.update(_beam_miss_diagnosis(index, addr, data, missed))
+            check_self_queries("beam", len(self_rows), len(missed),
+                               RECALL_BAR["beam"])
+            got = ask_burst(addr, "bkt", fresh)
+            out["beam_seconds"] = time.perf_counter() - t0
+            out["beam_recall_at_10"] = reference.recall_at_k(got, ref_ids, K)
+            require(out["beam_recall_at_10"] >= RECALL_BAR["beam"],
+                    f"beam recall@10 {out['beam_recall_at_10']:.4f}"
+                    f" < {RECALL_BAR['beam']}")
+            no_serve_errors("bkt_200k beam")
         out["self_queries"], out["fresh_queries"] = len(self_rows), len(fresh)
     emit(out)
 
@@ -559,21 +329,20 @@ def phase_int8(workdir, seed, size, counters, need_pallas: bool) -> None:
     if n != ASKED_BKT_N:
         out["cut"] = f"n {ASKED_BKT_N} -> {n}"
     with counters.phase(out) as compile_log:
-        data, fresh = make_clustered(seed + 2, n, 384, size["fresh"],
-                                     np.int8)
+        data, fresh = make_int8(seed + 2, n, 384, size["fresh"])
         folder, out["build_seconds"] = build_index(
-            workdir, "int8", data, "BKT", "Int8", "Cosine", BKT_PARAMS,
-            compile_log)
-        ref_ids, _ = exact_topk(data, fresh, K, "Cosine")
-        with served(workdir, "int8", folder) as (ctx, addr):
+            workdir, "int8", data, BKT_INT8, compile_log)
+        ref_ids, _ = exact_topk_int8_cosine(data, fresh, K)
+        with serving.served(workdir, "int8", folder, BKT_INT8) as (server,
+                                                                   addr):
             t0 = time.perf_counter()
             got = ask_burst(addr, "int8", fresh)
             out["dense_seconds"] = time.perf_counter() - t0
-            out.update(_dense_route(ctx.indexes["int8"]))
+            out.update(_dense_route(server.context.indexes["int8"]))
         no_serve_errors("int8_200k")
         require(out["pallas_ran"] or not need_pallas,
                 "int8 dense search did not take the Pallas route")
-        out["dense_recall_at_10"] = recall_at_k(got, ref_ids, K)
+        out["dense_recall_at_10"] = reference.recall_at_k(got, ref_ids, K)
         require(out["dense_recall_at_10"] >= RECALL_BAR["int8"],
                 f"int8 recall@10 {out['dense_recall_at_10']:.4f} < "
                 f"{RECALL_BAR['int8']}")
@@ -598,43 +367,29 @@ def _spread(name: str, array, n_dev: int) -> dict:
 
 
 def phase_four_chips(seed, size, counters) -> None:
-    """The sharded path and what it is compared with (the exact
-    reference) — nothing else runs under --chips 4."""
+    """The sharded BKT mesh and what it is compared with (the exact
+    reference) — nothing else runs under --chips 4 (sharded FLAT is the
+    cell `sharded_deep10m.saturate`)."""
     import jax
 
     from sptag_tpu.core.types import DistCalcMethod
-    from sptag_tpu.parallel.sharded import (ShardedBKTIndex,
-                                            ShardedFlatIndex, make_mesh)
+    from sptag_tpu.parallel.sharded import ShardedBKTIndex, make_mesh
 
     mesh = make_mesh(jax.devices()[:4])
-    out = {"phase": "sharded_flat_4m", "n": size["shard_flat_n"], "d": 128,
-           "k": K}
-    with counters.phase(out):
-        data, queries = make_clustered(seed + 3, size["shard_flat_n"], 128,
-                                       size["singles"])
-        index = ShardedFlatIndex(data, DistCalcMethod.L2, base=1, mesh=mesh)
-        out["data"] = _spread("flat data", index.data, 4)
-        _, got = index.search(queries, K)
-        ref_ids, ref_scores = exact_topk(data, queries, K, "L2")
-        out["ids_identical"], out["ids_tie_resolved"] = compare_exact(
-            data, queries, got, ref_ids, ref_scores, "L2")
-        del index, data
-    emit(out)
-
     n = size["bkt_n"]
     out = {"phase": "sharded_bkt_200k", "n": n, "d": 128, "k": K,
            "max_check": MAX_CHECK}
     with counters.phase(out):
-        data, fresh = make_clustered(seed + 1, n, 128, size["fresh"])
+        data, fresh = clustered_f32.make(seed + 1, n, 128, size["fresh"])
         t0 = time.perf_counter()
         index = ShardedBKTIndex.build(data, DistCalcMethod.L2, mesh=mesh,
-                                      params=dict(BKT_PARAMS), dense=True)
+                                      params=BKT_PARAMS, dense=True)
         out["build_seconds"] = time.perf_counter() - t0
         for name in ("data", "graph", "dense_perm"):
             out[name] = _spread(name, getattr(index, name), 4)
         self_rows = np.random.default_rng(seed).choice(
             n, size["selfq"], replace=False)
-        ref_ids, _ = exact_topk(data, fresh, K, "L2")
+        ref_ids, _ = reference.exact_topk(data, fresh, K)
         for mode, search in (("beam", index.search),
                              ("dense", index.search_dense)):
             _, own = search(data[self_rows], K, max_check=MAX_CHECK)
@@ -643,7 +398,8 @@ def phase_four_chips(seed, size, counters) -> None:
             check_self_queries(f"sharded {mode}", len(self_rows), missed,
                                RECALL_BAR[mode])
             _, got = search(fresh, K, max_check=MAX_CHECK)
-            out[f"{mode}_recall_at_10"] = recall_at_k(got, ref_ids, K)
+            out[f"{mode}_recall_at_10"] = reference.recall_at_k(got, ref_ids,
+                                                                K)
             require(out[f"{mode}_recall_at_10"] >= RECALL_BAR[mode],
                     f"sharded {mode} recall@10 "
                     f"{out[f'{mode}_recall_at_10']:.4f} < {RECALL_BAR[mode]}")
@@ -656,11 +412,11 @@ def parse_args(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=7)
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
-                    help="4 = the sharded path only (needs four chips)")
+                    help="4 = the sharded BKT path only (needs four chips)")
     ap.add_argument("--rehearse", action="store_true",
                     help="tiny sizes, any backend, Pallas in interpret "
                     "mode off the TPU; always ends \"ok\": false")
-    ap.add_argument("--phases", default="flat,bkt,int8",
+    ap.add_argument("--phases", default=ALL_PHASES,
                     help="one-chip phases to run (a partial run never "
                     "ends \"ok\": true)")
     return ap.parse_args(argv)
@@ -691,10 +447,8 @@ def run(args) -> dict:
             phase_four_chips(args.seed, size, counters)
         else:
             phases = args.phases.split(",")
-            if "flat" in phases:
-                phase_flat(workdir, args.seed, size, counters)
             if "bkt" in phases:
-                phase_bkt(workdir, args.seed, size, counters, on_tpu)
+                phase_bkt(workdir, args.seed, size, counters)
             if "int8" in phases:
                 phase_int8(workdir, args.seed, size, counters, on_tpu)
     finally:
@@ -713,7 +467,7 @@ def main(argv=None) -> int:
         # print the last line with "ok": false, exit non-zero
         traceback.print_exc()
         error = f"{type(e).__name__}: {e}"
-    complete = args.chips == 4 or args.phases == "flat,bkt,int8"
+    complete = args.chips == 4 or args.phases == ALL_PHASES
     ok = (error is None and not args.rehearse and complete
           and device is not None and device["platform"] == "tpu")
     last = {"ok": ok, "device": device,

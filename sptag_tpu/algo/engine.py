@@ -876,7 +876,12 @@ def _walk_iter_cost(Q, X, D, W, score_itemsize=4, merge_bins=0, L=0, N=0,
     ensemble is gone — what remains is the (L + X)-wide bin reduction +
     shortlist top-L (WALK_BINNED_* constants, per merged-row element)
     and the L-wide lazy-mark sort ensemble (the WALK_SORT_* constants at
-    width L)."""
+    width L).
+
+    Both bodies carry the corpus gather operand, N*D: cost analysis
+    charges a gather its whole operand, and at a small batch that is no
+    small term (Q=8, N=2048, D=64: 0.52 of 5.0 MB; without it the exact
+    body read 15.5-43 % low at Q=8, N=2048-8192)."""
     # int8 cascade scoring (score_scale > 0): the dequantize cast +
     # multiply is another 2·Q·X·D elementwise ops, and the dequantized
     # f32 copy doubles the post-gather traffic words
@@ -895,6 +900,7 @@ def _walk_iter_cost(Q, X, D, W, score_itemsize=4, merge_bins=0, L=0, N=0,
         return flops, nbytes
     flops = 2.0 * Q * X * D + deq_f + costmodel.WALK_SORT_FLOPS * Q * X
     nbytes = (2.0 * Q * X * D * score_itemsize + deq_b
+              + N * D * score_itemsize           # corpus gather operand
               + costmodel.WALK_SORT_TRAFFIC * Q * X * 4
               + 2.0 * Q * W * 4)
     return flops, nbytes

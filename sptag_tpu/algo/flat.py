@@ -316,25 +316,36 @@ def _flat_scan_cost(Q, N, D, k, itemsize=4, binned_bins=0, **_):
     top-k.  Bytes: corpus + queries + norms/tombstones in, results out,
     plus the materialized (Q, N) score matrix's mask/neg/top-k traffic
     (the SCAN_MATRIX_TRAFFIC calibration).  With `binned_bins` the
-    selection is the bin reduction: the (Q, N) matrix traversals stay
-    (mask + the min/argmin reduction reads), plus the shortlist select
-    (ops/topk_bins.binned_select_cost) — the win is the SORT the exact
-    top-k would add on top, which the exact branch's topk term carries
-    implicitly in XLA's numbers, not in this formula.  A row wide enough
+    selection is the bin reduction, fused into the scan: the contraction
+    writes the (Q, N) matrix and ONE fusion reads it back, masks it and
+    takes min and argmin in a single variadic reduce
+    (_BINNED_MATRIX_TRAFFIC), then the (Q, bins) winner rows go through
+    the shortlist top-k (_BINNED_WINNER_TRAFFIC; the flops are
+    ops/topk_bins.binned_select_cost's).  A row wide enough
     for the exact branch's two stages (`select_stages`) is costed by
     `_two_stage_select_cost` instead of the N-wide top-k."""
     flops = (costmodel.matmul_flops(Q, N, D) + 2.0 * D * (Q + N)
              + 2.0 * Q * N)
     nbytes = N * D * itemsize + Q * D * itemsize + N * 4 + N + Q * k * 8
     if binned_bins:
-        sel_f, sel_b = topk_bins.binned_select_cost(Q, N, k, binned_bins)
+        sel_f, _ = topk_bins.binned_select_cost(Q, N, k, binned_bins)
         return (flops + sel_f,
-                nbytes + costmodel.SCAN_MATRIX_TRAFFIC * Q * N * 4 + sel_b)
+                nbytes + (_BINNED_MATRIX_TRAFFIC * Q * N
+                          + _BINNED_WINNER_TRAFFIC * Q * binned_bins) * 4)
     if select_stages(Q, N, k) == 2:
         sel_f, sel_b = _two_stage_select_cost(Q, N, k)
         return flops + sel_f, nbytes + sel_b
     return flops, nbytes + costmodel.SCAN_MATRIX_TRAFFIC * Q * N * 4
 
+
+#: cost-analysis traversals of the (Q, N) score matrix under the binned
+#: select (one write by the contraction, one read by the fused mask +
+#: min/argmin reduce) and of the (Q, bins) winner rows (values and columns
+#: written, negated, read by the top-k, columns gathered) — fitted 2.0 and
+#: 5.4 against this container's XLA at bins/N = 1/32 .. 1/8 (the old
+#: 3.2 + 2 traversals read 30-120 % high: the reduce was two passes then)
+_BINNED_MATRIX_TRAFFIC = 2.0
+_BINNED_WINNER_TRAFFIC = 5.4
 
 #: cost-analysis traversals of the (Q, N) score matrix when the exact
 #: selection takes two stages (written and masked through its transpose,

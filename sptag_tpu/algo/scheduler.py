@@ -200,8 +200,7 @@ class _SlotPool:
         the cost ledger or the family is unregistered.  Estimated at the
         pool's slot count and divided down: the binned body's byte
         formula carries a per-DISPATCH corpus-operand term (N*D) that a
-        Q=1 estimate would charge in full to every query (the same
-        amortization bench.py's roofline row applies)."""
+        Q=1 estimate would charge in full to every query."""
         if self._iter_cost1 is None:
             try:
                 # max_slots, not capacity: the amortization base must be

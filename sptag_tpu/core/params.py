@@ -418,7 +418,7 @@ class KDTParams(ParamSet):
             _spec("dense_query_group", int, 0, "DenseQueryGroup"),
             _spec("dense_union_factor", int, 2, "DenseUnionFactor"),
             # builds refine ~15x faster through the dense engine at equal
-            # quality (reports/MAXCHECK_SWEEP.md); "beam" restores the
+            # quality (a CPU sweep; report removed in PR 29); "beam" restores the
             # reference's RefineGraph-by-walk semantics
             _spec("refine_search_mode", str, "dense", "RefineSearchMode"),
             # final-pass engine guardrail; see the BKT spec of the same name

@@ -421,15 +421,19 @@ def _host_scan_block_kernel(rows, dead, queries, k: int, metric: int,
 #: the per-(Q·N) sort/top-k ensemble of the sketch shortlist
 SKETCH_WORD_FLOPS = 5.0
 SKETCH_SELECT_FLOPS = 12.75
-#: per-(Q·N) word traffic of the Hamming scan + shortlist sort
-SKETCH_TRAFFIC = 18.0
+#: per-(Q·N) bytes of the Hamming scan + shortlist sort (re-fitted
+#: 24.3-25.3 at eight shapes of the standalone kernel; the 18.0 it
+#: replaces read 26-28 % low there and 6-11 % low in the fused programs)
+SKETCH_TRAFFIC = 25.0
 #: per-element flops/bytes of the gathered s8×s8→s32 re-rank (cast
 #: copies included)
 INT8_RERANK_FLOPS = 6.25
 INT8_RERANK_TRAFFIC = 18.5
-#: per-element flops/bytes of the gathered exact fp re-rank
+#: per-element flops/bytes of the gathered exact fp re-rank (bytes
+#: re-fitted 16.5-17.0 at five shapes of the standalone kernel; 20.7 read
+#: 22-25 % high there)
 FP_RERANK_FLOPS = 4.2
-FP_RERANK_TRAFFIC = 20.7
+FP_RERANK_TRAFFIC = 16.8
 
 
 def _sketch_stage_cost(Q, N, W, b1):
@@ -474,8 +478,7 @@ def _cascade_search_cost(Q, N, W, D, b1, b2, k, use_sketch=True,
         flops, nbytes = flops + f, nbytes + b
     else:
         # degenerate both-tiers-off config: the exact masked fp scan
-        f, b = _host_scan_block_cost(Q, N, D, k)
-        return f, b + 3.0 * N * D
+        return _host_scan_block_cost(Q, N, D, k)
     r = b2 if use_int8 else b1
     f, b = _fp_stage_cost(Q, D, r, k)
     return flops + f, nbytes + b + 4.0 * N * D
@@ -508,8 +511,13 @@ def _cascade_tiers_cost(Q, N, W, D, b1, b2, use_sketch=True,
 
 
 def _host_scan_block_cost(Q, R, D, k, **_):
+    """Exact masked scan of one (R, D) block: the (Q, R) scores are
+    traversed three times (12 bytes an element), the rows about four
+    (contraction + sqnorms), the queries four — fitted at eight shapes,
+    Q 1-128, R 1024-4096, D 64-128, within 0.7 % (16 / 19 / 0 read
+    15-21 % high)."""
     flops = costmodel.matmul_flops(Q, R, D) + 10.0 * Q * R
-    nbytes = 16.0 * Q * R + 19.0 * R * D + Q * k * 8
+    nbytes = 12.0 * Q * R + 16.4 * R * D + 16.0 * Q * D + Q * k * 8
     return flops, nbytes
 
 

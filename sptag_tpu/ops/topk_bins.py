@@ -2,8 +2,9 @@
 
 `lax.top_k` over an N-wide score row is a full sort under XLA:CPU and a
 multi-pass O(N log N) selection on TPU — at serving shapes it is the part
-of every scan/merge kernel that is NOT a matmul, and BENCH_r05 measured
-it (plus the argsort ensembles around it) dominating the beam path.
+of every scan/merge kernel that is NOT a matmul, and a CPU run (record
+removed in PR 29) read it, with the argsort ensembles around it,
+dominating the beam path.
 "TPU-KNN: K Nearest Neighbor Search at Peak FLOP/s" (arXiv:2206.14286)
 replaces it with a **partial bin reduction**: scatter the N scores into
 ``bins`` bins with a cheap strided rule, keep each bin's best element

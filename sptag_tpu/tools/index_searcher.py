@@ -48,7 +48,7 @@ def load_truth(path: str, k: int) -> List[set]:
 def calc_recall(ids: np.ndarray, truth: List[set], k: int) -> float:
     """Parity: CalcRecall (IndexSearcher/main.cpp:17-48).  Delegates to
     THE canonical definition in utils/qualmon.py (ISSUE 7 satellite) —
-    the CLI, bench.py and the online estimator share one recall."""
+    the CLI and the online estimator share one recall."""
     from sptag_tpu.utils.qualmon import recall_at_k
 
     return recall_at_k(ids, truth, k)

@@ -204,7 +204,7 @@ def reset() -> None:
 
 
 def counters() -> Dict[str, int]:
-    """Drop/overflow accounting — bench.py embeds this in BENCH json.
+    """Drop/overflow accounting.
     Per-buffer counters are monotonic and single-writer (see _Buf), so
     this read is exact once writers are quiescent and never loses or
     double-counts under concurrency."""

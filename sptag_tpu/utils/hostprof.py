@@ -390,8 +390,7 @@ def snapshot() -> dict:
 
 
 def top_stacks(n: int = 10) -> List[Tuple[str, int]]:
-    """The `n` hottest folded stacks, count-descending (bench.py embeds
-    the loadgen stage's top 10 so benchdiff has stable keys)."""
+    """The `n` hottest folded stacks, count-descending."""
     with _lock:
         rows = sorted(_folded.items(), key=lambda kv: -kv[1])
     return rows[:n]

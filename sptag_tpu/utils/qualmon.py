@@ -3,7 +3,7 @@
 The observability stack answers "where did the time go" (utils/flightrec.py)
 and "how well is the chip used" (utils/costmodel.py / utils/roofline.py);
 this module answers the third axis of every ANN tradeoff: **how good are
-the answers**.  Until now recall was measured only offline (bench.py, the
+the answers**.  Until now recall was measured only offline (the
 IndexSearcher CLI); no live query ever learned its own recall, yet every
 planned tradeoff — the tiered sketch→int8→exact pipeline, partial-
 reduction approximate top-k, live mutation's "bounded staleness" — spends
@@ -13,8 +13,8 @@ recall to buy speed.  This module is the measurement substrate:
   reference CalcRecall parity (IndexSearcher/main.cpp:17-48) — per truth
   slot, a hit is a served id match OR a served distance equal to the
   truth distance within tolerance (distinct vectors tied at the same
-  distance are equally correct answers).  bench.py and the IndexSearcher
-  CLI both delegate here, so the definition lives in exactly one place.
+  distance are equally correct answers).  The IndexSearcher CLI delegates
+  here, so the program's definition lives in exactly one place.
 * **online recall estimator**: the serve tier samples a
   `QualitySampleRate` fraction of served queries (deterministic 1-in-N
   counter — reproducible, no RNG on the hot path) and replays each on a
@@ -247,7 +247,7 @@ def recall_row(ids, truth_ids, k: int, dists=None, truth_dists=None,
 
 
 def recall_at_k(ids_all, truth, k: int) -> float:
-    """Mean id-match recall over a batch — the bench.py / IndexSearcher
+    """Mean id-match recall over a batch — the IndexSearcher
     shape: `ids_all` (Q, >=k) array-like, `truth` one container of true
     ids per query (set / list / ndarray row)."""
     n = min(len(ids_all), len(truth))

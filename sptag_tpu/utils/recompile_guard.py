@@ -12,8 +12,7 @@ bench regression rounds later.
 
 Every observed compile is also fed into utils/trace.py
 (`trace.record("xla.backend_compile[<label>]", dt)`), so `trace.report()`
-shows compile cost next to host spans — bench.py's trace dump picks it
-up with no extra wiring.
+shows compile cost next to host spans with no extra wiring.
 
 Listener registration is process-global and installed once, lazily (the
 module import does NOT import jax — importing the library must never

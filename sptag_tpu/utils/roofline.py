@@ -13,8 +13,8 @@ Two sources, in order:
   ``jax.devices()[0].device_kind`` substrings.  Numbers are per-chip
   public spec-sheet peaks; f32 matmul on the MXU runs the multi-pass
   bf16 algorithm at ~1/4 the bf16 rate, which is the convention the
-  table encodes (and what bench.py's old hard-coded ``49e12`` for v5e
-  meant — that constant now lives HERE, once, with provenance).
+  table encodes (v5e: ``49e12`` — the constant lives HERE, once, with
+  provenance).
 * **Measured micro-probe** for cpu/gpu/unknown kinds: a timed f32
   matmul (compute peak) and a timed device-to-device copy (memory
   bandwidth), disk-cached keyed on (device kind, jax version) with an

@@ -38,9 +38,8 @@ raised).
 
 Consumers: ``GET /debug/timeline`` (serve/metrics_http.py) serves the
 rings as JSON, ``python -m sptag_tpu.tools.timeline`` renders terminal
-sparklines from a live endpoint or a saved snapshot, bench.py embeds
-`summary()` in its artifact, and serve/slo.py evaluates burn rates over
-`window_values()`.
+sparklines from a live endpoint or a saved snapshot, and serve/slo.py
+evaluates burn rates over `window_values()`.
 
 Overhead contract (DESIGN.md §21): off (the default) there is NO
 sampler thread and `record()` is one module-flag test — the serve wire

@@ -76,9 +76,8 @@ def host_mesh():
     SMALLEST mesh that exercises the behavior: shard_map program compile
     time scales with the device count, and tier-1 is compile-bound
     (docs/DESIGN.md §12 compile-budget notes).  Processes without the
-    forced device count (bench, standalone children) must set the same
-    XLA_FLAGS in a SUBPROCESS env before jax imports — see bench.py's
-    mesh_serve stage."""
+    forced device count (standalone children) must set the same
+    XLA_FLAGS in a SUBPROCESS env before jax imports."""
     from sptag_tpu.parallel.sharded import make_mesh
 
     def make(n=None):
@@ -100,9 +99,9 @@ import threading  # noqa: E402
 class ServerThread(threading.Thread):
     """Run an asyncio server (SearchServer or AggregatorService) in a
     background thread with its own loop — THE one copy of the
-    boot/halt helper (tests import it as `from conftest import
-    ServerThread`; bench.py keeps a standalone variant because the
-    bench child runs without tests/ on sys.path).
+    boot/halt helper for tests (`from conftest import ServerThread`;
+    benchmark/harness/serving.py has the benchmark's, which runs
+    without tests/ on sys.path).
 
     The stored boot-task reference is LOAD-BEARING: a bare
     `loop.create_task(boot())` leaves the pending task referenced only

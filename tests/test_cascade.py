@@ -365,6 +365,11 @@ def test_crosscheck_cascade_kernels(Q, N, D, b1, b2, k):
         False, True).compile()
     close("cascade.search", c, Q=Q, N=N, W=W, D=D, b1=b1, b2=b2, k=k,
           use_sketch=False)
+    c = cascade._cascade_search_kernel.lower(
+        fp, i8, sk, mean, inv, scale, q, k, b1, b2, metric, base,
+        False, False).compile()
+    close("cascade.search", c, Q=Q, N=N, W=W, D=D, b1=b1, b2=b2, k=k,
+          use_sketch=False, use_int8=False)
     c = cascade._cascade_shortlist_kernel.lower(
         i8, sk, mean, inv, scale, q, b1, b2, metric, base, True).compile()
     close("cascade.shortlist", c, Q=Q, N=N, W=W, D=D, b1=b1, b2=b2)

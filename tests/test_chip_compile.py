@@ -2,7 +2,8 @@
 
 Interpret-mode and CPU tests cannot see what the chip's compiler refuses
 (an unaligned int8 row load got through every one of them), so the
-programs `chip_smoke.py` runs are compiled here at its real widths
+programs the benchmark's cells and `chip_smoke.py` run are compiled here
+at their real widths
 against a `v5e:2x2` topology that is described, not attached.  Nothing
 runs: a pass says "the compiler accepts it", never "it is right" or
 "it is fast".
@@ -287,8 +288,9 @@ def _assert_per_device(compiled, at_most_bytes):
 
 
 def test_sharded_flat_kernel_compiles_4m_on_four(mesh4):
-    """`chip_smoke.py --chips 4`'s flat phase: 4M x 128 f32, a quarter
-    (512 MB) per chip, per-shard top-k merged by an all-gather."""
+    """The sharded FLAT program at 4M x 128 f32, a quarter (512 MB) per
+    chip, per-shard top-k merged by an all-gather (the deep-10M cell's
+    own shape is compiled by the `deep10m` case)."""
     from sptag_tpu.parallel.sharded import (SHARD_AXIS,
                                             _sharded_search_kernel)
 

@@ -1,10 +1,11 @@
 """chip_smoke.py off the chip: what can be checked without one.
 
-The script's real run needs a TPU (the driver makes it).  Here: importing
-it initializes no backend, its data generator and exact reference agree
-with FlatIndex at a tiny size, the phase lines have the shape the
-contract names, and on the CPU it refuses — non-zero exit, `"ok": false`
-— with and without the rehearsal switch.
+The script's real run needs a TPU.  Here: importing it initializes no
+backend; the data generators and exact references it runs on (the
+benchmark's for f32 / L2, its own for int8 cosine) agree with FlatIndex at
+a tiny size; the phase lines have the shape the contract names, and on the
+CPU it refuses — non-zero exit, `"ok": false` — with and without the
+rehearsal switch.
 """
 
 import json
@@ -13,7 +14,6 @@ import subprocess
 import sys
 
 import numpy as np
-import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -42,29 +42,35 @@ def test_import_initializes_no_backend():
 
 def test_generator_is_seeded_and_shaped():
     import chip_smoke
+    from benchmark.datasets import clustered_f32
 
-    a, qa = chip_smoke.make_clustered(3, 2000, 128, 16)
-    b, qb = chip_smoke.make_clustered(3, 2000, 128, 16)
-    c, _ = chip_smoke.make_clustered(4, 2000, 128, 16)
+    a, qa = clustered_f32.make(3, 2000, 128, 16)
+    b, qb = clustered_f32.make(3, 2000, 128, 16)
+    c, _ = clustered_f32.make(4, 2000, 128, 16)
     assert a.shape == (2000, 128) and a.dtype == np.float32
     assert qa.shape == (16, 128)
     np.testing.assert_array_equal(a, b)
     np.testing.assert_array_equal(qa, qb)
     assert not np.array_equal(a, c)
-    i8, q8 = chip_smoke.make_clustered(3, 2000, 384, 16, np.int8)
+    i8, q8 = chip_smoke.make_int8(3, 2000, 384, 16)
     assert i8.dtype == np.int8 and q8.dtype == np.int8
+    assert i8.shape == (2000, 384) and q8.shape == (16, 384)
+    np.testing.assert_array_equal(i8, chip_smoke.make_int8(3, 2000, 384, 16)[0])
     norms = np.linalg.norm(i8.astype(np.float64), axis=1)
     assert np.abs(norms - 127.0).max() < 2.0          # unit norm x 127
 
 
 def test_exact_reference_agrees_with_flat_index():
-    """The script's numpy reference and FlatIndex answer alike — and
-    `compare_exact` refuses an answer that is really different."""
-    import chip_smoke
+    """The numpy reference the smoke and the benchmark share and FlatIndex
+    answer alike — and the benchmark's `exact_ids` rule refuses an answer
+    that is really different."""
     import sptag_tpu as sp
+    from benchmark.datasets import clustered_f32
+    from benchmark.harness import compare, reference
+    from benchmark.loadgen import load_by_name
 
-    data, queries = chip_smoke.make_clustered(5, 3000, 128, 24)
-    ref_ids, ref_scores = chip_smoke.exact_topk(data, queries, 10, "L2")
+    data, queries = clustered_f32.make(5, 3000, 128, 24)
+    ref_ids, _ = reference.exact_topk(data, queries, 10)
     brute = ((queries[:, None, :].astype(np.float64)
               - data[None, :, :].astype(np.float64)) ** 2).sum(-1)
     np.testing.assert_array_equal(
@@ -73,24 +79,33 @@ def test_exact_reference_agrees_with_flat_index():
     index = sp.create_instance("FLAT", "Float")
     index.set_parameter("DistCalcMethod", "L2")
     index.build(data)
-    _, got = index.search_batch(queries, 10)
-    same, ties = chip_smoke.compare_exact(data, queries, got, ref_ids,
-                                          ref_scores, "L2")
-    assert same + ties == len(queries) and same >= len(queries) - 2
+    dists, got = index.search_batch(queries, 10)
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "flat_1m_f32_l2.json")) as f:
+        config = json.load(f)
+    rule, sample = load_by_name("checks", "exact_ids"), np.arange(len(queries))
 
+    def wrong_lists(ids):
+        verdict = rule.check(data, queries, sample,
+                             compare.answers_as_window(ids, dists), config)
+        numbers = {n["name"]: n for n in verdict["numbers"]}
+        return numbers["id_lists_wrong"]
+
+    sound = wrong_lists(got)
+    assert sound["ok"] and sound["value"] == 0
     wrong = np.array(got)
     wrong[0, 9] = int(np.argmax(brute[0]))       # the farthest row
-    with pytest.raises(chip_smoke.SmokeFailure):
-        chip_smoke.compare_exact(data, queries, wrong, ref_ids, ref_scores,
-                                 "L2")
+    altered = wrong_lists(wrong)
+    assert not altered["ok"] and altered["value"] == 1
 
 
 def test_int8_reference_follows_the_integer_cosine_convention():
     import chip_smoke
     import sptag_tpu as sp
+    from benchmark.harness import reference
 
-    data, queries = chip_smoke.make_clustered(6, 3000, 384, 16, np.int8)
-    ref_ids, ref_scores = chip_smoke.exact_topk(data, queries, 10, "Cosine")
+    data, queries = chip_smoke.make_int8(6, 3000, 384, 16)
+    ref_ids, ref_scores = chip_smoke.exact_topk_int8_cosine(data, queries, 10)
     # scores are 127^2 - integer dot: integral, ascending
     assert np.array_equal(ref_scores, np.round(ref_scores))
     assert (np.diff(ref_scores, axis=1) >= 0).all()
@@ -100,7 +115,7 @@ def test_int8_reference_follows_the_integer_cosine_convention():
     dists, got = index.search_batch(queries, 10)
     # integer ties may order differently; the distances must be the same
     np.testing.assert_array_equal(np.asarray(dists, np.float64), ref_scores)
-    assert chip_smoke.recall_at_k(got, ref_ids, 10) >= 0.9
+    assert reference.recall_at_k(got, ref_ids, 10) >= 0.9
 
 
 def test_refuses_on_cpu_without_the_rehearsal_switch():
@@ -113,10 +128,10 @@ def test_refuses_on_cpu_without_the_rehearsal_switch():
 
 
 def test_rehearsal_prints_phase_lines_and_never_ok(tmp_path):
-    """Tiny FLAT phase end to end on the CPU (build CLI -> ini -> server
+    """Tiny beam phase end to end on the CPU (build CLI -> ini -> server
     -> socket clients): phase lines shaped as the contract says, the last
     line the contract's keys, and never `"ok": true`."""
-    proc, lines = _run("--rehearse", "--phases", "flat")
+    proc, lines = _run("--rehearse", "--phases", "bkt")
     assert proc.returncode != 0, proc.stderr[-2000:]
     last = lines[-1]
     assert last["ok"] is False and last["rehearsal"] is True
@@ -124,12 +139,13 @@ def test_rehearsal_prints_phase_lines_and_never_ok(tmp_path):
     assert set(last["device"]) == {"platform", "kind", "count"}
     assert last["device"]["platform"] == "cpu"
     phases = {ln["phase"]: ln for ln in lines[:-1]}
-    assert list(phases) == ["device", "compile_cache", "flat.build",
-                            "flat_1m"]
-    flat = phases["flat_1m"]
-    assert (flat["d"], flat["k"], flat["metric"]) == (128, 10, "L2")
-    assert flat["ids_identical"] + flat["ids_tie_resolved"] \
-        == flat["queries"] > 0
+    assert list(phases) == ["device", "compile_cache", "bkt.build",
+                            "bkt_200k"]
+    bkt = phases["bkt_200k"]
+    assert (bkt["d"], bkt["k"], bkt["metric"]) == (128, 10, "L2")
+    assert bkt["cut"] == f"n 200000 -> {bkt['n']}"
+    assert bkt["beam_recall_at_10"] >= 0.8
+    assert 0 < bkt["beam_self_first"] <= bkt["self_queries"]
     for key in ("seconds", "build_seconds", "compiles", "compile_seconds",
                 "cache_hits"):
-        assert key in flat, key
+        assert key in bkt, key

@@ -147,7 +147,7 @@ def test_crosscheck_beam_segment(Q, L, B, N, D, m, S):
         jnp.zeros((Q,), jnp.int32), jnp.zeros((Q,), jnp.int32),
         10, L, B, S, int(DistCalcMethod.L2), 1, 3, 0,
         None, None, None, None, None).compile()
-    _assert_close("beam.segment", compiled, Q=Q, X=B * m, D=D, W=W)
+    _assert_close("beam.segment", compiled, Q=Q, X=B * m, D=D, W=W, N=N)
 
 
 @pytest.mark.parametrize("Q,L,B,N,D,m,S",
@@ -209,7 +209,8 @@ def test_walk_iter_cost_matches_segment_family():
                               score_dtype="f32")
     est = eng.walk_iter_cost(4, 8)
     ref = costmodel.estimate("beam.segment", Q=4, X=8 * 8, D=16,
-                             W=E._num_words(200), score_itemsize=4)
+                             W=E._num_words(200), score_itemsize=4,
+                             N=200)
     assert est.flops == ref.flops and est.hbm_bytes == ref.hbm_bytes
 
 

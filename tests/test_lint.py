@@ -2483,7 +2483,7 @@ def test_gl100x_silent_on_subpackage_scoped_lint():
     """The contract graph is a whole-package analysis — a scoped lint
     of one subpackage must not report phantom cross-subpackage edges
     (serve/ reads series utils/ publishes, docs rows name core/params
-    specs, the bench vocabulary spans the tree)."""
+    specs)."""
     for sub in ("core", "serve", "utils"):
         root = os.path.join(REPO, "sptag_tpu", sub)
         if not os.path.isdir(root):

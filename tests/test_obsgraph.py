@@ -6,9 +6,6 @@ that need a live process:
 * ``metrics`` cross-kind registration guard (``MetricKindError``) — one
   name must never resolve to two instrument kinds, or the static model
   (and every Prometheus consumer) splits on it;
-* ``benchdiff`` startup catalog validation — a catalog entry whose
-  dotted segments no bench.py artifact key can produce is a config
-  error (exit 2), not a silently-skipped diff row;
 * the schema dump: boot the armed server+aggregator scenario, scrape
   every exposition surface, and diff live names against the static
   ObsModel in BOTH directions.  This is the e2e proof that the lint's
@@ -25,7 +22,6 @@ if REPO not in sys.path:
     sys.path.insert(0, REPO)
 
 from sptag_tpu.utils import metrics  # noqa: E402
-from tools import benchdiff  # noqa: E402
 
 
 # ---------------------------------------------------------------------------
@@ -52,44 +48,6 @@ def test_cross_kind_raises_through_convenience_helpers():
         metrics.set_gauge("obsgraphtest.helper_clash", 2.0)
     with pytest.raises(metrics.MetricKindError):
         metrics.observe("obsgraphtest.helper_clash", 3.0)
-
-
-# ---------------------------------------------------------------------------
-# benchdiff: catalog must match the bench-artifact vocabulary
-# ---------------------------------------------------------------------------
-
-def test_shipped_catalog_validates_clean():
-    assert benchdiff.validate_catalog(repo_root=REPO) == []
-
-
-def test_doctored_catalog_entry_is_flagged():
-    doctored = list(benchdiff.METRICS) + [
-        benchdiff.Metric("mutate.totally_bogus_key", benchdiff.HIGHER,
-                         0.2, 1.0)]
-    problems = benchdiff.validate_catalog(metrics=doctored,
-                                          repo_root=REPO)
-    assert len(problems) == 1
-    assert "totally_bogus_key" in problems[0]
-
-
-def test_doctored_catalog_exits_2_before_artifact_load(monkeypatch,
-                                                       capsys):
-    """The regression that motivated the check: a transposed path like
-    `mutate.p99_steady_ms` must kill the run at startup, not silently
-    skip the row for nine rounds."""
-    monkeypatch.chdir(REPO)
-    monkeypatch.setattr(
-        benchdiff, "METRICS",
-        list(benchdiff.METRICS) + [
-            benchdiff.Metric("mutate.p99_steady_ms", benchdiff.LOWER,
-                             0.25, 10.0)])
-    rc = benchdiff.main(["/nonexistent/base.json",
-                         "/nonexistent/cur.json"])
-    captured = capsys.readouterr()
-    assert rc == 2
-    assert "p99_steady_ms" in captured.err
-    # it never got as far as the artifact loader
-    assert "cannot load artifacts" not in captured.err
 
 
 # ---------------------------------------------------------------------------

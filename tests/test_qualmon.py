@@ -101,15 +101,20 @@ def test_dist_recall_greedy_match():
 
 
 def test_bench_and_cli_delegate_to_qualmon():
-    """The dedup satellite: both consumers call the single canonical
-    function (monkeypatch-visible delegation)."""
-    import bench
+    """One recall: the CLI delegates to the canonical function, and the
+    benchmark's reference (which imports nothing of the program, so it
+    cannot delegate) gives the same value -- `recall_at_10` is what a
+    `qps` of the ledger is stated at."""
+    from benchmark.harness import reference
     from sptag_tpu.tools import index_searcher
 
     ids_all = np.array([[1, 2, 3], [4, 5, -1]])
     truth = [{1, 9, 8}, {4, 5, 6}]
     expect = qualmon.recall_at_k(ids_all, truth, 3)
-    assert bench.recall_at_k(ids_all, truth, 3) == pytest.approx(expect)
+    # the harness takes the reference's (Q, k) id rows, not sets
+    ref_ids = np.array([[1, 9, 8], [4, 5, 6]])
+    assert reference.recall_at_k(ids_all, ref_ids, 3) == \
+        pytest.approx(expect)
     assert index_searcher.calc_recall(ids_all, truth, 3) == \
         pytest.approx(expect)
 

@@ -1,7 +1,6 @@
 """Capability registry + roofline wiring (ISSUE 6): table lookup, the
-disk-cached micro-probe, roofline_row math, the perf_report renderer,
-engine gauges, and THE acceptance e2e — an aggregator over two shard
-servers whose /metrics exposes engine.roofline_pct_peak and
+disk-cached micro-probe, roofline_row math, engine gauges, and THE
+acceptance e2e — an aggregator over two shard servers whose /metrics exposes engine.roofline_pct_peak and
 memory.device_bytes, /debug/memory answers, the slow-query log carries
 per-query GFLOP/s, and serve wire bytes stay byte-identical with the
 new knobs at their defaults."""
@@ -117,29 +116,6 @@ def test_roofline_row_math_and_binding_resource():
     assert row["pct_peak_hbm"] == pytest.approx(50.0)
     assert row["bound"] == "bandwidth"
     assert row["pct_peak"] == row["pct_peak_hbm"]
-
-
-def test_perf_report_renders_bench_artifact():
-    from sptag_tpu.tools import perf_report
-
-    obj = {"platform": "cpu", "flat_qps": 1000.0, "value": 2000.0,
-           "roofline": {
-               "peaks": {"device_kind": "cpu", "source": "probe",
-                         "peak_flops_f32": 1e11, "peak_flops_bf16": 1e11,
-                         "hbm_gbps": 10.0},
-               "rows": {"flat": {"family": "flat.scan",
-                                 "flops_per_query": 10 ** 8,
-                                 "hbm_bytes_per_query": 10 ** 6,
-                                 "achieved_gflops": 100.0,
-                                 "achieved_gbps": 1.0,
-                                 "pct_peak_flops": 0.1,
-                                 "pct_peak_hbm": 10.0,
-                                 "bound": "bandwidth"}}}}
-    lines = perf_report.report_from_bench(obj)
-    text = "\n".join(lines)
-    assert "| flat | flat.scan |" in text
-    assert "bandwidth" in text
-    assert "0.10 TFLOP/s" in text
 
 
 def test_engine_resolves_capability_without_sampling():

@@ -235,21 +235,39 @@ def test_slo_config_from_settings_duck_types_both_tiers():
 
 def test_canary_probe_truth_matches_oracle_exactly():
     """Pinned truth == the oracle's answer, and the probe text
-    round-trips the exact float32 vector (the parity satellite)."""
+    round-trips the exact float32 vector (the parity satellite).
+
+    Bit for bit against the oracle called as the pinning calls it (all
+    probes in one batch).  A probe is REPLAYED alone, and a one-row scan
+    rounds |q|^2 + |x|^2 - 2 q.x otherwise than a six-row one (a
+    self-distance reads 0.0 in one and 1 ulp of the norms, 3.8e-6, in
+    the other): there the ids must be equal and the distances equal by
+    the rule the prober itself judges with (qualmon.recall_row's
+    DEFAULT_DIST_TOL) — this test used to ask 1e-6 relative of a 0.0."""
     idx, data = _flat_index(n=50, d=8)
     ctx = ServiceContext(ServiceSettings())
     ctx.add_index("main", idx)
     probes = canary_mod.probes_from_context(ctx, count=6, k=5)
     assert len(probes) == 6
+    vecs = []
     for p in probes:
         parsed = protocol.parse_query(p.text)
         vec = parsed.extract_vector(idx.value_type, "|")
         assert vec is not None
+        vecs.append(vec)
         ex_d, ex_ids = idx.exact_search_batch(vec.reshape(1, -1), 5)
         assert p.truth_ids == [int(v) for v in ex_ids[0]]
-        assert p.truth_dists == pytest.approx(
-            [float(d) for d in ex_d[0]])
+        for got, want in zip(p.truth_dists, ex_d[0]):
+            assert abs(got - float(want)) <= \
+                qualmon.DEFAULT_DIST_TOL * max(abs(float(want)), 1.0)
         assert parsed.result_num == 5          # $resultnum pins served k
+    # the text round trip is exact: every probe is a corpus row
+    rows = [int(v) for v in np.linspace(0, 49, num=6, dtype=np.int64)]
+    np.testing.assert_array_equal(np.stack(vecs), data[rows])
+    ex_d, ex_ids = idx.exact_search_batch(np.stack(vecs), 5)
+    assert [p.truth_ids for p in probes] == ex_ids.tolist()
+    assert [p.truth_dists for p in probes] == \
+        [[float(d) for d in row] for row in ex_d]
 
 
 def test_admission_canary_exempt_from_fair_shares():

@@ -53,7 +53,7 @@ ARTIFACT_JSON = "autotune.json"
 
 def _git_rev() -> str:
     """Short git rev of the tuned tree; 'unknown' when git is
-    unavailable — never fatal (the bench.py provenance pattern)."""
+    unavailable — never fatal."""
     try:
         out = subprocess.run(
             ["git", "rev-parse", "--short", "HEAD"], cwd=REPO,

@@ -1,13 +1,15 @@
 """benchdiff — the noise-aware perf-regression sentinel (ISSUE 10).
 
-Five BENCH_r*.json snapshots sit in the repo root and until now nothing
-machine-checked that a PR didn't regress QPS-at-SLO or %-of-peak — the
-perf trajectory was tracked by hope.  This tool compares the CURRENT
-bench artifact against a PINNED BASELINE artifact and exits nonzero with
-a readable table when a watched metric regressed:
+Compares a CURRENT artifact against a PINNED BASELINE artifact and exits
+nonzero with a readable table when a watched metric regressed:
 
-    python -m tools.benchdiff BENCH_r05.json BENCH_current.json
+    python -m tools.benchdiff baseline.json current.json
     python -m tools.benchdiff --json baseline.json current.json
+
+The artifacts it was written for came from the pre-chip ``bench.py``,
+removed in PR 29 (the repo is measured by ``python3 -m benchmark.run``);
+what still produces one is ``tools/autotune.py --gate`` (ROADMAP C1b).
+Its tests pin ``tests/fixtures/bench_artifact.json``.
 
 Design decisions, in order of importance:
 
@@ -21,19 +23,17 @@ Design decisions, in order of importance:
   to one measured on ``tpu`` — throughput metrics are skipped with a
   visible note (recall and result-quality metrics still diff; the
   algorithm is platform-independent).
-* **Schema-versioned**: artifacts stamp ``schema_version`` (bench.py);
+* **Schema-versioned**: artifacts stamp ``schema_version``;
   the sentinel diffs the INTERSECTION of watched keys present in both
   artifacts and prints both versions, so a baseline from an older
   schema degrades to fewer checks, never to a false alarm.
 * **Direction-aware**: QPS/recall/%-of-peak regress DOWN, latency
   regresses UP; improvements are reported but never fail the gate.
 
-Driver-wrapped artifacts (``{"parsed": {...}}``) unwrap automatically —
-the same convention as tools/perf_report.py.  Exit codes: 0 pass,
-1 regression, 2 usage/load error.  Wired into tools/ci_check.sh as a
-self-test (identical artifacts must pass; a doctored −20 % loadgen p99
-must fail); the intended PR gate is
-``python -m tools.benchdiff BENCH_r<pinned>.json <fresh bench output>``.
+Driver-wrapped artifacts (``{"parsed": {...}}``) unwrap automatically.
+Exit codes: 0 pass, 1 regression, 2 usage/load error.  Wired into
+tools/ci_check.sh as a self-test (identical artifacts must pass; a
+doctored −20 % loadgen p99 must fail).
 """
 
 from __future__ import annotations
@@ -43,8 +43,7 @@ import json
 import sys
 from typing import Any, Dict, List, Optional, Tuple
 
-#: artifact schema this sentinel was written against (bench.py stamps
-#: the same constant into new artifacts)
+#: artifact schema this sentinel was written against
 SCHEMA_VERSION = 1
 
 HIGHER = "higher"     # regression = value went DOWN
@@ -122,7 +121,7 @@ METRICS: List[Metric] = [
     # silently dead from the day it landed — the stage emits
     # `steady_p99_ms` (this entry watched the transposed
     # `p99_steady_ms`) and emitted no read-throughput key at all
-    # (bench.py now produces `read_qps`)
+    # (the stage then gained `read_qps`)
     Metric("mutate.read_qps", HIGHER, 0.20, 25.0),
     Metric("mutate.steady_p99_ms", LOWER, 0.25, 10.0),
     # in-mesh sharded serving stage (ISSUE 11): the one-dispatch mesh
@@ -155,44 +154,9 @@ METRICS: List[Metric] = [
 ]
 
 
-def validate_catalog(metrics: Optional[List[Metric]] = None,
-                     repo_root: str = ".") -> List[str]:
-    """GL10xx startup contract: every catalog path's dotted segments
-    must appear in the bench-artifact vocabulary (string constants in
-    bench.py + the package) harvested by the observability graph —
-    otherwise the entry can never match an artifact key and the diff
-    silently skips it (how `mutate.p99_steady_ms` stayed dead).
-    Returns human-readable problems; empty = valid.  Harvest failures
-    (no bench.py next to the caller, no package tree) return [] — the
-    static GL1001 pass owns that environment, not the CLI."""
-    import os
-
-    try:
-        from tools.graftlint import obsgraph
-        from tools.graftlint.core import Project
-    except ImportError:
-        return []
-    pkg = os.path.join(repo_root, "sptag_tpu")
-    if not os.path.isdir(pkg):
-        return []
-    model = obsgraph.build_model(Project.from_tree(pkg))
-    if not model.has_bench_vocab:
-        return []
-    problems = []
-    for metric in (METRICS if metrics is None else metrics):
-        bad = obsgraph.unknown_catalog_segments(metric.path,
-                                                model.bench_vocab)
-        if bad:
-            problems.append(
-                f"catalog metric `{metric.path}`: segment(s) "
-                f"{', '.join(repr(b) for b in bad)} unknown to any "
-                "bench.py artifact key")
-    return problems
-
-
 def load_artifact(path: str) -> Dict[str, Any]:
     """Load one bench artifact, unwrapping the driver envelope
-    (``{"parsed": {...}}``) like tools/perf_report.py does."""
+    (``{"parsed": {...}}``)."""
     with open(path, encoding="utf-8") as f:
         obj = json.load(f)
     if not isinstance(obj, dict):
@@ -249,7 +213,7 @@ def judge(metric: Metric, base: float, cur: float) -> Verdict:
     return Verdict(metric, base, cur, "ok", "")
 
 
-#: per-stage compile-count lines (ISSUE 16): bench.py brackets each
+#: per-stage compile-count lines (ISSUE 16): the producer brackets each
 #: `trace.span("bench.X")` stage with a recompile_guard.track_compiles
 #: window, so the artifact's trace dict carries
 #: `xla.backend_compile[bench.X]` spans whose COUNT is the number of
@@ -377,7 +341,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         description="Compare a bench artifact against a pinned baseline "
                     "and fail on perf regressions.")
     parser.add_argument("baseline", help="pinned baseline artifact "
-                        "(e.g. BENCH_r05.json)")
+                        "(e.g. tests/fixtures/bench_artifact.json)")
     parser.add_argument("current", help="freshly produced artifact")
     parser.add_argument("--json", action="store_true",
                         help="emit machine-readable verdicts instead of "
@@ -386,13 +350,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                         help="print every checked metric, not only "
                         "regressions/improvements")
     args = parser.parse_args(argv)
-    problems = validate_catalog()
-    if problems:
-        for p in problems:
-            print(f"benchdiff: {p}", file=sys.stderr)
-        print("benchdiff: metric catalog does not match the bench "
-              "artifact schema (config error)", file=sys.stderr)
-        return 2
     try:
         baseline = load_artifact(args.baseline)
         current = load_artifact(args.current)

@@ -96,10 +96,11 @@ env JAX_PLATFORMS=cpu python -m pytest tests/test_hostprof.py -q \
 # pass; a doctored 20% loadgen-p99 regression fails with a table naming
 # the regressed metric — if this breaks, the perf gate is asleep
 echo "== benchdiff self-test (identity + doctored regression) =="
-python -m tools.benchdiff BENCH_r05.json BENCH_r05.json
+python -m tools.benchdiff tests/fixtures/bench_artifact.json \
+    tests/fixtures/bench_artifact.json
 python - <<'PYEOF'
 import copy, json, os, subprocess, sys, tempfile
-base = json.load(open("BENCH_r05.json"))
+base = json.load(open("tests/fixtures/bench_artifact.json"))
 cur = copy.deepcopy(base)
 broot = base["parsed"] if isinstance(base.get("parsed"), dict) else base
 croot = cur["parsed"] if isinstance(cur.get("parsed"), dict) else cur

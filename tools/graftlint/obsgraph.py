@@ -28,7 +28,7 @@ consumer site, then cross-checks the dataflow:
   through a bounded string evaluator that understands concatenation
   and refined ``base == "server"`` conditionals),
   ``metrics.counter_value/gauge_value/histogram_or_none`` reads,
-  benchdiff's metric catalog, hostprof's ``EXPECTED_ROUTES``
+  hostprof's ``EXPECTED_ROUTES``
   (tests/test_hostprof.py), docs/PARAMETERS.md rows, and
   ``[Service]``/``[Aggregator]``/``[Index]``/``[QueryConfig]`` INI key
   parsing.
@@ -67,8 +67,8 @@ Rules:
   but absent from ``EXPECTED_ROUTES`` (or vice versa): the
   route-contract tests would silently skip the new endpoint.
 
-Cross-tree surfaces (docs/PARAMETERS.md, tests/test_hostprof.py,
-tools/benchdiff.py, bench.py) are consulted only for disk-backed
+Cross-tree surfaces (docs/PARAMETERS.md, tests/test_hostprof.py)
+are consulted only for disk-backed
 projects (``project.source_root``); in-memory fixture projects may
 plant them via ``extra_sources`` (a ``docs/PARAMETERS.md`` key) or
 in-project assignments (``EXPECTED_ROUTES = [...]``).  The runtime
@@ -342,7 +342,7 @@ class SeriesProd:
 class ObsModel:
     """Every producer and consumer of a string-keyed telemetry/config
     name, project-wide.  Built once per lint invocation and shared via
-    ``project.cache[CACHE_KEY]`` (schemadump and benchdiff reuse it)."""
+    ``project.cache[CACHE_KEY]`` (schemadump reuses it)."""
 
     # producers
     metrics: Dict[str, Dict[str, List[Site]]] = \
@@ -373,18 +373,12 @@ class ObsModel:
         dataclasses.field(default_factory=list)
     ini_reads: List[Tuple[str, str, Site]] = \
         dataclasses.field(default_factory=list)   # (section, key, site)
-    benchdiff_paths: List[Tuple[str, Site]] = \
-        dataclasses.field(default_factory=list)
     doc_rows: Dict[str, int] = dataclasses.field(default_factory=dict)
     doc_mentions: Set[str] = dataclasses.field(default_factory=set)
     has_doc: bool = False
     #: docs/tests/tools text for the GL1002 "documented anywhere" check
     corpus: str = ""
     has_corpus: bool = False
-    #: identifier-ish string constants from bench.py + the project —
-    #: the bench-artifact segment vocabulary benchdiff validates against
-    bench_vocab: Set[str] = dataclasses.field(default_factory=set)
-    has_bench_vocab: bool = False
 
     # ------------------------------------------------------------ queries
 
@@ -623,10 +617,6 @@ class _ModuleHarvest:
                     and isinstance(key, ast.Constant) \
                     and isinstance(key.value, str):
                 self.model.ini_reads.append((sec.value, key.value, site))
-        if fn == "Metric" and self.mod.relpath.endswith("benchdiff.py"):
-            arg = _arg(call, 0, "path")
-            if isinstance(arg, ast.Constant) and isinstance(arg.value, str):
-                self.model.benchdiff_paths.append((arg.value, site))
         if modpath == _QUALMON_MODULE and fn == "record_sample":
             arg = _arg(call, 5, "verdict")
             if arg is not None:
@@ -897,7 +887,7 @@ def _harvest_corpus(project: Project, model: ObsModel) -> None:
                                 chunks.append(f.read())
                         except OSError:
                             continue
-        for fname in ("bench.py", "README.md", "ROADMAP.md", "CHANGES.md"):
+        for fname in ("README.md", "ROADMAP.md", "CHANGES.md"):
             full = os.path.join(root, fname)
             if os.path.isfile(full):
                 with open(full, encoding="utf-8") as f:
@@ -906,39 +896,6 @@ def _harvest_corpus(project: Project, model: ObsModel) -> None:
     elif project.extra_sources:
         model.has_corpus = True
     model.corpus = "\n".join(chunks)
-
-
-def _harvest_bench_vocab(project: Project, model: ObsModel) -> None:
-    """Identifier-ish string constants from bench.py plus the project —
-    every dotted segment of a benchdiff catalog path must appear here.
-
-    The vocabulary is only trustworthy when the WHOLE package was
-    parsed (artifact keys originate anywhere in it — e.g. `pct_peak`
-    in utils/roofline.py); a subpackage-scoped lint of a disk tree
-    would see a partial vocabulary and report phantom GL1001s, so it
-    leaves `has_bench_vocab` unset and the benchdiff check silent.
-    In-memory fixture projects are exempt: they are self-contained."""
-    complete = project.source_root is None or any(
-        p.endswith("utils/metrics.py") for p in project.modules)
-    trees: List[ast.AST] = [m.tree for m in project.modules.values()]
-    text = _read_surface(project, "bench.py")
-    if text is not None and complete:
-        try:
-            trees.append(ast.parse(text))
-            model.has_bench_vocab = True
-        except SyntaxError:
-            pass
-    for tree in trees:
-        for node in ast.walk(tree):
-            if isinstance(node, ast.Constant) and \
-                    isinstance(node.value, str) and \
-                    0 < len(node.value) <= 80:
-                val = node.value
-                if _IDENTISH.match(val):
-                    model.bench_vocab.add(val)
-                    for seg in val.split("."):
-                        if seg:
-                            model.bench_vocab.add(seg)
 
 
 # ---------------------------------------------------------------------------
@@ -954,15 +911,12 @@ def build_model(project: Project) -> ObsModel:
         _ModuleHarvest(mod, model).run()
     # cross-tree consumer surfaces (disk-backed projects only, unless a
     # fixture plants them): the route-contract test's EXPECTED_ROUTES,
-    # benchdiff's catalog, the docs, and the GL1002 corpus
+    # the docs, and the GL1002 corpus
     if not any(p.endswith("tests/test_hostprof.py")
                for p in project.modules):
         _harvest_external_module(project, model, "tests/test_hostprof.py")
-    if not any(p.endswith("benchdiff.py") for p in project.modules):
-        _harvest_external_module(project, model, "tools/benchdiff.py")
     _harvest_doc(project, model)
     _harvest_corpus(project, model)
-    _harvest_bench_vocab(project, model)
     project.cache[CACHE_KEY] = model
     return model
 
@@ -1184,30 +1138,6 @@ def _check_routes(model: ObsModel) -> List[Finding]:
     return out
 
 
-def _check_benchdiff(model: ObsModel) -> List[Finding]:
-    if not model.benchdiff_paths or not model.has_bench_vocab:
-        return []
-    out: List[Finding] = []
-    for path, site in model.benchdiff_paths:
-        bad = unknown_catalog_segments(path, model.bench_vocab)
-        if bad:
-            out.append(Finding(
-                "GL1001", site.path, site.line,
-                f"benchdiff catalog metric `{path}` has segment(s) "
-                f"{', '.join(repr(b) for b in bad)} that no bench.py "
-                "artifact key produces — the diff would silently skip "
-                "it", site.symbol))
-    return out
-
-
-def unknown_catalog_segments(path: str, vocab: Set[str]) -> List[str]:
-    """The dotted segments of a benchdiff catalog path absent from the
-    bench-artifact vocabulary (wildcard ``*`` segments are skipped).
-    Shared with tools/benchdiff.py's startup validation."""
-    return [seg for seg in path.split(".")
-            if seg and seg != "*" and seg not in vocab]
-
-
 def _covers_package(project: Project) -> bool:
     """The contract graph is a WHOLE-package analysis: producers and
     consumers live in different subpackages (slo.py reads series that
@@ -1238,5 +1168,4 @@ def check(project: Project) -> List[Finding]:
     out.extend(_check_docs(model))
     out.extend(_check_param_uses(model))
     out.extend(_check_routes(model))
-    out.extend(_check_benchdiff(model))
     return out

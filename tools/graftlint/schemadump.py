@@ -77,8 +77,7 @@ def _http_get(port: int, path: str) -> Tuple[int, str]:
 
 class _LoopThread(threading.Thread):
     """Standalone copy of tests/conftest.py::ServerThread — this module
-    must run without tests/ on sys.path (bench.py keeps the same
-    standalone variant for the same reason).  The stored boot-task
+    must run without tests/ on sys.path.  The stored boot-task
     reference is load-bearing: see the conftest comment."""
 
     def __init__(self, server) -> None:
