@@ -9,9 +9,13 @@ interactive stdin mode (SearchService.cpp:157-199).
 
 TPU reshape: instead of one worker thread per query (boost thread_pool,
 SearchService.cpp:114-130), concurrent requests are COALESCED — an asyncio
-micro-batcher drains whatever queries arrived within `batch_window_ms` and
-executes them as one device batch (service.SearchExecutor.execute_batch),
-which is how the hardware wants its load delivered.
+micro-batcher gathers the queries that are waiting and executes them as one
+device batch (service.SearchExecutor.execute_batch), which is how the
+hardware wants its load delivered.  It waits `batch_window_ms` for company
+only where the last window says waiting brings some: not for a lone caller
+whose window closed on its one request, and not for what queued while a
+batch executed once a window has brought such a backlog next to nothing
+(`_batcher`).
 """
 
 from __future__ import annotations
@@ -57,6 +61,13 @@ def _count_batch(size: int) -> None:
 
 #: body-size ceiling, shared with every framing reader (see wire.py)
 MAX_BODY_LENGTH = wire.MAX_BODY_LENGTH
+
+#: backlogs that leave at once after a window brought one a trickle of
+#: the callers just answered, before the next waits the window again
+#: (`SearchServer._batcher`): one window in 32 cycles costs a saturated
+#: server under 1 % of its time, and a changed crowd of callers is
+#: misjudged for 32 batches at most
+TRICKLE_BATCHES = 32
 
 
 class SearchServer:
@@ -683,28 +694,97 @@ class SearchServer:
 
     # --------------------------------------------------------- batched serve
 
+    def _drain(self, batch: list) -> None:
+        """Move what is queued NOW into `batch`, up to max_batch: no timer."""
+        while len(batch) < self.max_batch:
+            try:
+                batch.append(self._queue.get_nowait())
+            except asyncio.QueueEmpty:
+                return
+
     async def _batcher(self) -> None:
+        """Gather batches; wait `batch_window` only where waiting can
+        bring company, judged by what the last window brought.
+
+        An arrival at an idle server waits the window, unless the last
+        such window, waited in full, closed on one request (`futile`: a
+        lone caller); a batch of more than one undoes that, and so does
+        anything queued when the executor frees (a second caller).
+
+        What is queued when the executor frees waits the window too:
+        callers just answered that come straight back join it (sent on
+        at once, a handful of callers out of step would alternate in
+        half batches for good).  A window that brings back fewer than
+        half as many as were just answered is a trickle: the callers
+        are not back within a window, the next batch is waiting whenever
+        the executor frees, and a window only idles the device.  The
+        next TRICKLE_BATCHES backlogs then leave at once, after the
+        finished batch's replies, before one waits the window again
+        (`trickle` counts them down)."""
+        loop = asyncio.get_event_loop()
         t_prev = None                # the previous batch's t_assembled
+        replied = None               # set once its replies are written
+        answered = 0                 # its size
+        futile = False
+        trickle = 0
         while True:
-            first = await self._queue.get()
+            queued = self._queue.qsize()
+            if queued:
+                futile = False
+            backlog = queued > 0 and trickle > 0
+            if backlog:
+                trickle -= 1
+                if replied is not None:
+                    # the finished batch's replies leave first: once the
+                    # executor thread parses the next batch, the loop
+                    # thread that encodes and writes them waits out its
+                    # hold on the interpreter lock (3.7 ms on the chip:
+                    # PERF.md, PR 31).  A window at most: a connection
+                    # that does not read holds no batch longer than that
+                    t_wait = time.perf_counter()
+                    try:
+                        await asyncio.wait_for(replied.wait(),
+                                               self.batch_window)
+                    except asyncio.TimeoutError:
+                        metrics.inc("server.batch_reply_timeouts")
+                    trace.record("server.batch_reply_wait",
+                                 time.perf_counter() - t_wait)
+            first = await self._queue.get()      # sleeps only when idle
             t_first = time.perf_counter()
             batch = [first]
-            deadline = asyncio.get_event_loop().time() + self.batch_window
-            while len(batch) < self.max_batch:
-                timeout = deadline - asyncio.get_event_loop().time()
-                if timeout <= 0:
-                    break
-                try:
-                    batch.append(await asyncio.wait_for(
-                        self._queue.get(), timeout))
-                except asyncio.TimeoutError:
-                    break
-            t_prev = await self._serve_batch(batch, t_first, t_prev)
+            self._drain(batch)
+            if backlog:
+                metrics.inc("server.gather_backlog")
+            elif futile:
+                metrics.inc("server.gather_lone")
+            else:
+                metrics.inc("server.gather_window")
+                found = len(batch)               # before the window
+                deadline = loop.time() + self.batch_window
+                while len(batch) < self.max_batch:
+                    try:
+                        batch.append(await asyncio.wait_for(
+                            self._queue.get(), deadline - loop.time()))
+                    except asyncio.TimeoutError:
+                        if queued and 2 * (len(batch) - found) < answered:
+                            trickle = TRICKLE_BATCHES
+                        futile = True
+                        break
+                    self._drain(batch)
+            if len(batch) > 1:
+                futile = False
+            answered = len(batch)
+            replied = asyncio.Event()
+            t_prev = await self._serve_batch(batch, t_first, t_prev,
+                                             replied)
 
     async def _serve_batch(self, batch, t_first: float,
-                           t_prev: Optional[float]) -> float:
+                           t_prev: Optional[float],
+                           replied: asyncio.Event) -> float:
         """Execute one gathered batch and hand its responses off; returns
-        the instant the batch was assembled (the next one's `t_prev`)."""
+        the instant the batch was assembled (the next one's `t_prev`).
+        `replied` is set once the batch's replies are with their sockets
+        (or nothing of it is left to answer)."""
         t_assembled = time.perf_counter()
         # the batcher's cycle, from timestamps on either side of awaits
         # (an annotation here would name waiting as if it were work)
@@ -734,6 +814,7 @@ class SearchServer:
             await self._spawn_response_task(
                 self._respond_expired(expired, t_assembled))
             if not batch:
+                replied.set()
                 return t_assembled
         texts = []
         rids = []
@@ -810,9 +891,16 @@ class SearchServer:
             flightrec.record(self.flight_tier, "handoff",
                              payload={"batch": len(batch),
                                       "streamed": len(streamed)})
-        await self._spawn_response_task(
-            self._respond_batch(batch, results, streamed, t_assembled,
-                                t_executed))
+
+        async def respond():
+            try:
+                await self._respond_batch(batch, results, streamed,
+                                          t_assembled, t_executed)
+            finally:
+                replied.set()
+        if self._fault.enabled:
+            replied.set()       # an injected delay holds no batch back
+        await self._spawn_response_task(respond())
         return t_assembled
 
     def _stream_response(self, entry, result, t_assembled: float,
