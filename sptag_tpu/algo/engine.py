@@ -59,7 +59,7 @@ from __future__ import annotations
 
 import functools
 import time
-from typing import Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -69,12 +69,29 @@ from sptag_tpu.core.types import DistCalcMethod
 from sptag_tpu.ops import distance as dist_ops
 from sptag_tpu.ops import topk_bins
 from sptag_tpu.utils import (costmodel, devmem, flightrec, metrics,
-                             query_bucket, recompile_guard, roofline)
+                             query_bucket, recompile_guard, roofline, trace)
 
 MAX_DIST = np.float32(3.4e38)   # plain scalar: module import must NOT init a backend
 
 # visited-table memory budget per search call (bytes)
 _VISITED_BUDGET = 1 << 29
+
+
+class BeamWalk(NamedTuple):
+    """What the jitted walk programs return: the (Q, k) answers, and what
+    the walk did to get them — `live`, per row the trips (body
+    applications of the while loop) in which the row was still alive;
+    each one pops up to B nodes and scores their neighbours.  A loop runs
+    while any row is alive and dead is absorbing, so its trips are
+    `live.max()`.  The count rides the answers' readback; the chunked
+    forms carry a leading chunk axis on all three.  The field names reach
+    the program's StableHLO (`jax.result_info`): with them each beam
+    program has a compile-cache key of its own (core/types.py
+    `DeviceTopK`)."""
+
+    dists: Any
+    ids: Any
+    live: Any
 
 
 def _scatter_true(arr: jax.Array, idx: jax.Array) -> jax.Array:
@@ -188,6 +205,7 @@ def _sorted_dup_mask(ids: jax.Array):
     return _sorted_dedup(ids)[1]
 
 
+@jax.named_scope("beam.seed")
 def _seed_from_pivots(pivot_ids, pivot_vecs, pivot_mask, queries, L: int,
                       metric: int, seed_keep: int = 0):
     """Shared-pivot seeding (BKT): one dense (Q, P) matmul scores the whole
@@ -241,6 +259,7 @@ def _seed_from_pivots(pivot_ids, pivot_vecs, pivot_mask, queries, L: int,
     return cand_ids, cand_d, visited, spare_ids, spare_d
 
 
+@jax.named_scope("beam.seed")
 def _seed_from_seeds(data, sqnorm, seed_ids, queries, L: int, metric: int,
                      base: int, score_scale: float = 0.0):
     """Per-query seeding (KDT): `seed_ids` (Q, S) come from a host-side tree
@@ -544,182 +563,189 @@ def _walk_machine(data, sqnorm, graph, queries, t_limit, k: int, L: int,
         # one compiled program (mixed-MaxCheck slot pools)
         active = _active(no_better, ptr) & (it < t_limit)        # (Q,)
 
-        if merge_bins:
-            # ---- pop best B unexpanded entries: exact RANK-SELECT over
-            # the sorted pool (eligible entries stay ascending around the
-            # MAX_DIST voids, so the first B eligible positions ARE the
-            # best B — same selection, same tie order as the top_k below,
-            # without the L-wide sort)
-            elig = (~expanded[:, :L]) & (cand_d < MAX_DIST)
-            rank = jnp.where(elig,
-                             jnp.cumsum(elig.astype(jnp.int32), axis=1) - 1,
-                             B)                                  # B = drop
-            spos = jax.vmap(
-                lambda r: jnp.full((B,), L, jnp.int32).at[r].set(
-                    jnp.arange(L, dtype=jnp.int32), mode="drop"))(rank)
-            sel_ok = (spos < L) & active[:, None]
-            spos_safe = jnp.minimum(spos, L - 1)
-            sel_d = jnp.where(
-                sel_ok, jnp.take_along_axis(cand_d, spos_safe, axis=1),
-                MAX_DIST)
-            sel_ids = jnp.where(
-                sel_ok, jnp.take_along_axis(cand_ids, spos_safe, axis=1),
-                -1)
-            expanded = _scatter_true(expanded,
-                                     jnp.where(sel_ok, spos_safe, L))
-            best_pop_d = sel_d[:, 0]
-            frontier_worse = best_pop_d > cand_d[:, k_eff - 1]
-        else:
-            # ---- pop best B unexpanded entries ----------------------------
-            sel_score = jnp.where(expanded[:, :L], MAX_DIST, cand_d)
-            sneg, spos = jax.lax.top_k(-sel_score, B)            # (Q, B)
-            sel_ok = ((-sneg) < MAX_DIST) & active[:, None]
-            sel_ids = jnp.where(
-                sel_ok, jnp.take_along_axis(cand_ids, spos, axis=1), -1)
-            expanded = _scatter_true(expanded, jnp.where(sel_ok, spos, L))
-            # "no better propagation": the best popped frontier node is
-            # already farther than the current worst result (reference
-            # increments per such pop, BKTIndex.cpp:139-144; an iteration
-            # here aggregates B pops, so the caller scales the limit by
-            # 1/B)
-            best_pop_d = -sneg[:, 0]
-            frontier_worse = best_pop_d > cand_d[:, k_eff - 1]
+        with jax.named_scope("beam.merge"):
+            if merge_bins:
+                # ---- pop best B unexpanded entries: exact RANK-SELECT over
+                # the sorted pool (eligible entries stay ascending around the
+                # MAX_DIST voids, so the first B eligible positions ARE the
+                # best B — same selection, same tie order as the top_k below,
+                # without the L-wide sort)
+                elig = (~expanded[:, :L]) & (cand_d < MAX_DIST)
+                rank = jnp.where(
+                    elig, jnp.cumsum(elig.astype(jnp.int32), axis=1) - 1,
+                    B)                                           # B = drop
+                spos = jax.vmap(
+                    lambda r: jnp.full((B,), L, jnp.int32).at[r].set(
+                        jnp.arange(L, dtype=jnp.int32), mode="drop"))(rank)
+                sel_ok = (spos < L) & active[:, None]
+                spos_safe = jnp.minimum(spos, L - 1)
+                sel_d = jnp.where(
+                    sel_ok, jnp.take_along_axis(cand_d, spos_safe, axis=1),
+                    MAX_DIST)
+                sel_ids = jnp.where(
+                    sel_ok, jnp.take_along_axis(cand_ids, spos_safe, axis=1),
+                    -1)
+                expanded = _scatter_true(expanded,
+                                         jnp.where(sel_ok, spos_safe, L))
+                best_pop_d = sel_d[:, 0]
+                frontier_worse = best_pop_d > cand_d[:, k_eff - 1]
+            else:
+                # ---- pop best B unexpanded entries
+                sel_score = jnp.where(expanded[:, :L], MAX_DIST, cand_d)
+                sneg, spos = jax.lax.top_k(-sel_score, B)            # (Q, B)
+                sel_ok = ((-sneg) < MAX_DIST) & active[:, None]
+                sel_ids = jnp.where(
+                    sel_ok, jnp.take_along_axis(cand_ids, spos, axis=1), -1)
+                expanded = _scatter_true(expanded, jnp.where(sel_ok, spos, L))
+                # "no better propagation": the best popped frontier node is
+                # already farther than the current worst result (reference
+                # increments per such pop, BKTIndex.cpp:139-144; an iteration
+                # here aggregates B pops, so the caller scales the limit by
+                # 1/B)
+                best_pop_d = -sneg[:, 0]
+                frontier_worse = best_pop_d > cand_d[:, k_eff - 1]
 
-        # ---- gather neighbors, dedupe against visited ---------------------
-        nbrs = graph[jnp.maximum(sel_ids, 0)]                    # (Q, B, m)
-        nbrs = jnp.where(sel_ok[..., None], nbrs, -1)
-        flat = nbrs.reshape(Q, -1)                               # (Q, B*m)
-        flat_safe = jnp.where(flat >= 0, flat, N)
-        seen = _test_bits(visited, flat_safe)
-        if merge_bins:
-            # binned body: NO X-wide sort.  Same-iteration duplicates are
-            # collapsed after the merge's exact top-L (identical ids carry
-            # bit-identical distances and land adjacent there), and the
-            # visited marking is LAZY — only beam entrants are marked, in
-            # the merge below.  `seen` still excludes everything already
-            # in the beam or ever admitted to it (beam ⊆ visited).
-            fresh = (flat >= 0) & ~seen
-        else:
-            # ONE argsort serves both the intra-batch duplicate mask and
-            # the bit marking (the loop previously paid three sorts per
-            # iteration: dup-mask argsort + inverse argsort + mark sort).
-            # Sorting flat_safe keeps invalid ids (-> N) at the END so the
-            # array stays ascending for the segmented-OR marker; the
-            # inverse permutation comes from a scatter, not a second sort.
-            sorted_safe, dup = _sorted_dedup(flat_safe)
-            # a node reached from two popped parents in the SAME iteration
-            # is not yet in `visited` for either copy — dedupe within the
-            # batch or the beam accumulates duplicate entries
-            fresh = (flat >= 0) & ~seen & ~dup
-            # mark ALL valid candidates (OR is idempotent — re-marking
-            # seen ids changes nothing), so the pre-sorted array is
-            # reusable as-is
-            visited = _mark_bits_sorted(visited, sorted_safe)
+        with jax.named_scope("beam.gather"):
+            # ---- gather neighbors, dedupe against visited
+            nbrs = graph[jnp.maximum(sel_ids, 0)]  # (Q, B, m)
+            nbrs = jnp.where(sel_ok[..., None], nbrs, -1)
+            flat = nbrs.reshape(Q, -1)                               # (Q, B*m)
+            flat_safe = jnp.where(flat >= 0, flat, N)
+        with jax.named_scope("beam.merge"):
+            seen = _test_bits(visited, flat_safe)
+            if merge_bins:
+                # binned body: NO X-wide sort.  Same-iteration duplicates are
+                # collapsed after the merge's exact top-L (identical ids carry
+                # bit-identical distances and land adjacent there), and the
+                # visited marking is LAZY — only beam entrants are marked, in
+                # the merge below.  `seen` still excludes everything already
+                # in the beam or ever admitted to it (beam ⊆ visited).
+                fresh = (flat >= 0) & ~seen
+            else:
+                # ONE argsort serves both the intra-batch duplicate mask and
+                # the bit marking (the loop previously paid three sorts per
+                # iteration: dup-mask argsort + inverse argsort + mark sort).
+                # Sorting flat_safe keeps invalid ids (-> N) at the END so the
+                # array stays ascending for the segmented-OR marker; the
+                # inverse permutation comes from a scatter, not a second sort.
+                sorted_safe, dup = _sorted_dedup(flat_safe)
+                # a node reached from two popped parents in the SAME iteration
+                # is not yet in `visited` for either copy — dedupe within the
+                # batch or the beam accumulates duplicate entries
+                fresh = (flat >= 0) & ~seen & ~dup
+                # mark ALL valid candidates (OR is idempotent — re-marking
+                # seen ids changes nothing), so the pre-sorted array is
+                # reusable as-is
+                visited = _mark_bits_sorted(visited, sorted_safe)
 
-        # ---- score fresh candidates (one batched contraction) -------------
-        if nbr_vecs is not None:
-            # packed-neighbor layout (BeamPackedNeighbors): each popped
-            # node's m neighbor VECTORS live contiguously, so the gather
-            # is Q*B block reads of (m, D) instead of Q*B*m scattered
-            # rows — block-granular DMA, the same trick that won in the
-            # dense path, at m x corpus HBM.  Ordering matches `flat`
-            # (both derive from graph-row order); masked slots score
-            # garbage and are discarded by the `fresh` mask exactly like
-            # the row-gather path's index-0 placeholders.
-            sel_safe = jnp.maximum(sel_ids, 0)                   # (Q, B)
-            cvecs = nbr_vecs[sel_safe].reshape(Q, flat.shape[1], -1)
-            csq = nbr_sq[sel_safe].reshape(Q, flat.shape[1])
-        else:
-            gather_idx = jnp.where(fresh, flat, 0)
-            cvecs = score_src[gather_idx]                        # (Q, C, D)
-            csq = sqnorm[gather_idx]
-        if score_scale:
-            # int8 cascade tier (CascadeSearch, ops/cascade.py): the
-            # gathered rows are the int8 quantization of the corpus —
-            # dequantize so in-loop distances stay in (approximately)
-            # the true-distance space the f32-scored seeds live in; the
-            # finalize re-rank restores exact fp distances
-            cvecs = cvecs.astype(jnp.float32) * jnp.float32(score_scale)
-        nd = dist_ops.batched_gathered_distance(
-            queries_s, cvecs, DistCalcMethod(metric), base, csq)
-        nd = jnp.where(fresh, nd, MAX_DIST)
+        with jax.named_scope("beam.gather"):
+            # ---- score fresh candidates (one batched contraction)
+            if nbr_vecs is not None:
+                # packed-neighbor layout (BeamPackedNeighbors): each popped
+                # node's m neighbor VECTORS live contiguously, so the gather
+                # is Q*B block reads of (m, D) instead of Q*B*m scattered
+                # rows — block-granular DMA, the same trick that won in the
+                # dense path, at m x corpus HBM.  Ordering matches `flat`
+                # (both derive from graph-row order); masked slots score
+                # garbage and are discarded by the `fresh` mask exactly like
+                # the row-gather path's index-0 placeholders.
+                sel_safe = jnp.maximum(sel_ids, 0)                   # (Q, B)
+                cvecs = nbr_vecs[sel_safe].reshape(Q, flat.shape[1], -1)
+                csq = nbr_sq[sel_safe].reshape(Q, flat.shape[1])
+            else:
+                gather_idx = jnp.where(fresh, flat, 0)
+                cvecs = score_src[gather_idx]  # (Q, C, D)
+                csq = sqnorm[gather_idx]
+        with jax.named_scope("beam.score"):
+            if score_scale:
+                # int8 cascade tier (CascadeSearch, ops/cascade.py): the
+                # gathered rows are the int8 quantization of the corpus —
+                # dequantize so in-loop distances stay in (approximately)
+                # the true-distance space the f32-scored seeds live in; the
+                # finalize re-rank restores exact fp distances
+                cvecs = cvecs.astype(jnp.float32) * jnp.float32(score_scale)
+            nd = dist_ops.batched_gathered_distance(
+                queries_s, cvecs, DistCalcMethod(metric), base, csq)
+            nd = jnp.where(fresh, nd, MAX_DIST)
 
-        # ---- mid-walk re-seed: inject spare pivots when the frontier falls
-        # behind the next unvisited pivot OR the nbp counter trips with
-        # budget remaining (SearchTrees-on-demand, BKTIndex.cpp:139-155)
-        if use_spares:
-            next_d = jnp.take_along_axis(
-                spare_d, jnp.minimum(ptr, Ps - 1)[:, None], axis=1)[:, 0]
-            stalled = no_better + 1 >= nbp_limit     # would trip this iter
-            trigger = active & (ptr < n_spare) & (
-                (best_pop_d > next_d) | stalled)
-            idxs = ptr[:, None] + jnp.arange(inject, dtype=jnp.int32)
-            ok = trigger[:, None] & (idxs < Ps)
-            safe = jnp.minimum(idxs, Ps - 1)
-            inj_ids = jnp.where(ok, jnp.take_along_axis(spare_ids, safe,
-                                                        axis=1), -1)
-            inj_d = jnp.where(ok & (inj_ids >= 0),
-                              jnp.take_along_axis(spare_d, safe, axis=1),
-                              MAX_DIST)
-            ptr = jnp.where(trigger, ptr + inject, ptr)
-            nd = jnp.concatenate([nd, inj_d], axis=1)
-            flat_m = jnp.concatenate([flat, inj_ids], axis=1)
-        else:
-            trigger = None
-            flat_m = flat
+        with jax.named_scope("beam.merge"):
+            # ---- mid-walk re-seed: inject spare pivots when the frontier
+            # falls behind the next unvisited pivot OR the nbp counter trips
+            # with budget remaining (SearchTrees-on-demand,
+            # BKTIndex.cpp:139-155)
+            if use_spares:
+                next_d = jnp.take_along_axis(
+                    spare_d, jnp.minimum(ptr, Ps - 1)[:, None], axis=1)[:, 0]
+                stalled = no_better + 1 >= nbp_limit     # would trip this iter
+                trigger = active & (ptr < n_spare) & (
+                    (best_pop_d > next_d) | stalled)
+                idxs = ptr[:, None] + jnp.arange(inject, dtype=jnp.int32)
+                ok = trigger[:, None] & (idxs < Ps)
+                safe = jnp.minimum(idxs, Ps - 1)
+                inj_ids = jnp.where(ok, jnp.take_along_axis(spare_ids, safe,
+                                                            axis=1), -1)
+                inj_d = jnp.where(ok & (inj_ids >= 0),
+                                  jnp.take_along_axis(spare_d, safe, axis=1),
+                                  MAX_DIST)
+                ptr = jnp.where(trigger, ptr + inject, ptr)
+                nd = jnp.concatenate([nd, inj_d], axis=1)
+                flat_m = jnp.concatenate([flat, inj_ids], axis=1)
+            else:
+                trigger = None
+                flat_m = flat
 
-        # ---- merge beam + candidates, keep top-L --------------------------
-        all_d = jnp.concatenate([cand_d, nd], axis=1)
-        all_ids = jnp.concatenate([cand_ids, flat_m], axis=1)
-        all_exp = jnp.concatenate(
-            [expanded[:, :L],
-             jnp.zeros((Q, all_d.shape[1] - L), bool)], axis=1)
-        if merge_bins:
-            # bin-reduction merge: strided binning keeps the sorted beam
-            # prefix collision-free (cols 0..L-1 -> distinct bins because
-            # merge_bins >= L); each bin's best survives, then the exact
-            # top-L runs over the bins-wide winner row
-            vals, cols = topk_bins.bin_shortlist(all_d, merge_bins)
-            sh_ids = jnp.take_along_axis(all_ids, cols, axis=1)
-            sh_exp = jnp.take_along_axis(all_exp, cols, axis=1)
-            mneg, mpos = jax.lax.top_k(-vals, L)
-            cand_d = -mneg
-            cand_ids = jnp.take_along_axis(sh_ids, mpos, axis=1)
-            cand_ids = jnp.where(cand_d < MAX_DIST, cand_ids, -1)
-            new_exp = jnp.take_along_axis(sh_exp, mpos, axis=1)
-            # same-iteration multi-parent copies: collapse duplicates
-            # with the exact body's L-wide _sorted_dedup (an
-            # adjacency-only mask would miss copies separated by an
-            # unrelated bit-identical tie — common for integer
-            # distances).  The kept copy is the lowest original
-            # position = the better-ranked one, and the voids (-1 /
-            # MAX_DIST / expanded) keep the pool's eligible subsequence
-            # sorted, which the rank-select pop depends on.  ONE
-            # argsort serves both the dup mask and the lazy visited
-            # marking below.
-            safe_ids = jnp.where(cand_ids >= 0, cand_ids, N)
-            sorted_beam, dup = _sorted_dedup(safe_ids)
-            dup = dup & (cand_ids >= 0)
-            cand_ids = jnp.where(dup, -1, cand_ids)
-            cand_d = jnp.where(dup, MAX_DIST, cand_d)
-            expanded = jnp.concatenate(
-                [new_exp | dup, jnp.zeros((Q, 1), bool)], axis=1)
-            # lazy visited marking: beam ENTRANTS only (an L-wide mark
-            # instead of the exact body's X-wide ensemble; re-marking
-            # resident ids is an idempotent OR, so marking the voided
-            # dup copies too is harmless).  Shortlist-dropped
-            # candidates stay unmarked — rediscoverable via another
-            # parent, which is what keeps the binned walk's recall close
-            # to exact.
-            visited = _mark_bits_sorted(visited, sorted_beam)
-        else:
-            mneg, mpos = jax.lax.top_k(-all_d, L)
-            cand_d = -mneg
-            cand_ids = jnp.take_along_axis(all_ids, mpos, axis=1)
-            cand_ids = jnp.where(cand_d < MAX_DIST, cand_ids, -1)
-            expanded = jnp.concatenate(
-                [jnp.take_along_axis(all_exp, mpos, axis=1),
-                 jnp.zeros((Q, 1), bool)], axis=1)
+            # ---- merge beam + candidates, keep top-L
+            all_d = jnp.concatenate([cand_d, nd], axis=1)
+            all_ids = jnp.concatenate([cand_ids, flat_m], axis=1)
+            all_exp = jnp.concatenate(
+                [expanded[:, :L],
+                 jnp.zeros((Q, all_d.shape[1] - L), bool)], axis=1)
+            if merge_bins:
+                # bin-reduction merge: strided binning keeps the sorted beam
+                # prefix collision-free (cols 0..L-1 -> distinct bins because
+                # merge_bins >= L); each bin's best survives, then the exact
+                # top-L runs over the bins-wide winner row
+                vals, cols = topk_bins.bin_shortlist(all_d, merge_bins)
+                sh_ids = jnp.take_along_axis(all_ids, cols, axis=1)
+                sh_exp = jnp.take_along_axis(all_exp, cols, axis=1)
+                mneg, mpos = jax.lax.top_k(-vals, L)
+                cand_d = -mneg
+                cand_ids = jnp.take_along_axis(sh_ids, mpos, axis=1)
+                cand_ids = jnp.where(cand_d < MAX_DIST, cand_ids, -1)
+                new_exp = jnp.take_along_axis(sh_exp, mpos, axis=1)
+                # same-iteration multi-parent copies: collapse duplicates
+                # with the exact body's L-wide _sorted_dedup (an
+                # adjacency-only mask would miss copies separated by an
+                # unrelated bit-identical tie — common for integer
+                # distances).  The kept copy is the lowest original
+                # position = the better-ranked one, and the voids (-1 /
+                # MAX_DIST / expanded) keep the pool's eligible subsequence
+                # sorted, which the rank-select pop depends on.  ONE
+                # argsort serves both the dup mask and the lazy visited
+                # marking below.
+                safe_ids = jnp.where(cand_ids >= 0, cand_ids, N)
+                sorted_beam, dup = _sorted_dedup(safe_ids)
+                dup = dup & (cand_ids >= 0)
+                cand_ids = jnp.where(dup, -1, cand_ids)
+                cand_d = jnp.where(dup, MAX_DIST, cand_d)
+                expanded = jnp.concatenate(
+                    [new_exp | dup, jnp.zeros((Q, 1), bool)], axis=1)
+                # lazy visited marking: beam ENTRANTS only (an L-wide mark
+                # instead of the exact body's X-wide ensemble; re-marking
+                # resident ids is an idempotent OR, so marking the voided
+                # dup copies too is harmless).  Shortlist-dropped
+                # candidates stay unmarked — rediscoverable via another
+                # parent, which is what keeps the binned walk's recall close
+                # to exact.
+                visited = _mark_bits_sorted(visited, sorted_beam)
+            else:
+                mneg, mpos = jax.lax.top_k(-all_d, L)
+                cand_d = -mneg
+                cand_ids = jnp.take_along_axis(all_ids, mpos, axis=1)
+                cand_ids = jnp.where(cand_d < MAX_DIST, cand_ids, -1)
+                expanded = jnp.concatenate(
+                    [jnp.take_along_axis(all_exp, mpos, axis=1),
+                     jnp.zeros((Q, 1), bool)], axis=1)
 
         # non-live rows FREEZE their counter (see _walk_machine docstring:
         # resetting it on a non-worse frontier made a tripped row's fate
@@ -744,24 +770,34 @@ def _walk(data, sqnorm, graph, deleted, queries, cand_ids, cand_d, visited,
     """Monolithic walk: run the shared body under one `lax.while_loop`
     until no row is alive, then finalize.  `t_limit` is a (Q,) traced
     budget vector (iterations per row) — budgets no longer mint compiles,
-    only (L, B, k) do."""
+    only (L, B, k) do.  -> `BeamWalk`."""
     body, row_alive = _walk_machine(
         data, sqnorm, graph, queries, t_limit, k, L, B, metric, base,
         nbp_limit, spare_ids=spare_ids, spare_d=spare_d, inject=inject,
         data_score=data_score, nbr_vecs=nbr_vecs, nbr_sq=nbr_sq,
         merge_bins=merge_bins, score_scale=score_scale)
 
-    def cond(state):
-        return jnp.any(row_alive(state))
+    def cond(carry):
+        return jnp.any(row_alive(carry[1]))
+
+    def counted(carry):
+        # the count rides BESIDE the state: the body, and with it every
+        # row's trajectory, is the segmented kernel's
+        live, state = carry
+        return live + row_alive(state).astype(jnp.int32), body(state)
 
     state = _init_walk_state(cand_ids, cand_d, visited)
-    cand_ids, cand_d, *_ = jax.lax.while_loop(cond, body, state)
+    live, (cand_ids, cand_d, *_) = jax.lax.while_loop(
+        cond, counted,
+        (jnp.zeros(cand_ids.shape[:1], jnp.int32), state))
     rerank = data_score is not None and data_score.dtype != data.dtype
-    return _finalize(data, sqnorm, deleted, queries, cand_ids, cand_d,
-                     min(k, L), metric, base, rerank,
-                     binned_bins=finalize_bins)
+    final_d, final_ids = _finalize(
+        data, sqnorm, deleted, queries, cand_ids, cand_d, min(k, L),
+        metric, base, rerank, binned_bins=finalize_bins)
+    return BeamWalk(final_d, final_ids, live)
 
 
+@jax.named_scope("beam.finalize")
 def _finalize(data, sqnorm, deleted, queries, cand_ids, cand_d, k_eff: int,
               metric: int, base: int, rerank: bool, binned_bins: int = 0):
     """Walk epilogue shared by the monolithic kernels and the scheduler's
@@ -805,7 +841,8 @@ def _beam_segment_kernel(data, sqnorm, graph, queries, t_limit, cand_ids,
     monolithic walk runs, over loop-carried state passed in and returned
     intact — the device half of the continuous-batching walk
     (algo/scheduler.py).  Returns the updated 7-tuple plus the per-row
-    `alive` flag; a row with alive=False is in the absorbing done state
+    `alive` flag and `live`, per row the iterations of THIS segment it
+    was alive in; a row with alive=False is in the absorbing done state
     (retire it — its pool is final).  Empty slots are encoded as rows
     with t_limit=0 (never alive, body is a no-op on them)."""
     body, row_alive = _walk_machine(
@@ -815,16 +852,18 @@ def _beam_segment_kernel(data, sqnorm, graph, queries, t_limit, cand_ids,
         merge_bins=merge_bins, score_scale=score_scale)
 
     def cond(carry):
-        seg, state = carry
+        seg, _, state = carry
         return (seg < S) & jnp.any(row_alive(state))
 
     def sbody(carry):
-        seg, state = carry
-        return seg + 1, body(state)
+        seg, live, state = carry
+        return (seg + 1, live + row_alive(state).astype(jnp.int32),
+                body(state))
 
     state = (cand_ids, cand_d, expanded, visited, no_better, ptr, it)
-    _, state = jax.lax.while_loop(cond, sbody, (jnp.int32(0), state))
-    return state + (row_alive(state),)
+    _, live, state = jax.lax.while_loop(
+        cond, sbody, (jnp.int32(0), jnp.zeros_like(it), state))
+    return state + (row_alive(state), live)
 
 
 @functools.partial(
@@ -1314,7 +1353,9 @@ class GraphSearchEngine:
                     inject: int = 0) -> Tuple[dict, jax.Array]:
         """Advance every row of `state` by at most S walk iterations;
         returns (new state, (Q,) alive).  Rows with alive=False are done
-        (absorbing) — their pool is final and `finalize` may retire them."""
+        (absorbing) — their pool is final and `finalize` may retire them.
+        `new["live"]` (Q,) counts, per row, the iterations of THIS
+        segment it was alive in (what `_publish_walk` is fed from)."""
         spare_ids = state["spare_ids"]
         sample = False
         if self.device_sample_rate > 0:
@@ -1368,7 +1409,8 @@ class GraphSearchEngine:
                                       "bytes": int(nbytes)})
         new = dict(state)
         (new["cand_ids"], new["cand_d"], new["expanded"], new["visited"],
-         new["no_better"], new["ptr"], new["it"], alive) = out
+         new["no_better"], new["ptr"], new["it"], alive,
+         new["live"]) = out
         return new, alive
 
     def finalize(self, state: dict, k_eff: int
@@ -1383,15 +1425,16 @@ class GraphSearchEngine:
             # host-tier gather needs the pool ids on the host by design
             # (the trace sentinel blesses it; np.asarray here would trip
             # GL902 and, on real accelerators, the transfer guard)
-            ids_np = recompile_guard.device_get(state["cand_ids"])
+            with trace.span("index.readback"):
+                ids_np = recompile_guard.device_get(state["cand_ids"])
             safe = np.clip(ids_np, 0, self.fp_host.shape[0] - 1)
             rows = self.fp_host[safe]
             dead = self._deleted_np[safe]
             d, ids = _beam_finalize_gathered_kernel(
                 jnp.asarray(rows), jnp.asarray(dead), state["queries"],
                 state["cand_ids"], k_eff, int(self.metric), self.base)
-            return (recompile_guard.device_get(d),
-                    recompile_guard.device_get(ids))
+            with trace.span("index.readback"):
+                return recompile_guard.device_get((d, ids))
         rerank = (self.data_score is not None
                   and self.data_score.dtype != self.data.dtype)
         d, ids = _beam_finalize_kernel(
@@ -1400,8 +1443,9 @@ class GraphSearchEngine:
             self.base, rerank,
             binned_bins=self.finalize_bins_for(
                 k_eff, int(state["cand_ids"].shape[1])))
-        return (recompile_guard.device_get(d),
-                recompile_guard.device_get(ids))
+        with trace.span("index.readback"):
+            # the host blocks here until the walk and the re-rank have run
+            return recompile_guard.device_get((d, ids))
 
     def _search_segmented(self, queries: np.ndarray,
                           seeds: Optional[np.ndarray], k_eff: int, L: int,
@@ -1415,6 +1459,7 @@ class GraphSearchEngine:
         nq, D = queries.shape
         out_d = np.zeros((nq, k_eff), np.float32)
         out_i = np.zeros((nq, k_eff), np.int32)
+        trips, live = 0, np.zeros((nq,), np.int64)
         for start in range(0, nq, chunk):
             q = queries[start:start + chunk]
             nqc = q.shape[0]
@@ -1438,13 +1483,49 @@ class GraphSearchEngine:
                 state, alive = self.run_segment(state, t_limit, k_eff, L,
                                                 B, limit, S, inject=inject)
                 # explicit readback: the segment loop's continue-flag is
-                # the intended per-segment sync point
-                if not bool(recompile_guard.device_get(jnp.any(alive))):
+                # the intended per-segment sync point; the segment's live
+                # counts ride it
+                with trace.span("index.readback"):
+                    going, seg_live = recompile_guard.device_get(
+                        (jnp.any(alive), state["live"]))
+                # a row alive in a segment's last iteration was alive in
+                # all of them (dead is absorbing; pad rows never live)
+                trips += int(seg_live.max())
+                live[start:start + nqc] += seg_live[:nqc]
+                if not bool(going):
                     break
             d, ids = self.finalize(state, k_eff)
             out_d[start:start + nqc] = d[:nqc]
             out_i[start:start + nqc] = ids[:nqc]
+        metrics.inc("beam.segmented")
+        self._publish_walk(trips, live, nq, B, L)
         return out_d, out_i
+
+    def _publish_walk(self, trips: int, live, nq: int, B: int,
+                      L: int) -> None:
+        """What the batch just read back walked, from the count its
+        program returned with the answers (the caller has counted which
+        driver ran it: `beam.monolithic` / `.chunked` / `.segmented`):
+        its trips (`beam.trips`, `beam.trips_total`: the `live.max()` of
+        each while loop, so a chunked or segmented batch reads the sum
+        over the chunks it walked one after another), its pool, pivot
+        table and scoring itemsize, and the rows a real query had scored
+        — B pops x the neighbourhood for every trip the query was alive
+        in, over the `nq` real rows (pad rows walk in the monolithic
+        programs and are not counted): the batch's mean as a gauge, and
+        `beam.rows_scored_total` over `beam.queries_total` for a ratio
+        of totals.  docs/TELEMETRY.md; the benchmark's kernel.beam_*
+        read them."""
+        scored = int(np.sum(np.reshape(live, -1)[:nq])) * B \
+            * int(self.graph.shape[1])
+        metrics.inc("beam.trips_total", trips)
+        metrics.inc("beam.rows_scored_total", scored)
+        metrics.inc("beam.queries_total", nq)
+        metrics.set_gauge("beam.trips", trips)
+        metrics.set_gauge("beam.pool", L)
+        metrics.set_gauge("beam.pivots", int(self.pivot_ids.shape[0]))
+        metrics.set_gauge("beam.score_itemsize", self.score_itemsize())
+        metrics.set_gauge("beam.rows_scored_per_query", scored / nq)
 
     def search(self, queries: np.ndarray, k: int, max_check: int = 2048,
                beam_width: int = 16, pool_size: Optional[int] = None,
@@ -1498,7 +1579,7 @@ class GraphSearchEngine:
                     [q, np.zeros((q_pad - nq, D), q.dtype)])
             t_limit = jnp.full((q_pad,), T, jnp.int32)
             if seeds is None:
-                d, ids = _beam_search_kernel(
+                out = _beam_search_kernel(
                     self.data, self.sqnorm, self.graph, self.deleted,
                     self.pivot_ids, self.pivot_vecs, self.pivot_mask,
                     jnp.asarray(q), t_limit,
@@ -1513,7 +1594,7 @@ class GraphSearchEngine:
                     s = np.concatenate(
                         [s, np.full((q_pad - nq, s.shape[1]), -1,
                                     np.int32)])
-                d, ids = _beam_search_seeded_kernel(
+                out = _beam_search_seeded_kernel(
                     self.data, self.sqnorm, self.graph, self.deleted,
                     jnp.asarray(s), jnp.asarray(q), t_limit,
                     k_eff, L, B, int(self.metric), self.base, limit,
@@ -1521,8 +1602,14 @@ class GraphSearchEngine:
                     nbr_vecs=self.nbr_vecs, nbr_sq=self.nbr_sq,
                     merge_bins=mb, finalize_bins=fb,
                     score_scale=self.score_scale)
-            out_d[:, :k_eff] = np.asarray(d)[:nq]
-            out_i[:, :k_eff] = np.asarray(ids)[:nq]
+            with trace.span("index.readback"):
+                # the host blocks here until the program has run; the
+                # walk's count comes back with the answers
+                d, ids, live = recompile_guard.device_get(out)
+            out_d[:, :k_eff] = d[:nq]
+            out_i[:, :k_eff] = ids[:nq]
+            metrics.inc("beam.monolithic")
+            self._publish_walk(int(live.max()), live, nq, B, L)
             return out_d, out_i
         # multi-chunk: one lax.map device program (one upload / dispatch /
         # read — a Python chunk loop pays a synced host round trip once
@@ -1534,7 +1621,7 @@ class GraphSearchEngine:
                 [q, np.zeros((m * chunk - nq, D), q.dtype)])
         t_limit = jnp.full((chunk,), T, jnp.int32)
         if seeds is None:
-            d, ids = _beam_search_chunked(
+            out = _beam_search_chunked(
                 self.data, self.sqnorm, self.graph, self.deleted,
                 self.pivot_ids, self.pivot_vecs, self.pivot_mask,
                 jnp.asarray(q.reshape(m, chunk, D)), t_limit,
@@ -1549,7 +1636,7 @@ class GraphSearchEngine:
                 s = np.concatenate(
                     [s, np.full((m * chunk - nq, s.shape[1]), -1,
                                 np.int32)])
-            d, ids = _beam_search_seeded_chunked(
+            out = _beam_search_seeded_chunked(
                 self.data, self.sqnorm, self.graph, self.deleted,
                 jnp.asarray(s.reshape(m, chunk, -1)),
                 jnp.asarray(q.reshape(m, chunk, D)), t_limit,
@@ -1558,10 +1645,13 @@ class GraphSearchEngine:
                 nbr_vecs=self.nbr_vecs, nbr_sq=self.nbr_sq,
                 merge_bins=mb, finalize_bins=fb,
                 score_scale=self.score_scale)
-        d = np.asarray(d).reshape(m * chunk, -1)
-        ids = np.asarray(ids).reshape(m * chunk, -1)
-        out_d[:, :k_eff] = d[:nq]
-        out_i[:, :k_eff] = ids[:nq]
+        with trace.span("index.readback"):
+            d, ids, live = recompile_guard.device_get(out)
+        out_d[:, :k_eff] = d.reshape(m * chunk, -1)[:nq]
+        out_i[:, :k_eff] = ids.reshape(m * chunk, -1)[:nq]
+        metrics.inc("beam.chunked")
+        # (m, chunk): lax.map walks the chunks one after another
+        self._publish_walk(int(live.max(axis=1).sum()), live, nq, B, L)
         return out_d, out_i
 
 

@@ -391,7 +391,8 @@ def _sharded_beam_kernel(data, sqnorm, graph, deleted, pivot_ids, pivot_vecs,
         n_local = data_s.shape[0]
         shard = jax.lax.axis_index(SHARD_AXIS)
         t_limit = jnp.full((q_s.shape[0],), T, jnp.int32)
-        d, ids = _beam_search_kernel(
+        # the walk's live counts (engine.BeamWalk) are one chip's: unread
+        d, ids, _ = _beam_search_kernel(
             data_s, sqnorm_s, graph_s, deleted_s, pids_s[0], pvecs_s[0],
             pmask_s[0], q_s, t_limit, k_local, L, B, metric, base,
             nbp_limit, merge_bins=merge_bins, finalize_bins=finalize_bins,

@@ -14,8 +14,10 @@ device batch (service.SearchExecutor.execute_batch), which is how the
 hardware wants its load delivered.  It waits `batch_window_ms` for company
 only where the last window says waiting brings some: not for a lone caller
 whose window closed on its one request, and not for what queued while a
-batch executed once a window has brought such a backlog next to nothing
-(`_batcher`).
+batch executed once a window has brought such a backlog next to nothing;
+and past the window, for the callers just answered, where the batch they
+were in held the executor so long that the wait is an eighth of it at
+most (`_batcher`).
 """
 
 from __future__ import annotations
@@ -68,6 +70,15 @@ MAX_BODY_LENGTH = wire.MAX_BODY_LENGTH
 #: server under 1 % of its time, and a changed crowd of callers is
 #: misjudged for 32 batches at most
 TRICKLE_BATCHES = 32
+
+#: how far past its window a gather waits for the callers just answered,
+#: as a share of the time their batch held the executor
+#: (`SearchServer._batcher`): nothing under 16 ms a batch at the default
+#: 2 ms window, 50 ms after a 420 ms graph walk.  128 closed-loop callers
+#: of one generator process are back 15-40 ms after such a batch; where
+#: they are not, the wait is lost once and `trickle` sends the next 32
+#: backlogs on at once
+PATIENCE_SHARE = 1.0 / 8
 
 
 class SearchServer:
@@ -720,11 +731,23 @@ class SearchServer:
         the executor frees, and a window only idles the device.  The
         next TRICKLE_BATCHES backlogs then leave at once, after the
         finished batch's replies, before one waits the window again
-        (`trickle` counts them down)."""
+        (`trickle` counts them down).
+
+        A window whose last batch held the executor more than
+        window / PATIENCE_SHARE goes on past its end, PATIENCE_SHARE of
+        that time at most, until as many requests are here as were
+        queued when the executor freed plus the callers just answered
+        (`server.gather_patient`).  Where a batch is a 420 ms walk and
+        its 128 callers take 20 ms to come back, the window's 2 ms split
+        a closed loop into groups that took turns on the device, each
+        padded to its rung, and how they split (64 + 64, 26 + 102) was
+        the first window's accident and moved with every probe (PERF.md,
+        PR 32)."""
         loop = asyncio.get_event_loop()
         t_prev = None                # the previous batch's t_assembled
         replied = None               # set once its replies are written
         answered = 0                 # its size
+        held = 0.0                   # seconds it held the executor
         futile = False
         trickle = 0
         while True:
@@ -761,11 +784,24 @@ class SearchServer:
                 metrics.inc("server.gather_window")
                 found = len(batch)               # before the window
                 deadline = loop.time() + self.batch_window
+                patience = 0.0
+                if self.batch_window > 0:
+                    patience = max(0.0, PATIENCE_SHARE * held
+                                   - self.batch_window)
+                patient = False                  # past the window
                 while len(batch) < self.max_batch:
+                    if patient and len(batch) >= queued + answered:
+                        break                    # they are all back
                     try:
                         batch.append(await asyncio.wait_for(
                             self._queue.get(), deadline - loop.time()))
                     except asyncio.TimeoutError:
+                        if patience and not patient \
+                                and len(batch) < queued + answered:
+                            metrics.inc("server.gather_patient")
+                            patient = True
+                            deadline += patience
+                            continue
                         if queued and 2 * (len(batch) - found) < answered:
                             trickle = TRICKLE_BATCHES
                         futile = True
@@ -777,6 +813,7 @@ class SearchServer:
             replied = asyncio.Event()
             t_prev = await self._serve_batch(batch, t_first, t_prev,
                                              replied)
+            held = time.perf_counter() - t_prev
 
     async def _serve_batch(self, batch, t_first: float,
                            t_prev: Optional[float],

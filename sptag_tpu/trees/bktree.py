@@ -29,6 +29,7 @@ permutations) on host.
 
 from __future__ import annotations
 
+import heapq
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -250,35 +251,55 @@ class BKTree:
     def num_nodes(self) -> int:
         return len(self.nodes)
 
+    def _subtree_sizes(self) -> np.ndarray:
+        """Nodes under each node, itself included (every sample is some
+        node's centerid, so this is the rows a node stands for)."""
+        cs = self.nodes["childStart"]
+        ce = self.nodes["childEnd"]
+        size = np.ones(len(cs), np.int64)
+        # children are appended after their parents (algo/dense.py's cut
+        # leans on the same): a reverse scan sees them first
+        for ni in np.flatnonzero(cs >= 0)[::-1]:
+            size[ni] += size[cs[ni]:ce[ni]].sum()
+        return size
+
     def collect_pivots(self, max_pivots: int) -> np.ndarray:
-        """BFS over all trees collecting node centerids (actual sample ids)
-        top-down — the dense pivot set that replaces the reference's dynamic
-        tree-descent seeding (InitSearchTrees/SearchTrees, BKTree.h:279-320)
-        with one (Q, n_pivots) matmul at query time."""
+        """Node centerids (actual sample ids) from the top of the trees
+        down, the node that stands for the most rows expanded first — the
+        dense pivot set that replaces the reference's dynamic tree-descent
+        seeding (InitSearchTrees/SearchTrees, BKTree.h:279-320) with one
+        (Q, n_pivots) matmul at query time.
+
+        Largest first keeps the set as dense where the rows are as
+        anywhere else: every pivot ends up standing for about
+        n / max_pivots rows.  Level by level, cut where the budget ends
+        (this function until PR 32), the last level's pivots all went to
+        the first few subtrees: of 195 Gaussian clusters of 512 rows,
+        6-44 by seed held no pivot at all, the walk cannot cross to a
+        cluster no edge leads to, and every query drawn from one read
+        recall 0.0 (100k x 128, 4,166 pivots, MaxCheck 2048: recall@10
+        0.79-0.999 by seed; PERF.md section 2, PR 32)."""
         out: List[int] = []
         seen = set()
-        frontier: List[int] = list(self.tree_starts)
         cs = self.nodes["childStart"]
         ce = self.nodes["childEnd"]
         cid = self.nodes["centerid"]
-        while frontier and len(out) < max_pivots:
-            nxt: List[int] = []
-            for ni in frontier:
-                start = cs[ni]
-                if start < 0:
-                    # leaf or degenerate-duplicate node: nothing to descend
-                    continue
-                for c in range(start, ce[ni]):
-                    sid = int(cid[c])
-                    if sid >= 0 and sid not in seen:
-                        seen.add(sid)
-                        out.append(sid)
-                        if len(out) >= max_pivots:
-                            break
-                    nxt.append(c)
-                if len(out) >= max_pivots:
-                    break
-            frontier = nxt
+        size = self._subtree_sizes()
+        # ties: the lower node index, which is the older order's
+        heap = [(-int(size[t]), int(t)) for t in self.tree_starts]
+        heapq.heapify(heap)
+        while heap and len(out) < max_pivots:
+            _, ni = heapq.heappop(heap)
+            if cs[ni] < 0:
+                # leaf or degenerate-duplicate node: nothing to descend
+                continue
+            for c in range(cs[ni], ce[ni]):
+                sid = int(cid[c])
+                if sid >= 0 and sid not in seen:
+                    seen.add(sid)
+                    out.append(sid)
+                if cs[c] >= 0:
+                    heapq.heappush(heap, (-int(size[c]), c))
         return np.asarray(out[:max_pivots], np.int32)
 
     # ------------------------------------------------------------ persistence

@@ -9,9 +9,12 @@ What is queued when the executor frees waits the window too, until one
 brings back fewer than half as many as were just answered: the next
 TRICKLE_BATCHES backlogs leave at once, each as one batch
 (`server.gather_backlog`), after the finished batch's replies, before
-one waits the window again.  Under test: a real SearchServer over a tiny
-FLAT index, its executor wrapped so a test can hold it busy and read the
-batches it was handed.
+one waits the window again.  Past its window a gather waits for the
+callers just answered where their batch held the executor more than
+window / PATIENCE_SHARE, that share of its time at most
+(`server.gather_patient`; ISSUE 32).  Under test: a real SearchServer over
+a tiny FLAT index, its executor wrapped so a test can hold it busy and read
+the batches it was handed.
 """
 
 import asyncio
@@ -409,6 +412,108 @@ def test_a_backlog_waits_for_replies_that_take_their_time(served, held_up):
     assert low <= waited.sum < high
     assert metrics.counter_value("server.batch_reply_timeouts") == (
         held_up == "stuck_send")
+
+
+@pytest.mark.parametrize("back_after", ["window", "never", "short"])
+def test_a_long_batch_earns_its_callers_a_wait_past_the_window(
+        served, back_after):
+    """A batch that held the executor 1.6 s (a graph walk's, scaled up
+    for a sandbox's clock) earns its four callers an eighth of that:
+    back 0.1 s after a 0.04 s window they share ONE batch with what
+    queued behind them, sent on the moment the last is back.  Never
+    back, the wait ends after 0.2 s and the trickle rule takes over;
+    after a batch of 0.1 s no gather goes past its window at all."""
+    window_s = 0.04
+    pad_s = 0.1 if back_after == "short" else 1.6
+    s = served(batch_window_ms=1e3 * window_s, pad_s=pad_s,
+               connections=2)
+    s.send(4)                            # one write: one window, one batch
+    s.wait_batches(1)
+    s.send(2, conn=1)                    # queue while it executes
+    s.read(4)
+    t_read = time.perf_counter()
+    if back_after != "never":
+        time.sleep(0.1)                  # past the window
+        s.send(4)
+    s.read(2, conn=1)
+    if back_after != "never":
+        s.read(4)
+    patient = metrics.counter_value("server.gather_patient")
+    gather = metrics.histogram("server.batch_gather")
+    if back_after == "window":
+        assert s.held.sizes == [4, 6]
+        assert patient == 1
+        # all six were there 0.1 s in: the rest of the 0.2 s was not
+        # waited (the server's clock, less the first batch's window)
+        assert 0.08 <= gather.sum - window_s < 0.19
+    elif back_after == "never":
+        assert s.held.sizes == [4, 2]
+        assert patient == 1
+        assert time.perf_counter() - t_read >= pad_s / 8 - 0.01
+        assert 0.19 <= gather.sum - window_s < 0.4
+    else:
+        assert s.held.sizes == [4, 2, 4]
+        assert patient == 0
+        assert gather.sum < 4 * window_s
+    got = _gathers()
+    # (after the short batch the four come back while the two execute
+    # or after: a backlog sent on at once, or a window)
+    assert got["window"] + got["backlog"] == len(s.held.sizes)
+    assert got["window"] >= 2 and got["lone"] == 0
+
+
+@pytest.mark.parametrize("share,whole", [(server_module.PATIENCE_SHARE,
+                                          True), (0.0, False)])
+def test_callers_out_of_step_behind_long_batches_end_up_in_one(
+        served, monkeypatch, share, whole):
+    """Eight closed-loop callers that join 60 ms apart and take 12 ms
+    to come back with their next request (a generator process with 128
+    of them takes 15-40), behind batches of 0.24 s and a window of 4 ms:
+    with the wait past the window they are ONE batch a cycle within a
+    few cycles; without it (the share set to nothing: the batcher before
+    ISSUE 32) they go on in the groups they joined in, taking turns on
+    the executor."""
+    monkeypatch.setattr(server_module, "PATIENCE_SHARE", share)
+    callers = 8
+    s = served(batch_window_ms=4.0, connections=callers, pad_s=0.24)
+    stop = threading.Event()
+
+    def loop(conn):
+        time.sleep(0.06 * conn)
+        while not stop.is_set():
+            s.ask(conn)
+            time.sleep(0.012)
+    threads = [threading.Thread(target=loop, args=(c,), daemon=True,
+                                name=f"caller-{c}")
+               for c in range(callers)]
+    try:
+        for t in threads:
+            t.start()
+        s.wait_for(lambda: len(s.held.sizes) >= 14,
+                   "the callers are not served")
+        tail = s.held.sizes[8:14]
+    finally:
+        stop.set()
+        for t in threads:
+            t.join(20)
+    if whole:
+        assert sum(size == callers for size in tail) >= 5, s.held.sizes
+        assert metrics.counter_value("server.gather_patient") >= 1
+    else:
+        assert max(tail) < callers, s.held.sizes
+        assert metrics.counter_value("server.gather_patient") == 0
+
+
+def test_a_gather_without_a_window_is_never_patient(served):
+    """`batch_window_ms` 0 asks for no waiting: none is added."""
+    s = served(batch_window_ms=0.0, pad_s=0.8, connections=2)
+    s.send(4)
+    s.wait_batches(1)
+    s.send(2, conn=1)
+    s.read(4)
+    s.read(2, conn=1)
+    assert metrics.counter_value("server.gather_patient") == 0
+    assert metrics.histogram("server.batch_gather").sum < 0.05
 
 
 @pytest.mark.parametrize("callers", [1, 3, 8])

@@ -30,7 +30,7 @@ from conftest import ServerThread
 from sptag_tpu.serve.client import AnnClientPool
 from sptag_tpu.serve.server import SearchServer
 from sptag_tpu.serve.service import ServiceContext, ServiceSettings
-from sptag_tpu.utils import recompile_guard, trace
+from sptag_tpu.utils import metrics, recompile_guard, trace
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 #: what a run off the chip can fill; `device_trace` needs the chip's
@@ -39,44 +39,68 @@ OFF_CHIP_SOURCES = ("program_span", "program_counter")
 K, BURST, BURSTS = 5, 8, 3
 
 with open(os.path.join(REPO, "BENCHMARK.json")) as _f:
-    PER_LAYER = [m["name"] for m in json.load(_f)["per_layer"]
-                 if m["source"] in OFF_CHIP_SOURCES]
+    _BENCH = json.load(_f)
 
 
-@pytest.fixture(scope="module")
-def run():
-    """A warm-up burst (set-up: it compiles the rung), then a window of
-    BURSTS bursts -> the keys of run_cell's `run` that need no chip."""
-    data = np.random.default_rng(0).standard_normal((200, 8)).astype(
-        np.float32)
-    index = sp.create_instance("FLAT", "Float")
-    index.set_parameter("DistCalcMethod", "L2")
-    index.build(data)
+def _walks(cell: str) -> bool:
+    """Whether the cell's configuration serves `SearchMode=beam`."""
+    cells = {w["name"]: w for w in _BENCH["workloads"]}
+    files = {c["name"]: c["file"] for c in _BENCH["configs"]}
+    with open(os.path.join(REPO, files[cells[cell]["config"]])) as f:
+        return json.load(f)["index_params"].get("SearchMode") == "beam"
+
+
+#: metrics listed for beam cells alone read what the walk publishes: held
+#: against a served BKT index in SearchMode=beam, further down
+BEAM_ONLY = [m["name"] for m in _BENCH["per_layer"]
+             if m.get("workloads") and all(map(_walks, m["workloads"]))]
+PER_LAYER = [m["name"] for m in _BENCH["per_layer"]
+             if m["source"] in OFF_CHIP_SOURCES
+             and m["name"] not in BEAM_ONLY]
+
+
+def _served_bursts(index, rows, warm: int, bursts: int) -> dict:
+    """`warm` bursts of one query a row of `rows` through a SearchServer
+    in front of `index` (set-up: they compile the rung), then a window of
+    `bursts` more -> the keys of run_cell's `run` that need no chip.
+    Every answer's first id is its row's own."""
     ctx = ServiceContext(ServiceSettings(default_max_result=K))
     ctx.add_index("main", index)
     thread = ServerThread(SearchServer(ctx, batch_window_ms=20.0,
                                        max_batch=BURST))
     thread.start()
     host, port = thread.wait_ready()
-    texts = [serving.query_text("main", K, row) for row in data[:BURST]]
+    texts = [serving.query_text("main", K, row) for row in rows]
     try:
-        with AnnClientPool(host, port, connections=2, timeout_s=60.0,
+        with AnnClientPool(host, port, connections=2, timeout_s=120.0,
                            max_workers=BURST) as pool:
             def burst():
                 answers = [f.result() for f in
                            [pool.search_async(t) for t in texts]]
                 assert [a.results[0].ids[0] for a in answers] \
-                    == list(range(BURST))
+                    == list(range(len(texts)))
 
-            burst()
+            for _ in range(warm):
+                burst()
             before = trace.report()
             with recompile_guard.track_compiles("benchmark.window") as log:
-                for _ in range(BURSTS):
+                for _ in range(bursts):
                     burst()
             spans = span_deltas(before, trace.report())
     finally:
         thread.stop()
     return {"spans": spans, "compiles_in_window": log.count}
+
+
+@pytest.fixture(scope="module")
+def run():
+    """A tiny FLAT index: a warm-up burst, then a window of BURSTS."""
+    data = np.random.default_rng(0).standard_normal((200, 8)).astype(
+        np.float32)
+    index = sp.create_instance("FLAT", "Float")
+    index.set_parameter("DistCalcMethod", "L2")
+    index.build(data)
+    return _served_bursts(index, data[:BURST], 1, BURSTS)
 
 
 def test_benchmark_lists_readers_that_need_no_chip():
@@ -93,3 +117,86 @@ def test_reader_returns_a_number_from_the_served_program(run, metric):
         f"recorded under that name any more (spans seen: "
         f"{sorted(run['spans'])})")
     assert math.isfinite(value) and value >= 0
+
+
+# ---- the walk's readers (PR 32) -------------------------------------------
+
+@pytest.fixture(scope="module")
+def walked_index():
+    """A tiny BKT index whose parameters say SearchMode=beam."""
+    data = np.random.default_rng(1).standard_normal((400, 16)).astype(
+        np.float32)
+    index = sp.create_instance("BKT", "Float")
+    index.set_parameter("DistCalcMethod", "L2")
+    for name, value in [("BKTNumber", "1"), ("BKTKmeansK", "8"),
+                        ("Samples", "200"), ("TPTNumber", "2"),
+                        ("TPTLeafSize", "50"), ("NeighborhoodSize", "8"),
+                        ("CEF", "64"), ("MaxCheckForRefineGraph", "128"),
+                        ("RefineIterations", "1"), ("SearchMode", "beam"),
+                        ("MaxCheck", "256")]:
+        assert index.set_parameter(name, value), name
+    assert index.build(data) == sp.ErrorCode.Success
+    yield index, data
+    index.close()
+
+
+def _walked_run(index, data) -> dict:
+    """Two bursts through a SearchServer in front of the beam index.
+    Counters are read live by the readers, so this runs INSIDE each test
+    (conftest empties the registries before every test)."""
+    return _served_bursts(index, data[:BURST], 0, 2)
+
+
+def test_benchmark_lists_the_walks_readers():
+    assert {"kernel.beam_walk_roofline",
+            "kernel.beam_trips_per_batch"} <= set(BEAM_ONLY)
+
+
+def test_trips_reader_returns_a_number_from_the_served_walk(walked_index):
+    run = _walked_run(*walked_index)
+    value = load_by_name("layer_metrics",
+                         "kernel.beam_trips_per_batch").read(run)
+    plan = walked_index[0]._get_engine().walk_plan(K, 256)
+    assert isinstance(value, float) and 1 <= value <= plan[3]
+    # the span the walk's device wait is read from (index.device_wait_ms)
+    assert load_by_name("layer_metrics",
+                        "index.device_wait_ms").read(run) > 0
+    assert load_by_name("layer_metrics", "index.dispatch_ms").read(run) > 0
+
+
+def test_roofline_reader_finds_what_the_walk_publishes(walked_index):
+    """The chip's trace aside, kernel.beam_walk_roofline needs the walk's
+    two totals (rows scored over real queries), three gauges and the
+    beam programs' names."""
+    from sptag_tpu.algo import engine
+
+    reader = load_by_name("layer_metrics", "kernel.beam_walk_roofline")
+    assert reader.walked_per_query() is None        # nothing walked yet
+    run = _walked_run(*walked_index)
+    eng = walked_index[0]._get_engine()
+    _, L, B, T, _ = eng.walk_plan(K, 256)
+    rows, pool, pivots, itemsize = reader.walked_per_query()
+    assert 0 < rows <= T * B * 8 and pool == L and itemsize == 4
+    assert rows == metrics.counter_value("beam.rows_scored_total") \
+        / metrics.counter_value("beam.queries_total")
+    assert pivots == eng.pivot_ids.shape[0]
+    for program in reader.ENTRY + reader.REST:
+        assert program.startswith("jit_")
+        assert hasattr(engine, program[len("jit_"):]), program
+    # a hand-made slice: two batches of 8 queries in 1 ms of device time
+    run.update(trace={"programs": {"jit__beam_search_kernel":
+                                   {"runs": 2, "seconds": 1e-3}}},
+               config={"dim": 16},
+               peaks={"bf16_flops_per_s": 197e12,
+                      "hbm_bytes_per_s": 819e9})
+    assert 0 < reader.read(run) <= 100
+    assert reader.read({**run, "trace": None}) is None
+
+
+def test_the_walks_readers_read_nothing_without_a_walk(run):
+    """The parent's program, or a cell that walks no graph: None, no
+    raise."""
+    for metric in BEAM_ONLY:
+        assert load_by_name("layer_metrics", metric).read(
+            {**run, "trace": {"programs": {}}, "config": {"dim": 8},
+             "peaks": {}}) is None

@@ -96,3 +96,34 @@ def test_collect_pivots():
     assert 0 < len(piv) <= 64
     assert np.all((piv >= 0) & (piv < n))
     assert len(np.unique(piv)) == len(piv)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_pivots_are_as_dense_where_the_rows_are_as_anywhere(seed):
+    """The largest subtree is expanded first: a budget of n / 24 pivots
+    leaves no cluster without one and none with many times its share
+    (cut level by level at the budget, the last level's pivots all went
+    to the first few subtrees: ISSUE 32)."""
+    rng = np.random.default_rng(seed)
+    clusters, per, dim = 24, 250, 16
+    centers = rng.standard_normal((clusters, dim)).astype(np.float32) * 4
+    label = np.repeat(np.arange(clusters), per)
+    data = centers[label] + rng.standard_normal(
+        (clusters * per, dim)).astype(np.float32)
+    tree = BKTree(tree_number=1, kmeans_k=8, leaf_size=8, samples=1000)
+    tree.build(data, seed=seed)
+    budget = len(data) // 24
+    piv = tree.collect_pivots(budget)
+    assert len(piv) == budget == len(np.unique(piv))
+    held = np.bincount(label[piv], minlength=clusters)
+    assert held.min() >= 1, held
+    assert held.max() <= 4 * budget / clusters, held
+    # a node's rows: itself and everything under it (the root: every
+    # node but the sentinel the reference format ends on)
+    size = tree._subtree_sizes()
+    assert [int(size[t]) for t in tree.tree_starts] \
+        == [len(tree.nodes) - 1]
+    # the whole budget's worth and more: every centre, once
+    every = tree.collect_pivots(10 * len(data))
+    assert len(every) == len(np.unique(every)) <= len(data)
+    assert set(piv) <= set(every)

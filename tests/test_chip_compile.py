@@ -276,6 +276,31 @@ def test_beam_monolithic_walk_compiles_bf16_scoring(one_chip):
         nbp_limit=limit, inject=4, data_score=a["data_score"]).compile()
 
 
+@pytest.mark.parametrize("Q", [1, 8, 32, 128])
+def test_beam_cell_rungs_compile_at_100k(one_chip, Q):
+    """`bkt_100k_beam.saturate`'s own programs (PR 32): 100k x 128, the
+    n / 24 = 4,166 pivots, MaxCheck 2048's plan (L 320, B 64), one per
+    warm bucket; each returns the answers and the walk's live counts."""
+    from sptag_tpu.algo import engine
+
+    n, D = 100_000, 128
+    L = engine.beam_pool_size(K, 2048, n)
+    B = engine.beam_width_for(16, 2048, L)
+    assert (L, B) == (320, 64)
+    a = _engine_arrays(one_chip, n, D, pivots=n // 24)
+    lowered = engine._beam_search_kernel.lower(
+        a["data"], a["sqnorm"], a["graph"], a["deleted"], a["pivot_ids"],
+        a["pivot_vecs"], a["pivot_mask"], _s(one_chip, (Q, D), jnp.float32),
+        _s(one_chip, (Q,), jnp.int32), k=K, L=L, B=B, metric=L2, base=1,
+        nbp_limit=3, inject=4, data_score=a["data_score"])
+    out = lowered.out_info
+    assert [o.shape for o in out] == [(Q, K), (Q, K), (Q,)]
+    compiled = lowered.compile()
+    assert "bf16" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 << 30
+
+
 # ---------------------------------------------------------------------------
 # four chips: one program across a (4,) mesh of the described devices
 # ---------------------------------------------------------------------------
