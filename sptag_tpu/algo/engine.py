@@ -133,11 +133,14 @@ def _mark_bits(words: jax.Array, ids: jax.Array) -> jax.Array:
     return _mark_bits_sorted(words, jnp.sort(ids, axis=1))
 
 
-def _mark_bits_sorted(words: jax.Array, s: jax.Array) -> jax.Array:
+def _mark_bits_sorted(words: jax.Array, s: jax.Array,
+                      existing: Optional[jax.Array] = None) -> jax.Array:
     """_mark_bits for ids already sorted ascending along axis 1 — the walk
-    shares one argsort between duplicate detection and bit marking
+    shares one sort between duplicate detection and bit marking
     (marking is an OR, so re-marking already-visited ids is a no-op and
-    the caller can pass ALL valid candidates, not just fresh ones)."""
+    the caller can pass ALL valid candidates, not just fresh ones).
+    `existing` (Q, X): the words of `s` (`words` at `s >> 5`) where the
+    caller has gathered them already (`_walk_machine`'s visited test)."""
     Q, X = s.shape
     W = words.shape[1]
     w = jnp.right_shift(s, 5)
@@ -147,7 +150,8 @@ def _mark_bits_sorted(words: jax.Array, s: jax.Array) -> jax.Array:
     run_or = _seg_or(b, first)
     last = jnp.concatenate(
         [w[:, 1:] != w[:, :-1], jnp.ones((Q, 1), bool)], axis=1)
-    existing = jnp.take_along_axis(words, w, axis=1)
+    if existing is None:
+        existing = jnp.take_along_axis(words, w, axis=1)
     val = existing | run_or
     target = jnp.where(last, w, W)          # W = out of bounds -> dropped
     return jax.vmap(
@@ -186,9 +190,13 @@ def _sorted_dedup(ids: jax.Array) -> Tuple[jax.Array, jax.Array]:
     One argsort serves both outputs: `dup` is True on every occurrence of
     an id after the first (in original positions — the inverse permutation
     comes from a SCATTER, not a second sort), and the sorted array feeds
-    `_mark_bits_sorted` directly.  Shared by the walk's per-iteration
-    dedupe, the seeded kernel's seed dedupe, and the dense epilogue's
-    replica dedupe — previously three near-copies costing three sorts."""
+    `_mark_bits_sorted` directly.  Called by the seeded kernel's seed
+    dedupe, the dense epilogue's replica dedupe (`algo/dense.py`), the
+    binned walk body's L-wide pool dedupe and the packed-neighbour
+    layout's walk, whose vectors arrive in graph order.  The walk's
+    exact body with the row-gather layout no longer calls it: a trip's
+    candidates stay in sorted order there (`_sorted_fresh`), and the
+    two gathers and the scatter that carry the mask back fall away."""
     Q = ids.shape[0]
     order = jnp.argsort(ids, axis=1, stable=True)
     sorted_ids = jnp.take_along_axis(ids, order, axis=1)
@@ -203,6 +211,36 @@ def _sorted_dedup(ids: jax.Array) -> Tuple[jax.Array, jax.Array]:
 def _sorted_dup_mask(ids: jax.Array):
     """(Q, X) int -> (Q, X) bool duplicate mask (see _sorted_dedup)."""
     return _sorted_dedup(ids)[1]
+
+
+def dedup_in_sorted_order(merge_bins: int, packed: bool) -> bool:
+    """Whether a walk body keeps a trip's candidates in ascending-id
+    order from the de-duplication to the merge (`_sorted_fresh`): the
+    exact body (`merge_bins == 0`) that gathers its rows by id.  The
+    packed-neighbour layout reads vectors in graph order, so its mask has
+    to come back to original positions (`_sorted_dedup`); the binned
+    body has no X-wide sort.  `_walk_machine` traces by this rule and
+    `GraphSearchEngine._publish_walk` counts by it."""
+    return not merge_bins and not packed
+
+
+def _sorted_fresh(visited: jax.Array, flat_safe: jax.Array, n: int):
+    """The walk's visited / de-duplicate ensemble in sorted-id order.
+    `flat_safe` (Q, X): a trip's candidate ids, `n` in the holes.  ->
+    (ids (Q, X) ascending with -1 after them, fresh (Q, X) bool: first
+    occurrence of an id not in `visited`, visited with every valid id
+    marked).  One sort, ONE gather of `visited` words: it serves the
+    test and the marker's `existing`, and nothing is carried back to
+    the order the candidates came in."""
+    Q = flat_safe.shape[0]
+    s = jnp.sort(flat_safe, axis=1)
+    got = jnp.take_along_axis(visited, jnp.right_shift(s, 5), axis=1)
+    seen = (jnp.right_shift(got, s & 31) & 1).astype(bool)
+    dup = jnp.concatenate(
+        [jnp.zeros((Q, 1), bool), s[:, 1:] == s[:, :-1]], axis=1)
+    valid = s < n
+    return (jnp.where(valid, s, -1), valid & ~seen & ~dup,
+            _mark_bits_sorted(visited, s, existing=got))
 
 
 @jax.named_scope("beam.seed")
@@ -515,6 +553,7 @@ def _walk_machine(data, sqnorm, graph, queries, t_limit, k: int, L: int,
         assert merge_bins >= L, (merge_bins, L)
     Q = queries.shape[0]
     N = data.shape[0]
+    sorted_order = dedup_in_sorted_order(merge_bins, nbr_vecs is not None)
     score_src = data_score if data_score is not None else data
     # the bf16-shadow cast only applies between FLOAT dtypes: an int8
     # scoring corpus (score_scale below) keeps f32 queries — the
@@ -612,8 +651,14 @@ def _walk_machine(data, sqnorm, graph, queries, t_limit, k: int, L: int,
             flat = nbrs.reshape(Q, -1)                               # (Q, B*m)
             flat_safe = jnp.where(flat >= 0, flat, N)
         with jax.named_scope("beam.merge"):
-            seen = _test_bits(visited, flat_safe)
-            if merge_bins:
+            if sorted_order:
+                # the candidates go into ascending-id order ONCE and stay
+                # there: nothing downstream needs the graph's order (the
+                # merge is a top_k over distances, the row and norm
+                # gathers take any order, the spares are appended after)
+                flat, fresh, visited = _sorted_fresh(visited, flat_safe, N)
+            elif merge_bins:
+                seen = _test_bits(visited, flat_safe)
                 # binned body: NO X-wide sort.  Same-iteration duplicates are
                 # collapsed after the merge's exact top-L (identical ids carry
                 # bit-identical distances and land adjacent there), and the
@@ -622,6 +667,9 @@ def _walk_machine(data, sqnorm, graph, queries, t_limit, k: int, L: int,
                 # in the beam or ever admitted to it (beam ⊆ visited).
                 fresh = (flat >= 0) & ~seen
             else:
+                # packed-neighbour layout: the vectors arrive in graph
+                # order, so the mask is needed in original positions
+                seen = _test_bits(visited, flat_safe)
                 # ONE argsort serves both the intra-batch duplicate mask and
                 # the bit marking (the loop previously paid three sorts per
                 # iteration: dup-mask argsort + inverse argsort + mark sort).
@@ -905,11 +953,14 @@ def _beam_finalize_gathered_kernel(rows, dead, queries, cand_ids,
 # by their own iteration counts.
 
 def _walk_iter_cost(Q, X, D, W, score_itemsize=4, merge_bins=0, L=0, N=0,
-                    score_scale=0, **_):
+                    score_scale=0, packed=False, **_):
     """One _walk_machine body application at batch Q: the B*m = X
     candidate gather + scoring contraction dominates; the fitted
-    WALK_SORT_* constants carry the argsort/segmented-scan/top-k
-    ensemble (calibrated against HloCostAnalysis; tests pin ±15%).
+    WALK_SORTED_* constants carry the sort/segmented-scan/top-k
+    ensemble in sorted-id order (calibrated against HloCostAnalysis;
+    tests pin ±15%).  `packed` (BeamPackedNeighbors) prices the
+    positional ensemble that layout keeps (WALK_SORT_*; its bytes leave
+    out the m-fold vector table, as they always have).
 
     `merge_bins` > 0 prices the BINNED body instead: the X-wide sort
     ensemble is gone — what remains is the (L + X)-wide bin reduction +
@@ -937,10 +988,14 @@ def _walk_iter_cost(Q, X, D, W, score_itemsize=4, merge_bins=0, L=0, N=0,
                   + costmodel.WALK_SORT_TRAFFIC * Q * max(L, 1) * 4
                   + 2.0 * Q * W * 4)
         return flops, nbytes
-    flops = 2.0 * Q * X * D + deq_f + costmodel.WALK_SORT_FLOPS * Q * X
+    sort_f, sort_b = (
+        (costmodel.WALK_SORTED_FLOPS, costmodel.WALK_SORTED_TRAFFIC)
+        if dedup_in_sorted_order(merge_bins, packed)
+        else (costmodel.WALK_SORT_FLOPS, costmodel.WALK_SORT_TRAFFIC))
+    flops = 2.0 * Q * X * D + deq_f + sort_f * Q * X
     nbytes = (2.0 * Q * X * D * score_itemsize + deq_b
               + N * D * score_itemsize           # corpus gather operand
-              + costmodel.WALK_SORT_TRAFFIC * Q * X * 4
+              + sort_b * Q * X * 4
               + 2.0 * Q * W * 4)
     return flops, nbytes
 
@@ -968,10 +1023,10 @@ def _finalize_cost(Q, L, D, N, rerank=True, itemsize=4, **_):
 
 
 def _segment_cost(Q, X, D, W, score_itemsize=4, merge_bins=0, L=0, N=0,
-                  score_scale=0, **_):
+                  score_scale=0, packed=False, **_):
     return _walk_iter_cost(Q, X, D, W, score_itemsize,
                            merge_bins=merge_bins, L=L, N=N,
-                           score_scale=score_scale)
+                           score_scale=score_scale, packed=packed)
 
 
 def _walk_full_cost(Q, P, X, D, L, W, N, score_itemsize=4, merge_bins=0,
@@ -1320,7 +1375,8 @@ class GraphSearchEngine:
             D=self.data.shape[1], W=_num_words(self.n),
             score_itemsize=self.score_itemsize(),
             merge_bins=self.merge_bins_for(L, B) if L else 0, L=L,
-            N=self.n, score_scale=self.score_scale)
+            N=self.n, score_scale=self.score_scale,
+            packed=self.nbr_vecs is not None)
 
     def seed_state(self, queries: jax.Array, L: int,
                    seeds: Optional[jax.Array] = None) -> dict:
@@ -1505,7 +1561,9 @@ class GraphSearchEngine:
                       L: int) -> None:
         """What the batch just read back walked, from the count its
         program returned with the answers (the caller has counted which
-        driver ran it: `beam.monolithic` / `.chunked` / `.segmented`):
+        driver ran it: `beam.monolithic` / `.chunked` / `.segmented`;
+        counted here: which visited / de-duplicate ensemble its program
+        was traced with, `beam.dedup_sorted` / `.dedup_positional`):
         its trips (`beam.trips`, `beam.trips_total`: the `live.max()` of
         each while loop, so a chunked or segmented batch reads the sum
         over the chunks it walked one after another), its pool, pivot
@@ -1518,6 +1576,11 @@ class GraphSearchEngine:
         read them."""
         scored = int(np.sum(np.reshape(live, -1)[:nq])) * B \
             * int(self.graph.shape[1])
+        if dedup_in_sorted_order(self.merge_bins_for(L, B),
+                                 self.nbr_vecs is not None):
+            metrics.inc("beam.dedup_sorted")
+        else:
+            metrics.inc("beam.dedup_positional")
         metrics.inc("beam.trips_total", trips)
         metrics.inc("beam.rows_scored_total", scored)
         metrics.inc("beam.queries_total", nq)

@@ -181,13 +181,25 @@ def crosscheck(family: str, compiled, tol: float = DEFAULT_TOLERANCE,
 #: scan kernel (mask write+read, negation, top-k read) — fitted 3.1-3.3
 SCAN_MATRIX_TRAFFIC = 3.2
 
-#: per-element flops XLA attributes to the sort/scan/top-k ensemble of
-#: one beam-walk iteration (argsort + segmented OR/min scans + merges)
+#: per-element flops XLA attributes to the POSITIONAL sort/scan/top-k
+#: ensemble of one beam-walk iteration (`engine._sorted_dedup`: argsort,
+#: the mask carried back through the inverse permutation, two bitset-word
+#: gathers, segmented OR scan, merges).  Since ISSUE 33 the exact body
+#: runs it X-wide only with the packed-neighbour layout; the binned
+#: body prices its L-wide pool de-duplication with it
 WALK_SORT_FLOPS = 290.0
 
 #: per-element word traffic of the same ensemble (sorted copies,
 #: scan intermediates), in 4-byte words
 WALK_SORT_TRAFFIC = 130.0
+
+#: the same two for the exact body's X-wide ensemble IN SORTED-ID ORDER
+#: (`engine._sorted_fresh`, ISSUE 33: one one-operand sort, one word
+#: gather, no inverse permutation), fitted like the pair above at six
+#: shapes (flops 158-185 an element, words 93-112 where the graph's
+#: gather operand is small beside Q*X)
+WALK_SORTED_FLOPS = 170.0
+WALK_SORTED_TRAFFIC = 100.0
 
 #: per-merged-row-element flops of the BINNED walk body's selection
 #: ensemble (bin min/argmin reductions + shortlist top-L + the
