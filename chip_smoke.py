@@ -42,8 +42,8 @@ import traceback
 import numpy as np
 
 # numpy only: the harness imports the program inside its functions
-from benchmark.datasets import clustered_f32
-from benchmark.harness import reference, serving
+from benchmark.datasets import clustered_f32, clustered_int8
+from benchmark.harness import reference, reference_int8_cosine, serving
 from benchmark.harness.serving import require
 
 K = 10
@@ -76,53 +76,6 @@ ALL_PHASES = "bkt,int8"
 
 def emit(obj: dict) -> None:
     print(json.dumps(obj), flush=True)
-
-
-# ---------------------------------------------------------------------------
-# what the harness lacks: int8 rows and SPTAG's integer cosine
-# ---------------------------------------------------------------------------
-
-def make_int8(seed: int, n: int, d: int, nq: int):
-    """The benchmark's Gaussian clusters scaled to unit norm x 127 and
-    rounded, the way int8 cosine embeddings ship."""
-    def to_int8(x):
-        x = x / np.maximum(np.linalg.norm(x, axis=1, keepdims=True), 1e-9)
-        return np.clip(np.round(x * 127.0), -128, 127).astype(np.int8)
-    data, queries = clustered_f32.make(seed, n, d, nq)
-    return to_int8(data), to_int8(queries)
-
-
-def normalize_int8(x: np.ndarray) -> np.ndarray:
-    """SPTAG's ingest rule for integer cosine (Utils::Normalize,
-    CommonUtils.h:93-108): every row — corpus and query alike — is
-    rescaled to length 127 and C-cast back to int8, i.e. TRUNCATED."""
-    f = x.astype(np.float64)
-    f = f / np.sqrt((f * f).sum(-1, keepdims=True)) * 127.0
-    return np.trunc(f).astype(np.int8)
-
-
-def exact_topk_int8_cosine(data: np.ndarray, queries: np.ndarray, k: int,
-                            block: int = 131_072):
-    """Exact top-k in SPTAG's integer cosine convention -> ((Q, k) ids,
-    (Q, k) float64 scores, ascending = nearest first): 127^2 - dot of the
-    `normalize_int8` rows (DistanceUtils.h:452).  A float cosine of the
-    rows as given ranks quantization near-ties differently and is not what
-    an int8 index promises (the harness's reference is L2 over floats).
-    The float32 GEMM is exact: rows no longer than 127 keep every partial
-    sum of integers within 127^2."""
-    x, q = normalize_int8(data), normalize_int8(queries).astype(np.float32)
-    ids, scores = [], []
-    for lo in range(0, len(x), block):
-        s = 127.0 ** 2 - (q @ x[lo:lo + block].astype(np.float32).T
-                          ).astype(np.float64)
-        kk = min(k, s.shape[1])
-        part = np.argpartition(s, kk - 1, axis=1)[:, :kk]
-        ids.append(part + lo)
-        scores.append(np.take_along_axis(s, part, axis=1))
-    ids, scores = np.concatenate(ids, axis=1), np.concatenate(scores, axis=1)
-    order = np.argsort(scores, axis=1, kind="stable")[:, :k]
-    return (np.take_along_axis(ids, order, axis=1),
-            np.take_along_axis(scores, order, axis=1))
 
 
 # ---------------------------------------------------------------------------
@@ -329,10 +282,11 @@ def phase_int8(workdir, seed, size, counters, need_pallas: bool) -> None:
     if n != ASKED_BKT_N:
         out["cut"] = f"n {ASKED_BKT_N} -> {n}"
     with counters.phase(out) as compile_log:
-        data, fresh = make_int8(seed + 2, n, 384, size["fresh"])
+        data, fresh = clustered_int8.make(seed + 2, n, 384, size["fresh"])
         folder, out["build_seconds"] = build_index(
             workdir, "int8", data, BKT_INT8, compile_log)
-        ref_ids, _ = exact_topk_int8_cosine(data, fresh, K)
+        ref_ids, _ = reference_int8_cosine.exact_topk_int8_cosine(
+            data, fresh, K)
         with serving.served(workdir, "int8", folder, BKT_INT8) as (server,
                                                                    addr):
             t0 = time.perf_counter()

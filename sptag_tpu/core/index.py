@@ -318,7 +318,8 @@ class VectorIndex(abc.ABC):
         if normalize and self.dist_calc_method == DistCalcMethod.Cosine:
             # Build-time corpus normalization, parity with the reference
             # (BKTIndex.cpp:289-296 + Utils::Normalize CommonUtils.h:93-108).
-            data = dist_ops.normalize(data, self.base)
+            with trace.span("index.normalize"):
+                data = dist_ops.normalize(data, self.base)
         return np.ascontiguousarray(data)
 
     # ---- build / search ---------------------------------------------------
@@ -568,7 +569,8 @@ class VectorIndex(abc.ABC):
         at load (Utils::PrepareQuerys, CommonUtils.h:110-143)."""
         queries = queries.astype(dtype_of(self.value_type), copy=False)
         if self.dist_calc_method == DistCalcMethod.Cosine:
-            queries = dist_ops.normalize(queries, self.base)
+            with trace.span("index.normalize"):
+                queries = dist_ops.normalize(queries, self.base)
         return np.ascontiguousarray(queries)
 
     # ---- mutation ---------------------------------------------------------

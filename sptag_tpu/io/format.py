@@ -64,7 +64,9 @@ def write_matrix(path_or_stream, array: np.ndarray) -> None:
     with open_write(path_or_stream) as f:
         f.write(np.int32(rows).tobytes())
         f.write(np.int32(cols).tobytes())
-        f.write(array.tobytes())
+        # the rows as they lie in memory: `tobytes()` is a second copy of
+        # the block (3.4 GB beside 3.4 GB at 8.84M x 384 int8)
+        f.write(array.reshape(-1).view(np.uint8).data)
 
 
 def read_matrix(path_or_stream, dtype) -> np.ndarray:
@@ -73,8 +75,19 @@ def read_matrix(path_or_stream, dtype) -> np.ndarray:
         header = f.read(8)
         rows = int(np.frombuffer(header, "<i4", 1, 0)[0])
         cols = int(np.frombuffer(header, "<i4", 1, 4)[0])
-        payload = f.read(rows * cols * dtype.itemsize)
-    return np.frombuffer(payload, dtype=dtype).reshape(rows, cols).copy()
+        # read INTO the array: a bytes payload and then its copy held the
+        # block twice while a corpus loaded
+        out = np.empty((rows, cols), dtype)
+        into = out.reshape(-1).view(np.uint8).data
+        got = 0
+        while got < len(into):
+            n = f.readinto(into[got:])
+            if not n:
+                raise ValueError(
+                    f"matrix of {rows} x {cols} {dtype} ends after {got} of "
+                    f"{len(into)} bytes")
+            got += n
+    return out
 
 
 def write_graph(path_or_stream, graph: np.ndarray) -> None:

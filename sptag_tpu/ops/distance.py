@@ -35,6 +35,7 @@ plain f32 accumulation.  Floats accumulate in float32.
 
 from __future__ import annotations
 
+import concurrent.futures
 import functools
 from typing import Optional
 
@@ -43,7 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from sptag_tpu.core.types import DistCalcMethod, VectorValueType, base_of
-from sptag_tpu.utils import costmodel
+from sptag_tpu.utils import costmodel, host_cores
 
 # Values considered "integer typed" for the base^2 - dot convention.
 _INT_DTYPES = (jnp.int8, jnp.uint8, jnp.int16)
@@ -130,6 +131,17 @@ def _int16_parts_i32(hh, mixed, ll) -> jax.Array:
     return ((hh << 16) + (mixed << 8) + ll).astype(jnp.int32)
 
 
+def dot_kind(dtype, d: int) -> str:
+    """Which contraction `pairwise_dot` traces for operands of `dtype`
+    `d` wide — what the host counters `flat.dot_<kind>` are named after:
+    "int8_native" (one-byte integers on the MXU, int32 accumulation,
+    exact), "int16_split" (three int32-exact contractions of the high and
+    low bytes) or "f32" (floats, and int16 past the split's guard)."""
+    if exact_int_dot(dtype):
+        return "int8_native"
+    return "int16_split" if _use_int16_exact(dtype, d) else "f32"
+
+
 def exact_int_dot(dtype) -> bool:
     """True for integer dtypes whose dot products accumulate exactly in
     int32 (int8/uint8: the bound D*255^2 cannot overflow).  int16 products
@@ -141,8 +153,9 @@ def exact_int_dot(dtype) -> bool:
 def pairwise_dot(q: jax.Array, x: jax.Array) -> jax.Array:
     """(Q, D) x (N, D) -> (Q, N) dot products, float32.
 
-    int8/uint8 contract with int32 accumulation (exact, and the bound
-    D * 127^2 can never overflow).  int16 accumulates in float32 like the
+    int8/uint8 contract natively with int32 accumulation (exact: the
+    bound D * 255^2 stays inside int32 up to D = 33,025, D * 128^2 up to
+    131,071).  int16 accumulates in float32 like the
     reference's SIMD path (DistanceUtils.h int16 kernels convert lanes to
     float before the horizontal add): an int32 accumulator overflows on
     raw int16 L2 data (a single product reaches 2^30).  Floats contract
@@ -157,9 +170,14 @@ def pairwise_dot(q: jax.Array, x: jax.Array) -> jax.Array:
     """
     dn = (((1,), (1,)), ((), ()))
     if exact_int_dot(q.dtype):
-        out = jax.lax.dot_general(
-            q.astype(jnp.int32), x.astype(jnp.int32), dn,
-            preferred_element_type=jnp.int32)
+        if x.dtype != q.dtype:
+            q, x = q.astype(jnp.int32), x.astype(jnp.int32)
+        # one-byte operands of one type go to the contraction AS THEY
+        # ARE: the MXU multiplies int8 natively and accumulates in int32,
+        # and nothing asks for an int32 copy of a resident corpus block
+        # (13.6 GB at 8.84M x 384)
+        out = jax.lax.dot_general(q, x, dn,
+                                  preferred_element_type=jnp.int32)
         return out.astype(jnp.float32)
     if _use_int16_exact(q.dtype, q.shape[-1]):
         def contract(a, b):
@@ -311,19 +329,67 @@ def batched_gathered_distance(q: jax.Array, cand: jax.Array,
     return jnp.maximum(qn + cand_sqnorm - 2.0 * dot, 0.0)
 
 
+#: elements of a `normalize` block: 8 MB of float64, so the ingest of a
+#: corpus never holds a float64 copy of the whole (27 GB at 8.84M x 384)
+NORMALIZE_BLOCK_ELEMENTS = 1 << 20
+
+
+def _normalize_span(out: np.ndarray, vectors: np.ndarray, base: int,
+                    rows: int) -> None:
+    """`out` <- the rows of `vectors` (n, D) scaled to length `base` and
+    C-cast to `out`'s type, `rows` rows at a time through TWO float64
+    scratch blocks allocated once and written in place.  Nothing
+    block-sized is allocated per block: five float64 temporaries made and
+    released by every worker for every block ended the build of 8.84M x
+    384 rows at the chip host's 40 GiB (its sandbox gives freed mappings
+    back late); in place the whole run peaks at 17 GB there (PR 34)."""
+    d = vectors.shape[1]
+    f = np.empty((rows, d), np.float64)
+    sq = np.empty_like(f)
+    constant = (1.0 / np.sqrt(d)) * base
+    for lo in range(0, vectors.shape[0], rows):
+        block = vectors[lo:lo + rows]
+        fb, sb = f[:len(block)], sq[:len(block)]
+        np.copyto(fb, block)
+        np.multiply(fb, fb, out=sb)
+        norms = np.sqrt(np.sum(sb, axis=-1, keepdims=True))
+        np.divide(fb, np.maximum(norms, 1e-30), out=fb)
+        np.multiply(fb, base, out=fb)
+        zero = norms[:, 0] < 1e-6
+        if zero.any():
+            fb[zero] = constant
+        np.copyto(out[lo:lo + rows], fb, casting="unsafe")    # a C cast
+
+
 def normalize(vectors: np.ndarray, base: int) -> np.ndarray:
     """Host-side ingest normalization, parity with Utils::Normalize
     (CommonUtils.h:93-108): scale each row to length `base`, casting back to
-    the storage dtype; zero-norm rows become the constant vector
-    ``base/sqrt(D)``."""
+    the storage dtype (a C cast: integers are TRUNCATED); zero-norm rows
+    become the constant vector ``base/sqrt(D)``.
+
+    Rows are independent: a matrix of more than one block's elements is
+    split into one span of rows for each core this process may run on
+    (numpy releases the interpreter lock inside the operations), and a
+    span goes block by block through its worker's own scratch
+    (`_normalize_span`) - the same float64 operations in the same order
+    whatever the split, at two blocks of float64 a core."""
     vectors = np.asarray(vectors)
-    out_dtype = vectors.dtype
-    f = vectors.astype(np.float64)
-    norms = np.sqrt(np.sum(f * f, axis=-1, keepdims=True))
-    d = vectors.shape[-1]
-    constant = (1.0 / np.sqrt(d)) * base
-    scaled = np.where(norms < 1e-6, constant, f / np.maximum(norms, 1e-30) * base)
-    return scaled.astype(out_dtype)
+    x = vectors.reshape(-1, vectors.shape[-1])
+    out = np.empty_like(x)
+    n, d = x.shape
+    rows = max(1, NORMALIZE_BLOCK_ELEMENTS // d)
+    workers = min(-(-n // rows), host_cores())
+    if workers <= 1:
+        _normalize_span(out, x, base, max(1, min(n, rows)))
+    else:
+        span = -(-n // workers)
+
+        def work(lo: int) -> None:
+            _normalize_span(out[lo:lo + span], x[lo:lo + span], base, rows)
+
+        with concurrent.futures.ThreadPoolExecutor(workers) as pool:
+            list(pool.map(work, range(0, n, span)))
+    return out.reshape(vectors.shape)
 
 
 def convert_cosine_similarity_to_distance(cs):

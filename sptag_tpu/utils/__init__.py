@@ -12,6 +12,18 @@ def round_up(n: int, m: int) -> int:
 QUERY_BUCKETS = (1, 8, 32, 128, 256, 1024)
 
 
+def host_cores() -> int:
+    """Cores THIS process may run on (its affinity mask) - what a pool of
+    worker threads is sized by.  `os.cpu_count()` counts the machine's,
+    and a pool that wide holds that many blocks' scratch at once on a
+    host that gives the process a few of its cores (the chip's gives 13:
+    PR 34)."""
+    try:
+        return max(1, len(os.sched_getaffinity(0)))
+    except AttributeError:                  # not on this platform
+        return os.cpu_count() or 1
+
+
 def query_bucket(q: int, cap: int) -> int:
     """Pad q up to the smallest bucket, bounded by the caller's chunk cap."""
     for b in QUERY_BUCKETS:

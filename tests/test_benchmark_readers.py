@@ -200,3 +200,60 @@ def test_the_walks_readers_read_nothing_without_a_walk(run):
         assert load_by_name("layer_metrics", metric).read(
             {**run, "trace": {"programs": {}}, "config": {"dim": 8},
              "peaks": {}}) is None
+
+
+# ---- the int8 scan's roofline (PR 34) -------------------------------------
+
+def test_int8_roofline_reader_finds_what_the_scan_publishes(run):
+    """The chip's trace aside, kernel.int8_scan_roofline needs the two
+    server spans (queries a program run) and the scan program's name; the
+    work comes from the configuration's file."""
+    from sptag_tpu.algo import flat
+
+    reader = load_by_name("layer_metrics", "kernel.int8_scan_roofline")
+    assert reader.PROGRAM.startswith("jit_")
+    assert hasattr(flat, reader.PROGRAM[len("jit_"):])
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "flat_msmarco_i8_cosine.json")) as f:
+        config = json.load(f)
+    peaks = serving.peaks_for("TPU v5 lite")
+    # a hand-made slice: BURSTS program runs in 30 ms of device time
+    traced = {**run, "config": config, "peaks": peaks, "trace": {
+        "programs": {reader.PROGRAM: {"runs": BURSTS, "seconds": 30e-3}}}}
+    least, seconds = reader.bound(traced)
+    assert seconds == 30e-3 and least["bound"] == "hbm"     # 8 a run
+    assert least["seconds"] == pytest.approx(
+        BURSTS * 8841823 * 384 / 819e9)
+    assert reader.read(traced) == pytest.approx(
+        100 * least["seconds"] / 30e-3)
+    assert 0 < reader.read(traced) <= 100
+    # nothing to read: no trace, no such program, rows of another type
+    assert reader.read({**traced, "trace": None}) is None
+    assert reader.read({**traced, "trace": {"programs": {}}}) is None
+    assert reader.read({**traced, "config": {**config,
+                                             "value_type": "Float"}}) is None
+    assert reader.read({**traced, "spans": {}}) is None
+
+
+@pytest.mark.parametrize("queries,bound,seconds", [
+    # 10 runs of 32 queries over 1M x 384 one-byte rows: the read of the
+    # rows (3.84e9 bytes at 819e9 a second) outlasts the products
+    # (2.4576e11 operations at 393e12 a second)
+    (32, "hbm", 10 * 1_000_000 * 384 / 819e9),
+    # at 512 queries the products take longer: 2 x 1M x 384 x 512 x 10
+    # = 3.93216e12 operations
+    (512, "ops", 3.93216e12 / 393e12),
+])
+def test_int8_roofline_arithmetic_against_a_hand_count(queries, bound,
+                                                       seconds):
+    from benchmark.harness import roofline_int8
+
+    got = roofline_int8.int8_scan_least_seconds(
+        10, queries, 1_000_000, 384,
+        {"int8_ops_per_s": 393e12, "hbm_bytes_per_s": 819e9})
+    assert got["bound"] == bound
+    assert got["seconds"] == pytest.approx(seconds, rel=1e-12)
+    assert got["hbm_seconds"] == pytest.approx(3.84e9 / 819e9, rel=1e-12)
+    assert got["op_seconds"] == pytest.approx(
+        2 * 1e6 * 384 * queries * 10 / 393e12, rel=1e-12)
+    assert got["seconds"] == max(got["hbm_seconds"], got["op_seconds"])

@@ -204,6 +204,38 @@ def test_flat_search_kernel_compiles_1m(one_chip, Q):
         assert mem.temp_size_in_bytes < 1.01 * (Q * n * 4 + slabs)
 
 
+@pytest.mark.parametrize("Q", [1, 8, 32, 128])
+def test_flat_search_kernel_compiles_msmarco_int8(one_chip, Q):
+    """`flat_msmarco_i8.saturate`'s own programs (PR 34): 8,841,823 x 384
+    int8 rows in SPTAG's integer cosine, one per warm bucket.  The
+    contraction takes the one-byte rows as they are (s8 x s8 -> s32: no
+    int32 copy of the 3.4 GB block, which would be 13.6 GB), and the
+    scores are written once: rows + the (Q, N) float32 scores fit."""
+    from sptag_tpu.algo.flat import _flat_search_kernel, pad_rows
+
+    n, D = pad_rows(8_841_823), 384
+    assert n == 8_841_856
+    compiled = _flat_search_kernel.lower(
+        _s(one_chip, (n, D), jnp.int8), _s(one_chip, (n,), jnp.float32),
+        _s(one_chip, (n,), jnp.bool_), _s(one_chip, (Q, D), jnp.int8),
+        k=K, metric=COS, base=127).compile()
+    mem = compiled.memory_analysis()
+    rows = n * D
+    assert rows <= mem.argument_size_in_bytes < rows + 5 * n + (1 << 20)
+    # nothing beside the scores: no widened copy of the rows
+    assert mem.temp_size_in_bytes < 1.01 * Q * n * 4 + (1 << 20)
+    text = compiled.as_text()
+    for scope in ("flat.distance", "flat.topk"):
+        assert scope in text, scope
+    contractions = [line for line in text.splitlines()
+                    if " convolution(" in line or " dot(" in line]
+    # (one query: a multiply-and-add fusion over the rows, no contraction)
+    assert len(contractions) == (Q > 1)
+    assert all(" s32[" in line for line in contractions)
+    if Q in (8, 128):
+        assert not _row_wide_selections(compiled, n)        # two stages
+
+
 # ---------------------------------------------------------------------------
 # beam walk (SearchMode=beam) with the bf16 scoring corpus the engine
 # picks only when it sees a TPU — a branch no CPU test takes

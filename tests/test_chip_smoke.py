@@ -41,8 +41,7 @@ def test_import_initializes_no_backend():
 
 
 def test_generator_is_seeded_and_shaped():
-    import chip_smoke
-    from benchmark.datasets import clustered_f32
+    from benchmark.datasets import clustered_f32, clustered_int8
 
     a, qa = clustered_f32.make(3, 2000, 128, 16)
     b, qb = clustered_f32.make(3, 2000, 128, 16)
@@ -52,10 +51,10 @@ def test_generator_is_seeded_and_shaped():
     np.testing.assert_array_equal(a, b)
     np.testing.assert_array_equal(qa, qb)
     assert not np.array_equal(a, c)
-    i8, q8 = chip_smoke.make_int8(3, 2000, 384, 16)
+    i8, q8 = clustered_int8.make(3, 2000, 384, 16)
     assert i8.dtype == np.int8 and q8.dtype == np.int8
     assert i8.shape == (2000, 384) and q8.shape == (16, 384)
-    np.testing.assert_array_equal(i8, chip_smoke.make_int8(3, 2000, 384, 16)[0])
+    np.testing.assert_array_equal(i8, clustered_int8.make(3, 2000, 384, 16)[0])
     norms = np.linalg.norm(i8.astype(np.float64), axis=1)
     assert np.abs(norms - 127.0).max() < 2.0          # unit norm x 127
 
@@ -100,12 +99,13 @@ def test_exact_reference_agrees_with_flat_index():
 
 
 def test_int8_reference_follows_the_integer_cosine_convention():
-    import chip_smoke
     import sptag_tpu as sp
-    from benchmark.harness import reference
+    from benchmark.datasets import clustered_int8
+    from benchmark.harness import reference, reference_int8_cosine
 
-    data, queries = chip_smoke.make_int8(6, 3000, 384, 16)
-    ref_ids, ref_scores = chip_smoke.exact_topk_int8_cosine(data, queries, 10)
+    data, queries = clustered_int8.make(6, 3000, 384, 16)
+    ref_ids, ref_scores = reference_int8_cosine.exact_topk_int8_cosine(
+        data, queries, 10)
     # scores are 127^2 - integer dot: integral, ascending
     assert np.array_equal(ref_scores, np.round(ref_scores))
     assert (np.diff(ref_scores, axis=1) >= 0).all()
