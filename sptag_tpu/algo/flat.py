@@ -37,7 +37,7 @@ from sptag_tpu.core.types import (
 from sptag_tpu.io import format as fmt
 from sptag_tpu.ops import cascade
 from sptag_tpu.ops import distance as dist_ops
-from sptag_tpu.ops import topk_bins
+from sptag_tpu.ops import pallas_kernels, topk_bins
 from sptag_tpu.utils import costmodel, devmem, metrics, round_up, trace
 
 _ROW_PAD = 128      # pad corpus rows to multiples of this (TPU lane width)
@@ -69,7 +69,8 @@ def pad_to_bucket(queries: np.ndarray) -> np.ndarray:
         axis=0)
 
 
-_GROUP = 128        # columns a group of the two-stage select holds: a lane tile
+# columns a group of the two-stage select holds: a lane tile
+_GROUP = pallas_kernels.SCAN_GROUP
 # When the two-stage select pays, as the chip showed it (PERF.md section
 # 6, PR 28; v5e, whole scan programs).  Rows at least this many times
 # k*_GROUP wide: below, one top-k over the row is no slower (1M x 128 at
@@ -121,6 +122,49 @@ def count_dot(dtype, d: int) -> None:
         metrics.inc("flat.dot_f32")
 
 
+def fused_minima(dtype, q: int, n: int, d: int, k: int, metric: int,
+                 platform: str) -> bool:
+    """Whether the exact scan of `q` queries over `n` rows of `dtype`,
+    `d` wide, takes its group minima from the pass that computes the
+    distances (`pallas_kernels.scan_group_minima`) and never holds the
+    (q, n) scores: one-byte rows in the integer cosine, where a re-scored
+    row is the very number the scan saw (int32 accumulation, `base^2 -
+    dot` exact in float32), a two-stage select at a query block of whole
+    128-lane tiles (the 128 and 512 rungs), rows in whole groups of
+    whole lane tiles, on a TPU.  L2 stays materialised (its minima need
+    the row norms as one more sublane-major operand), and so do float
+    rows: two differently scheduled float32 contractions would have to
+    agree to the last bit.  A function of what can be seen BEFORE the
+    call, as `pallas_kernels.supported` is: no switch turns the route off
+    after a failure.  The host counters `flat.scan_fused_minima` /
+    `flat.scan_materialized` ask it what the program they dispatch was
+    traced as."""
+    return (dist_ops.dot_kind(dtype, d) == "int8_native"
+            and metric == int(DistCalcMethod.Cosine)
+            and select_stages(q, n, k) == 2 and q % _LANES == 0
+            and n % _GROUP == 0 and d % _LANES == 0
+            and platform in ("tpu", "interpret"))
+
+
+def scan_route(dtype, q: int, n: int, d: int, k: int, metric: int) -> dict:
+    """`fused_minima` here and now, as the static arguments `fused` /
+    `interpret` of the scan programs (`_flat_search_kernel`;
+    `_sharded_search_kernel`, where `n` is one shard's block)."""
+    fused = fused_minima(dtype, q, n, d, k, metric,
+                         pallas_kernels.platform())
+    return {"fused": fused,
+            "interpret": fused and pallas_kernels.interpret()}
+
+
+def count_route(fused: bool) -> None:
+    """One dispatched exact scan, counted by where its group minima came
+    from (`fused_minima`)."""
+    if fused:
+        metrics.inc("flat.scan_fused_minima")
+    else:
+        metrics.inc("flat.scan_materialized")
+
+
 def exact_topk(d, k: int):
     """The `k` smallest of every row of `d` (Q, N), ascending, with their
     columns: `lax.top_k(-d, k)`'s answer BIT FOR BIT (values, columns,
@@ -148,7 +192,11 @@ def exact_topk(d, k: int):
     query's own lane is kept.  (Laid out query-major, the chip's compiler
     transposes all of `d` for the reduction and once more for the gather;
     a corpus placed in whole groups, as the snapshots and the mesh shards
-    are, has no tail to slice the groups off.)"""
+    are, has no tail to slice the groups off.)
+
+    Stages 2 and 3 are `_select_from_groups`, which `scan_topk`'s fused
+    route shares: there the minima come out of the distance pass and the
+    chosen groups' columns are scored again, and `d` never exists."""
     q, n = d.shape
     if select_stages(q, n, k) == 1:
         neg, idx = jax.lax.top_k(-d, k)
@@ -157,14 +205,33 @@ def exact_topk(d, k: int):
     dt = d.T
     grouped = (dt[:groups * _GROUP] if tail else dt).reshape(
         groups, _GROUP, q)
-    _, chosen = jax.lax.top_k(-grouped.min(axis=1).T, k)
+
+    def columns(chosen):
+        slabs = jnp.take(grouped, chosen.reshape(-1), axis=0, mode="clip")
+        own = jnp.eye(q, dtype=bool)[:, None, None, :]
+        return jnp.where(own, slabs.reshape(q, k, _GROUP, q),
+                         jnp.float32(np.inf)).min(axis=3).reshape(
+                             q, k * _GROUP)
+
+    return _select_from_groups(
+        grouped.min(axis=1), columns, k,
+        dt[groups * _GROUP:].T if tail else None)
+
+
+def _select_from_groups(minima, columns, k: int, tail=None):
+    """Stages 2 and 3 of `exact_topk`, whose proof this is the code of:
+    `minima` (groups, Q) holds every group's smallest score a query,
+    `columns(chosen)` gives the scores of the (Q, k) chosen groups'
+    columns as (Q, k*_GROUP), groups ascending, `tail` (Q, t) the scores
+    past the last whole group.  Where the minima and the columns come
+    from is the caller's: the materialised scores (`exact_topk`) or the
+    fused scan and a re-score (`scan_topk`)."""
+    groups = minima.shape[0]
+    _, chosen = jax.lax.top_k(-minima.T, k)
     chosen = jnp.sort(chosen, axis=1)                   # column order
-    slabs = jnp.take(grouped, chosen.reshape(-1), axis=0, mode="clip")
-    own = jnp.eye(q, dtype=bool)[:, None, None, :]
-    cand = jnp.where(own, slabs.reshape(q, k, _GROUP, q),
-                     jnp.float32(np.inf)).min(axis=3).reshape(q, k * _GROUP)
-    if tail:
-        cand = jnp.concatenate([cand, dt[groups * _GROUP:].T], axis=1)
+    cand = columns(chosen)
+    if tail is not None:
+        cand = jnp.concatenate([cand, tail], axis=1)
     neg, pos = jax.lax.top_k(-cand, k)
     group = jnp.take_along_axis(chosen, jnp.minimum(pos // _GROUP, k - 1),
                                 axis=1)
@@ -173,26 +240,62 @@ def exact_topk(d, k: int):
     return -neg, cols
 
 
+def _rescored_columns(data, invalid, queries, k: int, base: int):
+    """`_select_from_groups`' `columns` for the fused route: the chosen
+    groups' rows, k contiguous slabs of _GROUP rows a query, gathered
+    from the resident block and scored again with the integer cosine
+    (exact, so the very numbers the scan took its minima of) and the
+    mask."""
+    q = queries.shape[0]
+    groups = data.shape[0] // _GROUP
+
+    def columns(chosen):
+        rows = jnp.take(data.reshape(groups, _GROUP, -1), chosen, axis=0)
+        d = dist_ops.batched_gathered_distance(
+            queries, rows.reshape(q, k * _GROUP, -1), DistCalcMethod.Cosine,
+            base)
+        dead = jnp.take(invalid.reshape(groups, _GROUP), chosen, axis=0)
+        return jnp.where(dead.reshape(q, k * _GROUP),
+                         jnp.float32(MAX_DIST), d)
+
+    return columns
+
+
 def scan_topk(data, sqnorm, invalid, queries, k: int, metric: int,
               base: int, approx: bool = False, recall_target: float = 0.99,
-              binned_bins: int = 0):
+              binned_bins: int = 0, fused: bool = False,
+              interpret: bool = False):
     """Distance matrix -> mask -> top-k of one resident block of rows:
     THE scan body, traced into the one-chip program
     (`_flat_search_kernel`) and, per shard, into the mesh program
     (parallel/sharded.py `_sharded_search_kernel`), so a distance or
     top-k change reaches both.  -> ((Q, k) float32 distances, (Q, k)
-    int32 row ids of THIS block; -1 where only masked rows were left)."""
+    int32 row ids of THIS block; -1 where only masked rows were left).
+
+    `fused` (the caller's `fused_minima`, decided before the call): the
+    exact select's group minima come out of the distance pass itself
+    (`pallas_kernels.scan_group_minima`; `interpret` runs it on the CPU)
+    and the chosen groups are scored again: the same answers bit for
+    bit, and no (Q, N) matrix."""
     # the scope names are what a profiler trace calls the two stages
     # (benchmark kernel.topk_ms_per_batch reads `flat.topk`): kernel PRs
     # keep them
     with jax.named_scope("flat.distance"):
-        if metric == int(DistCalcMethod.L2):
-            d = dist_ops.pairwise_l2(queries, data, sqnorm)
+        if fused:
+            minima = pallas_kernels.scan_group_minima(
+                data, invalid, queries, base=base, interpret=interpret)
         else:
-            d = dist_ops.pairwise_cosine(queries, data, base)
-        d = jnp.where(invalid[None, :], jnp.float32(MAX_DIST), d)
+            if metric == int(DistCalcMethod.L2):
+                d = dist_ops.pairwise_l2(queries, data, sqnorm)
+            else:
+                d = dist_ops.pairwise_cosine(queries, data, base)
+            d = jnp.where(invalid[None, :], jnp.float32(MAX_DIST), d)
     with jax.named_scope("flat.topk"):
-        if binned_bins:
+        if fused:
+            dists, idx = _select_from_groups(
+                minima, _rescored_columns(data, invalid, queries, k, base),
+                k)
+        elif binned_bins:
             dists, idx = topk_bins.binned_topk(d, k, binned_bins)
         elif approx:
             neg, idx = jax.lax.approx_max_k(-d, k,
@@ -207,11 +310,13 @@ def scan_topk(data, sqnorm, invalid, queries, k: int, metric: int,
 
 @functools.partial(jax.jit,
                    static_argnames=("k", "metric", "base", "approx",
-                                    "recall_target", "binned_bins"))
+                                    "recall_target", "binned_bins", "fused",
+                                    "interpret"))
 def _flat_search_kernel(data, sqnorm, invalid, queries, k: int,
                         metric: int, base: int, approx: bool = False,
                         recall_target: float = 0.99,
-                        binned_bins: int = 0):
+                        binned_bins: int = 0, fused: bool = False,
+                        interpret: bool = False):
     """One fused program: distance matrix -> mask -> top-k (`scan_topk`).
 
     `approx=True` selects `lax.approx_max_k` — the TPU's hardware-
@@ -225,9 +330,13 @@ def _flat_search_kernel(data, sqnorm, invalid, queries, k: int,
     `binned_bins` > 0 selects the portable bin-reduction top-k instead
     (ops/topk_bins.py, BinnedTopK): same coarse-select shape, but it
     accelerates every backend — `approx_max_k` lowers to a full sort
-    off-TPU.  When both are set, binned wins (it subsumes the recipe)."""
+    off-TPU.  When both are set, binned wins (it subsumes the recipe).
+
+    `fused` / `interpret`: the exact select's route, the caller's
+    `fused_minima` (see `scan_topk`)."""
     return DeviceTopK(*scan_topk(data, sqnorm, invalid, queries, k, metric,
-                                 base, approx, recall_target, binned_bins))
+                                 base, approx, recall_target, binned_bins,
+                                 fused, interpret))
 
 
 def exact_device_scan(data_d, sqnorm_d, invalid_d, queries: np.ndarray,
@@ -243,9 +352,12 @@ def exact_device_scan(data_d, sqnorm_d, invalid_d, queries: np.ndarray,
     registered `flat.scan` cost-ledger family (no new jit site)."""
     q = queries.shape[0]
     k_eff = min(k, data_d.shape[0])
+    queries = pad_to_bucket(queries)
     dists, ids = _flat_search_kernel(
-        data_d, sqnorm_d, invalid_d, jnp.asarray(pad_to_bucket(queries)),
-        k_eff, metric, base, approx=False)
+        data_d, sqnorm_d, invalid_d, jnp.asarray(queries), k_eff, metric,
+        base, approx=False,
+        **scan_route(data_d.dtype, queries.shape[0], *data_d.shape, k_eff,
+                     metric))
     return np.asarray(dists)[:q], np.asarray(ids)[:q]
 
 
@@ -326,7 +438,8 @@ def _flat_sketch_kernel(data, sqnorm, invalid, sketches, mean, queries,
 # cost-ledger entries (utils/costmodel.py; graftlint GL605)
 # ---------------------------------------------------------------------------
 
-def _flat_scan_cost(Q, N, D, k, itemsize=4, binned_bins=0, **_):
+def _flat_scan_cost(Q, N, D, k, itemsize=4, binned_bins=0, fused=False,
+                    **_):
     """Exact scan: one (Q, D) x (N, D) contraction + norms + masked
     top-k.  Bytes: corpus + queries + norms/tombstones in, results out,
     plus the materialized (Q, N) score matrix's mask/neg/top-k traffic
@@ -338,7 +451,16 @@ def _flat_scan_cost(Q, N, D, k, itemsize=4, binned_bins=0, **_):
     the shortlist top-k (_BINNED_WINNER_TRAFFIC; the flops are
     ops/topk_bins.binned_select_cost's).  A row wide enough
     for the exact branch's two stages (`select_stages`) is costed by
-    `_two_stage_select_cost` instead of the N-wide top-k."""
+    `_two_stage_select_cost` instead of the N-wide top-k.  On the `fused`
+    route (`fused_minima`) there is no score matrix to bill: the kernel's
+    own entry (`pallas.scan_group_minima`: the rows once) and the select
+    over its minima with the re-scored slabs."""
+    if fused:
+        scan = costmodel.estimate("pallas.scan_group_minima", Q=Q, N=N, D=D,
+                                  itemsize=itemsize)
+        sel_f, sel_b = _two_stage_select_cost(Q, N, k, fused=True, D=D,
+                                              itemsize=itemsize)
+        return scan.flops + sel_f, scan.hbm_bytes + sel_b + Q * k * 8
     flops = (costmodel.matmul_flops(Q, N, D) + 2.0 * D * (Q + N)
              + 2.0 * Q * N)
     nbytes = N * D * itemsize + Q * D * itemsize + N * 4 + N + Q * k * 8
@@ -368,15 +490,27 @@ _BINNED_WINNER_TRAFFIC = 5.4
 _TWO_STAGE_MATRIX_TRAFFIC = 5.2
 
 
-def _two_stage_select_cost(Q, N, k):
+def _two_stage_select_cost(Q, N, k, fused=False, D=0, itemsize=4):
     """`exact_topk`'s two stages over (Q, N) scores: the group-minimum
     pass (with the transpose's elementwise ops, fitted 3 an element), the
     top-k over the (Q, N/_GROUP) minima, the slabs — k groups a query,
     each fetched for all Q queries, written and read back by the
     select-reduce that keeps the query's own lane — and the top-k
     over the k*_GROUP (+ tail) candidates.  A tail costs one more
-    traversal (the whole groups are sliced off it)."""
+    traversal (the whole groups are sliced off it).
+
+    `fused`: stages 2 and 3 alone (`_select_from_groups` over minima the
+    scan wrote; no tail) - the minima read by the top-k, the chosen
+    groups' rows (`D` wide, `itemsize` bytes) gathered and read back by
+    the re-score, the candidates' top-k."""
     groups, tail = divmod(N, _GROUP)
+    if fused:
+        cand = Q * k * _GROUP
+        flops = (costmodel.topk_flops(Q, groups)
+                 + costmodel.matmul_flops(1, cand, D)
+                 + costmodel.topk_flops(Q, k * _GROUP))
+        return flops, (3.0 * groups * Q * 4 + 2.0 * cand * D * itemsize
+                       + 3.0 * cand * 4)
     slab = Q * k * _GROUP * Q
     flops = (3.0 * Q * N + costmodel.topk_flops(Q, groups) + 3.0 * slab
              + costmodel.topk_flops(Q, k * _GROUP + tail))
@@ -743,13 +877,18 @@ class FlatIndex(VectorIndex):
                 str(getattr(self.params, "binned_topk", "off")), k_eff,
                 data_d.shape[0], rt)
             approx = bool(getattr(self.params, "approx_topk", False))
+            route = {}
             if not (approx or bins):
                 count_select(queries.shape[0], data_d.shape[0], k_eff)
+                route = scan_route(data_d.dtype, queries.shape[0],
+                                   *data_d.shape, k_eff,
+                                   int(self.dist_calc_method))
+                count_route(route["fused"])
             count_dot(data_d.dtype, data_d.shape[1])
             dists, ids = _flat_search_kernel(
                 data_d, sqnorm_d, invalid_d, jnp.asarray(queries), k_eff,
                 int(self.dist_calc_method), self.base, approx=approx,
-                recall_target=rt, binned_bins=bins)
+                recall_target=rt, binned_bins=bins, **route)
         with trace.span("index.readback"):
             # the host blocks here until the program has run
             dists = np.asarray(dists)[:q]

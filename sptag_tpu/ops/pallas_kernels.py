@@ -27,6 +27,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from sptag_tpu.core.index import MAX_DIST
 from sptag_tpu.utils import costmodel
 
 _INTERPRET = False        # tests may flip this to run on CPU
@@ -40,6 +41,12 @@ def set_interpret(value: bool) -> None:
 
 def interpret() -> bool:
     return _INTERPRET
+
+
+def platform() -> str:
+    """What a kernel's gate is asked about: the first device's platform,
+    or "interpret" while the kernels run interpreted (CPU tests)."""
+    return "interpret" if _INTERPRET else jax.devices()[0].platform
 
 
 def supported(data_perm) -> bool:
@@ -56,9 +63,7 @@ def supported(data_perm) -> bool:
     min_sub = 32 if data_perm.dtype == jnp.dtype(jnp.int8) else 8
     if P % min_sub != 0 or D % 128 != 0:
         return False
-    if _INTERPRET:
-        return True
-    return jax.devices()[0].platform == "tpu"
+    return platform() in ("tpu", "interpret")
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -197,6 +202,91 @@ def group_block_dots(data_perm: jax.Array, queries: jax.Array,
     return out.reshape(NG, U, G, P)
 
 
+SCAN_GROUP = 128      # rows a group minimum covers: a lane tile
+# rows one grid step brings into VMEM, in bytes (twice: Pallas
+# double-buffers the block), and rows x queries one MXU contraction of a
+# step scores before their maxima are taken, in elements
+_SCAN_TILE_BYTES = 4 << 20
+_SCAN_CHUNK_SCORES = 1 << 17
+_SCAN_VMEM_BYTES = 48 << 20
+
+
+def _scan_tiling(d: int, q: int):
+    """(groups a grid step, groups a contraction) of `scan_group_minima`
+    at rows `d` bytes wide and `q` queries: powers of two, a step's rows
+    within `_SCAN_TILE_BYTES`, a contraction's scores within
+    `_SCAN_CHUNK_SCORES` (8,192 rows a step in chunks of 1,024 at 384
+    bytes and 128 queries)."""
+    chunk = max(1, _SCAN_CHUNK_SCORES // (SCAN_GROUP * q))
+    tile = max(8, _SCAN_TILE_BYTES // (SCAN_GROUP * d))
+    tile = 1 << (tile.bit_length() - 1)
+    chunk = min(1 << (chunk.bit_length() - 1), tile)
+    return tile, chunk
+
+
+@functools.partial(jax.jit, static_argnames=("base", "interpret"))
+def scan_group_minima(data: jax.Array, invalid: jax.Array,
+                      queries: jax.Array, base: int,
+                      interpret: bool = False) -> jax.Array:
+    """(N, D) one-byte rows, (N,) bool `invalid`, (Q, D) queries of the
+    rows' type -> (N/128, Q) float32: for every group of 128 consecutive
+    rows and every query the smallest integer-cosine distance `base^2 -
+    dot` over the group's valid rows, `MAX_DIST` where none is valid.
+    What `where(invalid, MAX_DIST, pairwise_cosine(queries, data, base))`
+    holds as group minima, bit for bit, without the (Q, N) scores ever
+    being written: the FLAT scan's HBM traffic is the rows, once.
+
+    The grid walks the rows in tiles (Pallas double-buffers the DMA); the
+    (D, Q) queries stay in VMEM.  A step contracts 1,024-row chunks s8 x
+    s8 -> s32 on the MXU with the rows on the sublanes and the queries on
+    the lanes, so a group's minimum is an elementwise one over 16 vregs
+    and one sublane reduce.  The minimum is taken as the MAXIMUM of the
+    int32 dots: `base^2 - float32(dot)` falls as the dot rises (the
+    conversion and the subtraction round monotonically where they round
+    at all), so the largest dot's distance is the smallest distance, and
+    one conversion a group replaces one a row.  Invalid rows drop out
+    under a cap a row (int32's minimum, which no dot of one-byte operands
+    reaches; int32's maximum on a valid row): one `minimum` a score.  The
+    last tile may pass the rows' end: what it reads there lands in groups
+    past the output's end, which are not written."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n, d = data.shape
+    q = queries.shape[0]
+    groups = n // SCAN_GROUP
+    tile, chunk = _scan_tiling(d * data.dtype.itemsize, q)
+    int_min = jnp.iinfo(jnp.int32).min
+    base2 = float(base) * float(base)
+
+    def kernel(qt_ref, rows_ref, cap_ref, out_ref):
+        for c in range(tile // chunk):
+            at = slice(c * chunk, (c + 1) * chunk)
+            dot = jnp.dot(rows_ref[c * chunk * SCAN_GROUP:(c + 1) * chunk * SCAN_GROUP],
+                          qt_ref[...], preferred_element_type=jnp.int32)
+            best = jnp.minimum(dot.reshape(chunk, SCAN_GROUP, q),
+                               cap_ref[at][:, :, None]).max(axis=1)
+            out_ref[at] = jnp.where(
+                best == int_min, jnp.float32(MAX_DIST),
+                jnp.float32(base2) - best.astype(jnp.float32))
+
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((groups, q), jnp.float32),
+        grid=(pl.cdiv(groups, tile),),
+        in_specs=[pl.BlockSpec((d, q), lambda i: (0, 0)),
+                  pl.BlockSpec((tile * SCAN_GROUP, d), lambda i: (i, 0)),
+                  pl.BlockSpec((tile, SCAN_GROUP), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((tile, q), lambda i: (i, 0)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_SCAN_VMEM_BYTES),
+        interpret=interpret,
+    )(queries.T, data,
+      jnp.where(invalid, int_min, jnp.iinfo(jnp.int32).max).reshape(
+          groups, SCAN_GROUP))
+
+
 # ---------------------------------------------------------------------------
 # cost-ledger entries (utils/costmodel.py; graftlint GL605).  The Pallas
 # kernels stream blocks through VMEM, so bytes here are the TRUE block
@@ -218,6 +308,18 @@ def _group_block_cost(NG, U, G, P, D, itemsize=4, **_):
     return flops, nbytes
 
 
+def _scan_group_minima_cost(Q, N, D, itemsize=1, **_):
+    """The rows once, the mask (read as bytes, written and read again as
+    int32), the queries, the (N/128, Q) minima; the contraction, and a
+    cap and a running maximum a score."""
+    flops = 2.0 * Q * N * D + 2.0 * Q * N
+    nbytes = (N * D * itemsize + 9 * N + Q * D * itemsize
+              + N // SCAN_GROUP * Q * 4)
+    return flops, nbytes
+
+
+costmodel.register("pallas.scan_group_minima", scan_group_minima,
+                   _scan_group_minima_cost)
 costmodel.register("pallas.probe_block_dots", probe_block_dots,
                    _probe_block_cost)
 costmodel.register("pallas.group_block_dots", group_block_dots,

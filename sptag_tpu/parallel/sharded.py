@@ -29,8 +29,8 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from sptag_tpu.algo.flat import (count_select, pad_rows, pad_to_bucket,
-                                 scan_topk)
+from sptag_tpu.algo.flat import (count_route, count_select, pad_rows,
+                                 pad_to_bucket, scan_route, scan_topk)
 from sptag_tpu.core.index import MAX_DIST
 from sptag_tpu.core.types import DistCalcMethod
 from sptag_tpu.ops import distance as dist_ops
@@ -82,20 +82,24 @@ class MeshTopK(NamedTuple):
 
 @functools.partial(jax.jit,
                    static_argnames=("k_local", "k_final", "metric", "base",
-                                    "mesh", "row_stride"))
+                                    "mesh", "row_stride", "fused",
+                                    "interpret"))
 def _sharded_search_kernel(data, sqnorm, invalid, queries, k_local: int,
                            k_final: int, metric: int, base: int, mesh: Mesh,
-                           row_stride: Optional[int] = None):
+                           row_stride: Optional[int] = None,
+                           fused: bool = False, interpret: bool = False):
     """One program: per shard the one-chip scan body (`algo/flat.py
     scan_topk`: distances, mask, local top-k_local), then under scope
     `mesh.merge` the ICI all-gather of the (dist, global-id) candidates
     and the global top-k_final re-rank.  `row_stride`: the corpus rows a
     shard stands for, where its device block is padded beyond them
-    (absent: the block's own row count)."""
+    (absent: the block's own row count).  `fused` / `interpret`: the
+    exact select's route in every shard (`flat.fused_minima` of one
+    shard's block, decided by the caller)."""
 
     def local_search(data_s, sqnorm_s, invalid_s, q_s):
         d, ids = scan_topk(data_s, sqnorm_s, invalid_s, q_s, k_local,
-                           metric, base)
+                           metric, base, fused=fused, interpret=interpret)
         # the merge is what a trace calls the mesh's own stage (benchmark
         # kernel.mesh_merge_ms_per_batch reads `mesh.merge`)
         with jax.named_scope("mesh.merge"):
@@ -351,10 +355,13 @@ class ShardedFlatIndex:
         k_local = min(k, n_local)
         k_final = min(k, k_local * n_dev)
         count_select(queries.shape[0], n_local, k_local)
+        route = scan_route(self.data.dtype, queries.shape[0], n_local,
+                           self.data.shape[1], k_local, int(self.metric))
+        count_route(route["fused"])
         dists, ids = _sharded_search_kernel(
             self.data, self.sqnorm, self.invalid, jnp.asarray(queries),
             k_local, k_final, int(self.metric), self.base, self.mesh,
-            row_stride=self.row_stride)
+            row_stride=self.row_stride, **route)
         with trace.span("index.readback"):
             # the host blocks here until the program has run
             dists = np.asarray(dists)[:q]

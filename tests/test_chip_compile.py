@@ -115,6 +115,27 @@ def test_probe_block_dots_compiles(one_chip, dtype, D, Q, nprobe):
     assert _has_mosaic_kernel(compiled)
 
 
+@pytest.mark.parametrize("dtype,Q", [
+    (jnp.int8, 128), (jnp.uint8, 128), (jnp.int8, 512),
+])
+def test_scan_group_minima_compiles_at_msmarco(one_chip, dtype, Q):
+    """The fused FLAT scan's kernel alone (PR 37) at
+    `flat_msmarco_i8.saturate`'s shape: 69,077 groups in tiles of 64 (the
+    last one ragged), every rung `flat.fused_minima` sends to it; its
+    only output is the (groups, Q) minima."""
+    from sptag_tpu.algo.flat import pad_rows
+    from sptag_tpu.ops import pallas_kernels
+
+    n, D = pad_rows(8_841_823), 384
+    lowered = pallas_kernels.scan_group_minima.lower(
+        _s(one_chip, (n, D), dtype), _s(one_chip, (n,), jnp.bool_),
+        _s(one_chip, (Q, D), dtype), base=127)
+    assert lowered.out_info.shape == (n // 128, Q)
+    compiled = lowered.compile()
+    assert _has_mosaic_kernel(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
 @pytest.mark.parametrize("dtype,D,G", [
     ("f32", 128, 8), ("f32", 128, 32), ("int8", 128, 32), ("int8", 384, 32),
 ])
@@ -204,35 +225,52 @@ def test_flat_search_kernel_compiles_1m(one_chip, Q):
         assert mem.temp_size_in_bytes < 1.01 * (Q * n * 4 + slabs)
 
 
-@pytest.mark.parametrize("Q", [1, 8, 32, 128])
+@pytest.mark.parametrize("Q", [1, 8, 32, 128, 512])
 def test_flat_search_kernel_compiles_msmarco_int8(one_chip, Q):
     """`flat_msmarco_i8.saturate`'s own programs (PR 34): 8,841,823 x 384
-    int8 rows in SPTAG's integer cosine, one per warm bucket.  The
-    contraction takes the one-byte rows as they are (s8 x s8 -> s32: no
-    int32 copy of the 3.4 GB block, which would be 13.6 GB), and the
-    scores are written once: rows + the (Q, N) float32 scores fit."""
-    from sptag_tpu.algo.flat import _flat_search_kernel, pad_rows
+    int8 rows in SPTAG's integer cosine, one per warm bucket (and the 512
+    rung the rule sends the same way).  The contraction takes the
+    one-byte rows as they are (s8 x s8 -> s32: no int32 copy of the 3.4
+    GB block, which would be 13.6 GB).  At 128 queries and more (PR 37)
+    the group minima come out of the Pallas scan and the (Q, N) scores -
+    4.53 GB at 128 queries - are never written: the route is the rule's
+    for a TPU, passed as the index passes it (this process sees a CPU)."""
+    from sptag_tpu.algo.flat import (_flat_search_kernel, fused_minima,
+                                     pad_rows)
 
     n, D = pad_rows(8_841_823), 384
     assert n == 8_841_856
+    fused = fused_minima(np.dtype(np.int8), Q, n, D, K, COS, "tpu")
+    assert fused == (Q >= 128)
     compiled = _flat_search_kernel.lower(
         _s(one_chip, (n, D), jnp.int8), _s(one_chip, (n,), jnp.float32),
         _s(one_chip, (n,), jnp.bool_), _s(one_chip, (Q, D), jnp.int8),
-        k=K, metric=COS, base=127).compile()
+        k=K, metric=COS, base=127, fused=fused).compile()
     mem = compiled.memory_analysis()
     rows = n * D
-    assert rows <= mem.argument_size_in_bytes < rows + 5 * n + (1 << 20)
-    # nothing beside the scores: no widened copy of the rows
-    assert mem.temp_size_in_bytes < 1.01 * Q * n * 4 + (1 << 20)
     text = compiled.as_text()
     for scope in ("flat.distance", "flat.topk"):
         assert scope in text, scope
+    assert _has_mosaic_kernel(compiled) == fused
+    if fused:
+        # the cosine program never reads the norms: they are no argument
+        assert rows <= mem.argument_size_in_bytes < rows + n + (1 << 20)
+        # the minima, the chosen slabs (Q*k*128*384 bytes) and their
+        # scores: nothing N x Q wide, no N-wide selection
+        assert mem.temp_size_in_bytes < 0.3 * 2 ** 30
+        assert f"[{n},{Q}]" not in text and f"[{Q},{n}]" not in text
+        assert not _row_wide_selections(compiled, n)
+        assert f"f32[{n // 128},{Q}]" in text               # the minima
+        return
+    assert rows <= mem.argument_size_in_bytes < rows + 5 * n + (1 << 20)
+    # nothing beside the scores: no widened copy of the rows
+    assert mem.temp_size_in_bytes < 1.01 * Q * n * 4 + (1 << 20)
     contractions = [line for line in text.splitlines()
                     if " convolution(" in line or " dot(" in line]
     # (one query: a multiply-and-add fusion over the rows, no contraction)
     assert len(contractions) == (Q > 1)
     assert all(" s32[" in line for line in contractions)
-    if Q in (8, 128):
+    if Q == 8:
         assert not _row_wide_selections(compiled, n)        # two stages
 
 
@@ -412,6 +450,34 @@ def test_sharded_flat_kernel_compiles_deep10m_on_four(mesh4, Q):
     # a chip's share of the corpus, its (Q, 2.5M) scores and the top-k's
     # workspace: well inside 16 GB
     _assert_per_device(compiled, 6 * 2 ** 30)
+
+
+def test_sharded_flat_kernel_compiles_int8_cosine_fused_on_four(mesh4):
+    """An int8 cosine mesh folder (no cell runs one yet): every shard
+    takes the fused scan (PR 37), 2.2M x 384 one-byte rows a chip, and
+    holds no (Q, rows) scores."""
+    from sptag_tpu.algo.flat import fused_minima, pad_rows
+    from sptag_tpu.parallel.sharded import (SHARD_AXIS, ShardedFlatIndex,
+                                            _sharded_search_kernel)
+
+    D, Q, stride = 384, 128, ShardedFlatIndex.rows_per_shard(8_841_823, 4)
+    n_slot = pad_rows(stride)
+    assert fused_minima(np.dtype(np.int8), Q, n_slot, D, K, COS, "tpu")
+    rows = NamedSharding(mesh4, P(SHARD_AXIS, None))
+    vec = NamedSharding(mesh4, P(SHARD_AXIS))
+    rep = NamedSharding(mesh4, P(None, None))
+    compiled = _sharded_search_kernel.lower(
+        _s(rows, (4 * n_slot, D), jnp.int8),
+        _s(vec, (4 * n_slot,), jnp.float32),
+        _s(vec, (4 * n_slot,), jnp.bool_), _s(rep, (Q, D), jnp.int8),
+        k_local=K, k_final=K, metric=COS, base=127, mesh=mesh4,
+        row_stride=stride, fused=True).compile()
+    text = compiled.as_text()
+    assert _has_mosaic_kernel(compiled) and "all-gather" in text
+    for scope in ("flat.distance", "flat.topk", "mesh.merge"):
+        assert scope in text, scope
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.3 * 2 ** 30
+    _assert_per_device(compiled, n_slot * D + 2 ** 30)
 
 
 def test_sharded_beam_kernel_compiles_on_four(mesh4):

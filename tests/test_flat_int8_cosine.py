@@ -102,6 +102,32 @@ def test_served_answers_are_the_exact_integer_scan(served, q):
     assert all(n["ok"] for n in got["numbers"]), got
 
 
+def test_the_fused_route_serves_the_same_answers(served):
+    """PR 37: with the Pallas kernels interpreted the 128-query rung takes
+    its group minima from the scan itself (`flat.fused_minima`) - the
+    served answers are the materialised route's to the last bit, the
+    benchmark's rule passes them, and the counters say which route ran."""
+    from sptag_tpu.ops import pallas_kernels
+
+    ctx, data, queries, (_, ref_scores) = served
+    want_ids, want_d = _ask(ctx, queries)
+    assert metrics.counter_value("flat.scan_materialized") == 1
+    pallas_kernels.set_interpret(True)
+    try:
+        ids, dists = _ask(ctx, queries)
+        _ask(ctx, queries[:32])             # one stage: stays materialised
+    finally:
+        pallas_kernels.set_interpret(False)
+    assert metrics.counter_value("flat.scan_fused_minima") == 1
+    assert metrics.counter_value("flat.scan_materialized") == 2
+    assert np.array_equal(ids, want_ids) and np.array_equal(dists, want_d)
+    assert np.array_equal(dists.astype(np.int64), ref_scores)
+    rule = load_by_name("checks", "exact_ids_int_cosine")
+    got = rule.check(data, queries, np.arange(128),
+                     compare.answers_as_window(ids, dists), CONFIG)
+    assert all(n["ok"] for n in got["numbers"]), got
+
+
 def test_a_twin_is_answered_lowest_row_first(served):
     """`exact_topk` breaks ties as `lax.top_k` over the whole row would:
     of two equal scores the lower row comes first."""

@@ -403,6 +403,42 @@ def test_a_wide_shard_selects_in_two_stages_and_is_exact(host_mesh,
     np.testing.assert_allclose(dists, want_d, rtol=1e-4, atol=1e-3)
 
 
+def test_an_int8_cosine_shard_takes_the_fused_scan_and_is_exact(host_mesh):
+    """PR 37: `scan_topk` is the mesh program's scan body too, so a shard
+    of one-byte cosine rows takes its group minima from the Pallas scan
+    (interpret mode here) and scores the chosen groups again - the answers
+    of the materialised route bit for bit, deleted rows and the padded
+    block's tail among them, and the counter says which route ran."""
+    from sptag_tpu.algo import flat
+    from sptag_tpu.ops import pallas_kernels
+    from sptag_tpu.parallel.sharded import ShardedFlatIndex
+
+    rng = np.random.default_rng(37)
+    n, dim, k = 2 * 12_900 + 11, 128, 1
+    data = rng.integers(-127, 128, (n, dim)).astype(np.int8)
+    queries = data[rng.integers(0, n, 128)]
+    deleted = np.zeros(n, bool)
+    deleted[rng.integers(0, n, 300)] = True
+    index = ShardedFlatIndex(data, DistCalcMethod.Cosine, base=127,
+                             mesh=host_mesh(2), deleted=deleted,
+                             normalized=True)
+    n_slot = index.data.shape[0] // 2
+    assert flat.fused_minima(data.dtype, 128, n_slot, dim, k,
+                             int(DistCalcMethod.Cosine), "interpret")
+    want = index.search(queries, k, normalized=True)
+    assert metrics.counter_value("flat.scan_materialized") == 1
+    pallas_kernels.set_interpret(True)
+    try:
+        got = index.search(queries, k, normalized=True)
+    finally:
+        pallas_kernels.set_interpret(False)
+    assert metrics.counter_value("flat.scan_fused_minima") == 1
+    assert np.array_equal(got[0], want[0])
+    assert np.array_equal(got[1], want[1])
+    assert set(got[1].ravel() // index.row_stride) == {0, 1}
+    assert not deleted[got[1].ravel()].any()
+
+
 # ---------------------------------------------------------------- (f) ----
 
 def golden_rows(n=300, d=24):

@@ -444,7 +444,9 @@ STATIC_ATTRS = {"shape", "dtype", "ndim", "size", "itemsize"}
 #: jax/jnp functions that return HOST values even under trace — metadata
 #: queries, not array computations
 _JAX_STATIC_FNS = {"issubdtype", "dtype", "result_type", "shape", "ndim",
-                   "iinfo", "finfo", "can_cast", "promote_types", "size"}
+                   "iinfo", "finfo", "can_cast", "promote_types", "size",
+                   # what the process runs on: Device objects, no arrays
+                   "devices", "local_devices", "default_backend"}
 
 
 def _is_jax_producing_call(call: ast.Call, mod: ModuleInfo) -> bool:
