@@ -80,6 +80,12 @@ TRICKLE_BATCHES = 32
 #: backlogs on at once
 PATIENCE_SHARE = 1.0 / 8
 
+#: the event loop's heartbeat (`SearchServer._beat`): a timer re-armed
+#: from its own callback records how late the loop ran it
+#: (`server.loop_lag`): what a frame arriving at a random instant waits
+#: before the loop can read it.  100 wake-ups a second
+HEARTBEAT_S = 0.010
+
 
 class SearchServer:
     def __init__(self, context: ServiceContext,
@@ -157,6 +163,13 @@ class SearchServer:
         self._queue: asyncio.Queue = asyncio.Queue(maxsize=8 * max_batch)
         self._server: Optional[asyncio.AbstractServer] = None
         self._batcher_task: Optional[asyncio.Task] = None
+        self._heartbeat: Optional[asyncio.Handle] = None
+        # what the arrival and departure sums are counted from
+        # (`server.clock_origin_s`): a perf_counter reading, taken in
+        # start()
+        self._clock_origin = 0.0
+        # the loop thread's CPU seconds at the last batch's t_assembled
+        self._loop_cpu: Optional[float] = None
         # response handoff (ISSUE 4 satellite): encoding + draining a
         # batch's responses runs in a SEPARATE task so the batcher
         # assembles and executes batch N+1 while batch N's responses
@@ -370,8 +383,18 @@ class SearchServer:
                 slo=self._slo_debug,
                 controller=self._controller_debug)
             self._metrics_http.start()
+        # every stamp a request gets is on time.perf_counter(): on Linux
+        # CLOCK_MONOTONIC, one clock for every process of the machine, so
+        # a caller that stamps its sends and reads with it can place the
+        # server's mean arrival and departure instants (the two
+        # record_sum entries of _serve_batch and _respond_*) on its own
+        # time line.  The origin keeps those sums small
+        self._clock_origin = time.perf_counter()
+        metrics.set_gauge("server.clock_origin_s", self._clock_origin)
         self._server = await asyncio.start_server(self._on_client, host, port)
         self._batcher_task = asyncio.create_task(self._batcher())
+        loop = asyncio.get_event_loop()
+        self._heartbeat = loop.call_soon(self._beat, loop, loop.time())
         addr = self._server.sockets[0].getsockname()
         log.info("search server listening on %s:%d", addr[0], addr[1])
         if self.canary_interval_ms > 0:
@@ -405,6 +428,9 @@ class SearchServer:
         if self._metrics_http:
             self._metrics_http.shutdown()
             self._metrics_http = None
+        if self._heartbeat is not None:
+            self._heartbeat.cancel()
+            self._heartbeat = None
         if self._batcher_task:
             self._batcher_task.cancel()
         for task in list(self._response_tasks):
@@ -412,6 +438,16 @@ class SearchServer:
         if self._server:
             self._server.close()
             await self._server.wait_closed()
+
+    def _beat(self, loop: asyncio.AbstractEventLoop, due: float) -> None:
+        """The heartbeat: how long after `due` the loop got to this
+        callback — its own callbacks ahead of it plus its wait for the
+        interpreter lock — then the next one, HEARTBEAT_S on.  A timer
+        may fire a clock tick early: no lag is negative."""
+        now = loop.time()
+        trace.record("server.loop_lag", max(0.0, now - due))
+        due = now + HEARTBEAT_S
+        self._heartbeat = loop.call_at(due, self._beat, loop, due)
 
     def _healthz(self) -> dict:
         """/healthz payload: load state per registered index (sample count,
@@ -775,7 +811,8 @@ class SearchServer:
             first = await self._queue.get()      # sleeps only when idle
             t_first = time.perf_counter()
             batch = [first]
-            self._drain(batch)
+            with trace.annotate("server.gather"):
+                self._drain(batch)
             if backlog:
                 metrics.inc("server.gather_backlog")
             elif futile:
@@ -806,7 +843,8 @@ class SearchServer:
                             trickle = TRICKLE_BATCHES
                         futile = True
                         break
-                    self._drain(batch)
+                    with trace.annotate("server.gather"):
+                        self._drain(batch)
             if len(batch) > 1:
                 futile = False
             answered = len(batch)
@@ -826,8 +864,14 @@ class SearchServer:
         # the batcher's cycle, from timestamps on either side of awaits
         # (an annotation here would name waiting as if it were work)
         trace.record("server.batch_gather", t_assembled - t_first)
+        # this (the loop's) thread's CPU seconds over the same cycle:
+        # beside the executor thread's (run_batch) it says who held the
+        # interpreter, and for how much of the cycle neither did
+        loop_cpu = time.thread_time()
         if t_prev is not None:
             trace.record("server.batch_cycle", t_assembled - t_prev)
+            trace.record("server.loop_cpu", loop_cpu - self._loop_cpu)
+        self._loop_cpu = loop_cpu
         metrics.set_gauge("server.queue_depth", self._queue.qsize())
         _count_batch(len(batch))
         rec = flightrec.enabled()
@@ -855,14 +899,23 @@ class SearchServer:
                 return t_assembled
         texts = []
         rids = []
-        for cid, header, query, t_enq, _deadline, _deg in batch:
-            texts.append(query.query if query is not None else "")
-            rids.append(query.request_id if query is not None else "")
-            trace.record("server.queue_wait", t_assembled - t_enq)
-            if rec:
-                flightrec.record(
-                    self.flight_tier, "queue_wait", rids[-1],
-                    dur_ns=int((t_assembled - t_enq) * 1e9))
+        arrived = 0.0
+        with trace.annotate("server.assemble"):
+            for cid, header, query, t_enq, _deadline, _deg in batch:
+                texts.append(query.query if query is not None else "")
+                rids.append(query.request_id if query is not None else "")
+                trace.record("server.queue_wait", t_assembled - t_enq)
+                arrived += t_enq
+                if rec:
+                    flightrec.record(
+                        self.flight_tier, "queue_wait", rids[-1],
+                        dur_ns=int((t_assembled - t_enq) * 1e9))
+            # the batch's arrival instants, summed: their mean over a
+            # window, against the callers' mean send instant on the same
+            # clock, is a request's way in (socket, loop, decode)
+            trace.record_sum("server.arrival_clock",
+                             arrived - len(batch) * self._clock_origin,
+                             len(batch))
         loop = asyncio.get_event_loop()
         # per-query streaming (continuous batching): the executor invokes
         # on_ready from ITS thread as individual queries finish; each
@@ -895,6 +948,7 @@ class SearchServer:
                     live = [r for r in rids if r]
                     hostprof.set_stage(
                         "execute", live[0] if len(live) == 1 else "")
+                cpu0 = time.thread_time()
                 try:
                     with trace.span("server.execute_batch"):
                         out = self.executor.execute_batch(
@@ -903,6 +957,10 @@ class SearchServer:
                             degrade_floor=deg_floor)
                 finally:
                     hostprof.clear_stage()
+                # this (an executor) thread's CPU seconds in the batch:
+                # the most it can have held the interpreter
+                trace.record("server.executor_cpu",
+                             time.thread_time() - cpu0)
                 return out, time.perf_counter()
             results, t_returned = await loop.run_in_executor(None,
                                                              run_batch)
@@ -934,6 +992,9 @@ class SearchServer:
                 await self._respond_batch(batch, results, streamed,
                                           t_assembled, t_executed)
             finally:
+                # executed -> the batch's last reply with its socket
+                trace.record("server.batch_reply",
+                             time.perf_counter() - t_executed)
                 replied.set()
         if self._fault.enabled:
             replied.set()       # an injected delay holds no batch back
@@ -1005,16 +1066,23 @@ class SearchServer:
             with trace.span("server.drain"):
                 await self._send(cid, b"".join(p for _e, _r, p in sent))
             now = time.perf_counter()
-            for entry, result, _payload in sent:
-                self._after_response(entry, result, t_assembled,
-                                     t_executed, t_send0, now)
+            with trace.annotate("server.after_response"):
+                for entry, result, _payload in sent:
+                    self._after_response(entry, result, t_assembled,
+                                         t_executed, t_send0, now)
+            # these replies' departure instants, summed (all `now`): the
+            # callers' mean read instant less their mean is the way back
+            trace.record_sum("server.departure_clock",
+                             (now - self._clock_origin) * len(sent),
+                             len(sent))
 
     async def _respond_expired(self, entries, t_assembled: float) -> None:
         """Answer deadline-expired queries with Timeout — cheap, honest,
         and the client (which may already have given up) stays
-        stream-aligned either way."""
+        stream-aligned either way.  They reached no batch: they are in
+        neither `server.arrival_clock` nor `server.departure_clock`."""
         for entry in entries:
-            await self._respond_one(
+            await self._write_one(
                 entry, wire.RemoteSearchResult(wire.ResultStatus.Timeout,
                                                []),
                 t_assembled, t_assembled)
@@ -1089,6 +1157,17 @@ class SearchServer:
 
     async def _respond_one(self, entry, result, t_assembled: float,
                            t_executed: float) -> None:
+        """One answer of a batch in a write of its own (streamed, or any
+        while a fault spec is live), and its departure instant."""
+        now = await self._write_one(entry, result, t_assembled, t_executed)
+        if now is not None:
+            trace.record_sum("server.departure_clock",
+                             now - self._clock_origin, 1)
+
+    async def _write_one(self, entry, result, t_assembled: float,
+                         t_executed: float) -> Optional[float]:
+        """Encode, write, drain, `_after_response` -> the instant the
+        drain returned; None where an injected fault consumed the reply."""
         cid = entry[0]
         with trace.span("server.encode"):
             result, payload = self._encode_response(entry, result)
@@ -1097,12 +1176,14 @@ class SearchServer:
             if fault is not None:
                 payload = await self._apply_fault(fault, cid, payload)
                 if payload is None:
-                    return          # drop / disconnect consumed it
+                    return None     # drop / disconnect consumed it
         t_send0 = time.perf_counter()
         with trace.span("server.drain"):
             await self._send(cid, payload)
+        now = time.perf_counter()
         self._after_response(entry, result, t_assembled, t_executed,
-                             t_send0, time.perf_counter())
+                             t_send0, now)
+        return now
 
     def _after_response(self, entry, result, t_assembled: float,
                         t_executed: float, t_send0: float,

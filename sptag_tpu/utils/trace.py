@@ -83,6 +83,19 @@ def record(name: str, seconds: float) -> None:
     metrics.observe(name, seconds)
 
 
+def record_sum(name: str, total_s: float, count: int) -> None:
+    """Add `count` occurrences worth `total_s` seconds in all to an entry,
+    in one locked update, and feed no histogram: for a per-batch site
+    whose per-request values only their sum is wanted of (serve/server.py
+    sums its requests' arrival and departure instants this way).
+    `report()` hands out count, total and mean like a span's; max_s stays
+    0 and there are no percentiles."""
+    with _lock:
+        rec = _spans.setdefault(name, [0, 0.0, 0.0])
+        rec[0] += count
+        rec[1] += total_s
+
+
 def report() -> Dict[str, Dict[str, float]]:
     """Snapshot of all spans: {name: {count, total_s, mean_s, max_s,
     p50_s, p90_s, p99_s}} — the percentiles come from the log-bucketed

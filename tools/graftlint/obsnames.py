@@ -11,8 +11,8 @@ value set is bounded by definition).
 
 Rules:
 
-* GL601 — the name argument of `trace.span(...)` / `trace.record(...)`
-  is not a string literal or module-level string constant.
+* GL601 — the name argument of `trace.span(...)` / `trace.record(...)` /
+  `trace.record_sum(...)` is not a string literal or module-level string constant.
 * GL602 — the name argument of a metrics-registry call
   (`metrics.counter/gauge/histogram/inc/set_gauge/observe/
   counter_value/histogram_or_none`) is not a string literal or
@@ -96,7 +96,7 @@ _HOSTPROF_MODULE = "sptag_tpu.utils.hostprof"
 _TIMELINE_MODULE = "sptag_tpu.utils.timeline"
 _CTLAUDIT_MODULE = "sptag_tpu.serve.ctlaudit"
 
-_TRACE_FNS = {"span", "record"}
+_TRACE_FNS = {"span", "record", "record_sum"}
 _METRICS_FNS = {"counter", "gauge", "histogram", "inc", "set_gauge",
                 "observe", "counter_value", "histogram_or_none"}
 _FLIGHT_FNS = {"record", "span"}
