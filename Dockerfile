@@ -11,9 +11,11 @@ WORKDIR /app
 COPY . .
 
 RUN pip install --no-cache-dir "jax[cpu]" numpy pytest hypothesis
-# the native host library is built by its own loader, which stamps it with
-# source, flags and this machine's CPU; a container started on another
-# machine finds the stamp stale and rebuilds (g++ stays in the image)
+# the native host library (TSV ingestion, wire codec, and the served query-
+# vector parse sptag_parse_query_vectors) is built by its own loader, which
+# stamps it with source, flags and this machine's CPU; a container started
+# on another machine finds the stamp stale and rebuilds when its first
+# SearchServer is constructed (g++ stays in the image)
 RUN python -c "from sptag_tpu import native; assert native.load() is not None"
 
 RUN python -m pytest tests/ -q

@@ -11,18 +11,88 @@
 //    into a row-major float32 matrix + metadata offsets, one block per
 //    thread;
 //  * the wire packet-header codec (inc/Socket/Packet.h:52-76) for
-//    high-throughput serving front doors.
+//    high-throughput serving front doors;
+//  * the served query-vector parser: the text vectors of one batch group
+//    ("<v1>|<v2>|...", SearchExecutionContext::ExtractVector's text form)
+//    parsed by one call into the group's (Q, D) array of the index's
+//    value type.  It accepts a strict subset of what the Python route
+//    (serve/protocol.py::ParsedQuery.extract_vector) accepts, with the
+//    same value; a row it does not accept is left for that route to decide.
 //
 // Exposed as a plain C ABI for ctypes (no pybind11 in this toolchain).
 //
 // Build: g++ -O3 -march=native -shared -fPIC -o libsptag_host.so
 //        sptag_host.cpp -lpthread
 
+#include <cfloat>
+#include <charconv>
+#include <cmath>
 #include <cstdint>
 #include <cstdlib>
 #include <cstring>
+#include <limits>
+#include <system_error>
 #include <thread>
+#include <type_traits>
 #include <vector>
+
+// Query-vector parse helpers (templates: outside the C linkage block).
+namespace {
+
+// One row "<v1><sep><v2>..." -> out[0..dim).  Empty elements are skipped
+// (doubled / leading / trailing separators), as extract_vector's
+// `[p for p in text.split(sep) if p != ""]` skips them.  An element is
+// accepted only if std::from_chars (locale-free, correctly rounded: the
+// value Python's float() gives) reads a number that ends exactly at the
+// next separator or at the row's end, without error, and the double is
+// finite and the row type can hold it: ndarray.astype's C cast is then
+// defined and is this cast.  What from_chars reads is made of [0-9.eE+-]
+// alone - or spells inf / nan, which are not finite - so it holds no
+// separator (the caller keeps separators of that alphabet away) and is an
+// element str.split would have cut the same way; Python's float() accepts
+// every such element with the same value.  So a false return never
+// decides a request: the caller hands the row to the Python route.
+template <typename T>
+bool parse_row(const char* p, const char* end, char sep, int dim, T* out) {
+    int d = 0;
+    while (p < end) {
+        if (*p == sep) {
+            ++p;
+            continue;
+        }
+        if (d == dim) return false;
+        double v = 0.0;
+        const std::from_chars_result r = std::from_chars(p, end, v);
+        if (r.ec != std::errc() || (r.ptr != end && *r.ptr != sep)
+            || !std::isfinite(v))
+            return false;
+        if constexpr (std::is_floating_point<T>::value) {
+            if (std::fabs(v) > static_cast<double>(FLT_MAX)) return false;
+            out[d] = static_cast<T>(v);
+        } else {
+            // astype truncates toward zero; in range after truncation
+            const double t = std::trunc(v);
+            if (t < static_cast<double>(std::numeric_limits<T>::min())
+                || t > static_cast<double>(std::numeric_limits<T>::max()))
+                return false;
+            out[d] = static_cast<T>(t);
+        }
+        ++d;
+        p = r.ptr;
+    }
+    return d == dim;
+}
+
+template <typename T>
+void parse_rows(const char* buf, const long long* offsets, long long rows,
+                int dim, char sep, T* out, std::uint8_t* row_ok) {
+    for (long long r = 0; r < rows; ++r) {
+        row_ok[r] = parse_row<T>(buf + offsets[r], buf + offsets[r + 1],
+                                 sep, dim, out + r * dim) ? 1 : 0;
+    }
+}
+
+}  // namespace
 
 extern "C" {
 
@@ -173,6 +243,43 @@ long long sptag_parse_tsv(const char* buf, long long len, char delim,
         }
     }
     return total_rows;
+}
+
+// ------------------------------------------------------- query-vector parse
+
+// Row r is the bytes buf[offsets[r] : offsets[r + 1]].  value_type is the
+// reference's VectorValueType code (DefinitionList.h: Int8 0, UInt8 1,
+// Int16 2, Float 3); out is (rows x dim) of that type.  row_ok[r] is 1
+// only if row r had exactly dim accepted elements; the contents of a row
+// that is not ok are unspecified.  sep is no character a number is written
+// with ([0-9.eE+-]).  Returns 0, or -1 for such a separator or a value
+// type it does not know (nothing is written).
+int sptag_parse_query_vectors(const char* buf, const long long* offsets,
+                              long long rows, int dim, char sep,
+                              int value_type, void* out,
+                              std::uint8_t* row_ok) {
+    if ((sep >= '0' && sep <= '9') || std::strchr(".eE+-", sep)) return -1;
+    if (rows <= 0 || dim <= 0) return 0;
+    switch (value_type) {
+    case 0:
+        parse_rows(buf, offsets, rows, dim, sep,
+                   static_cast<std::int8_t*>(out), row_ok);
+        return 0;
+    case 1:
+        parse_rows(buf, offsets, rows, dim, sep,
+                   static_cast<std::uint8_t*>(out), row_ok);
+        return 0;
+    case 2:
+        parse_rows(buf, offsets, rows, dim, sep,
+                   static_cast<std::int16_t*>(out), row_ok);
+        return 0;
+    case 3:
+        parse_rows(buf, offsets, rows, dim, sep,
+                   static_cast<float*>(out), row_ok);
+        return 0;
+    default:
+        return -1;
+    }
 }
 
 // ------------------------------------------------------------ packet codec

@@ -2,10 +2,13 @@
 
 The reference's host runtime is C++ end to end; here the TPU compute path is
 XLA and the native library accelerates the host-side hot paths (parallel TSV
-ingestion, wire codec).  Built on demand with g++ (this toolchain has no
+ingestion, wire codec, and the served query-vector parse:
+`parse_query_vectors`, one call a batch group with the interpreter lock
+free).  Built on demand with g++ (this toolchain has no
 pybind11 — plain C ABI + ctypes), cached next to the source, and every
 caller degrades gracefully to the pure-Python implementation when the
-library is unavailable.
+library is unavailable.  A server loads (or builds) it when its
+`SearchExecutor` is constructed, never inside a batch.
 
 The binary is compiled with -march=native, so it is only ever valid on the
 machine that built it.  A stamp file next to it records what it was built
@@ -121,6 +124,13 @@ def load() -> Optional[ctypes.CDLL]:
             ctypes.c_char_p, ctypes.c_longlong, ctypes.c_char, ctypes.c_int,
             ctypes.c_int, ctypes.POINTER(ctypes.c_float), ctypes.c_char_p,
             ctypes.POINTER(ctypes.c_longlong)]
+        # addresses as plain integers (`ndarray.ctypes.data`): this one is
+        # called once a served batch group
+        lib.sptag_parse_query_vectors.restype = ctypes.c_int
+        lib.sptag_parse_query_vectors.argtypes = [
+            ctypes.c_char_p, ctypes.c_void_p, ctypes.c_longlong,
+            ctypes.c_int, ctypes.c_char, ctypes.c_int, ctypes.c_void_p,
+            ctypes.c_void_p]
         _lib = lib
         return _lib
 
@@ -155,3 +165,40 @@ def parse_tsv(blob: bytes, delimiter: str, dim: int, threads: int):
         metas.append(raw[off:off + n])
         off += n
     return out, metas
+
+
+def parse_query_vectors(texts, sep: str, dim: int, value_type):
+    """The text vectors of one served batch group ("<v1><sep><v2>...", one
+    str a query) -> ((len(texts), dim) array of `value_type`'s dtype, bool
+    mask of the rows it holds), by ONE native call that runs with the
+    interpreter lock released (ctypes.CDLL).  None when the library is
+    unavailable or `sep` / `dim` / `value_type` is not something it takes.
+
+    A row is accepted only in forms `ParsedQuery.extract_vector` accepts
+    too, with the same value (native/sptag_host.cpp::parse_row); a row
+    whose mask is False was not decided: hand it to `extract_vector`."""
+    import numpy as np
+
+    from sptag_tpu.core.types import dtype_of
+
+    lib = load()
+    if lib is None or dim <= 0 or len(sep) != 1 or ord(sep) > 127:
+        return None
+    try:
+        dtype = dtype_of(value_type)
+    except (KeyError, ValueError):
+        return None
+    rows = len(texts)
+    offsets = np.zeros(rows + 1, np.int64)
+    np.cumsum(np.fromiter(map(len, texts), np.int64, rows),
+              out=offsets[1:])
+    # one '?' a non-ASCII character: lengths stay the strs', and '?' is
+    # no element the parser accepts
+    buf = "".join(texts).encode("ascii", "replace")
+    out = np.empty((rows, dim), dtype)
+    ok = np.zeros(rows, np.bool_)
+    if lib.sptag_parse_query_vectors(
+            buf, offsets.ctypes.data, rows, dim, sep.encode(),
+            int(value_type), out.ctypes.data, ok.ctypes.data) < 0:
+        return None
+    return out, ok

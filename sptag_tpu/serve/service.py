@@ -21,6 +21,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from sptag_tpu import native
 from sptag_tpu.core.index import VectorIndex, load_index
 from sptag_tpu.core.vectorset import metas_for
 from sptag_tpu.serve.protocol import (
@@ -506,6 +507,10 @@ class SearchExecutor:
 
     def __init__(self, context: ServiceContext):
         self.context = context
+        # the batch path's vector parse (`_parse_vectors`): load the host
+        # library, or build it on a machine without a fresh binary, while
+        # the server is set up — never inside its first batch
+        native.load()
 
     def execute(self, query_text: str) -> RemoteSearchResult:
         parsed = parse_query(query_text)
@@ -956,20 +961,53 @@ class SearchExecutor:
                        ) -> Tuple[Optional[np.ndarray], List[int]]:
         """The group's query texts -> one (Q, D) array and the batch
         positions it holds; a query whose vector does not parse or has
-        the wrong width is answered FailedExecute here."""
-        vecs = []
-        ok: List[int] = []
+        the wrong width is answered FailedExecute here.
+
+        `ParsedQuery.extract_vector` defines what a vector is.  The rows
+        that carry a text vector in the index's own value type go to ONE
+        native call first (`native.parse_query_vectors`, the interpreter
+        lock free while it runs), which accepts only forms that
+        `extract_vector` accepts with the same value; whatever it did not
+        accept — and every `#base64` row, every row whose `$datatype`
+        names another type, every row when the library is absent — is
+        decided by `extract_vector`, so the arrays, the order of `ok` and
+        the failures are the all-Python loop's for every input.  Counters
+        `service.parse_native` / `service.parse_python`: queries by the
+        route that decided them."""
+        sep = self.context.settings.vector_separator
+        vtype = index.value_type
+        dim = index.feature_dim
         with trace.span("service.parse"):
+            cand = [i for i in idxs
+                    if parsed[i].vector_base64 is None
+                    and parsed[i].vector_text is not None
+                    and (parsed[i].data_type or vtype) == vtype]
+            got = (native.parse_query_vectors(
+                [parsed[i].vector_text for i in cand], sep, dim, vtype)
+                if cand else None)
+            rows: Dict[int, np.ndarray] = {}
+            if got is not None:
+                block, accepted = got
+                if len(cand) == len(idxs) and accepted.all():
+                    metrics.inc("service.parse_native", len(idxs))
+                    return block, list(idxs)
+                rows = {i: block[r] for r, i in enumerate(cand)
+                        if accepted[r]}
+            vecs = []
+            ok: List[int] = []
             for i in idxs:
-                v = parsed[i].extract_vector(
-                    parsed[i].data_type or index.value_type,
-                    self.context.settings.vector_separator)
-                if v is None or v.shape[-1] != index.feature_dim:
+                v = rows.get(i)
+                if v is None:
+                    v = parsed[i].extract_vector(
+                        parsed[i].data_type or vtype, sep)
+                if v is None or v.shape[-1] != dim:
                     results[i] = RemoteSearchResult(
                         ResultStatus.FailedExecute, [])
                 else:
                     vecs.append(v)
                     ok.append(i)
+            metrics.inc("service.parse_native", len(rows))
+            metrics.inc("service.parse_python", len(idxs) - len(rows))
             return (np.stack(vecs) if ok else None), ok
 
     def _degrade_max_check(self, mc: Optional[int],

@@ -538,11 +538,22 @@ def test_mutation_off_parity_serve_bytes():
 # ------------------------------------------------- e2e kill/restart
 
 def _free_port():
-    s = socket.socket()
-    s.bind(("127.0.0.1", 0))
-    port = s.getsockname()[1]
-    s.close()
-    return port
+    """A free port BELOW the kernel's ephemeral range (32768 up): the
+    child binds it seconds after this probe, and a port the kernel hands
+    out for `bind(0)` can go to another xdist worker's test server in
+    between (seen once: the client then talked to that server)."""
+    import random
+
+    rng = random.Random(os.getpid() ^ time.time_ns())
+    for _ in range(64):
+        port = rng.randrange(20000, 30000)
+        with socket.socket() as s:
+            try:
+                s.bind(("127.0.0.1", port))
+            except OSError:
+                continue
+        return port
+    raise RuntimeError("no free port under the ephemeral range")
 
 
 def _spawn_server(cfg):
