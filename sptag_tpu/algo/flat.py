@@ -6,13 +6,19 @@ minimum end-to-end slice (SURVEY.md §7 step 3): exact top-K as one
 ground-truth oracle for recall tests and as the search path for not-yet-merged
 delta rows in the mutable graph indexes.
 
-Device layout: the corpus lives as an immutable (Npad, D) jax.Array snapshot
-(rows padded to a lane-friendly multiple); deletes and padding are folded into
-the top-k as +inf distances (the reference filters tombstones in its hot loop
-instead, BKTIndex.cpp:234-239 — on TPU a masked dense top-k is cheaper than
-divergent control flow).  Mutation follows the single-writer snapshot design
-(SURVEY.md §2b P7): the host buffer grows, a dirty flag triggers a fresh
-device snapshot on the next search.
+Device layout: the corpus lives as one resident (slots, D) jax.Array block
+(rows padded to a lane-friendly multiple); deletes, padding and slots not yet
+taken are folded into the top-k as +inf distances (the reference filters
+tombstones in its hot loop instead, BKTIndex.cpp:234-239 — on TPU a masked
+dense top-k is cheaper than divergent control flow).  Mutation follows the
+single-writer design (SURVEY.md §2b P7) and the device state follows the
+mutations: an add writes its rows, their norms and their mask bits into
+the block IN PLACE, a delete its mask bits (`_block_write_rows`,
+`_block_mask_rows`: the block's arrays are donated to the write, so what
+crosses to the device is what changed); slots are reserved ahead of the
+rows (`reserved_slots`), so between two growths every program keeps its
+shape.  A wholesale change (build, load, refine) still marks the block
+dirty and the next search places a fresh one.
 """
 
 from __future__ import annotations
@@ -47,6 +53,33 @@ _QUERY_BUCKETS = (1, 8, 32, 128, 512)
 def pad_rows(n: int) -> int:
     """Row slots of the device block that holds `n` corpus rows."""
     return max(_ROW_PAD, round_up(n, _ROW_PAD))
+
+
+# An in-place write carries its rows at one of these counts (pad rows are
+# written masked into slots nothing occupies yet): a few programs a block,
+# whatever sizes the adds come in.  More rows than the top rung go in
+# pieces of it.
+_WRITE_RUNGS = (8, 128, 1024)
+# Slots reserved ahead of the rows when a block has to grow: a sixteenth
+# of the rows (the scan reads every slot, so the reserve is what an empty
+# slot costs a search: 6 %), and no fewer than this many.
+_RESERVE_SHARE, _RESERVE_MIN = 16, 8192
+
+
+def reserved_slots(need: int) -> int:
+    """Row slots of a block grown to hold `need` rows and the adds to
+    come: a rule of the rows alone, as the host buffer's doubling is
+    (`FlatIndex._reserve`)."""
+    return pad_rows(need + max(need // _RESERVE_SHARE, _RESERVE_MIN))
+
+
+def _write_pieces(rows: int) -> list:
+    """`rows` rows as the in-place writes that carry them: (first row,
+    rows, rung) a write, whole top rungs and then the rest at its own."""
+    top = _WRITE_RUNGS[-1]
+    return [(lo, min(top, rows - lo),
+             next(r for r in _WRITE_RUNGS if min(top, rows - lo) <= r))
+            for lo in range(0, rows, top)]
 
 
 def _query_bucket(q: int) -> int:
@@ -371,6 +404,38 @@ _PACK_JIT = jax.jit(_pack_sign_bits)    # one wrapper -> shape-keyed cache
 _INT_SQNORMS = jax.jit(dist_ops.row_sqnorms)
 
 
+# ---------------------------------------------------------------------------
+# the resident block's in-place writes
+# ---------------------------------------------------------------------------
+
+@functools.partial(jax.jit, donate_argnums=(0, 1, 2))
+def _block_write_rows(data, sqnorm, invalid, rows, dead, start):
+    """`rows` (a write rung, D), their norms and their mask bits `dead`
+    written at slots [start, start + rung) of the donated block: the
+    arrays are updated where they lie, and the host sends the rows, one
+    byte a row of mask and `start`."""
+    return (jax.lax.dynamic_update_slice(data, rows, (start, 0)),
+            jax.lax.dynamic_update_slice(
+                sqnorm, dist_ops.row_sqnorms(rows), (start,)),
+            jax.lax.dynamic_update_slice(invalid, dead, (start,)))
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _block_mask_rows(invalid, slots):
+    """Mask bits of `slots` (a write rung of int32; a slot past the end
+    is dropped: the rung's padding) set in the donated mask."""
+    return invalid.at[slots].set(True, mode="drop")
+
+
+@functools.partial(jax.jit, static_argnames=("slots",))
+def _block_grown(data, sqnorm, invalid, slots: int):
+    """The block copied on the device into one of `slots` row slots, the
+    new ones zero and masked.  Nothing is donated: the shapes differ."""
+    extra = slots - data.shape[0]
+    return (jnp.pad(data, ((0, extra), (0, 0))), jnp.pad(sqnorm, (0, extra)),
+            jnp.pad(invalid, (0, extra), constant_values=True))
+
+
 _CAL_SAMPLE = 64        # rows sampled as self-queries for calibration
 _CAL_K = 10             # neighbor depth the shortlist is calibrated to
 
@@ -546,7 +611,24 @@ def _pack_bits_cost(R, D, **_):
     return 3.0 * R * D, R * D * 4 + R * ((D + 31) // 32) * 4
 
 
+def _block_write_cost(R, D, itemsize=4, **_):
+    """In-place write of R rows: the rows in, rows + norms + mask out."""
+    return 2.0 * R * D, 2.0 * R * D * itemsize + R * 5
+
+
+def _block_mask_cost(R, **_):
+    return 0.0, R * 5
+
+
+def _block_grow_cost(N, D, itemsize=4, **_):
+    """A device copy of the whole block: read once, written once."""
+    return 0.0, 2.0 * N * (D * itemsize + 5)
+
+
 costmodel.register("flat.scan", _flat_search_kernel, _flat_scan_cost)
+costmodel.register("flat.block_write", _block_write_rows, _block_write_cost)
+costmodel.register("flat.block_mask", _block_mask_rows, _block_mask_cost)
+costmodel.register("flat.block_grow", _block_grown, _block_grow_cost)
 costmodel.register("flat.sketch_scan", _flat_sketch_kernel,
                    _flat_sketch_cost)
 costmodel.register("flat.sketch_cal", _sketch_cal_kernel, _sketch_cal_cost)
@@ -564,7 +646,14 @@ class FlatIndex(VectorIndex):
         self._deleted = np.zeros(0, dtype=bool)
         self._num_deleted = 0
         self._dirty = True
+        # the resident block (rows, norms, mask).  Its arrays are DONATED
+        # to the next in-place write: whoever reads the tuple dispatches
+        # on it under `_lock` (`_live`, `_device_append`)
         self._device: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None
+        # scan programs dispatched on this block, as their static
+        # arguments: a growth runs each once on the new shape, so the
+        # compile is the writer's and not the next search's
+        self._programs: set = set()
         self._sketch: Optional[Tuple[jax.Array, jax.Array]] = None
         # tiered cascade snapshot (ops/cascade.py, ISSUE 14); rebuilt on
         # mutation like the sketch cache
@@ -633,40 +722,106 @@ class FlatIndex(VectorIndex):
         self._reserve(data.shape[0])
         self._host[begin:begin + data.shape[0]] = data
         self._n += data.shape[0]
-        self._dirty = True
+        if self._live():
+            self._device_append(begin, self._host[begin:self._n])
+        else:
+            self._dirty = True
         self._invalidate_derived()
         return begin
 
     def _delete_id(self, vid: int) -> bool:
-        if self._deleted[vid]:
-            return False
-        self._deleted[vid] = True
-        self._num_deleted += 1
-        self._dirty = True
+        return self._delete_ids([vid]) == 1
+
+    def _delete_ids(self, vids) -> int:
+        fresh = [v for v in dict.fromkeys(map(int, vids))
+                 if not self._deleted[v]]
+        if not fresh:
+            return 0
+        self._deleted[fresh] = True
+        self._num_deleted += len(fresh)
+        if self._live():
+            self._device_mask(fresh)
+        else:
+            self._dirty = True
         self._invalidate_derived()
-        return True
+        return len(fresh)
 
-    # ---- delta shard (ISSUE 9) --------------------------------------------
+    # ---- the resident block follows its mutations -------------------------
 
-    def _append_rows_unlinked(self, data: np.ndarray) -> Optional[int]:
-        """Delta-shard fast path: rows land in host storage WITHOUT
-        dirtying the device snapshot — the (Npad, D) upload FLAT would
-        otherwise pay per add is exactly what the bounded delta scan
-        avoids.  The snapshot keeps covering [0, _main_rows())."""
-        begin = self._n
-        self._reserve(data.shape[0])
-        self._host[begin:begin + data.shape[0]] = data
-        self._n += data.shape[0]
-        return begin
+    def _live(self) -> bool:
+        """Whether a mutation writes the resident block in place (lock
+        held): a block is placed and current, and nothing cached was
+        derived from it.  A sketch or a cascade state holds the block's
+        arrays outside the lock (`_calibrate`, `CascadeState.fp_dev`)
+        and is rebuilt over the whole corpus after every mutation
+        anyway: with one cached the mutation marks the block dirty, as
+        every mutation did before the block took writes in place."""
+        return (self._device is not None and not self._dirty
+                and self._sketch is None and self._cascade is None)
 
-    def _tombstone_mask(self) -> Optional[np.ndarray]:
-        return self._deleted[:self._n]
+    def _device_append(self, begin: int, rows: np.ndarray) -> None:
+        """Rows [begin, begin + len(rows)) of the host buffer written
+        into the resident block (lock held), growing it first where the
+        write's rung would pass its last slot.  Host -> device: the
+        rungs' rows, a byte of mask a row, the start."""
+        pieces = _write_pieces(len(rows))
+        need = begin + pieces[-1][0] + pieces[-1][2]
+        if need > self._device[0].shape[0]:
+            self._grow(need)
+        sent = 0
+        with trace.span("flat.block_update"):
+            block = self._device
+            for lo, count, rung in pieces:
+                padded = np.zeros((rung, rows.shape[1]), rows.dtype)
+                padded[:count] = rows[lo:lo + count]
+                dead = np.ones(rung, bool)
+                dead[:count] = self._deleted[begin + lo:begin + lo + count]
+                block = _block_write_rows(*block, padded, dead,
+                                          np.int32(begin + lo))
+                sent += padded.nbytes + dead.nbytes + 4
+            self._publish(block)
+        metrics.inc("flat.block_updates")
+        trace.record_sum("flat.block_upload_bytes", sent, len(rows))
 
-    def _absorb_delta_impl(self, begin: int, count: int) -> None:
-        # the rows are already resident in _host; absorbing is just
-        # letting the next snapshot cover them
-        self._dirty = True
-        self._invalidate_derived()
+    def _device_mask(self, vids) -> None:
+        """Mask bits of rows `vids` set in the resident block (lock
+        held).  Host -> device: four bytes a row of its rung."""
+        sent = 0
+        with trace.span("flat.block_update"):
+            data_d, sqnorm_d, invalid_d = self._device
+            for lo, count, rung in _write_pieces(len(vids)):
+                slots = np.full(rung, invalid_d.shape[0], np.int32)
+                slots[:count] = vids[lo:lo + count]
+                invalid_d = _block_mask_rows(invalid_d, slots)
+                sent += slots.nbytes
+            self._publish((data_d, sqnorm_d, invalid_d))
+        metrics.inc("flat.block_updates")
+        trace.record_sum("flat.block_upload_bytes", sent, len(vids))
+
+    def _grow(self, need: int) -> None:
+        """A block of `reserved_slots(need)` slots in the resident one's
+        place (lock held), copied on the device, and every scan program
+        that ran on the old shape run once on the new one: the writer
+        that outgrew the reserve pays the compiles, not the searches
+        after it."""
+        with trace.span("flat.block_grow"):
+            block = _block_grown(*self._device, slots=reserved_slots(need))
+            zeros = {}
+            for q, k, statics in self._programs:
+                if q not in zeros:
+                    zeros[q] = jnp.zeros((q, block[0].shape[1]),
+                                         block[0].dtype)
+                _flat_search_kernel(*block, zeros[q], k, **dict(statics))
+            self._publish(block)
+        metrics.inc("flat.block_grows")
+
+    def _publish(self, block) -> None:
+        """`block` as the resident one (lock held)."""
+        self._device = block
+        metrics.set_gauge("flat.rows_resident", self._n)
+        metrics.set_gauge("flat.slots_reserved", block[0].shape[0])
+        devmem.track("corpus", block[0],
+                     block[0].nbytes + block[1].nbytes + block[2].nbytes)
 
     # ---- device snapshot --------------------------------------------------
 
@@ -687,6 +842,9 @@ class FlatIndex(VectorIndex):
                 self._cascade.register_devmem()
 
     def _snapshot(self):
+        """The resident block, placed anew where a wholesale change left
+        it dirty.  The tuple is good for a dispatch made under `_lock`:
+        the next in-place write donates its arrays."""
         if not self._dirty and self._device is not None:
             return self._device
         # Rebuild under the index's single-writer lock so a mutation landing
@@ -694,9 +852,7 @@ class FlatIndex(VectorIndex):
         with self._lock:
             if not self._dirty and self._device is not None:
                 return self._device
-            # snapshot coverage stops at the delta base: rows beyond it
-            # are served by the FLAT-scanned delta shard until absorbed
-            n = self._main_rows()
+            n = self._n
             n_pad = pad_rows(n)
             dt = dtype_of(self.value_type)
             data = np.zeros((n_pad, self.feature_dim), dtype=dt)
@@ -711,14 +867,13 @@ class FlatIndex(VectorIndex):
             sqnorm_d = (_INT_SQNORMS(data_d) if np.issubdtype(dt, np.integer)
                         else dist_ops.row_sqnorms(data_d))
             invalid_d = jnp.asarray(invalid)
-            self._device = (data_d, sqnorm_d, invalid_d)
-            metrics.set_gauge("flat.rows_resident", n)
             metrics.set_gauge("flat.row_itemsize", data_d.dtype.itemsize)
-            # device-memory ledger: the corpus snapshot's resident bytes,
-            # owned by the data array itself — a snapshot rebuild drops
-            # the old entry when the old arrays are collected
-            devmem.track("corpus", data_d,
-                         data_d.nbytes + sqnorm_d.nbytes + invalid_d.nbytes)
+            # device-memory ledger (in `_publish`): the block's resident
+            # bytes, owned by the data array itself — a rebuild, a growth
+            # or an in-place write drops the old entry when the old array
+            # is collected
+            self._publish((data_d, sqnorm_d, invalid_d))
+            self._programs = set()
             self._sketch = None          # derived; rebuilt on demand
             self._dirty = False
             return self._device
@@ -801,7 +956,7 @@ class FlatIndex(VectorIndex):
         if cal_r is not None:
             return device, packed, mean, cal_r
         loaded = self._loaded_cal
-        if loaded is not None and loaded[0] == self._main_rows() \
+        if loaded is not None and loaded[0] == self._n \
                 and loaded[1] == self._num_deleted and loaded[2] > 0:
             cal_r = int(loaded[2])
         else:
@@ -838,12 +993,11 @@ class FlatIndex(VectorIndex):
                 int(getattr(self.params, "tier_budget_sketch", 0)),
                 int(getattr(self.params, "tier_budget_int8", 0)))
             return self._pad_k(dists[:q], ids[:q], q, k, k_eff)
-        data_d, sqnorm_d, invalid_d = self._snapshot()
-        k_eff = min(k, data_d.shape[0])
         if getattr(self.params, "sketch_prefilter", False) \
-                and data_d.shape[0] > 256:
-            # re-read atomically WITH the sketches (a concurrent mutation
-            # may have rebuilt the snapshot since the read above)
+                and pad_rows(self._n) > 256:
+            # the sketches are read atomically WITH the block they were
+            # derived from; while they are cached no write donates it
+            # (`_live`), so the dispatch needs no lock
             explicit_r = getattr(self.params, "sketch_rerank", 0)
             if explicit_r:
                 (data_d, sqnorm_d, invalid_d), sketches, mean, cal_r = \
@@ -873,22 +1027,34 @@ class FlatIndex(VectorIndex):
         else:
             rt = topk_bins.validate_recall_target(
                 getattr(self.params, "approx_recall_target", 0.99))
-            bins = topk_bins.resolve_bins(
-                str(getattr(self.params, "binned_topk", "off")), k_eff,
-                data_d.shape[0], rt)
             approx = bool(getattr(self.params, "approx_topk", False))
-            route = {}
-            if not (approx or bins):
-                count_select(queries.shape[0], data_d.shape[0], k_eff)
-                route = scan_route(data_d.dtype, queries.shape[0],
-                                   *data_d.shape, k_eff,
-                                   int(self.dist_calc_method))
-                count_route(route["fused"])
-            count_dot(data_d.dtype, data_d.shape[1])
-            dists, ids = _flat_search_kernel(
-                data_d, sqnorm_d, invalid_d, jnp.asarray(queries), k_eff,
-                int(self.dist_calc_method), self.base, approx=approx,
-                recall_target=rt, binned_bins=bins, **route)
+            queries_d = jnp.asarray(queries)
+            # read the block and enqueue the program on it under the
+            # writer's lock: the next in-place write donates these arrays,
+            # and the device runs it after what is already enqueued.  A
+            # search sees the block before a mutation or after it
+            with self._lock:
+                data_d, sqnorm_d, invalid_d = self._snapshot()
+                k_eff = min(k, data_d.shape[0])
+                statics = {"metric": int(self.dist_calc_method),
+                           "base": self.base, "approx": approx,
+                           "recall_target": rt,
+                           "binned_bins": topk_bins.resolve_bins(
+                               str(getattr(self.params, "binned_topk",
+                                           "off")),
+                               k_eff, data_d.shape[0], rt)}
+                if not (approx or statics["binned_bins"]):
+                    count_select(queries.shape[0], data_d.shape[0], k_eff)
+                    statics.update(scan_route(
+                        data_d.dtype, queries.shape[0], *data_d.shape,
+                        k_eff, int(self.dist_calc_method)))
+                    count_route(statics["fused"])
+                count_dot(data_d.dtype, data_d.shape[1])
+                self._programs.add((queries.shape[0], k_eff,
+                                    tuple(statics.items())))
+                dists, ids = _flat_search_kernel(
+                    data_d, sqnorm_d, invalid_d, queries_d, k_eff,
+                    **statics)
         with trace.span("index.readback"):
             # the host blocks here until the program has run
             dists = np.asarray(dists)[:q]
@@ -928,7 +1094,7 @@ class FlatIndex(VectorIndex):
             st = self._cascade
             if st is not None and st.tier == tier:
                 return st
-            n = self._main_rows()
+            n = self._n
             st = cascade.CascadeState(
                 np.asarray(self._host[:n], np.float32),
                 self._deleted[:n], tier, int(self.dist_calc_method),
@@ -971,9 +1137,11 @@ class FlatIndex(VectorIndex):
                     min(k, st.n_pad), int(self.dist_calc_method),
                     self.base)
                 return d[:q], ids[:q]
-        data_d, sqnorm_d, invalid_d = self._snapshot()
-        return exact_device_scan(data_d, sqnorm_d, invalid_d, queries, k,
-                                 int(self.dist_calc_method), self.base)
+        with self._lock:     # the block's arrays are the next write's
+            data_d, sqnorm_d, invalid_d = self._snapshot()
+            return exact_device_scan(data_d, sqnorm_d, invalid_d, queries,
+                                     k, int(self.dist_calc_method),
+                                     self.base)
 
     # ---- refine / persistence ---------------------------------------------
 
@@ -1027,7 +1195,7 @@ class FlatIndex(VectorIndex):
         saves stay byte-identical file sets)."""
         import struct
 
-        n, ndel = self._main_rows(), self._num_deleted
+        n, ndel = self._n, self._num_deleted
         cal_r = 0
         with self._lock:
             if not self._dirty and self._sketch is not None \

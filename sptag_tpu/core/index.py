@@ -193,6 +193,12 @@ class VectorIndex(abc.ABC):
     def _add(self, data: np.ndarray) -> int:
         """Append rows (already normalized if cosine); returns first new id."""
 
+    def _delete_ids(self, vids) -> int:
+        """Tombstone rows `vids` (lock held) -> how many were live.  One
+        call a delete, so that a family whose device state follows its
+        mutations sends the mask bits once (algo/flat.py)."""
+        return sum(1 for v in vids if self._delete_id(int(v)))
+
     @abc.abstractmethod
     def _delete_id(self, vid: int) -> bool:
         """Tombstone one id; returns False if already deleted."""
@@ -586,29 +592,30 @@ class VectorIndex(abc.ABC):
         set, the rows land in the FLAT-scanned delta shard and are
         searchable immediately, without re-linking the graph or
         invalidating the engine snapshot."""
-        data = self._prepare_vectors(vectors)
-        if data.size == 0:
-            return ErrorCode.EmptyData
-        metas = ([metadata.get_metadata(i) for i in range(data.shape[0])]
-                 if metadata is not None else None)
-        with self._lock:
-            # log BEFORE apply (standard WAL ordering, review fix): a
-            # failed append leaves the in-memory index untouched, so an
-            # un-acked add is never resident (and never folded into a
-            # later save); a torn record truncates at replay.  `begin`
-            # is the tail by construction — every add path appends.
-            # Redo semantics for the inverse failure (append succeeded,
-            # apply raised): the caller sees an exception and the
-            # write's outcome is INDETERMINATE — a restart may replay
-            # the durable record.  That is the standard WAL contract;
-            # what is guaranteed is never a HALF-applied state.
-            begin = self.num_samples
-            self._wal_log(wal.pack_add(begin, data, metas))
-            applied = self._apply_add(data, metas, with_meta_index)
-            assert applied == begin, (applied, begin)
-        self.publish_quality_health(background=True)
-        self._maybe_auto_refine()
-        return ErrorCode.Success
+        with trace.span("index.add"):
+            data = self._prepare_vectors(vectors)
+            if data.size == 0:
+                return ErrorCode.EmptyData
+            metas = ([metadata.get_metadata(i) for i in range(data.shape[0])]
+                     if metadata is not None else None)
+            with self._lock:
+                # log BEFORE apply (standard WAL ordering, review fix): a
+                # failed append leaves the in-memory index untouched, so an
+                # un-acked add is never resident (and never folded into a
+                # later save); a torn record truncates at replay.  `begin`
+                # is the tail by construction — every add path appends.
+                # Redo semantics for the inverse failure (append succeeded,
+                # apply raised): the caller sees an exception and the
+                # write's outcome is INDETERMINATE — a restart may replay
+                # the durable record.  That is the standard WAL contract;
+                # what is guaranteed is never a HALF-applied state.
+                begin = self.num_samples
+                self._wal_log(wal.pack_add(begin, data, metas))
+                applied = self._apply_add(data, metas, with_meta_index)
+                assert applied == begin, (applied, begin)
+            self.publish_quality_health(background=True)
+            self._maybe_auto_refine()
+            return ErrorCode.Success
 
     def _apply_add(self, data: np.ndarray, metas: Optional[List[bytes]],
                    with_meta_index: bool) -> int:
@@ -800,7 +807,8 @@ class VectorIndex(abc.ABC):
         the caller's exception propagates and the client must retry."""
         if self._wal is None or self._wal_replaying:
             return
-        self._wal.append(payload)
+        with trace.span("index.wal_append"):    # the write and its fsync
+            self._wal.append(payload)
         self._acked_writes += 1
         metrics.inc("mutation.wal_appends")
 
@@ -842,9 +850,9 @@ class VectorIndex(abc.ABC):
                             self._apply_add(np.ascontiguousarray(rows),
                                             metas, False)
                         else:
-                            for vid in rec.vids:
-                                if 0 <= vid < self.num_samples:
-                                    self._delete_id(int(vid))
+                            self._delete_ids(
+                                [int(v) for v in rec.vids
+                                 if 0 <= v < self.num_samples])
                         applied += 1
                     except Exception:                    # noqa: BLE001
                         # a record that fails to APPLY (resource
@@ -869,42 +877,48 @@ class VectorIndex(abc.ABC):
     def delete(self, vectors) -> ErrorCode:
         """Delete-by-content: search each vector, tombstone exact matches
         (dist <= eps), parity with BKT::DeleteIndex (BKTIndex.cpp:439-453)."""
-        if self.num_samples == 0:
-            return ErrorCode.VectorNotFound
-        data = self._prepare_vectors(vectors, normalize=True)
-        if data.shape[1] != self.feature_dim:
-            return ErrorCode.DimensionSizeMismatch
-        found_any = False
-        # data is already normalized — call the subclass engine directly
-        # rather than search_batch, which would normalize a second time.
-        # The reference searches with k=CEF for deletes (BKTIndex.cpp:441).
-        # The delta merge rides along: a row acked into the delta shard
-        # moments ago is deletable-by-content like any other.
-        k = int(getattr(self.params, "cef", 32))
-        k_eff = min(k, self.num_samples)
-        dists, ids = self._merge_delta(
-            data, k_eff, self._search_batch(data, k_eff))
-        tombstoned: List[int] = []
-        seen = set()
-        with self._lock:
-            # collect the matches first, LOG, then apply (the add
-            # path's log-before-apply ordering, review fix)
-            for q, row_d, row_i in zip(data, dists, ids):
-                for d, v in zip(row_d, row_i):
-                    if v >= 0 and d <= max(DELETE_EPS, _NEAR_EPS) and \
-                            self._exact_distance(q, int(v)) <= DELETE_EPS:
-                        found_any = True
-                        if int(v) not in seen and \
-                                self.contains_sample(int(v)):
-                            seen.add(int(v))
-                            tombstoned.append(int(v))
-            if tombstoned:
-                self._wal_log(wal.pack_delete(tombstoned))
-                for v in tombstoned:
-                    self._delete_id(v)
-        if found_any:
-            self.publish_quality_health(background=True)
-        return ErrorCode.Success if found_any else ErrorCode.VectorNotFound
+        return self.delete_rows(vectors)[0]
+
+    def delete_rows(self, vectors) -> Tuple[ErrorCode, int]:
+        """`delete`, and how many rows it tombstoned (what `$admin:delete`
+        replies): -> (code, rows tombstoned by this call)."""
+        with trace.span("index.delete"):
+            if self.num_samples == 0:
+                return ErrorCode.VectorNotFound, 0
+            data = self._prepare_vectors(vectors, normalize=True)
+            if data.shape[1] != self.feature_dim:
+                return ErrorCode.DimensionSizeMismatch, 0
+            found_any = False
+            # data is already normalized — call the subclass engine directly
+            # rather than search_batch, which would normalize a second time.
+            # The reference searches with k=CEF for deletes (BKTIndex.cpp:441).
+            # The delta merge rides along: a row acked into the delta shard
+            # moments ago is deletable-by-content like any other.
+            k = int(getattr(self.params, "cef", 32))
+            k_eff = min(k, self.num_samples)
+            dists, ids = self._merge_delta(
+                data, k_eff, self._search_batch(data, k_eff))
+            tombstoned: List[int] = []
+            seen = set()
+            with self._lock:
+                # collect the matches first, LOG, then apply (the add
+                # path's log-before-apply ordering, review fix)
+                for q, row_d, row_i in zip(data, dists, ids):
+                    for d, v in zip(row_d, row_i):
+                        if v >= 0 and d <= max(DELETE_EPS, _NEAR_EPS) and \
+                                self._exact_distance(q, int(v)) <= DELETE_EPS:
+                            found_any = True
+                            if int(v) not in seen and \
+                                    self.contains_sample(int(v)):
+                                seen.add(int(v))
+                                tombstoned.append(int(v))
+                if tombstoned:
+                    self._wal_log(wal.pack_delete(tombstoned))
+                    self._delete_ids(tombstoned)
+            if found_any:
+                self.publish_quality_health(background=True)
+            return (ErrorCode.Success if found_any
+                    else ErrorCode.VectorNotFound), len(tombstoned)
 
     def _exact_distance(self, q: np.ndarray, vid: int) -> float:
         """Host recheck of one candidate at float64, by DIRECT subtraction/

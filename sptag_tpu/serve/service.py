@@ -743,11 +743,11 @@ class SearchExecutor:
                     parsed, index.value_type, index.feature_dim)
                 if err is not None:
                     return err
-                code = index.delete(rows)
+                code, tombstoned = index.delete_rows(rows)
                 ok = code == ErrorCode.Success
                 return self._admin_reply(ok,
                                          "deleted" if ok else str(code),
-                                         len(rows) if ok else 0)
+                                         tombstoned)
             if op == "deletemeta":
                 raw_meta = parsed.options.get("metadata")
                 if raw_meta is None:

@@ -54,9 +54,23 @@ def _walks(cell: str) -> bool:
 #: against a served BKT index in SearchMode=beam, further down
 BEAM_ONLY = [m["name"] for m in _BENCH["per_layer"]
              if m.get("workloads") and all(map(_walks, m["workloads"]))]
+
+
+def _mutates(cell: str) -> bool:
+    """Whether the cell's traffic has a writer beside its searchers."""
+    cells = {w["name"]: w for w in _BENCH["workloads"]}
+    with open(os.path.join(REPO, "benchmark", "traffic",
+                           cells[cell]["traffic"] + ".json")) as f:
+        return "writer_connections" in json.load(f)
+
+
+#: metrics listed for cells with a writer alone read what a mutation
+#: records: held against a served index that is mutated, further down
+LIVE_ONLY = [m["name"] for m in _BENCH["per_layer"]
+             if m.get("workloads") and all(map(_mutates, m["workloads"]))]
 PER_LAYER = [m["name"] for m in _BENCH["per_layer"]
              if m["source"] in OFF_CHIP_SOURCES
-             and m["name"] not in BEAM_ONLY]
+             and m["name"] not in BEAM_ONLY + LIVE_ONLY]
 
 
 def _served_bursts(index, rows, warm: int, bursts: int) -> dict:
@@ -200,6 +214,91 @@ def test_the_walks_readers_read_nothing_without_a_walk(run):
         assert load_by_name("layer_metrics", metric).read(
             {**run, "trace": {"programs": {}}, "config": {"dim": 8},
              "peaks": {}}) is None
+
+
+# ---- the mutations' readers (PR 40) ----------------------------------------
+
+LIVE_OFF_CHIP = [m for m in LIVE_ONLY
+                 if {x["name"]: x for x in _BENCH["per_layer"]}[m]["source"]
+                 in OFF_CHIP_SOURCES]
+
+
+@pytest.fixture()
+def mutated_run(tmp_path):
+    """A tiny FLAT index with its log armed: a warm add and delete, then
+    a window of two adds and one delete by content (what `run_cell`'s
+    `spans` would hold of them)."""
+    rng = np.random.default_rng(2)
+    data = rng.standard_normal((300, 8)).astype(np.float32)
+    index = sp.create_instance("FLAT", "Float")
+    index.set_parameter("DistCalcMethod", "L2")
+    index.set_parameter("WalEnabled", "1")
+    index.build(data)
+    assert index.save_index(str(tmp_path / "idx")) == sp.ErrorCode.Success
+    index = sp.load_index(str(tmp_path / "idx"))
+    index.search_batch(data[:1], K)                 # place the block
+    blocks = [rng.standard_normal((4, 8)).astype(np.float32)
+              for _ in range(3)]
+    index.add(blocks[0])
+    assert index.delete_rows(blocks[0]) == (sp.ErrorCode.Success, 4)
+    before = trace.report()
+    index.add(blocks[1])
+    index.add(blocks[2])
+    assert index.delete_rows(blocks[1]) == (sp.ErrorCode.Success, 4)
+    return {"spans": span_deltas(before, trace.report())}
+
+
+def test_benchmark_lists_the_mutations_readers():
+    assert {"mutation.add_ms", "mutation.delete_ms",
+            "mutation.block_update_ms", "mutation.upload_bytes_per_row",
+            "mutation.wal_appends_per_op"} <= set(LIVE_OFF_CHIP)
+    assert "kernel.live_scan_roofline" in LIVE_ONLY
+
+
+@pytest.mark.parametrize("metric", LIVE_OFF_CHIP)
+def test_reader_returns_a_number_from_the_mutated_index(mutated_run, metric):
+    value = load_by_name("layer_metrics", metric).read(mutated_run)
+    assert isinstance(value, (int, float)), (
+        f"{metric} read {value!r} (spans seen: "
+        f"{sorted(mutated_run['spans'])})")
+    assert math.isfinite(value) and value > 0
+    if metric == "mutation.wal_appends_per_op":
+        assert value == 1.0
+    if metric == "mutation.upload_bytes_per_row":
+        # two adds of 4 rows on the 8-row rung, one delete of 4 rows
+        assert value == (2 * (8 * 33 + 4) + 8 * 4) / 12
+
+
+def test_the_mutations_readers_read_nothing_without_a_mutation(run):
+    """The parent's program, or a cell that mutates nothing: None, no
+    raise."""
+    for metric in LIVE_ONLY:
+        assert load_by_name("layer_metrics", metric).read(
+            {**run, "trace": {"programs": {}},
+             "config": {"algo": "FLAT", "rows": 200, "dim": 8},
+             "peaks": {}}) is None
+
+
+def test_live_roofline_arithmetic_against_a_hand_count(run):
+    """5M x 100 f32 read once a run at 819 GB/s is 2.442 ms: 64 queries
+    a run in 15.5 ms of device time read 15.75 %, bound by the HBM."""
+    reader = load_by_name("layer_metrics", "kernel.live_scan_roofline")
+    spans = {"server.queue_wait": {"count": 640, "total_s": 1.0},
+             "server.execute_batch": {"count": 10, "total_s": 1.0}}
+    got = reader.bound({
+        "spans": spans, "peaks": {"bf16_flops_per_s": 197e12,
+                                  "hbm_bytes_per_s": 819e9},
+        "config": {"algo": "FLAT", "rows": 5_000_000, "dim": 100},
+        "trace": {"programs": {reader.PROGRAM: {"runs": 10,
+                                                "seconds": 0.155}}}})
+    least, seconds = got
+    assert least["bound"] == "hbm" and seconds == 0.155
+    assert math.isclose(least["seconds"], 10 * 2e9 / 819e9)
+    assert math.isclose(100 * least["seconds"] / seconds, 15.7549,
+                        rel_tol=1e-4)
+    # the program the reader names is the program the index runs
+    from sptag_tpu.algo import flat
+    assert hasattr(flat, reader.PROGRAM[len("jit_"):])
 
 
 # ---- the int8 scan's roofline (PR 34) -------------------------------------

@@ -275,6 +275,81 @@ def test_flat_search_kernel_compiles_msmarco_int8(one_chip, Q):
 
 
 # ---------------------------------------------------------------------------
+# FLAT with a corpus that changes (PR 40): `flat_live5m.stream`'s block
+# once the first add has reserved a sixteenth ahead, 5,312,640 x 100 f32
+# ---------------------------------------------------------------------------
+
+LIVE_ROWS, LIVE_D = 5_000_000, 100
+
+
+def _live_block(sh):
+    from sptag_tpu.algo.flat import reserved_slots
+
+    n = reserved_slots(LIVE_ROWS + 128)
+    assert n == 5_312_640
+    return n, (_s(sh, (n, LIVE_D), jnp.float32), _s(sh, (n,), jnp.float32),
+               _s(sh, (n,), jnp.bool_))
+
+
+@pytest.mark.parametrize("Q,k", [(1, 10), (8, 10), (32, 10), (128, 10),
+                                 (128, 32)])
+def test_flat_search_kernel_compiles_live5m(one_chip, Q, k):
+    """The cell's four search rungs and a delete's search by content
+    (128 rows at k = CEF's default 32): the resident block, the (Q,
+    slots) scores and the select's workspace fit the chip with the
+    block's next copy (a growth holds two) to spare."""
+    from sptag_tpu.algo.flat import _flat_search_kernel
+
+    n, block = _live_block(one_chip)
+    compiled = _flat_search_kernel.lower(
+        *block, _s(one_chip, (Q, LIVE_D), jnp.float32), k=k, metric=L2,
+        base=1).compile()
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes) < 8 * 2 ** 30
+    if Q in (8, 128):
+        assert not _row_wide_selections(compiled, n)        # two stages
+
+
+@pytest.mark.parametrize("rung", [8, 128, 1024])
+def test_flat_block_writes_compile_in_place_live5m(one_chip, rung):
+    """An add's write and a delete's mask write update the donated block
+    where it lies: no second copy of the 2.1 GB of rows among the
+    temporaries or the outputs."""
+    from sptag_tpu.algo.flat import _block_mask_rows, _block_write_rows
+
+    n, block = _live_block(one_chip)
+    compiled = _block_write_rows.lower(
+        *block, _s(one_chip, (rung, LIVE_D), jnp.float32),
+        _s(one_chip, (rung,), jnp.bool_),
+        _s(one_chip, (), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= n * LIVE_D * 4        # donated
+    assert mem.temp_size_in_bytes < 2 ** 20
+    compiled = _block_mask_rows.lower(
+        block[2], _s(one_chip, (rung,), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= n and mem.temp_size_in_bytes < 2 ** 20
+
+
+def test_flat_block_growth_compiles_live5m(one_chip):
+    """The growth the cell's first add makes: the block as built
+    (5,000,064 slots) copied on the device into the reserved one."""
+    from sptag_tpu.algo.flat import _block_grown, pad_rows
+
+    n0 = pad_rows(LIVE_ROWS)
+    n, _ = _live_block(one_chip)
+    compiled = _block_grown.lower(
+        _s(one_chip, (n0, LIVE_D), jnp.float32),
+        _s(one_chip, (n0,), jnp.float32), _s(one_chip, (n0,), jnp.bool_),
+        slots=n).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes >= n * LIVE_D * 4
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes) < 6 * 2 ** 30
+
+
+# ---------------------------------------------------------------------------
 # beam walk (SearchMode=beam) with the bf16 scoring corpus the engine
 # picks only when it sees a TPU — a branch no CPU test takes
 # ---------------------------------------------------------------------------

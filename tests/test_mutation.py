@@ -276,14 +276,25 @@ def test_wal_fsync_off_still_crash_consistent(tmp_path):
 
 
 # ------------------------------------------------------- delta shard
+#
+# FLAT takes adds in its resident block in place (PR 40: algo/flat.py
+# `_device_append`), so `DeltaShardCapacity` routes nothing there any
+# more: the five FLAT cases below keep their scenarios (immediate
+# visibility, tombstones over old and new rows, adds past the old
+# capacity, a bulk add, WAL replay) and now hold the resident block to
+# them, with `delta_rows` 0 throughout; the shard itself is tested on
+# its own (`test_delta_shard_unit_masking`, `test_merge_topk_*`) and
+# under BKT (`test_bkt_delta_*`).  tests/test_flat_live.py has the
+# block's own contract (no compiles, bytes sent, growth, the served path).
 
 def test_delta_shard_immediate_visibility_flat():
     idx = _flat(wal_on=False, DeltaShardCapacity=16)
-    idx.search_batch(DATA[:4], 3)               # materialize snapshot
+    idx.search_batch(DATA[:4], 3)               # place the block
     fresh = RNG.standard_normal((5, D)).astype(np.float32)
     assert idx.add(fresh) == sp.ErrorCode.Success
     st = idx.mutation_state()
-    assert st["delta_rows"] == 5 and st["delta_capacity"] == 16
+    assert st["delta_rows"] == 0 and st["delta_capacity"] == 16
+    assert metrics.gauge_value("flat.rows_resident") == 53
     d, ids = idx.search_batch(fresh, 1)
     assert (ids[:, 0] == np.arange(48, 53)).all()
     assert (d[:, 0] <= 1e-4).all()
@@ -317,9 +328,10 @@ def test_delta_overflow_absorbs_then_reuses():
     a = RNG.standard_normal((6, D)).astype(np.float32)
     b = RNG.standard_normal((6, D)).astype(np.float32)
     idx.add(a)
-    assert idx.mutation_state()["delta_rows"] == 6
-    idx.add(b)      # 6+6 > 8: absorb, then b starts a fresh shard
-    assert idx.mutation_state()["delta_rows"] == 6
+    grows = metrics.counter_value("flat.block_grows")
+    idx.add(b)      # 6+6 > 8 (the old shard's bound): the block's reserve
+    assert idx.mutation_state()["delta_rows"] == 0
+    assert metrics.counter_value("flat.block_grows") == grows
     _, ids = idx.search_batch(np.concatenate([a, b]), 1)
     assert (ids[:, 0] == np.arange(48, 60)).all()
 
@@ -340,9 +352,9 @@ def test_delta_wal_compose_replay_lands_in_delta(tmp_path):
     fresh = RNG.standard_normal((3, D)).astype(np.float32)
     idx.add(fresh)
     loaded = sp.load_index(str(folder))
-    # replayed adds route through the same delta path
+    # replayed adds take the live path's route: the resident block
     assert loaded.num_samples == 51
-    assert loaded.mutation_state()["delta_rows"] == 3
+    assert loaded.mutation_state()["delta_rows"] == 0
     _, ids = loaded.search_batch(fresh, 1)
     assert (ids[:, 0] >= 48).all()
 
@@ -474,14 +486,15 @@ def test_healthz_and_debug_mutation_expose_swap_state():
                 f"http://127.0.0.1:{mport}/healthz", timeout=10) as r:
             health = json.loads(r.read())
         mut = health["indexes"]["main"]["mutation"]
-        assert mut["delta_rows"] == 2
+        # FLAT holds no delta: its adds are in the resident block
+        assert mut["delta_rows"] == 0 and mut["delta_capacity"] == 16
         assert mut["swap_count"] == 0
         with urllib.request.urlopen(
                 f"http://127.0.0.1:{mport}/debug/mutation",
                 timeout=10) as r:
             dbg = json.loads(r.read())
         assert dbg["tier"] == "server"
-        assert dbg["indexes"]["main"]["delta_rows"] == 2
+        assert dbg["indexes"]["main"]["delta_rows"] == 0
         assert "wal_appends" in dbg
     finally:
         t.stop()
