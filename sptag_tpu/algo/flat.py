@@ -25,7 +25,7 @@ from __future__ import annotations
 
 import functools
 import os
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -155,28 +155,58 @@ def count_dot(dtype, d: int) -> None:
         metrics.inc("flat.dot_f32")
 
 
+# Groups the proved select chooses beyond k, a rule of k alone: the
+# (k + _SPARE_GROUPS + 1)-th smallest group minimum has to clear the k-th
+# answer by the filter's `eps` (`_select_from_groups`), and on rows that do
+# not tie it does so by orders of magnitude with one spare; the rest buy
+# duplicated rows (up to _SPARE_GROUPS copies of a neighbour in groups of
+# their own) at 128 re-scored rows a query each.
+_SPARE_GROUPS = 6
+
+
 def fused_minima(dtype, q: int, n: int, d: int, k: int, metric: int,
                  platform: str) -> bool:
     """Whether the exact scan of `q` queries over `n` rows of `dtype`,
     `d` wide, takes its group minima from the pass that computes the
     distances (`pallas_kernels.scan_group_minima`) and never holds the
-    (q, n) scores: one-byte rows in the integer cosine, where a re-scored
-    row is the very number the scan saw (int32 accumulation, `base^2 -
-    dot` exact in float32), a two-stage select at a query block of whole
-    128-lane tiles (the 128 and 512 rungs), rows in whole groups of
-    whole lane tiles, on a TPU.  L2 stays materialised (its minima need
-    the row norms as one more sublane-major operand), and so do float
-    rows: two differently scheduled float32 contractions would have to
-    agree to the last bit.  A function of what can be seen BEFORE the
-    call, as `pallas_kernels.supported` is: no switch turns the route off
-    after a failure.  The host counters `flat.scan_fused_minima` /
+    (q, n) scores: a two-stage select at a query block of whole 128-lane
+    tiles (the 128 and 512 rungs), rows in whole groups of whole lane
+    tiles, on a TPU, and either
+
+    - one-byte rows in the integer cosine, `d` in whole lane tiles, where
+      a re-scored row is the very number the scan saw (int32
+      accumulation, `base^2 - dot` exact in float32), or
+    - float32 rows under L2, a lane tile wide at most or in whole lane
+      tiles (narrower than one they are resident column-major and the
+      kernel reads the transpose; `d` = 96, 100, 128 in the cells), where
+      the kernel's minima are a FILTER: another schedule of the float32
+      contraction than the re-score's, within a stated `eps` of it, so
+      the selection is proved on the device a query and a run with a
+      query unproved answers from the materialised scores
+      (`_select_from_groups`; `flat.scan_margin_unproved` counts them).
+
+    L2 on one-byte rows, float cosine and int16 stay materialised (no
+    cell runs them).  A function of what can be seen BEFORE the call, as
+    `pallas_kernels.supported` is: no switch turns the route off after a
+    failure.  The host counters `flat.scan_fused_minima` /
     `flat.scan_materialized` ask it what the program they dispatch was
     traced as."""
-    return (dist_ops.dot_kind(dtype, d) == "int8_native"
-            and metric == int(DistCalcMethod.Cosine)
-            and select_stages(q, n, k) == 2 and q % _LANES == 0
-            and n % _GROUP == 0 and d % _LANES == 0
-            and platform in ("tpu", "interpret"))
+    staged = (select_stages(q, n, k) == 2 and q % _LANES == 0
+              and n % _GROUP == 0 and platform in ("tpu", "interpret"))
+    int8_cosine = (dist_ops.dot_kind(dtype, d) == "int8_native"
+                   and metric == int(DistCalcMethod.Cosine)
+                   and d % _LANES == 0)
+    float_l2 = (np.dtype(dtype) == np.float32
+                and metric == int(DistCalcMethod.L2)
+                and (d % _LANES == 0 or d < _LANES))
+    return staged and (int8_cosine or float_l2)
+
+
+def proved_form(fused: bool, dtype) -> bool:
+    """Whether a scan on the fused route (`fused_minima` passed it) is
+    the float32 form: minima as a filter, the answer from the re-score,
+    the selection proved, the run's flag a third output."""
+    return fused and np.dtype(dtype) == np.float32
 
 
 def scan_route(dtype, q: int, n: int, d: int, k: int, metric: int) -> dict:
@@ -196,6 +226,15 @@ def count_route(fused: bool) -> None:
         metrics.inc("flat.scan_fused_minima")
     else:
         metrics.inc("flat.scan_materialized")
+
+
+def count_unproved(unproved) -> None:
+    """One fused scan whose proved select left a query unproved, so that
+    the run answered from the materialised scores: the flag the program
+    returned beside its answers (`ProvedTopK.unproved`), read where the
+    host reads the answers."""
+    if bool(np.asarray(unproved)):
+        metrics.inc("flat.scan_margin_unproved")
 
 
 def exact_topk(d, k: int):
@@ -229,7 +268,11 @@ def exact_topk(d, k: int):
 
     Stages 2 and 3 are `_select_from_groups`, which `scan_topk`'s fused
     route shares: there the minima come out of the distance pass and the
-    chosen groups' columns are scored again, and `d` never exists."""
+    chosen groups' columns are scored again, and `d` never exists.  On
+    one-byte cosine rows the minima are the re-score's own numbers and
+    the proof above holds as it stands; on float32 L2 rows they are a
+    FILTER within `eps` of them, k + `_SPARE_GROUPS` groups are chosen
+    and the proof carries the margin (`_select_from_groups`)."""
     q, n = d.shape
     if select_stages(q, n, k) == 1:
         neg, idx = jax.lax.top_k(-d, k)
@@ -251,47 +294,91 @@ def exact_topk(d, k: int):
         dt[groups * _GROUP:].T if tail else None)
 
 
-def _select_from_groups(minima, columns, k: int, tail=None):
+def _select_from_groups(minima, columns, k: int, tail=None, spare: int = 0,
+                        eps=None):
     """Stages 2 and 3 of `exact_topk`, whose proof this is the code of:
     `minima` (groups, Q) holds every group's smallest score a query,
-    `columns(chosen)` gives the scores of the (Q, k) chosen groups'
-    columns as (Q, k*_GROUP), groups ascending, `tail` (Q, t) the scores
-    past the last whole group.  Where the minima and the columns come
-    from is the caller's: the materialised scores (`exact_topk`) or the
-    fused scan and a re-score (`scan_topk`)."""
-    groups = minima.shape[0]
-    _, chosen = jax.lax.top_k(-minima.T, k)
+    `columns(chosen)` gives the scores of the (Q, k + spare) chosen
+    groups' columns as (Q, (k + spare)*_GROUP), groups ascending, `tail`
+    (Q, t) the scores past the last whole group.  Where the minima and
+    the columns come from is the caller's: the materialised scores
+    (`exact_topk`) or the fused scan and a re-score (`scan_topk`).
+
+    With `eps` (Q,) the minima are a FILTER and the select proves itself:
+    -> (values, columns, proved (Q,) bool).  Let s(x) be the score
+    `columns` computes for column x and s~(x) the one the minima were
+    taken of, |s~(x) - s(x)| <= eps for every valid x of the block
+    (`pallas_kernels.l2_minima_eps`: (4 d + 32) 2^-24 (|q|^2 + max
+    |x|^2); a masked x reads MAX_DIST on both sides).  The k' = k + spare
+    groups with the smallest minima are chosen and ALL their columns
+    scored with s; v_k is the k-th smallest of those scores, m the
+    (k' + 1)-th smallest minimum.  Every column x of an unchosen group g
+    has s(x) >= s~(x) - eps >= min~(g) - eps >= m - eps.  So where
+    m - eps > v_k no column of an unchosen group can precede the k-th
+    answer in (value, column) order: the k smallest of the candidates, met
+    in column order, are `lax.top_k` of s over ALL columns, bit for bit.
+    Where it does not hold nothing is claimed: `proved` is False and the
+    caller answers otherwise."""
+    groups, kc = minima.shape[0], k + spare
+    neg_min, chosen = jax.lax.top_k(-minima.T, kc)
+    if eps is not None:
+        # m: the smallest (minimum, group) pair past the last chosen one
+        # (`top_k` takes the lowest group first among equals).  Read off
+        # the minima in one elementwise pass, and from `top_k`'s outputs
+        # WHOLE: a slice of them (one more rank, or `[:, k - 1]` below)
+        # turns the chip's partial TopK into a sort of the whole row
+        # (4.1 ms over 41,505 groups where TopK takes 0.04)
+        last_min = jnp.max(-neg_min, axis=1)
+        last = jnp.max(jnp.where(-neg_min == last_min[:, None], chosen, -1),
+                       axis=1)
+        group_of = jax.lax.broadcasted_iota(jnp.int32, minima.shape, 0)
+        m = jnp.min(jnp.where(
+            (minima > last_min[None, :])
+            | ((minima == last_min[None, :]) & (group_of > last[None, :])),
+            minima, jnp.float32(np.inf)), axis=0)
     chosen = jnp.sort(chosen, axis=1)                   # column order
     cand = columns(chosen)
     if tail is not None:
         cand = jnp.concatenate([cand, tail], axis=1)
     neg, pos = jax.lax.top_k(-cand, k)
-    group = jnp.take_along_axis(chosen, jnp.minimum(pos // _GROUP, k - 1),
+    group = jnp.take_along_axis(chosen, jnp.minimum(pos // _GROUP, kc - 1),
                                 axis=1)
-    cols = jnp.where(pos < k * _GROUP, group * _GROUP + pos % _GROUP,
-                     pos + (groups - k) * _GROUP)
-    return -neg, cols
+    cols = jnp.where(pos < kc * _GROUP, group * _GROUP + pos % _GROUP,
+                     pos + (groups - kc) * _GROUP)
+    if eps is None:
+        return -neg, cols
+    return -neg, cols, m - eps > jnp.max(-neg, axis=1)
 
 
-def _rescored_columns(data, invalid, queries, k: int, base: int):
+def _rescored_columns(data, sqnorm, invalid, queries, kc: int, metric: int,
+                      base: int, interpret: bool = False):
     """`_select_from_groups`' `columns` for the fused route: the chosen
-    groups' rows, k contiguous slabs of _GROUP rows a query, gathered
-    from the resident block and scored again with the integer cosine
-    (exact, so the very numbers the scan took its minima of) and the
-    mask."""
+    groups' rows, `kc` contiguous slabs of _GROUP rows a query, gathered
+    from the resident block and scored again under the mask: the integer
+    cosine (exact, so the very numbers the scan took its minima of) or
+    float32 L2 at `highest` with the cached norms (the numbers that are
+    returned)."""
     q = queries.shape[0]
     groups = data.shape[0] // _GROUP
+    l2 = metric == int(DistCalcMethod.L2)
 
     def columns(chosen):
-        rows = jnp.take(data.reshape(groups, _GROUP, -1), chosen, axis=0)
+        rows = pallas_kernels.group_rows(data, chosen, interpret)
+        norms = (jnp.take(sqnorm.reshape(groups, _GROUP), chosen,
+                          axis=0).reshape(q, kc * _GROUP) if l2 else None)
         d = dist_ops.batched_gathered_distance(
-            queries, rows.reshape(q, k * _GROUP, -1), DistCalcMethod.Cosine,
-            base)
+            queries, rows.reshape(q, kc * _GROUP, -1),
+            DistCalcMethod(metric), base, norms)
         dead = jnp.take(invalid.reshape(groups, _GROUP), chosen, axis=0)
-        return jnp.where(dead.reshape(q, k * _GROUP),
+        return jnp.where(dead.reshape(q, kc * _GROUP),
                          jnp.float32(MAX_DIST), d)
 
     return columns
+
+
+def _masked_ids(dists, idx):
+    return jnp.where(dists >= jnp.float32(MAX_DIST), -1,
+                     idx).astype(jnp.int32)
 
 
 def scan_topk(data, sqnorm, invalid, queries, k: int, metric: int,
@@ -308,8 +395,13 @@ def scan_topk(data, sqnorm, invalid, queries, k: int, metric: int,
     `fused` (the caller's `fused_minima`, decided before the call): the
     exact select's group minima come out of the distance pass itself
     (`pallas_kernels.scan_group_minima`; `interpret` runs it on the CPU)
-    and the chosen groups are scored again: the same answers bit for
-    bit, and no (Q, N) matrix."""
+    and the chosen groups are scored again, and no (Q, N) matrix is
+    written.  One-byte cosine rows: the same answers bit for bit.
+    Float32 L2 rows (`_proved_scan`): one more output, the run's
+    `unproved` flag."""
+    if proved_form(fused, data.dtype):
+        return _proved_scan(data, sqnorm, invalid, queries, k, base,
+                            interpret)
     # the scope names are what a profiler trace calls the two stages
     # (benchmark kernel.topk_ms_per_batch reads `flat.topk`): kernel PRs
     # keep them
@@ -326,8 +418,8 @@ def scan_topk(data, sqnorm, invalid, queries, k: int, metric: int,
     with jax.named_scope("flat.topk"):
         if fused:
             dists, idx = _select_from_groups(
-                minima, _rescored_columns(data, invalid, queries, k, base),
-                k)
+                minima, _rescored_columns(data, sqnorm, invalid, queries, k,
+                                          metric, base, interpret), k)
         elif binned_bins:
             dists, idx = topk_bins.binned_topk(d, k, binned_bins)
         elif approx:
@@ -336,9 +428,54 @@ def scan_topk(data, sqnorm, invalid, queries, k: int, metric: int,
             dists = -neg
         else:
             dists, idx = exact_topk(d, k)
-        ids = jnp.where(dists >= jnp.float32(MAX_DIST), -1,
-                        idx).astype(jnp.int32)
+        ids = _masked_ids(dists, idx)
     return dists, ids
+
+
+def _proved_scan(data, sqnorm, invalid, queries, k: int, base: int,
+                 interpret: bool):
+    """`scan_topk`'s fused route on float32 rows under L2 -> (distances,
+    ids, unproved).  The kernel's minima FILTER the groups, k +
+    _SPARE_GROUPS of them a query are scored again at `highest` from the
+    cached norms - the only numbers that are returned: each the float32
+    distance of the id it comes with, the list `lax.top_k` of those
+    scores over the whole block - and the selection is proved a query
+    (`_select_from_groups`).  What is not proved is not returned: with
+    any query of the run unproved (`unproved`, a scalar the host counts:
+    `count_unproved`) the SAME program answers from the materialised
+    scores instead, as the unfused route does (`lax.cond`: no second
+    dispatch, nothing compiled later; its (Q, N) temporary is the
+    program's as it was the unfused program's)."""
+    l2 = int(DistCalcMethod.L2)
+    with jax.named_scope("flat.distance"):
+        minima = pallas_kernels.scan_group_minima(
+            data, invalid, queries, base=base, interpret=interpret,
+            sqnorm=sqnorm)
+    with jax.named_scope("flat.topk"):
+        eps = pallas_kernels.l2_minima_eps(
+            data.shape[1], dist_ops.row_sqnorms(queries), sqnorm)
+        dists, idx, proved = _select_from_groups(
+            minima, _rescored_columns(data, sqnorm, invalid, queries,
+                                      k + _SPARE_GROUPS, l2, base,
+                                      interpret), k,
+            spare=_SPARE_GROUPS, eps=eps)
+        ids = _masked_ids(dists, idx)
+        unproved = jnp.logical_not(jnp.all(proved))
+    dists, ids = jax.lax.cond(
+        unproved,
+        lambda: scan_topk(data, sqnorm, invalid, queries, k, l2, base),
+        lambda: (dists, ids))
+    return dists, ids, unproved
+
+
+class ProvedTopK(NamedTuple):
+    """What a scan program on the proved route returns (`_proved_scan`):
+    `DeviceTopK`'s pair and the run's flag.  Every other program returns
+    `DeviceTopK` as before: its StableHLO is what it was."""
+
+    dists: jax.Array
+    ids: jax.Array
+    unproved: jax.Array
 
 
 @functools.partial(jax.jit,
@@ -366,10 +503,12 @@ def _flat_search_kernel(data, sqnorm, invalid, queries, k: int,
     off-TPU.  When both are set, binned wins (it subsumes the recipe).
 
     `fused` / `interpret`: the exact select's route, the caller's
-    `fused_minima` (see `scan_topk`)."""
-    return DeviceTopK(*scan_topk(data, sqnorm, invalid, queries, k, metric,
-                                 base, approx, recall_target, binned_bins,
-                                 fused, interpret))
+    `fused_minima` (see `scan_topk`; on float32 L2 rows the answer is
+    `ProvedTopK`)."""
+    answer = ProvedTopK if proved_form(fused, data.dtype) else DeviceTopK
+    return answer(*scan_topk(data, sqnorm, invalid, queries, k, metric, base,
+                             approx, recall_target, binned_bins, fused,
+                             interpret))
 
 
 def exact_device_scan(data_d, sqnorm_d, invalid_d, queries: np.ndarray,
@@ -390,7 +529,7 @@ def exact_device_scan(data_d, sqnorm_d, invalid_d, queries: np.ndarray,
         data_d, sqnorm_d, invalid_d, jnp.asarray(queries), k_eff, metric,
         base, approx=False,
         **scan_route(data_d.dtype, queries.shape[0], *data_d.shape, k_eff,
-                     metric))
+                     metric))[:2]
     return np.asarray(dists)[:q], np.asarray(ids)[:q]
 
 
@@ -518,13 +657,18 @@ def _flat_scan_cost(Q, N, D, k, itemsize=4, binned_bins=0, fused=False,
     for the exact branch's two stages (`select_stages`) is costed by
     `_two_stage_select_cost` instead of the N-wide top-k.  On the `fused`
     route (`fused_minima`) there is no score matrix to bill: the kernel's
-    own entry (`pallas.scan_group_minima`: the rows once) and the select
-    over its minima with the re-scored slabs."""
+    own entry (`pallas.scan_group_minima`: the rows once, at their item
+    size) and the select over its minima with the re-scored slabs; the
+    materialised body a float program keeps for an unproved run is not
+    billed (it does not run)."""
     if fused:
+        # float rows (4 bytes) take the proved form: k + _SPARE_GROUPS
+        # groups a query re-scored, their norms gathered beside them
         scan = costmodel.estimate("pallas.scan_group_minima", Q=Q, N=N, D=D,
                                   itemsize=itemsize)
-        sel_f, sel_b = _two_stage_select_cost(Q, N, k, fused=True, D=D,
-                                              itemsize=itemsize)
+        sel_f, sel_b = _two_stage_select_cost(
+            Q, N, k + (_SPARE_GROUPS if itemsize == 4 else 0), fused=True,
+            D=D, itemsize=itemsize)
         return scan.flops + sel_f, scan.hbm_bytes + sel_b + Q * k * 8
     flops = (costmodel.matmul_flops(Q, N, D) + 2.0 * D * (Q + N)
              + 2.0 * Q * N)
@@ -811,7 +955,14 @@ class FlatIndex(VectorIndex):
                 if q not in zeros:
                     zeros[q] = jnp.zeros((q, block[0].shape[1]),
                                          block[0].dtype)
-                _flat_search_kernel(*block, zeros[q], k, **dict(statics))
+                statics = dict(statics)
+                if "fused" in statics:
+                    # the exact select's route is a rule of the shape:
+                    # what the searches after the growth will ask for
+                    statics.update(scan_route(
+                        block[0].dtype, q, *block[0].shape, k,
+                        statics["metric"]))
+                _flat_search_kernel(*block, zeros[q], k, **statics)
             self._publish(block)
         metrics.inc("flat.block_grows")
 
@@ -993,6 +1144,7 @@ class FlatIndex(VectorIndex):
                 int(getattr(self.params, "tier_budget_sketch", 0)),
                 int(getattr(self.params, "tier_budget_int8", 0)))
             return self._pad_k(dists[:q], ids[:q], q, k, k_eff)
+        flag = ()       # the proved route's `unproved`, where it ran
         if getattr(self.params, "sketch_prefilter", False) \
                 and pad_rows(self._n) > 256:
             # the sketches are read atomically WITH the block they were
@@ -1052,13 +1204,15 @@ class FlatIndex(VectorIndex):
                 count_dot(data_d.dtype, data_d.shape[1])
                 self._programs.add((queries.shape[0], k_eff,
                                     tuple(statics.items())))
-                dists, ids = _flat_search_kernel(
+                dists, ids, *flag = _flat_search_kernel(
                     data_d, sqnorm_d, invalid_d, queries_d, k_eff,
                     **statics)
         with trace.span("index.readback"):
             # the host blocks here until the program has run
             dists = np.asarray(dists)[:q]
             ids = np.asarray(ids)[:q]
+            if flag:
+                count_unproved(flag[0])
         return self._pad_k(dists, ids, q, k, k_eff)
 
     @staticmethod

@@ -224,31 +224,108 @@ def _scan_tiling(d: int, q: int):
     return tile, chunk
 
 
+def column_major(dtype, d: int) -> bool:
+    """Whether a resident (N, d) block of `dtype` lies column-major on a
+    TPU: float32 rows narrower than a lane tile do (the compiler's
+    compact layout, `f32[N,100]{0,1:T(8,128)}`: 400 bytes a row in HBM
+    and no lane padding), so what reads them row by row - a `(rows, d)`
+    kernel block, a gather of `(128, d)` slabs - would first copy the
+    whole block into the other layout, every call.  Seen in the programs
+    compiled for a described v5e (tests/test_chip_compile.py holds the
+    temporaries that a copy would show in)."""
+    return jnp.dtype(dtype) == jnp.float32 and d < SCAN_GROUP
+
+
+# slabs one grid step of `group_rows` copies: as many block DMAs in flight
+_SLABS_A_STEP = 8
+
+
+def group_rows(data: jax.Array, chosen: jax.Array,
+               interpret: bool = False) -> jax.Array:
+    """(N, D) rows and (Q, c) int32 group ids -> (Q, c, SCAN_GROUP, D):
+    the rows of every chosen group, each group one contiguous slab of the
+    resident block as it lies.  Row-major blocks: XLA's gather.  A
+    `column_major` block is read through its transpose (a bitcast) as
+    `(D, SCAN_GROUP)` slabs at whole lane tiles by a Pallas copy whose
+    BlockSpecs follow the prefetched ids, `_SLABS_A_STEP` slabs a step
+    (XLA's gather of such slabs is a loop of one copy at a time: 3.4 us
+    a slab on the chip, 7 ms for a 128-query batch at k = 10)."""
+    n, d = data.shape
+    if not column_major(data.dtype, d):
+        return jnp.take(data.reshape(n // SCAN_GROUP, SCAN_GROUP, d), chosen,
+                        axis=0)
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    per = _SLABS_A_STEP
+    ids = chosen.reshape(-1)
+    steps = -(-ids.shape[0] // per)
+    ids = jnp.pad(ids, (0, steps * per - ids.shape[0]))
+
+    def kernel(ids_ref, *refs):
+        for j in range(per):
+            refs[per][j] = refs[j][...]
+
+    slabs = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((steps * per, d, SCAN_GROUP),
+                                       data.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(steps,),
+            in_specs=[pl.BlockSpec((d, SCAN_GROUP),
+                                   functools.partial(
+                                       lambda i, g, j: (0, g[i * per + j]),
+                                       j=j))
+                      for j in range(per)],
+            out_specs=pl.BlockSpec((per, d, SCAN_GROUP),
+                                   lambda i, g: (i, 0, 0))),
+        interpret=interpret,
+    )(ids, *[data.T] * per)
+    return slabs[:chosen.size].reshape(*chosen.shape, d,
+                                       SCAN_GROUP).swapaxes(2, 3)
+
+
 @functools.partial(jax.jit, static_argnames=("base", "interpret"))
 def scan_group_minima(data: jax.Array, invalid: jax.Array,
                       queries: jax.Array, base: int,
-                      interpret: bool = False) -> jax.Array:
-    """(N, D) one-byte rows, (N,) bool `invalid`, (Q, D) queries of the
-    rows' type -> (N/128, Q) float32: for every group of 128 consecutive
-    rows and every query the smallest integer-cosine distance `base^2 -
-    dot` over the group's valid rows, `MAX_DIST` where none is valid.
-    What `where(invalid, MAX_DIST, pairwise_cosine(queries, data, base))`
-    holds as group minima, bit for bit, without the (Q, N) scores ever
-    being written: the FLAT scan's HBM traffic is the rows, once.
+                      interpret: bool = False, sqnorm=None) -> jax.Array:
+    """(N, D) rows, (N,) bool `invalid`, (Q, D) queries of the rows' type
+    -> (N/128, Q) float32: for every group of 128 consecutive rows and
+    every query the smallest distance over the group's valid rows,
+    `MAX_DIST` where none is valid, without the (Q, N) scores ever being
+    written: the FLAT scan's HBM traffic is the rows, once.
 
     The grid walks the rows in tiles (Pallas double-buffers the DMA); the
-    (D, Q) queries stay in VMEM.  A step contracts 1,024-row chunks s8 x
-    s8 -> s32 on the MXU with the rows on the sublanes and the queries on
-    the lanes, so a group's minimum is an elementwise one over 16 vregs
-    and one sublane reduce.  The minimum is taken as the MAXIMUM of the
-    int32 dots: `base^2 - float32(dot)` falls as the dot rises (the
-    conversion and the subtraction round monotonically where they round
-    at all), so the largest dot's distance is the smallest distance, and
-    one conversion a group replaces one a row.  Invalid rows drop out
-    under a cap a row (int32's minimum, which no dot of one-byte operands
-    reaches; int32's maximum on a valid row): one `minimum` a score.  The
-    last tile may pass the rows' end: what it reads there lands in groups
-    past the output's end, which are not written."""
+    (D, Q) queries stay in VMEM.  A step contracts 1,024-row chunks on
+    the MXU with the rows on the sublanes and the queries on the lanes,
+    so a group's minimum is an elementwise one over 16 vregs and one
+    sublane reduce.  The third operand is (groups, 128), a group's rows
+    on the lanes.  The last tile may pass the rows' end: what it reads
+    there lands in groups past the output's end, which are not written.
+
+    One-byte rows (`sqnorm` absent), the integer cosine `base^2 - dot`:
+    what `where(invalid, MAX_DIST, pairwise_cosine(queries, data, base))`
+    holds as group minima, BIT FOR BIT.  The contraction is s8 x s8 ->
+    s32 and the minimum is taken as the MAXIMUM of the int32 dots:
+    `base^2 - float32(dot)` falls as the dot rises (the conversion and
+    the subtraction round monotonically where they round at all), so the
+    largest dot's distance is the smallest distance, and one conversion a
+    group replaces one a row.  Invalid rows drop out under a cap a row
+    (int32's minimum, which no dot of one-byte operands reaches; int32's
+    maximum on a valid row): one `minimum` a score.
+
+    Float32 rows (`sqnorm` (N,), the rows' cached squared norms), squared
+    L2: the group minima of `|q|^2 + |x|^2 - 2 q.x`, clamped at 0, within
+    `l2_minima_eps` of what `pairwise_l2` and the gathered re-score hold
+    for the same row, NOT bit for bit (another schedule of the same
+    float32 contraction): a FILTER, whose caller proves its selection
+    (`algo/flat.py::_select_from_groups`).  The contraction runs at
+    `Precision.HIGHEST` against the queries doubled (exact), the third
+    operand is `where(invalid, MAX_DIST, sqnorm)` - a live row's norm,
+    `MAX_DIST` for a masked one, which no finite dot moves - so a score
+    is one subtraction, and `|q|^2` is added once a group (monotone).
+    The rows are taken as wide as they are resident (100 and 96 columns
+    in two of the cells: a block whose last dimension is the array's)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -258,33 +335,94 @@ def scan_group_minima(data: jax.Array, invalid: jax.Array,
     tile, chunk = _scan_tiling(d * data.dtype.itemsize, q)
     int_min = jnp.iinfo(jnp.int32).min
     base2 = float(base) * float(base)
+    float_path = sqnorm is not None
+    by_column = float_path and column_major(data.dtype, d)
+
+    def chunks():
+        for c in range(tile // chunk):
+            yield (slice(c * chunk, (c + 1) * chunk),
+                   slice(c * chunk * SCAN_GROUP, (c + 1) * chunk * SCAN_GROUP))
 
     def kernel(qt_ref, rows_ref, cap_ref, out_ref):
-        for c in range(tile // chunk):
-            at = slice(c * chunk, (c + 1) * chunk)
-            dot = jnp.dot(rows_ref[c * chunk * SCAN_GROUP:(c + 1) * chunk * SCAN_GROUP],
-                          qt_ref[...], preferred_element_type=jnp.int32)
+        for at, rows in chunks():
+            dot = jnp.dot(rows_ref[rows], qt_ref[...],
+                          preferred_element_type=jnp.int32)
             best = jnp.minimum(dot.reshape(chunk, SCAN_GROUP, q),
                                cap_ref[at][:, :, None]).max(axis=1)
             out_ref[at] = jnp.where(
                 best == int_min, jnp.float32(MAX_DIST),
                 jnp.float32(base2) - best.astype(jnp.float32))
 
-    return pl.pallas_call(
-        kernel,
+    def float_kernel(qt_ref, rows_ref, norm_ref, out_ref):
+        for at, rows in chunks():
+            # (rows, d) x (d, Q); a column-major block is (d, rows) and
+            # contracts over its sublanes
+            dot2 = jax.lax.dot_general(
+                rows_ref[:, rows] if by_column else rows_ref[rows],
+                qt_ref[...],
+                (((0 if by_column else 1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.HIGHEST)
+            out_ref[at] = (norm_ref[at][:, :, None]
+                           - dot2.reshape(chunk, SCAN_GROUP, q)).min(axis=1)
+
+    rows_spec = pl.BlockSpec((tile * SCAN_GROUP, d), lambda i: (i, 0))
+    if float_path:
+        qf = queries.astype(jnp.float32)
+        operands = ((2.0 * qf).T, data,
+                    jnp.where(invalid, jnp.float32(MAX_DIST), sqnorm).reshape(
+                        groups, SCAN_GROUP))
+        if by_column:
+            # the transpose is a bitcast where a `(rows, d)` block would
+            # be a copy of the whole corpus a call
+            operands = (operands[0], data.T, operands[2])
+            rows_spec = pl.BlockSpec((d, tile * SCAN_GROUP),
+                                     lambda i: (0, i))
+    else:
+        operands = (queries.T, data,
+                    jnp.where(invalid, int_min,
+                              jnp.iinfo(jnp.int32).max).reshape(
+                                  groups, SCAN_GROUP))
+    minima = pl.pallas_call(
+        float_kernel if float_path else kernel,
         out_shape=jax.ShapeDtypeStruct((groups, q), jnp.float32),
         grid=(pl.cdiv(groups, tile),),
-        in_specs=[pl.BlockSpec((d, q), lambda i: (0, 0)),
-                  pl.BlockSpec((tile * SCAN_GROUP, d), lambda i: (i, 0)),
+        in_specs=[pl.BlockSpec((d, q), lambda i: (0, 0)), rows_spec,
                   pl.BlockSpec((tile, SCAN_GROUP), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((tile, q), lambda i: (i, 0)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
             vmem_limit_bytes=_SCAN_VMEM_BYTES),
         interpret=interpret,
-    )(queries.T, data,
-      jnp.where(invalid, int_min, jnp.iinfo(jnp.int32).max).reshape(
-          groups, SCAN_GROUP))
+    )(*operands)
+    if float_path:
+        # MAX_DIST + |q|^2 is MAX_DIST: a masked group stays masked
+        return jnp.maximum(minima + jnp.sum(qf * qf, axis=-1)[None, :], 0.0)
+    return minima
+
+
+def l2_minima_eps(d: int, qnorm: jax.Array, sqnorm: jax.Array) -> jax.Array:
+    """(Q,) float32: a bound a query on |s~ - s| over every valid row of
+    the block, s~ the score `scan_group_minima` takes its float32 minima
+    of and s the one XLA computes for the same row at `highest`
+    (`distance.pairwise_l2`, `batched_gathered_distance`), both from the
+    cached norms.  With u = 2^-24, S = |q|^2 + max |x|^2 over the block
+    and P = sum |q_i x_i| <= |q| |x| <= S / 2:
+
+    - either contraction of `d` products accumulated in float32, in any
+      order, with the six-pass bf16 split's dropped terms (2u a product),
+      is off the true dot by at most (d + 8) u P: the two doubled dots
+      differ by at most 2 (d + 8) u S;
+    - the two sides add their three terms in different orders, every
+      partial sum at most 2 S in magnitude: 3 u S and 4 u S of rounding;
+    - each side reduces |q|^2 itself: at most 2 d u |q|^2 <= 2 d u S
+      apart;
+    - the clamp at 0 is applied on both sides and moves nothing apart.
+
+    (4 d + 23) u S in all; (4 d + 32) u S is what is used, which leaves
+    the comparison `m - eps > v` its own rounding."""
+    bound = jnp.float32((4 * d + 32) * 2.0 ** -24)
+    return bound * (qnorm + jnp.max(sqnorm))
 
 
 # ---------------------------------------------------------------------------
@@ -309,12 +447,16 @@ def _group_block_cost(NG, U, G, P, D, itemsize=4, **_):
 
 
 def _scan_group_minima_cost(Q, N, D, itemsize=1, **_):
-    """The rows once, the mask (read as bytes, written and read again as
-    int32), the queries, the (N/128, Q) minima; the contraction, and a
-    cap and a running maximum a score."""
+    """The rows once at their item size, the mask (read as bytes, written
+    and read again as int32; float rows: the norms read beside it), the
+    queries, the (N/128, Q) minima (float rows: read and written once
+    more for `|q|^2`); the contraction, and a cap and a running maximum a
+    score.  No score matrix."""
+    floats = itemsize == 4
     flops = 2.0 * Q * N * D + 2.0 * Q * N
-    nbytes = (N * D * itemsize + 9 * N + Q * D * itemsize
-              + N // SCAN_GROUP * Q * 4)
+    nbytes = (N * D * itemsize + (13 if floats else 9) * N
+              + Q * D * itemsize
+              + N // SCAN_GROUP * Q * 4 * (3 if floats else 1))
     return flops, nbytes
 
 

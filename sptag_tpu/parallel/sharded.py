@@ -29,8 +29,9 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from sptag_tpu.algo.flat import (count_route, count_select, pad_rows,
-                                 pad_to_bucket, scan_route, scan_topk)
+from sptag_tpu.algo.flat import (count_route, count_select, count_unproved,
+                                 pad_rows, pad_to_bucket, proved_form,
+                                 scan_route, scan_topk)
 from sptag_tpu.core.index import MAX_DIST
 from sptag_tpu.core.types import DistCalcMethod
 from sptag_tpu.ops import distance as dist_ops
@@ -80,6 +81,15 @@ class MeshTopK(NamedTuple):
     global_ids: jax.Array
 
 
+class ProvedMeshTopK(NamedTuple):
+    """`MeshTopK` and the run's flag: the mesh program's answer on the
+    proved route (float32 L2 rows, `flat._proved_scan`)."""
+
+    merged_dists: jax.Array
+    global_ids: jax.Array
+    unproved: jax.Array
+
+
 @functools.partial(jax.jit,
                    static_argnames=("k_local", "k_final", "metric", "base",
                                     "mesh", "row_stride", "fused",
@@ -98,26 +108,34 @@ def _sharded_search_kernel(data, sqnorm, invalid, queries, k_local: int,
     shard's block, decided by the caller)."""
 
     def local_search(data_s, sqnorm_s, invalid_s, q_s):
-        d, ids = scan_topk(data_s, sqnorm_s, invalid_s, q_s, k_local,
-                           metric, base, fused=fused, interpret=interpret)
+        d, ids, *flag = scan_topk(data_s, sqnorm_s, invalid_s, q_s, k_local,
+                                  metric, base, fused=fused,
+                                  interpret=interpret)
         # the merge is what a trace calls the mesh's own stage (benchmark
         # kernel.mesh_merge_ms_per_batch reads `mesh.merge`)
         with jax.named_scope("mesh.merge"):
             shard = jax.lax.axis_index(SHARD_AXIS)
             gids = jnp.where(
                 ids >= 0, ids + shard * (row_stride or data_s.shape[0]), -1)
-            return _gather_merge(d, gids, k_final)
+            # a shard's `unproved` (the proved route: each shard answers
+            # from its own materialised scores where its own proof
+            # failed): any shard's, on every shard
+            return _gather_merge(d, gids, k_final) + tuple(
+                jax.lax.pmax(f.astype(jnp.int32), SHARD_AXIS) > 0
+                for f in flag)
 
-    return MeshTopK(*shard_map(
+    proved = proved_form(fused, data.dtype)
+    out = shard_map(
         local_search,
         mesh=mesh,
         in_specs=(P(SHARD_AXIS, None), P(SHARD_AXIS), P(SHARD_AXIS),
                   P(None, None)),
-        out_specs=(P(None, None), P(None, None)),
+        out_specs=(P(None, None), P(None, None)) + (P(),) * proved,
         # outputs are replicated by construction (all_gather + identical
         # top_k on every shard); the static VMA check can't see that
         check_vma=False,
-    )(data, sqnorm, invalid, queries))
+    )(data, sqnorm, invalid, queries)
+    return ProvedMeshTopK(*out) if proved else MeshTopK(*out)
 
 
 MANIFEST = "sharded.json"
@@ -358,7 +376,7 @@ class ShardedFlatIndex:
         route = scan_route(self.data.dtype, queries.shape[0], n_local,
                            self.data.shape[1], k_local, int(self.metric))
         count_route(route["fused"])
-        dists, ids = _sharded_search_kernel(
+        dists, ids, *flag = _sharded_search_kernel(
             self.data, self.sqnorm, self.invalid, jnp.asarray(queries),
             k_local, k_final, int(self.metric), self.base, self.mesh,
             row_stride=self.row_stride, **route)
@@ -366,6 +384,8 @@ class ShardedFlatIndex:
             # the host blocks here until the program has run
             dists = np.asarray(dists)[:q]
             ids = np.asarray(ids)[:q]
+            if flag:
+                count_unproved(flag[0])
         return _pad_to_k(dists, ids, k, k_final)
 
 

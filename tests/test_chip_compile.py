@@ -194,19 +194,126 @@ def test_dense_grouped_kernel_compiles_with_pallas(one_chip, dtype, D,
     assert _has_mosaic_kernel(compiled)
 
 
+@pytest.mark.parametrize("n,D,Q", [
+    (1_000_064, 128, 128), (1_000_064, 128, 512),       # flat_1m
+    (5_312_640, 100, 128), (5_312_640, 100, 512),       # flat_live5m
+    (2_500_096, 96, 128),                               # a deep-10M shard
+])
+def test_scan_group_minima_compiles_on_float_rows(one_chip, n, D, Q):
+    """The float32 L2 form of the scan kernel (PR 41) at the three float
+    cells' blocks.  Rows narrower than a lane tile are resident
+    column-major and read through their transpose: a kernel that asked
+    for `(rows, D)` blocks of them would show a copy of the whole block
+    among the temporaries (2.72 GB at 5,312,640 x 100)."""
+    from sptag_tpu.ops import pallas_kernels
+
+    lowered = pallas_kernels.scan_group_minima.lower(
+        _s(one_chip, (n, D), jnp.float32), _s(one_chip, (n,), jnp.bool_),
+        _s(one_chip, (Q, D), jnp.float32), base=1,
+        sqnorm=_s(one_chip, (n,), jnp.float32))
+    assert lowered.out_info.shape == (n // 128, Q)
+    compiled = lowered.compile()
+    assert _has_mosaic_kernel(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+    param = re.search(r"entry_computation_layout=\{\(f32\[\d+,\d+\]\{(\d),",
+                      compiled.as_text())
+    assert (param.group(1) == "0") == pallas_kernels.column_major(
+        jnp.float32, D)
+
+
+@pytest.mark.parametrize("D", [100, 128])
+def test_float_filter_stays_inside_eps_on_the_chip(D):
+    """CHIP-ONLY (skipped wherever the first device is no TPU; run it
+    with `chiprun -- python -m pytest tests/test_chip_compile.py -k
+    on_the_chip --noconftest`: tests/conftest.py pins the CPU): over a
+    real block of clustered rows the kernel's group minima lie within a
+    small share of `l2_minima_eps` of the materialised scores' (the
+    bound is a worst case: 0.003-0.004 of it was read, PERF.md section
+    6, PR 41), a masked group reads MAX_DIST on both sides, and the
+    program's selection is proved with the materialised program's
+    lists, place for place inside the configurations' tie rule."""
+    if jax.devices()[0].platform != "tpu":
+        pytest.skip("needs the chip: the MXU's float32 contraction")
+    from sptag_tpu.algo import flat
+    from sptag_tpu.core.index import MAX_DIST
+    from sptag_tpu.ops import distance as dist_ops
+    from sptag_tpu.ops import pallas_kernels
+
+    n, Q = 1_000_064, 128
+    keys = jax.random.split(jax.random.PRNGKey(D), 5)
+    centers = 4.0 * jax.random.normal(keys[0], (256, D), jnp.float32)
+    data = jax.jit(lambda: centers[jax.random.randint(keys[1], (n,), 0, 256)]
+                   + jax.random.normal(keys[2], (n, D), jnp.float32))()
+    queries = (centers[jax.random.randint(keys[3], (Q,), 0, 256)]
+               + jax.random.normal(keys[4], (Q, D), jnp.float32))
+    queries = queries.at[Q // 2:].set(0.0)
+    invalid = jnp.zeros((n,), bool).at[-4_000:].set(True)
+    sqnorm = dist_ops.row_sqnorms(data)
+    got = pallas_kernels.scan_group_minima(data, invalid, queries, base=1,
+                                           sqnorm=sqnorm)
+    d = jnp.where(invalid[None, :], jnp.float32(MAX_DIST),
+                  dist_ops.pairwise_l2(queries, data, sqnorm))
+    want = d.T.reshape(n // 128, 128, Q).min(axis=1)
+    eps = pallas_kernels.l2_minima_eps(D, dist_ops.row_sqnorms(queries),
+                                       sqnorm)
+    live = want < MAX_DIST
+    assert bool(jnp.all((got < MAX_DIST) == live))
+    off = jnp.where(live, jnp.abs(got - want), 0.0) / eps[None, :]
+    assert float(off.max()) < 0.05
+    dists, ids, unproved = flat._flat_search_kernel(
+        data, sqnorm, invalid, queries, K, metric=L2, base=1, fused=True)
+    want_dists, want_ids = flat._flat_search_kernel(
+        data, sqnorm, invalid, queries, K, metric=L2, base=1)
+    assert not bool(unproved)
+    # the two programs score a row with two schedules of one float32
+    # contraction: place for place the lists agree inside the
+    # configurations' tie rule (8 float32 ulps of |q|^2 + |x|^2, the
+    # magnitude both round at), and an id differs only where two
+    # neighbours lie that close (one swap in 1,280 places was seen)
+    scale = np.asarray(dist_ops.row_sqnorms(queries) + jnp.max(sqnorm))
+    assert (np.abs(np.asarray(dists) - np.asarray(want_dists))
+            <= 8 * np.finfo(np.float32).eps * scale[:, None]).all()
+    assert float(jnp.mean(ids == want_ids)) > 0.99
+
+
+def _assert_proved_route(compiled, n, Q, k):
+    """A float program on the fused route (PR 41): the kernel, the flag,
+    nothing sorted N wide, no copy of the block, and the (Q, N) scores
+    alone among the temporaries (the branch an unproved run takes)."""
+    text = compiled.as_text()
+    assert _has_mosaic_kernel(compiled)
+    assert not _row_wide_selections(compiled, n)
+    assert not _row_wide_selections(compiled, n // 128)     # a partial TopK
+    assert f"f32[{n // 128},{Q}]" in text                   # the minima
+    # (at 512 queries that branch's slabs of the chosen groups, Q*Q*k*512
+    # bytes, do not stay out of device memory: `exact_topk`)
+    mem = compiled.memory_analysis()
+    slabs = Q * Q * k * 128 * 4 if Q > 128 else 0
+    assert mem.temp_size_in_bytes < Q * n * 4 + slabs \
+        + Q * (k + 6) * (1 << 16) + (64 << 20)
+
+
 # ---------------------------------------------------------------------------
 # FLAT exact scan at SIFT1M's shape
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("Q", [1, 128, 512])
 def test_flat_search_kernel_compiles_1m(one_chip, Q):
-    from sptag_tpu.algo.flat import _flat_search_kernel
+    from sptag_tpu.algo.flat import _flat_search_kernel, fused_minima
 
     n, D = 1_000_064, 128           # 1M rows padded to the 128-row bucket
-    compiled = _flat_search_kernel.lower(
+    # the route is the rule's for a TPU, passed as the index passes it
+    # (this process sees a CPU): the 128 and 512 rungs since PR 41
+    fused = fused_minima(np.dtype(np.float32), Q, n, D, K, L2, "tpu")
+    assert fused == (Q >= 128)
+    lowered = _flat_search_kernel.lower(
         _s(one_chip, (n, D), jnp.float32), _s(one_chip, (n,), jnp.float32),
         _s(one_chip, (n,), jnp.bool_), _s(one_chip, (Q, D), jnp.float32),
-        k=K, metric=L2, base=1).compile()
+        k=K, metric=L2, base=1, fused=fused)
+    assert len(lowered.out_info) == 2 + fused
+    compiled = lowered.compile()
+    if fused:
+        _assert_proved_route(compiled, n, Q, K)
     mem = compiled.memory_analysis()
     # corpus + the (Q, N) distance matrix + top-k scratch must fit 16 GB
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
@@ -298,12 +405,16 @@ def test_flat_search_kernel_compiles_live5m(one_chip, Q, k):
     (128 rows at k = CEF's default 32): the resident block, the (Q,
     slots) scores and the select's workspace fit the chip with the
     block's next copy (a growth holds two) to spare."""
-    from sptag_tpu.algo.flat import _flat_search_kernel
+    from sptag_tpu.algo.flat import _flat_search_kernel, fused_minima
 
     n, block = _live_block(one_chip)
+    fused = fused_minima(np.dtype(np.float32), Q, n, LIVE_D, k, L2, "tpu")
+    assert fused == (Q >= 128)
     compiled = _flat_search_kernel.lower(
         *block, _s(one_chip, (Q, LIVE_D), jnp.float32), k=k, metric=L2,
-        base=1).compile()
+        base=1, fused=fused).compile()
+    if fused:
+        _assert_proved_route(compiled, n, Q, k)
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes) < 8 * 2 ** 30
@@ -505,12 +616,19 @@ def test_sharded_flat_kernel_compiles_deep10m_on_four(mesh4, Q):
     rows = NamedSharding(mesh4, P(SHARD_AXIS, None))
     vec = NamedSharding(mesh4, P(SHARD_AXIS))
     rep = NamedSharding(mesh4, P(None, None))
-    compiled = _sharded_search_kernel.lower(
+    from sptag_tpu.algo.flat import fused_minima
+
+    fused = fused_minima(np.dtype(np.float32), Q, n_slot, D, K, L2, "tpu")
+    assert fused == (Q >= 128)
+    lowered = _sharded_search_kernel.lower(
         _s(rows, (n, D), jnp.float32), _s(vec, (n,), jnp.float32),
         _s(vec, (n,), jnp.bool_), _s(rep, (Q, D), jnp.float32),
         k_local=K, k_final=K, metric=L2, base=1, mesh=mesh4,
-        row_stride=stride).compile()
+        row_stride=stride, fused=fused)
+    assert len(lowered.out_info) == 2 + fused       # the flag, replicated
+    compiled = lowered.compile()
     text = compiled.as_text()
+    assert _has_mosaic_kernel(compiled) == fused    # under shard_map too
     # at one query the compiler gathers by all-reduce of a padded slice
     assert "all-gather" in text or "all-reduce" in text
     # the stage names reach the compiled program's metadata
@@ -521,7 +639,7 @@ def test_sharded_flat_kernel_compiles_deep10m_on_four(mesh4, Q):
         # temporaries are the (Q, 2.5M) scores as before (1,280.1 MB)
         assert not _row_wide_selections(compiled, n_slot)
         assert compiled.memory_analysis().temp_size_in_bytes \
-            < 1.01 * Q * n_slot * 4
+            < 1.02 * Q * n_slot * 4
     # a chip's share of the corpus, its (Q, 2.5M) scores and the top-k's
     # workspace: well inside 16 GB
     _assert_per_device(compiled, 6 * 2 ** 30)

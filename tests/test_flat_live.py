@@ -369,6 +369,56 @@ def test_a_growth_compiles_for_the_writer_not_for_the_next_search():
     assert log.count == 0, log.count
 
 
+def test_the_fused_scan_sees_a_write_in_place_and_a_growth():
+    """PR 41: at the 128 rung the float scan takes its group minima from
+    the Pallas kernel (interpret mode here) over the donated block as it
+    lies: a search after a growth, after a write in place and after a
+    delete takes the route again, answers from the new state, and the
+    growth compiled the route's program for the writer."""
+    from sptag_tpu.ops import pallas_kernels
+
+    q = _near(np.resize(QUERIES, (128, DIM)), 41, sigma=0.05)
+    names = ("flat.scan_fused_minima", "flat.scan_materialized",
+             "flat.scan_margin_unproved", "flat.block_grows",
+             "flat.block_updates")
+    pallas_kernels.set_interpret(True)
+    try:
+        idx = _index()
+        ref = Reference(BASE)
+        assert flat.fused_minima(np.float32, 128, flat.pad_rows(ROWS), DIM, 1,
+                                 0, "interpret")
+        before = {n: metrics.counter_value(n) for n in names}
+        np.testing.assert_array_equal(idx.search_batch(q, 1)[1],
+                                      ref.topk(q, 1))
+        first = _near(q[:64], 42, sigma=0.01)       # grows the block
+        assert idx.add(first) == sp.ErrorCode.Success
+        ref.add(first)
+        with recompile_guard.track_compiles("fused_after_growth") as log:
+            d, ids = idx.search_batch(q, 1)
+        assert log.count == 0, log.count
+        np.testing.assert_array_equal(ids, ref.topk(q, 1))
+        assert (ids[:64, 0] >= ROWS).all()          # the rows just added
+        second = _near(q[64:], 43, sigma=0.01)      # inside the reserve
+        assert idx.add(second) == sp.ErrorCode.Success
+        ref.add(second)
+        d, ids = idx.search_batch(q, 1)
+        np.testing.assert_array_equal(ids, ref.topk(q, 1))
+        assert (ids[:, 0] >= ROWS).all()
+        assert idx.delete_rows(first[:32])[1] == ref.delete(first[:32]) == 32
+        d, ids = idx.search_batch(q, 1)
+        np.testing.assert_array_equal(ids, ref.topk(q, 1))
+        assert not np.isin(ids, np.arange(ROWS, ROWS + 32)).any()
+        moved = {n: metrics.counter_value(n) - before[n] for n in names}
+    finally:
+        pallas_kernels.set_interpret(False)
+    # four searches and the delete's own search by content (32 rows pad
+    # to the 32 rung: materialised), one growth, three writes in place
+    assert moved == {"flat.scan_fused_minima": 4,
+                     "flat.scan_materialized": 1,
+                     "flat.scan_margin_unproved": 0,
+                     "flat.block_grows": 1, "flat.block_updates": 3}
+
+
 def test_derived_caches_fall_back_to_a_fresh_block():
     """A sketch holds the block's arrays outside the lock: with one
     cached a mutation does not write in place (`_live`), and answers

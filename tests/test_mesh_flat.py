@@ -439,6 +439,77 @@ def test_an_int8_cosine_shard_takes_the_fused_scan_and_is_exact(host_mesh):
     assert not deleted[got[1].ravel()].any()
 
 
+@pytest.mark.parametrize("dim", [96, 128])
+def test_a_float_l2_shard_takes_the_proved_scan(host_mesh, dim):
+    """PR 41: a shard of float32 rows under L2 takes its group minima
+    from the Pallas scan too (interpret mode here; 96 columns: the
+    deep-10M cell's, read column-major), proves its selection per shard
+    and answers the ids the materialised mesh program answers, deleted
+    rows and the padded block's tail among them; the flag comes back
+    replicated and nothing is counted unproved.  Rows hold small whole
+    numbers, so both routes' distances are one number a row."""
+    from sptag_tpu.algo import flat
+    from sptag_tpu.ops import pallas_kernels
+    from sptag_tpu.parallel.sharded import ShardedFlatIndex
+
+    rng = np.random.default_rng(41 + dim)
+    n, k = 2 * 12_900 + 11, 1
+    data = rng.integers(-60, 61, (n, dim)).astype(np.float32)
+    queries = (data[rng.integers(0, n, 128)]
+               + rng.integers(-2, 3, (128, dim))).astype(np.float32)
+    deleted = np.zeros(n, bool)
+    deleted[rng.integers(0, n, 300)] = True
+    index = ShardedFlatIndex(data, DistCalcMethod.L2, base=1,
+                             mesh=host_mesh(2), deleted=deleted)
+    n_slot = index.data.shape[0] // 2
+    assert flat.fused_minima(data.dtype, 128, n_slot, dim, k,
+                             int(DistCalcMethod.L2), "interpret")
+    want = index.search(queries, k)
+    assert metrics.counter_value("flat.scan_materialized") == 1
+    pallas_kernels.set_interpret(True)
+    try:
+        got = index.search(queries, k)
+    finally:
+        pallas_kernels.set_interpret(False)
+    assert metrics.counter_value("flat.scan_fused_minima") == 1
+    assert metrics.counter_value("flat.scan_margin_unproved") == 0
+    assert np.array_equal(got[0], want[0])
+    assert np.array_equal(got[1], want[1])
+    assert set(got[1].ravel() // index.row_stride) == {0, 1}
+    assert not deleted[got[1].ravel()].any()
+
+
+def test_a_shard_that_cannot_prove_answers_from_its_scores(host_mesh):
+    """One shard holds a query's nearest row in nine groups (more copies
+    than the proved select has spare groups): that shard answers from
+    its materialised scores, the other from its proved selection, the
+    flag comes back set on every shard and is counted once; the merged
+    answer is the materialised mesh program's."""
+    from sptag_tpu.ops import pallas_kernels
+    from sptag_tpu.parallel.sharded import ShardedFlatIndex
+
+    rng = np.random.default_rng(4141)
+    n, dim = 2 * 12_900 + 11, 96
+    data = rng.integers(-60, 61, (n, dim)).astype(np.float32)
+    queries = (data[rng.integers(0, n, 128)]
+               + rng.integers(-2, 3, (128, dim))).astype(np.float32)
+    for g in range(9):
+        data[(3 + 5 * g) * 128 + g] = queries[0]        # all in shard 0
+    index = ShardedFlatIndex(data, DistCalcMethod.L2, base=1,
+                             mesh=host_mesh(2))
+    want = index.search(queries, 1)
+    pallas_kernels.set_interpret(True)
+    try:
+        got = index.search(queries, 1)
+    finally:
+        pallas_kernels.set_interpret(False)
+    assert metrics.counter_value("flat.scan_fused_minima") == 1
+    assert metrics.counter_value("flat.scan_margin_unproved") == 1
+    assert np.array_equal(got[0], want[0])
+    assert np.array_equal(got[1], want[1])
+    assert got[1][0, 0] == 3 * 128 and got[0][0, 0] == 0.0
+
+
 # ---------------------------------------------------------------- (f) ----
 
 def golden_rows(n=300, d=24):
