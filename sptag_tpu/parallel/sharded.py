@@ -29,11 +29,13 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from sptag_tpu.algo.flat import (count_route, count_select, count_unproved,
+from sptag_tpu.algo.flat import (FlatIndex, _block_grown, _block_mask_rows,
+                                 _block_write_rows, _write_pieces,
+                                 count_route, count_select, count_unproved,
                                  pad_rows, pad_to_bucket, proved_form,
-                                 scan_route, scan_topk)
+                                 reserved_slots, scan_route, scan_topk)
 from sptag_tpu.core.index import MAX_DIST
-from sptag_tpu.core.types import DistCalcMethod
+from sptag_tpu.core.types import DistCalcMethod, value_type_of
 from sptag_tpu.ops import distance as dist_ops
 from sptag_tpu.ops import topk_bins
 from sptag_tpu.utils import (costmodel, devmem, locksan, metrics, round_up,
@@ -224,34 +226,73 @@ def _publish_placement(n_shards: int, rows_per_shard: int) -> None:
     metrics.set_gauge("mesh.rows_per_shard", rows_per_shard)
 
 
-class ShardedFlatIndex:
+class ShardedFlatIndex(FlatIndex):
     """Exact search over a corpus sharded across every device of a mesh.
 
     The data-parallel analog of running one reference Server per machine
     behind an Aggregator — minus the sockets.
+
+    It IS a `FlatIndex` whose placement is a mesh (docs/DESIGN.md "A
+    living corpus on the mesh"): rows, tombstones, stable ids, the log,
+    `add` and delete by content are `core/index.py`'s and `algo/flat.py`'s
+    code, as on one chip; what this class gives is the hooks that write a
+    placement (`_device_append` / `_device_mask` / `_grow`) and the
+    program that searches it (`_search_batch`: `_sharded_search_kernel`).
+
+    **Ids.**  A base row's id is its row in the partitioned corpus; a
+    streamed row's is `rows so far`, in arrival order, never reused: the
+    row's index in the host buffer either way.  Where a row LIES is the
+    placement's: base row i in shard i // row_stride at slot i %
+    row_stride; a write rung whole in ONE shard, successive rungs in
+    successive shards, at the shard's next free slots.  Until the first
+    write the program turns a shard's slot into the id by arithmetic
+    (`row_stride`, today's static program); from it on the program's
+    `shard * slots + slot` indexes a host table of ids (`_slot_ids`: a
+    rung's slots are filled in when it is written, so a search
+    dispatched before the write cannot return them).
     """
 
     def __init__(self, data: np.ndarray, metric: DistCalcMethod, base: int,
                  mesh: Optional[Mesh] = None,
                  deleted: Optional[np.ndarray] = None,
                  normalized: bool = False):
+        super().__init__(value_type_of(np.dtype(data.dtype)))
+        if base != self.base:
+            raise ValueError(f"base {base} is not {data.dtype}'s "
+                             f"({self.base})")
         self.mesh = mesh if mesh is not None else make_mesh()
+        self._row_sharding = NamedSharding(self.mesh, P(SHARD_AXIS, None))
+        self._vec_sharding = NamedSharding(self.mesh, P(SHARD_AXIS))
         self.metric = DistCalcMethod(metric)
-        self.base = base
-        self.n = data.shape[0]
-        self.metadata = None
+        self.params.dist_calc_method = self.metric
         n_dev = self.mesh.devices.size
 
         if self.metric == DistCalcMethod.Cosine and not normalized:
             data = dist_ops.normalize(data, base)
+        # the host-side truth, FlatIndex's own
+        self._build(data)
+        if deleted is not None:
+            self._deleted[:self._n] = deleted[:self._n]
+            self._num_deleted = int(self._deleted.sum())
 
         # a shard stands for `row_stride` consecutive corpus rows (the
         # last one for what is left) and holds them in a device block
         # padded as the one-chip snapshot is, under the `invalid` mask
-        self.row_stride = stride = self.rows_per_shard(self.n, n_dev)
+        self.row_stride = stride = self.rows_per_shard(self._n, n_dev)
         n_slot = pad_rows(stride)
-        if deleted is None:
-            deleted = np.zeros(self.n, bool)
+        # slots a shard has filled, and which of them are tombstoned
+        self._fill = [max(0, min(stride, self._n - s * stride))
+                      for s in range(n_dev)]
+        self._dead = [int(self._deleted[s * stride:(s + 1) * stride].sum())
+                      for s in range(n_dev)]
+        # what the first in-place write makes (`_grow`): the blocks a
+        # device, the ids a slot, the (shard, slot) a streamed row
+        self._parts: Optional[list] = None
+        self._slot_ids: Optional[np.ndarray] = None
+        self._place = np.empty((0, 2), np.int32)
+        self._streamed_from = self._n
+        self._next_shard = 0
+        self._write_rungs: set = set()
 
         def blocks_of(source, fill):
             def block(index):
@@ -263,26 +304,31 @@ class ShardedFlatIndex:
                 return out
             return block
 
-        row_sharding = NamedSharding(self.mesh, P(SHARD_AXIS, None))
-        vec_sharding = NamedSharding(self.mesh, P(SHARD_AXIS))
         n_pad = n_slot * n_dev
-        self.data = jax.make_array_from_callback(
-            (n_pad, data.shape[1]), row_sharding, blocks_of(data, 0))
-        self.invalid = jax.make_array_from_callback(
-            (n_pad,), vec_sharding, blocks_of(deleted[:self.n], True))
+        data_d = jax.make_array_from_callback(
+            (n_pad, data.shape[1]), self._row_sharding,
+            blocks_of(self._host, 0))
+        invalid_d = jax.make_array_from_callback(
+            (n_pad,), self._vec_sharding,
+            blocks_of(self._deleted[:self._n], True))
         if self.metric == DistCalcMethod.L2:
-            self.sqnorm = jax.jit(
+            sqnorm_d = jax.jit(
                 dist_ops.row_sqnorms,
-                out_shardings=vec_sharding)(self.data)
+                out_shardings=self._vec_sharding)(data_d)
         else:
             # cosine kernel never reads sqnorm; keep a zero placeholder so
             # the kernel signature stays uniform without HBM cost
-            self.sqnorm = jax.device_put(
-                np.zeros(n_pad, np.float32), vec_sharding)
-        devmem.track("shard_blocks", self,
-                     self.data.nbytes + self.sqnorm.nbytes
-                     + self.invalid.nbytes)
+            sqnorm_d = jax.device_put(
+                np.zeros(n_pad, np.float32), self._vec_sharding)
+        self._publish((data_d, sqnorm_d, invalid_d))
+        self._dirty = False
         _publish_placement(n_dev, stride)
+
+    # the resident block, as the static index named its arrays
+    data = property(lambda self: self._device[0])
+    sqnorm = property(lambda self: self._device[1])
+    invalid = property(lambda self: self._device[2])
+    n = property(lambda self: self._n)
 
     @staticmethod
     def rows_per_shard(n: int, n_shards: int) -> int:
@@ -292,14 +338,16 @@ class ShardedFlatIndex:
 
     @classmethod
     def save_shards(cls, data: np.ndarray, folder: str, n_shards: int,
-                    value_type, params=(), metadata=None) -> None:
+                    value_type, params=(), metadata=None,
+                    deleted: Optional[np.ndarray] = None) -> None:
         """Persist `data` as a mesh FLAT folder without touching a device:
         `n_shards` contiguous blocks of `rows_per_shard` rows (the last
         one shorter — its padding exists only on the device), each a
         reference-format FLAT folder `shard_NNN` exactly as a reference
         Server persists its partition, plus the manifest.  `params` are
         (name, value) pairs set on every shard index (DistCalcMethod
-        among them), so they persist in each shard's indexloader.ini."""
+        among them), so they persist in each shard's indexloader.ini;
+        `deleted` the rows' tombstones (a living index's save)."""
         from sptag_tpu.core.index import create_instance
         from sptag_tpu.core.types import ErrorCode
 
@@ -309,27 +357,44 @@ class ShardedFlatIndex:
             raise ValueError(
                 f"corpus ({n} rows) leaves one of {n_shards} shards empty")
         os.makedirs(folder, exist_ok=True)
+        params = [(str(name), str(value)) for name, value in params]
         for s in range(n_shards):
             sub = create_instance("FLAT", value_type)
             for name, value in params:
-                sub.set_parameter(name, str(value))
+                # the log is the whole index's, at the folder's top
+                if name.lower() != "walenabled":
+                    sub.set_parameter(name, value)
             code = sub.build(data[s * n_local:(s + 1) * n_local])
+            if code == ErrorCode.Success and deleted is not None:
+                # a shard's own save compacts nothing: an id is a row of
+                # the whole corpus
+                sub.set_parameter("DeletePercentageForRefine", "2")
+                sub._delete_ids(np.flatnonzero(
+                    deleted[s * n_local:(s + 1) * n_local]))
             if code == ErrorCode.Success:
                 code = sub.save_index(
                     os.path.join(folder, f"shard_{s:03d}"))
             if code != ErrorCode.Success:
                 raise RuntimeError(f"shard {s}: {code}")
-        write_manifest(folder, {
+        manifest = {
             "n_shards": n_shards, "n": n, "dim": int(data.shape[1]),
             "metric": int(sub.dist_calc_method),
-            "value_type": int(sub.value_type), "algo": "FLAT"}, metadata)
+            "value_type": int(sub.value_type), "algo": "FLAT"}
+        whole = {name: value for name, value in params
+                 if name.lower() == "walenabled"}
+        if whole:
+            # what the shards' own files were not given
+            manifest["index_params"] = whole
+        write_manifest(folder, manifest, metadata)
 
     @classmethod
     def load(cls, folder: str,
              mesh: Optional[Mesh] = None) -> "ShardedFlatIndex":
         """Load a folder `save_shards` wrote: the shard folders are read
         in order, their rows and tombstones laid end to end (cosine rows
-        were normalized at ingest) and placed over the mesh."""
+        were normalized at ingest) and placed over the mesh; then, with
+        `WalEnabled`, the folder's log is replayed over the placement
+        and armed (`core/index.py load_index`'s order)."""
         from sptag_tpu.core.index import load_index
 
         meta = read_manifest(folder)
@@ -350,43 +415,294 @@ class ShardedFlatIndex:
                    deleted=np.concatenate([sub._deleted[:r]
                                            for sub, r in zip(subs, rows)]),
                    normalized=True)
+        # the parameters the folder was saved with: the shards' own, and
+        # what only the whole index was given
+        self.params = subs[0].params
+        self.params.load_config(meta.get("index_params", {}))
+        del subs
         self.metadata = _folder_metadata(folder)
+        if int(getattr(self.params, "wal_enabled", 0) or 0):
+            self._replay_wal(folder)
+            self._arm_wal(folder)
         return self
 
-    def search(self, queries: np.ndarray,
-               k: int = 10, normalized: bool = False,
-               max_check: Optional[int] = None
-               ) -> Tuple[np.ndarray, np.ndarray]:
-        # `max_check` is accepted (and ignored — the scan is exact) so
-        # the flat mesh index serves behind ServingAdapter, whose wire
-        # surface forwards the $maxcheck option to every index type
-        del max_check
-        queries = np.asarray(queries)
-        if self.metric == DistCalcMethod.Cosine and not normalized:
-            queries = dist_ops.normalize(queries, self.base)
+    def save_index(self, folder: str):
+        """The living index as a mesh folder (`save_shards`' format, no
+        other): every row so far, base and streamed, partitioned anew in
+        id order with its tombstones, and an empty log beside it.  An id
+        stays the row's index in that order, so `load` places a streamed
+        row by the base rule."""
+        from sptag_tpu.core.types import ErrorCode
+        from sptag_tpu.io import wal
+
+        with self._lock:
+            n = self._n
+            self.save_shards(
+                self._host[:n], folder, int(self.mesh.devices.size),
+                self.value_type, params=list(self.params.non_default_items()),
+                metadata=self.metadata, deleted=self._deleted[:n])
+            if int(getattr(self.params, "wal_enabled", 0) or 0):
+                wal.create_empty(os.path.join(folder, wal.WAL_NAME))
+                self._arm_wal(folder)
+        return ErrorCode.Success
+
+    # ---- the placement follows its mutations ------------------------------
+
+    def _live(self) -> bool:
+        # a sketch or a cascade state stays out of the mesh: every
+        # mutation writes the placement in place
+        return self._device is not None
+
+    def _snapshot(self):
+        return self._device
+
+    def _cascade_active(self) -> bool:
+        return False
+
+    def _slots(self) -> int:
+        """Row slots a shard's block has (lock held)."""
+        return self._device[0].shape[0] // self.mesh.devices.size
+
+    def _shard_blocks(self) -> list:
+        """`_parts`: the resident block as one [rows, norms, mask] a
+        device, in the mesh's order, made at the first write (lock held):
+        each a single-device array ON the buffer the whole array holds
+        there, so a donated write of one device's touches no other's."""
+        if self._parts is None:
+            per_array = [{shard.device: shard.data
+                          for shard in array.addressable_shards}
+                         for array in self._device]
+            self._parts = [[by_device[d] for by_device in per_array]
+                           for d in self.mesh.devices.flat]
+        return self._parts
+
+    def _assembled(self):
+        """The devices' blocks as the program's three arrays: no copy."""
+        n_dev = len(self._parts)
+        rows, dim = self._parts[0][0].shape
+        return tuple(
+            jax.make_array_from_single_device_arrays(shape, sharding,
+                                                     [p[i] for p in
+                                                      self._parts])
+            for i, (shape, sharding) in enumerate((
+                ((n_dev * rows, dim), self._row_sharding),
+                ((n_dev * rows,), self._vec_sharding),
+                ((n_dev * rows,), self._vec_sharding))))
+
+    def _publish(self, block) -> None:
+        """`block` as the resident one (lock held)."""
+        self._device = block
+        n_dev = self.mesh.devices.size
+        metrics.set_gauge("flat.rows_resident", self._n)
+        metrics.set_gauge("flat.slots_reserved", block[0].shape[0] // n_dev)
+        live = [f - d for f, d in zip(self._fill, self._dead)]
+        metrics.set_gauge("mesh.live_rows_max", max(live))
+        metrics.set_gauge("mesh.live_rows_min", min(live))
+        if self._slot_ids is not None:
+            metrics.set_gauge("mesh.rows_per_shard", max(self._fill))
+        devmem.track("shard_blocks", self,
+                     block[0].nbytes + block[1].nbytes + block[2].nbytes)
+
+    def _reserve(self, extra: int) -> None:
+        super()._reserve(extra)
+        room = self._host.shape[0] - self._streamed_from
+        if room > self._place.shape[0]:
+            grown = np.empty((room, 2), np.int32)
+            grown[:len(self._place)] = self._place
+            self._place = grown
+
+    def _device_append(self, begin: int, rows: np.ndarray) -> None:
+        """Rows [begin, begin + len(rows)) of the host buffer written
+        into the placement (lock held): each write rung whole into ONE
+        shard's block at its next free slots, by the one-chip write
+        program on that device, successive rungs to successive shards;
+        the blocks of every device grow first (`_grow`) where a rung
+        would pass the fullest shard's last slot, and at the first write
+        of all."""
+        n_dev = self.mesh.devices.size
+        pieces = _write_pieces(len(rows))
+        # room for each rung past the FULLEST shard as its turn finds
+        # them (`_warm_rung` writes a rung's first use on every shard)
+        fill, need = list(self._fill), 0
+        for turn, (_, count, rung) in enumerate(pieces):
+            need = max(need, max(fill) + rung)
+            fill[(self._next_shard + turn) % n_dev] += count
+        if self._slot_ids is None or need > self._slots():
+            self._grow(need)
+        slots, sent, written = self._slots(), 0, set()
+        with trace.span("flat.block_update"):
+            for lo, count, rung in pieces:
+                self._warm_rung("rows", rung)
+                s, self._next_shard = (self._next_shard,
+                                       (self._next_shard + 1) % n_dev)
+                start = self._fill[s]
+                padded = np.zeros((rung, rows.shape[1]), rows.dtype)
+                padded[:count] = rows[lo:lo + count]
+                dead = np.ones(rung, bool)
+                dead[:count] = self._deleted[begin + lo:begin + lo + count]
+                self._parts[s] = list(_block_write_rows(
+                    *self._parts[s], padded, dead, np.int32(start)))
+                ids = np.arange(begin + lo, begin + lo + count)
+                self._slot_ids[s * slots + start:
+                               s * slots + start + count] = ids
+                at = ids - self._streamed_from
+                self._place[at, 0], self._place[at, 1] = s, start + (
+                    ids - ids[0])
+                self._fill[s] += count
+                self._dead[s] += int(dead[:count].sum())
+                sent += padded.nbytes + dead.nbytes + 4
+                written.add(s)
+            self._publish(self._assembled())
+        metrics.inc("flat.block_updates")
+        trace.record_sum("flat.block_upload_bytes", sent, len(rows))
+        trace.record_sum("mesh.write_devices", len(written), 1)
+
+    def _device_mask(self, vids) -> None:
+        """Mask bits of rows `vids` set where they lie (lock held): one
+        mask write a rung on each shard that owns some of them."""
+        parts = self._shard_blocks()
+        vids = np.asarray(vids, np.int64)
+        late = vids >= self._streamed_from
+        shard = np.where(late, 0, vids // self.row_stride)
+        slot = np.where(late, 0, vids % self.row_stride)
+        at = vids[late] - self._streamed_from
+        shard[late], slot[late] = self._place[at, 0], self._place[at, 1]
+        slots, sent, written = self._slots(), 0, set()
+        with trace.span("flat.block_update"):
+            for s in np.unique(shard):
+                mine = slot[shard == s].astype(np.int32)
+                for lo, count, rung in _write_pieces(len(mine)):
+                    self._warm_rung("mask", rung)
+                    where = np.full(rung, slots, np.int32)
+                    where[:count] = mine[lo:lo + count]
+                    parts[s][2] = _block_mask_rows(parts[s][2], where)
+                    sent += where.nbytes
+                self._dead[s] += len(mine)
+                written.add(int(s))
+            self._publish(self._assembled())
+        metrics.inc("flat.block_updates")
+        trace.record_sum("flat.block_upload_bytes", sent, len(vids))
+        trace.record_sum("mesh.write_devices", len(written), 1)
+
+    def _warm_rung(self, kind: str, rung: int) -> None:
+        """A write rung's first use on these blocks runs its program once
+        on EVERY device (lock held), so the compiles of a rung are the
+        writer's that first sends one and no later rung's, whichever
+        shard its turn gives it.  What is run changes nothing: a mask of
+        slots past the end (dropped); zero rows, masked, at a shard's
+        free slots (`_device_append` left room on the fullest)."""
+        if (kind, rung) in self._write_rungs:
+            return
+        self._write_rungs.add((kind, rung))
+        dim, dtype = self._parts[0][0].shape[1], self._parts[0][0].dtype
+        for s, part in enumerate(self._parts):
+            if kind == "mask":
+                part[2] = _block_mask_rows(
+                    part[2], np.full(rung, part[2].shape[0], np.int32))
+            else:
+                self._parts[s] = list(_block_write_rows(
+                    *part, np.zeros((rung, dim), dtype), np.ones(rung, bool),
+                    np.int32(self._fill[s])))
+
+    def _grow(self, need: int) -> None:
+        """Every device's block copied, on its device, into one of
+        `reserved_slots(need)` slots (lock held; the shapes stay equal
+        under `shard_map`), the table of ids laid out anew, and every
+        scan program and write rung that ran on the old shape run once
+        on the new one: the writer that outgrew the reserve pays the
+        compiles, not the searches after it."""
+        with trace.span("flat.block_grow"):
+            slots, old = reserved_slots(need), self._slots()
+            n_dev = self.mesh.devices.size
+            self._parts = [list(_block_grown(*part, slots=slots))
+                           for part in self._shard_blocks()]
+            table = np.full(n_dev * slots, -1, np.int32)
+            for s in range(n_dev):
+                if self._slot_ids is None:
+                    table[s * slots:s * slots + self._fill[s]] = (
+                        s * self.row_stride + np.arange(self._fill[s]))
+                else:
+                    table[s * slots:s * slots + old] = \
+                        self._slot_ids[s * old:(s + 1) * old]
+            self._slot_ids = table
+            block = self._assembled()
+            zeros = {}
+            for q, k in self._programs:
+                if q not in zeros:
+                    zeros[q] = jnp.zeros((q, block[0].shape[1]),
+                                         block[0].dtype)
+                _sharded_search_kernel(*block, zeros[q],
+                                       **self._scan_statics(q, k, slots))
+            rungs, self._write_rungs = self._write_rungs, set()
+            for kind, rung in sorted(rungs):
+                self._warm_rung(kind, rung)
+            self._publish(self._assembled())
+        metrics.inc("flat.block_grows")
+
+    # ---- search -----------------------------------------------------------
+
+    def _scan_statics(self, q: int, k: int, slots: int) -> dict:
+        """The mesh program's static arguments for `q` queries at `k`
+        over blocks of `slots` slots a shard: the exact select's route
+        is a rule of that shape, the id rule of whether a write has
+        happened (lock held)."""
+        n_dev = self.mesh.devices.size
+        k_local = min(k, slots)
+        return {"k_local": k_local, "k_final": min(k, k_local * n_dev),
+                "metric": int(self.metric), "base": self.base,
+                "mesh": self.mesh,
+                "row_stride": (self.row_stride if self._slot_ids is None
+                               else None),
+                **scan_route(self._device[0].dtype, q, slots,
+                             self._device[0].shape[1], k_local,
+                             int(self.metric))}
+
+    def _search_batch(self, queries: np.ndarray, k: int,
+                      max_check: Optional[int] = None,
+                      search_mode: Optional[str] = None
+                      ) -> Tuple[np.ndarray, np.ndarray]:
+        del max_check, search_mode      # exact scan: no budget, no modes
         # the one-chip scan's ladder: a served window forms batches of
         # every size, and each size would be a program of its own
         q = queries.shape[0]
         queries = pad_to_bucket(queries)
-        n_dev = self.mesh.devices.size
-        n_local = self.data.shape[0] // n_dev
-        k_local = min(k, n_local)
-        k_final = min(k, k_local * n_dev)
-        count_select(queries.shape[0], n_local, k_local)
-        route = scan_route(self.data.dtype, queries.shape[0], n_local,
-                           self.data.shape[1], k_local, int(self.metric))
-        count_route(route["fused"])
-        dists, ids, *flag = _sharded_search_kernel(
-            self.data, self.sqnorm, self.invalid, jnp.asarray(queries),
-            k_local, k_final, int(self.metric), self.base, self.mesh,
-            row_stride=self.row_stride, **route)
+        queries_d = jnp.asarray(queries)
+        # read the block and enqueue the program on it under the writer's
+        # lock, as on one chip: the next in-place write donates a
+        # device's arrays, and each device runs the program before it
+        with self._lock:
+            block, table = self._device, self._slot_ids
+            statics = self._scan_statics(queries.shape[0], k, self._slots())
+            count_select(queries.shape[0], self._slots(),
+                         statics["k_local"])
+            count_route(statics["fused"])
+            self._programs.add((queries.shape[0], k))
+            dists, ids, *flag = _sharded_search_kernel(*block, queries_d,
+                                                       **statics)
         with trace.span("index.readback"):
             # the host blocks here until the program has run
             dists = np.asarray(dists)[:q]
             ids = np.asarray(ids)[:q]
             if flag:
                 count_unproved(flag[0])
-        return _pad_to_k(dists, ids, k, k_final)
+        if table is not None:
+            ids = np.where(ids >= 0, table[np.maximum(ids, 0)], -1)
+        return _pad_to_k(dists, ids, k, statics["k_final"])
+
+    _exact_scan = _search_batch
+
+    def search(self, queries: np.ndarray,
+               k: int = 10, normalized: bool = False,
+               max_check: Optional[int] = None
+               ) -> Tuple[np.ndarray, np.ndarray]:
+        """A (Q, D) block -> ((Q, k) distances, (Q, k) ids): the mesh
+        index's own batch surface, which `ServingAdapter` serves.
+        `max_check` is accepted (and ignored — the scan is exact): the
+        wire surface forwards the $maxcheck option to every index type."""
+        queries = np.asarray(queries)
+        if self.metric == DistCalcMethod.Cosine and not normalized:
+            queries = dist_ops.normalize(queries, self.base)
+        return self._search_batch(queries, k, max_check)
 
 
 # --------------------------------------------------------------------------
@@ -681,6 +997,24 @@ class ServingAdapter:
                 "scheduler": getattr(impl, "_scheduler", None) is not None,
             },
         }
+
+    # ---- mutation surface -------------------------------------------------
+    # The backing index's own (`$admin:add` / `$admin:delete` / `$admin:
+    # save` reach it through these): a mesh FLAT index is a `FlatIndex`
+    # and takes them in place; a mesh BKT index has no such method and
+    # the call raises, which the admin surface answers as before.
+
+    def add(self, vectors, metadata=None, with_meta_index: bool = False):
+        return self._impl.add(vectors, metadata, with_meta_index)
+
+    def delete(self, vectors):
+        return self._impl.delete(vectors)
+
+    def delete_rows(self, vectors):
+        return self._impl.delete_rows(vectors)
+
+    def save_index(self, folder: str):
+        return self._impl.save_index(folder)
 
     def submit_batch(self, queries: np.ndarray, k: int = 10,
                      max_check: Optional[int] = None,

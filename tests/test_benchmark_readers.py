@@ -68,6 +68,12 @@ def _mutates(cell: str) -> bool:
 #: records: held against a served index that is mutated, further down
 LIVE_ONLY = [m["name"] for m in _BENCH["per_layer"]
              if m.get("workloads") and all(map(_mutates, m["workloads"]))]
+#: and of those, the ones listed for four-chip cells alone read what a
+#: mesh placement records: held against a mutated mesh index, at the end
+MESH_LIVE_ONLY = [
+    m["name"] for m in _BENCH["per_layer"] if m["name"] in LIVE_ONLY
+    and all({w["name"]: w for w in _BENCH["workloads"]}[c]["chips"] == 4
+            for c in m["workloads"])]
 PER_LAYER = [m["name"] for m in _BENCH["per_layer"]
              if m["source"] in OFF_CHIP_SOURCES
              and m["name"] not in BEAM_ONLY + LIVE_ONLY]
@@ -218,8 +224,8 @@ def test_the_walks_readers_read_nothing_without_a_walk(run):
 
 # ---- the mutations' readers (PR 40) ----------------------------------------
 
-LIVE_OFF_CHIP = [m for m in LIVE_ONLY
-                 if {x["name"]: x for x in _BENCH["per_layer"]}[m]["source"]
+LIVE_OFF_CHIP = [m for m in LIVE_ONLY if m not in MESH_LIVE_ONLY
+                 and {x["name"]: x for x in _BENCH["per_layer"]}[m]["source"]
                  in OFF_CHIP_SOURCES]
 
 
@@ -356,3 +362,124 @@ def test_int8_roofline_arithmetic_against_a_hand_count(queries, bound,
     assert got["op_seconds"] == pytest.approx(
         2 * 1e6 * 384 * queries * 10 / 393e12, rel=1e-12)
     assert got["seconds"] == max(got["hbm_seconds"], got["op_seconds"])
+
+
+# ---- the living mesh's readers (PR 43) -------------------------------------
+
+def test_benchmark_lists_the_living_meshs_readers():
+    assert set(MESH_LIVE_ONLY) == {"kernel.sharded_live_scan_roofline",
+                                   "mutation.devices_per_write"}
+    cell = {w["name"]: w for w in _BENCH["workloads"]}[
+        "sharded_live20m.stream"]
+    assert cell["chips"] == 4 and cell["traffic"] == "stream128"
+    listed = {m["name"] for m in _BENCH["per_layer"]
+              if cell["name"] in m.get("workloads", ())}
+    assert set(LIVE_OFF_CHIP) | set(MESH_LIVE_ONLY) | {
+        "kernel.topk_ms_per_batch", "kernel.mesh_merge_ms_per_batch",
+        "server.reply_ms", "loop.lag_ms", "loop.cpu_share",
+        "executor.cpu_share"} == listed
+
+
+def test_devices_per_write_reads_a_mutated_mesh_index(host_mesh):
+    """Three adds of a rung each and a delete of one of them, on four
+    shards: one device written an operation, whichever shard its turn
+    gave it; and the accepted mutation readers read the mesh index's
+    spans as they read the one-chip index's."""
+    from sptag_tpu.core.types import DistCalcMethod
+    from sptag_tpu.parallel.sharded import ShardedFlatIndex
+
+    rng = np.random.default_rng(3)
+    data = rng.standard_normal((300, 8)).astype(np.float32)
+    index = ShardedFlatIndex(data, DistCalcMethod.L2, 1, mesh=host_mesh(4))
+    blocks = [rng.standard_normal((4, 8)).astype(np.float32)
+              for _ in range(4)]
+    index.add(blocks[0])                            # the growth
+    assert index.delete_rows(blocks[0]) == (sp.ErrorCode.Success, 4)
+    before = trace.report()
+    for block in blocks[1:]:
+        index.add(block)
+    assert index.delete_rows(blocks[2]) == (sp.ErrorCode.Success, 4)
+    run = {"spans": span_deltas(before, trace.report())}
+    reader = load_by_name("layer_metrics", "mutation.devices_per_write")
+    assert reader.read(run) == 1.0
+    assert run["spans"]["mesh.write_devices"]["count"] == 4
+    assert load_by_name("layer_metrics",
+                        "mutation.upload_bytes_per_row").read(run) \
+        == (3 * (8 * 33 + 4) + 8 * 4) / 16
+    for metric in ("mutation.add_ms", "mutation.delete_ms",
+                   "mutation.block_update_ms"):
+        assert load_by_name("layer_metrics", metric).read(run) > 0
+    # a delete whose rows lie on two shards: two devices written
+    before = trace.report()
+    assert index.delete_rows(data[[0, 299]]) == (sp.ErrorCode.Success, 2)
+    assert reader.read({"spans": span_deltas(before, trace.report())}) == 2.0
+
+
+def test_the_living_meshs_readers_read_nothing_on_one_chip(mutated_run, run):
+    """The parent's program, or a one-chip index that mutates: None, no
+    raise."""
+    reader = load_by_name("layer_metrics", "mutation.devices_per_write")
+    assert reader.read(mutated_run) is None and reader.read(run) is None
+    roofline = load_by_name("layer_metrics",
+                            "kernel.sharded_live_scan_roofline")
+    traced = {**run, "peaks": {"bf16_flops_per_s": 197e12,
+                               "hbm_bytes_per_s": 819e9},
+              "trace": {"programs": {roofline.PROGRAM: {"runs": 1,
+                                                        "seconds": 1.0}}}}
+    for config in ({"algo": "FLAT", "rows": 200, "dim": 8},
+                   {"algo": "FLAT", "rows": 200, "dim": 8, "chips": 1},
+                   {"algo": "BKT", "rows": 200, "dim": 8, "chips": 4}):
+        assert roofline.read({**traced, "config": config}) is None
+    assert roofline.read({**run, "trace": {"programs": {}}, "peaks": {},
+                          "config": {"algo": "FLAT", "rows": 200, "dim": 8,
+                                     "chips": 4}}) is None
+
+
+def test_sharded_live_roofline_arithmetic_against_a_hand_count():
+    """A chip's share of 20M x 100 f32 (5M rows, 2.0 GB) read once a run
+    at 819 GB/s is 2.442 ms: 64 queries a run in 7.0 ms of device time on
+    the busiest plane read 34.89 %, bound by the HBM; the reserve and the
+    merge are not counted."""
+    reader = load_by_name("layer_metrics",
+                          "kernel.sharded_live_scan_roofline")
+    with open(os.path.join(REPO, "benchmark", "configs",
+                           "sharded_flat_live_msturing20m_f32_l2.json")) as f:
+        config = json.load(f)
+    spans = {"server.queue_wait": {"count": 640, "total_s": 1.0},
+             "server.execute_batch": {"count": 10, "total_s": 1.0}}
+    least, seconds = reader.bound({
+        "spans": spans, "config": config,
+        "peaks": serving.peaks_for("TPU v5 lite"),
+        "trace": {"programs": {reader.PROGRAM: {"runs": 10,
+                                                "seconds": 0.070}}}})
+    assert least["bound"] == "hbm" and seconds == 0.070
+    assert math.isclose(least["seconds"], 10 * 2e9 / 819e9)
+    assert math.isclose(100 * least["seconds"] / seconds, 34.886,
+                        rel_tol=1e-4)
+    # at 128 queries a run the dots still take less than the read
+    assert least["flop_seconds"] * 2 < least["hbm_seconds"]
+    from sptag_tpu.parallel import sharded
+    assert hasattr(sharded, reader.PROGRAM[len("jit_"):])
+
+
+
+@pytest.mark.parametrize("mode,correct", [("bits23", True), ("bits7", False)])
+def test_the_living_control_puts_the_plain_scan_through_the_rule(
+        mode, correct, capsys):
+    """benchmark/tools/control_reference_live.py at a test's size: a
+    serial record gives every answer ONE admissible state, so the plain
+    scan in float32 is correct to the list, and with its inputs cut to
+    bfloat16's mantissa it is not, by `dist_err_ulps_rms`."""
+    from benchmark.tools import control_reference_live
+
+    assert control_reference_live.main(
+        ["sharded_flat_live_msturing20m_f32_l2", "7", mode, "3000"]) == 0
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["correct"] is correct and line["rows"] == 3000
+    assert line["answers_with_streamed_row"] > line["answers_compared"] / 2
+    compared = line["compared"]
+    assert compared["writer_steps_done_share"] == 1.0
+    assert compared["mutations_failed"] == compared["invalid_lists"] == 0
+    if correct:
+        assert compared["stale_or_wrong_lists"] == 0
+    assert (compared["dist_err_ulps_rms"] <= 5.0) is correct

@@ -390,10 +390,13 @@ LIVE_ROWS, LIVE_D = 5_000_000, 100
 
 
 def _live_block(sh):
+    """-> (slots, the block's shapes on `sh`; None: the slots alone)."""
     from sptag_tpu.algo.flat import reserved_slots
 
     n = reserved_slots(LIVE_ROWS + 128)
     assert n == 5_312_640
+    if sh is None:
+        return n, None
     return n, (_s(sh, (n, LIVE_D), jnp.float32), _s(sh, (n,), jnp.float32),
                _s(sh, (n,), jnp.bool_))
 
@@ -643,6 +646,130 @@ def test_sharded_flat_kernel_compiles_deep10m_on_four(mesh4, Q):
     # a chip's share of the corpus, its (Q, 2.5M) scores and the top-k's
     # workspace: well inside 16 GB
     _assert_per_device(compiled, 6 * 2 ** 30)
+
+
+# `sharded_live20m.stream` (PR 43): 20M x 100 f32 over four chips, a shard
+# what `flat_live5m.stream`'s one chip holds (5,312,640 x 100 once the
+# first add has reserved a sixteenth ahead)
+
+def _mesh_live_block(mesh4, n_slot):
+    from sptag_tpu.parallel.sharded import SHARD_AXIS
+
+    rows = NamedSharding(mesh4, P(SHARD_AXIS, None))
+    vec = NamedSharding(mesh4, P(SHARD_AXIS))
+    return (_s(rows, (4 * n_slot, LIVE_D), jnp.float32),
+            _s(vec, (4 * n_slot,), jnp.float32),
+            _s(vec, (4 * n_slot,), jnp.bool_))
+
+
+@pytest.mark.parametrize("Q,k", [(1, 10), (8, 10), (32, 10), (128, 10),
+                                 (128, 32)])
+def test_sharded_flat_kernel_compiles_live20m_on_four(mesh4, Q, k):
+    """The cell's four search rungs and a delete's search by content
+    (k = 32) as the living mesh index dispatches them after its first
+    write: `row_stride` absent (the program's id is shard * slots + slot,
+    the host's table turns it into the row's), the fused proved route a
+    shard at 128 queries, no (Q, slots) scores outside its unproved
+    branch, a chip's share well inside 16 GB with the block's next copy
+    (a growth holds two) to spare."""
+    from sptag_tpu.algo.flat import fused_minima
+    from sptag_tpu.parallel.sharded import (ShardedFlatIndex,
+                                            _sharded_search_kernel)
+
+    assert ShardedFlatIndex.rows_per_shard(20_000_000, 4) == LIVE_ROWS
+    n_slot, _ = _live_block(None)
+    fused = fused_minima(np.dtype(np.float32), Q, n_slot, LIVE_D, k, L2,
+                         "tpu")
+    assert fused == (Q >= 128)
+    lowered = _sharded_search_kernel.lower(
+        *_mesh_live_block(mesh4, n_slot),
+        _s(NamedSharding(mesh4, P(None, None)), (Q, LIVE_D), jnp.float32),
+        k_local=k, k_final=k, metric=L2, base=1, mesh=mesh4,
+        row_stride=None, fused=fused)
+    assert len(lowered.out_info) == 2 + fused
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    assert _has_mosaic_kernel(compiled) == fused
+    assert "all-gather" in text or "all-reduce" in text
+    for scope in ("flat.distance", "flat.topk", "mesh.merge"):
+        assert scope in text, scope
+    if Q in (8, 128):
+        assert not _row_wide_selections(compiled, n_slot)    # two stages
+    _assert_per_device(compiled, 8 * 2 ** 30)
+
+
+@pytest.mark.parametrize("rung", [8, 128, 1024])
+def test_mesh_block_writes_are_one_devices_and_in_place_live20m(topo, rung):
+    """A write rung and a mask rung of the living mesh index are the
+    one-chip programs on the OWNING device's arrays (`ShardedFlatIndex.
+    _device_append` / `_device_mask`): compiled for a device of the
+    described 2x2 that is not the first, each is one device's program
+    (no collective, no other device in it), donates the shard's block
+    and keeps no temporary near its size."""
+    from sptag_tpu.algo.flat import _block_mask_rows, _block_write_rows
+
+    third = SingleDeviceSharding(topo.devices[2])
+    n, block = _live_block(third)
+    for compiled, donated in (
+            (_block_write_rows.lower(
+                *block, _s(third, (rung, LIVE_D), jnp.float32),
+                _s(third, (rung,), jnp.bool_),
+                _s(third, (), jnp.int32)).compile(), n * LIVE_D * 4),
+            (_block_mask_rows.lower(
+                block[2], _s(third, (rung,), jnp.int32)).compile(), n)):
+        text = compiled.as_text()
+        for collective in ("all-gather", "all-reduce", "all-to-all",
+                           "collective-permute"):
+            assert collective not in text, collective
+        assert "num_partitions=1" in text or "num_partitions" not in text
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= donated
+        assert mem.temp_size_in_bytes < 2 ** 20
+
+
+def test_mesh_block_growth_compiles_a_device_live20m(topo):
+    """The growth the cell's first add makes on every device: a shard's
+    block as placed (5,000,064 slots) copied on ITS device into the
+    reserved one; two copies of a shard's rows fit a chip."""
+    from sptag_tpu.algo.flat import _block_grown, pad_rows
+
+    last = SingleDeviceSharding(topo.devices[3])
+    n0 = pad_rows(LIVE_ROWS)
+    n, _ = _live_block(last)
+    compiled = _block_grown.lower(
+        _s(last, (n0, LIVE_D), jnp.float32), _s(last, (n0,), jnp.float32),
+        _s(last, (n0,), jnp.bool_), slots=n).compile()
+    assert "all-gather" not in compiled.as_text()
+    _assert_per_device(compiled, 6 * 2 ** 30)
+
+
+def test_a_write_to_one_shard_leaves_the_others_buffers_on_the_chip():
+    """CHIP-ONLY, four chips (skipped wherever the first device is no
+    TPU; `chiprun --chips 4 -- python -m pytest tests/test_chip_compile.py
+    -k on_the_chip --noconftest`): an add's rung is written on the owning
+    device alone — the other three devices' rows, norms and mask are the
+    same buffers before and after — and the next search finds its rows."""
+    if jax.devices()[0].platform != "tpu" or len(jax.devices()) < 4:
+        pytest.skip("needs four chips")
+    from sptag_tpu.parallel.sharded import ShardedFlatIndex, make_mesh
+
+    rng = np.random.default_rng(43)
+    data = rng.standard_normal((400_000, LIVE_D)).astype(np.float32)
+    index = ShardedFlatIndex(data, DistCalcMethod.L2, 1,
+                             mesh=make_mesh(jax.devices()[:4]))
+    first = data[:128] + np.float32(0.01)
+    index.add(first)                                    # the growth
+    for turn in range(4):
+        before = [[a.unsafe_buffer_pointer() for a in part]
+                  for part in index._parts]
+        owner = index._next_shard
+        block = data[1000 * turn:1000 * turn + 128] + np.float32(0.02)
+        index.add(block)
+        after = [[a.unsafe_buffer_pointer() for a in part]
+                 for part in index._parts]
+        assert all(after[s] == before[s] for s in range(4) if s != owner)
+        ids = index.search(block[:8], 1)[1][:, 0]
+        assert list(ids) == list(range(index.n - 128, index.n - 120))
 
 
 def test_sharded_flat_kernel_compiles_int8_cosine_fused_on_four(mesh4):
