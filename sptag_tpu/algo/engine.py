@@ -224,6 +224,32 @@ def dedup_in_sorted_order(merge_bins: int, packed: bool) -> bool:
     return not merge_bins and not packed
 
 
+#: the largest corpus whose ids leave a bit free in an int32 word
+#: (`_flag_ids`); `_walk_machine` refuses a larger one
+MAX_FLAGGED_ROWS = 1 << 30
+
+
+def _flag_ids(ids: jax.Array, flag: jax.Array) -> jax.Array:
+    """int32 ids (-1 or < `MAX_FLAGGED_ROWS`) and a bool per id -> ONE
+    int32 word per id, the flag in bit 0 (a void stays negative: -2 / -1),
+    so a gather by position fetches both at once; `_split_flagged`
+    undoes it."""
+    return jnp.left_shift(ids, 1) | flag.astype(jnp.int32)
+
+
+def _split_flagged(key: jax.Array):
+    """`_flag_ids`' word -> (ids, flag); the arithmetic shift brings a
+    void back as -1 whatever its flag."""
+    return jnp.right_shift(key, 1), (key & 1).astype(bool)
+
+
+def walk_takes_norm(metric) -> bool:
+    """Whether a walk body's score has a candidate norm in it (L2; cosine
+    has none): `_publish_walk`'s `beam.norm_from_rows` and the engines'
+    `walk_iter_cost` follow it."""
+    return int(metric) == int(DistCalcMethod.L2)
+
+
 def _sorted_fresh(visited: jax.Array, flat_safe: jax.Array, n: int):
     """The walk's visited / de-duplicate ensemble in sorted-id order.
     `flat_safe` (Q, X): a trip's candidate ids, `n` in the holes.  ->
@@ -369,9 +395,8 @@ def _beam_search_kernel(data, sqnorm, graph, deleted, pivot_ids, pivot_vecs,
                         pivot_mask, queries, t_limit, k: int, L: int,
                         B: int, metric: int, base: int, nbp_limit: int,
                         inject: int = 4, data_score=None, nbr_vecs=None,
-                        nbr_sq=None, merge_bins: int = 0,
-                        finalize_bins: int = 0, seed_keep: int = 0,
-                        score_scale: float = 0.0):
+                        merge_bins: int = 0, finalize_bins: int = 0,
+                        seed_keep: int = 0, score_scale: float = 0.0):
     """Pivot-seeded monolithic walk: seed + walk + finalize fused in one
     program.  `t_limit` (Q,) carries the per-row iteration budget as a
     TRACED array, so distinct MaxCheck values that map to the same (L, B)
@@ -382,7 +407,7 @@ def _beam_search_kernel(data, sqnorm, graph, deleted, pivot_ids, pivot_vecs,
     return _walk(data, sqnorm, graph, deleted, queries, cand_ids, cand_d,
                  visited, k, L, B, t_limit, metric, base, nbp_limit,
                  spare_ids=spare_ids, spare_d=spare_d, inject=inject,
-                 data_score=data_score, nbr_vecs=nbr_vecs, nbr_sq=nbr_sq,
+                 data_score=data_score, nbr_vecs=nbr_vecs,
                  merge_bins=merge_bins, finalize_bins=finalize_bins,
                  score_scale=score_scale)
 
@@ -395,15 +420,14 @@ def _beam_search_seeded_kernel(data, sqnorm, graph, deleted, seed_ids,
                                queries, t_limit, k: int, L: int, B: int,
                                metric: int, base: int, nbp_limit: int,
                                data_score=None, nbr_vecs=None,
-                               nbr_sq=None, merge_bins: int = 0,
-                               finalize_bins: int = 0,
+                               merge_bins: int = 0, finalize_bins: int = 0,
                                score_scale: float = 0.0):
     cand_ids, cand_d, visited = _seed_from_seeds(data, sqnorm, seed_ids,
                                                  queries, L, metric, base,
                                                  score_scale=score_scale)
     return _walk(data, sqnorm, graph, deleted, queries, cand_ids, cand_d,
                  visited, k, L, B, t_limit, metric, base, nbp_limit,
-                 data_score=data_score, nbr_vecs=nbr_vecs, nbr_sq=nbr_sq,
+                 data_score=data_score, nbr_vecs=nbr_vecs,
                  merge_bins=merge_bins, finalize_bins=finalize_bins,
                  score_scale=score_scale)
 
@@ -417,9 +441,8 @@ def _beam_search_chunked(data, sqnorm, graph, deleted, pivot_ids, pivot_vecs,
                          pivot_mask, queries3, t_limit, k: int, L: int,
                          B: int, metric: int, base: int, nbp_limit: int,
                          inject: int = 4, data_score=None, nbr_vecs=None,
-                         nbr_sq=None, merge_bins: int = 0,
-                         finalize_bins: int = 0, seed_keep: int = 0,
-                         score_scale: float = 0.0):
+                         merge_bins: int = 0, finalize_bins: int = 0,
+                         seed_keep: int = 0, score_scale: float = 0.0):
     """(M, chunk, D) query chunks under one `lax.map` — a single device
     program for any batch size (one upload, one dispatch, one read;
     every synced host round trip has a fixed cost).  The per-chunk
@@ -431,7 +454,7 @@ def _beam_search_chunked(data, sqnorm, graph, deleted, pivot_ids, pivot_vecs,
                                    pivot_vecs, pivot_mask, q, t_limit, k,
                                    L, B, metric, base, nbp_limit, inject,
                                    data_score=data_score,
-                                   nbr_vecs=nbr_vecs, nbr_sq=nbr_sq,
+                                   nbr_vecs=nbr_vecs,
                                    merge_bins=merge_bins,
                                    finalize_bins=finalize_bins,
                                    seed_keep=seed_keep,
@@ -447,8 +470,7 @@ def _beam_search_seeded_chunked(data, sqnorm, graph, deleted, seeds3,
                                 queries3, t_limit, k: int, L: int, B: int,
                                 metric: int, base: int, nbp_limit: int,
                                 data_score=None, nbr_vecs=None,
-                                nbr_sq=None, merge_bins: int = 0,
-                                finalize_bins: int = 0,
+                                merge_bins: int = 0, finalize_bins: int = 0,
                                 score_scale: float = 0.0):
     def body(args):
         s, q = args
@@ -456,7 +478,7 @@ def _beam_search_seeded_chunked(data, sqnorm, graph, deleted, seeds3,
                                           q, t_limit, k, L, B, metric,
                                           base, nbp_limit,
                                           data_score=data_score,
-                                          nbr_vecs=nbr_vecs, nbr_sq=nbr_sq,
+                                          nbr_vecs=nbr_vecs,
                                           merge_bins=merge_bins,
                                           finalize_bins=finalize_bins,
                                           score_scale=score_scale)
@@ -483,7 +505,7 @@ def _init_walk_state(cand_ids, cand_d, visited):
 def _walk_machine(data, sqnorm, graph, queries, t_limit, k: int, L: int,
                   B: int, metric: int, base: int, nbp_limit: int,
                   spare_ids=None, spare_d=None, inject: int = 0,
-                  data_score=None, nbr_vecs=None, nbr_sq=None,
+                  data_score=None, nbr_vecs=None,
                   merge_bins: int = 0, score_scale: float = 0.0):
     """One beam iteration as a reusable (body, row_alive) pair over the
     walk's constants — shared verbatim by the monolithic `lax.while_loop`
@@ -542,9 +564,22 @@ def _walk_machine(data, sqnorm, graph, queries, t_limit, k: int, L: int,
     top-k (_finalize), so returned distances (and the included/excluded
     boundary at k) are computed at full precision.
 
-    `nbr_vecs` (N, m, D) / `nbr_sq` (N, m): optional packed per-node
-    neighbor vectors (BeamPackedNeighbors) — the in-loop gather becomes B
-    block reads per query instead of B*m scattered row reads."""
+    `nbr_vecs` (N, m, D): optional packed per-node neighbor vectors
+    (BeamPackedNeighbors) — the in-loop gather becomes B block reads per
+    query instead of B*m scattered row reads.
+
+    WHAT A TRIP FETCHES (PR 44).  By candidate id, once: the rows it
+    scores.  A candidate's norm is the float32 square sum of the row the
+    gather just brought — the shadow's, the dequantised tier's, the
+    packed block's — so an in-loop L2 distance is |q~ - x~|^2 of the
+    pair that was contracted, up to accumulation, whatever the scoring
+    source; `sqnorm` (the float32 rows' norms) is read by the seeding
+    from seeds and by `_finalize` only.  By pool position, once: the
+    merge's `_flag_ids` word, a column's id with its `expanded` flag in
+    bit 0.  Beside them: the pops' graph rows and the `visited` words
+    (`_sorted_fresh`).  An element gather is priced on the chip by the
+    element fetched (a float a candidate cost almost half of what its
+    256-byte row costs), so what a fetch already carries is computed."""
     if merge_bins:
         # the strided binning maps the sorted beam prefix (cols 0..L-1)
         # onto distinct bins ONLY when bins >= L — a narrower reduction
@@ -553,6 +588,10 @@ def _walk_machine(data, sqnorm, graph, queries, t_limit, k: int, L: int,
         assert merge_bins >= L, (merge_bins, L)
     Q = queries.shape[0]
     N = data.shape[0]
+    if N > MAX_FLAGGED_ROWS:
+        raise ValueError(
+            "the walk's merge keeps an id and its expanded flag in one "
+            "int32 word: more than MAX_FLAGGED_ROWS (2^30) rows", N)
     sorted_order = dedup_in_sorted_order(merge_bins, nbr_vecs is not None)
     score_src = data_score if data_score is not None else data
     # the bf16-shadow cast only applies between FLOAT dtypes: an int8
@@ -699,11 +738,8 @@ def _walk_machine(data, sqnorm, graph, queries, t_limit, k: int, L: int,
                 # the row-gather path's index-0 placeholders.
                 sel_safe = jnp.maximum(sel_ids, 0)                   # (Q, B)
                 cvecs = nbr_vecs[sel_safe].reshape(Q, flat.shape[1], -1)
-                csq = nbr_sq[sel_safe].reshape(Q, flat.shape[1])
             else:
-                gather_idx = jnp.where(fresh, flat, 0)
-                cvecs = score_src[gather_idx]  # (Q, C, D)
-                csq = sqnorm[gather_idx]
+                cvecs = score_src[jnp.where(fresh, flat, 0)]  # (Q, C, D)
         with jax.named_scope("beam.score"):
             if score_scale:
                 # int8 cascade tier (CascadeSearch, ops/cascade.py): the
@@ -712,8 +748,10 @@ def _walk_machine(data, sqnorm, graph, queries, t_limit, k: int, L: int,
                 # the true-distance space the f32-scored seeds live in; the
                 # finalize re-rank restores exact fp distances
                 cvecs = cvecs.astype(jnp.float32) * jnp.float32(score_scale)
+            # no norm operand: a candidate's norm is the square sum of
+            # the row just gathered, not a float fetched again by id
             nd = dist_ops.batched_gathered_distance(
-                queries_s, cvecs, DistCalcMethod(metric), base, csq)
+                queries_s, cvecs, DistCalcMethod(metric), base)
             nd = jnp.where(fresh, nd, MAX_DIST)
 
         with jax.named_scope("beam.merge"):
@@ -743,24 +781,25 @@ def _walk_machine(data, sqnorm, graph, queries, t_limit, k: int, L: int,
                 flat_m = flat
 
             # ---- merge beam + candidates, keep top-L
+            # ONE word a merged column, the id with its `expanded` flag
+            # in bit 0 (a fresh candidate's is clear): what is gathered by
+            # position below is gathered once
             all_d = jnp.concatenate([cand_d, nd], axis=1)
-            all_ids = jnp.concatenate([cand_ids, flat_m], axis=1)
-            all_exp = jnp.concatenate(
-                [expanded[:, :L],
-                 jnp.zeros((Q, all_d.shape[1] - L), bool)], axis=1)
+            all_key = jnp.concatenate(
+                [_flag_ids(cand_ids, expanded[:, :L]),
+                 jnp.left_shift(flat_m, 1)], axis=1)
             if merge_bins:
                 # bin-reduction merge: strided binning keeps the sorted beam
                 # prefix collision-free (cols 0..L-1 -> distinct bins because
                 # merge_bins >= L); each bin's best survives, then the exact
                 # top-L runs over the bins-wide winner row
                 vals, cols = topk_bins.bin_shortlist(all_d, merge_bins)
-                sh_ids = jnp.take_along_axis(all_ids, cols, axis=1)
-                sh_exp = jnp.take_along_axis(all_exp, cols, axis=1)
+                sh_key = jnp.take_along_axis(all_key, cols, axis=1)
                 mneg, mpos = jax.lax.top_k(-vals, L)
                 cand_d = -mneg
-                cand_ids = jnp.take_along_axis(sh_ids, mpos, axis=1)
+                cand_ids, new_exp = _split_flagged(
+                    jnp.take_along_axis(sh_key, mpos, axis=1))
                 cand_ids = jnp.where(cand_d < MAX_DIST, cand_ids, -1)
-                new_exp = jnp.take_along_axis(sh_exp, mpos, axis=1)
                 # same-iteration multi-parent copies: collapse duplicates
                 # with the exact body's L-wide _sorted_dedup (an
                 # adjacency-only mask would miss copies separated by an
@@ -789,11 +828,11 @@ def _walk_machine(data, sqnorm, graph, queries, t_limit, k: int, L: int,
             else:
                 mneg, mpos = jax.lax.top_k(-all_d, L)
                 cand_d = -mneg
-                cand_ids = jnp.take_along_axis(all_ids, mpos, axis=1)
+                cand_ids, new_exp = _split_flagged(
+                    jnp.take_along_axis(all_key, mpos, axis=1))
                 cand_ids = jnp.where(cand_d < MAX_DIST, cand_ids, -1)
                 expanded = jnp.concatenate(
-                    [jnp.take_along_axis(all_exp, mpos, axis=1),
-                     jnp.zeros((Q, 1), bool)], axis=1)
+                    [new_exp, jnp.zeros((Q, 1), bool)], axis=1)
 
         # non-live rows FREEZE their counter (see _walk_machine docstring:
         # resetting it on a non-worse frontier made a tripped row's fate
@@ -813,7 +852,7 @@ def _walk_machine(data, sqnorm, graph, queries, t_limit, k: int, L: int,
 def _walk(data, sqnorm, graph, deleted, queries, cand_ids, cand_d, visited,
           k: int, L: int, B: int, t_limit, metric: int, base: int,
           nbp_limit: int, spare_ids=None, spare_d=None, inject: int = 0,
-          data_score=None, nbr_vecs=None, nbr_sq=None, merge_bins: int = 0,
+          data_score=None, nbr_vecs=None, merge_bins: int = 0,
           finalize_bins: int = 0, score_scale: float = 0.0):
     """Monolithic walk: run the shared body under one `lax.while_loop`
     until no row is alive, then finalize.  `t_limit` is a (Q,) traced
@@ -822,7 +861,7 @@ def _walk(data, sqnorm, graph, deleted, queries, cand_ids, cand_d, visited,
     body, row_alive = _walk_machine(
         data, sqnorm, graph, queries, t_limit, k, L, B, metric, base,
         nbp_limit, spare_ids=spare_ids, spare_d=spare_d, inject=inject,
-        data_score=data_score, nbr_vecs=nbr_vecs, nbr_sq=nbr_sq,
+        data_score=data_score, nbr_vecs=nbr_vecs,
         merge_bins=merge_bins, score_scale=score_scale)
 
     def cond(carry):
@@ -883,7 +922,7 @@ def _beam_segment_kernel(data, sqnorm, graph, queries, t_limit, cand_ids,
                          k: int, L: int, B: int, S: int, metric: int,
                          base: int, nbp_limit: int, inject: int = 0,
                          spare_ids=None, spare_d=None, data_score=None,
-                         nbr_vecs=None, nbr_sq=None, merge_bins: int = 0,
+                         nbr_vecs=None, merge_bins: int = 0,
                          score_scale: float = 0.0):
     """Segmented walk: at most S iterations of the SAME body the
     monolithic walk runs, over loop-carried state passed in and returned
@@ -896,7 +935,7 @@ def _beam_segment_kernel(data, sqnorm, graph, queries, t_limit, cand_ids,
     body, row_alive = _walk_machine(
         data, sqnorm, graph, queries, t_limit, k, L, B, metric, base,
         nbp_limit, spare_ids=spare_ids, spare_d=spare_d, inject=inject,
-        data_score=data_score, nbr_vecs=nbr_vecs, nbr_sq=nbr_sq,
+        data_score=data_score, nbr_vecs=nbr_vecs,
         merge_bins=merge_bins, score_scale=score_scale)
 
     def cond(carry):
@@ -953,7 +992,7 @@ def _beam_finalize_gathered_kernel(rows, dead, queries, cand_ids,
 # by their own iteration counts.
 
 def _walk_iter_cost(Q, X, D, W, score_itemsize=4, merge_bins=0, L=0, N=0,
-                    score_scale=0, packed=False, **_):
+                    score_scale=0, packed=False, l2=True, **_):
     """One _walk_machine body application at batch Q: the B*m = X
     candidate gather + scoring contraction dominates; the fitted
     WALK_SORTED_* constants carry the sort/segmented-scan/top-k
@@ -961,6 +1000,12 @@ def _walk_iter_cost(Q, X, D, W, score_itemsize=4, merge_bins=0, L=0, N=0,
     tests pin ±15%).  `packed` (BeamPackedNeighbors) prices the
     positional ensemble that layout keeps (WALK_SORT_*; its bytes leave
     out the m-fold vector table, as they always have).
+
+    `l2`: an L2 body takes each candidate's norm from the block it just
+    gathered (PR 44; the `sqnorm` gather went): 2*Q*X*D flops more, and
+    the float32 squares' traffic as XLA:CPU's cost analysis sees it,
+    costmodel.WALK_ROW_NORM_TRAFFIC words an element.  Cosine has no
+    norm.
 
     `merge_bins` > 0 prices the BINNED body instead: the X-wide sort
     ensemble is gone — what remains is the (L + X)-wide bin reduction +
@@ -977,12 +1022,15 @@ def _walk_iter_cost(Q, X, D, W, score_itemsize=4, merge_bins=0, L=0, N=0,
     # f32 copy doubles the post-gather traffic words
     deq_f = 2.0 * Q * X * D if score_scale else 0.0
     deq_b = Q * X * D * 4.0 if score_scale else 0.0
+    norm_f = 2.0 * Q * X * D if l2 else 0.0
+    norm_b = (costmodel.WALK_ROW_NORM_TRAFFIC * Q * X * D * 4.0 if l2
+              else 0.0)
     if merge_bins:
         wall = X + max(L, 1)
-        flops = (2.0 * Q * X * D + deq_f
+        flops = (2.0 * Q * X * D + deq_f + norm_f
                  + costmodel.WALK_BINNED_FLOPS * Q * wall
                  + costmodel.WALK_SORT_FLOPS * Q * max(L, 1))
-        nbytes = (2.0 * Q * X * D * score_itemsize + deq_b
+        nbytes = (2.0 * Q * X * D * score_itemsize + deq_b + norm_b
                   + N * D * score_itemsize       # corpus gather operand
                   + costmodel.WALK_BINNED_TRAFFIC * Q * wall * 4
                   + costmodel.WALK_SORT_TRAFFIC * Q * max(L, 1) * 4
@@ -992,8 +1040,8 @@ def _walk_iter_cost(Q, X, D, W, score_itemsize=4, merge_bins=0, L=0, N=0,
         (costmodel.WALK_SORTED_FLOPS, costmodel.WALK_SORTED_TRAFFIC)
         if dedup_in_sorted_order(merge_bins, packed)
         else (costmodel.WALK_SORT_FLOPS, costmodel.WALK_SORT_TRAFFIC))
-    flops = 2.0 * Q * X * D + deq_f + sort_f * Q * X
-    nbytes = (2.0 * Q * X * D * score_itemsize + deq_b
+    flops = 2.0 * Q * X * D + deq_f + norm_f + sort_f * Q * X
+    nbytes = (2.0 * Q * X * D * score_itemsize + deq_b + norm_b
               + N * D * score_itemsize           # corpus gather operand
               + sort_b * Q * X * 4
               + 2.0 * Q * W * 4)
@@ -1023,10 +1071,10 @@ def _finalize_cost(Q, L, D, N, rerank=True, itemsize=4, **_):
 
 
 def _segment_cost(Q, X, D, W, score_itemsize=4, merge_bins=0, L=0, N=0,
-                  score_scale=0, packed=False, **_):
+                  score_scale=0, packed=False, l2=True, **_):
     return _walk_iter_cost(Q, X, D, W, score_itemsize,
                            merge_bins=merge_bins, L=L, N=N,
-                           score_scale=score_scale, packed=packed)
+                           score_scale=score_scale, packed=packed, l2=l2)
 
 
 def _walk_full_cost(Q, P, X, D, L, W, N, score_itemsize=4, merge_bins=0,
@@ -1208,13 +1256,11 @@ class GraphSearchEngine:
         # -1 graph slots point at row 0; the walk's `fresh` mask discards
         # their scores exactly like the row-gather path's placeholders.
         self.nbr_vecs = None
-        self.nbr_sq = None
         if packed_neighbors:
             src = (self.data_score if self.data_score is not None
                    else self.data)
             g = jnp.maximum(self.graph, 0)
             self.nbr_vecs = src[g]
-            self.nbr_sq = self.sqnorm[g]
         # device-time attribution (FlightDeviceSampleRate): every Nth
         # segment dispatch is timed to completion (block_until_ready) and
         # fed to the flight recorder + the engine.segment_device_ns
@@ -1267,7 +1313,7 @@ class GraphSearchEngine:
                      + self.pivot_mask.nbytes)
         if self.nbr_vecs is not None:
             devmem.track("packed_neighbors", self,
-                         self.nbr_vecs.nbytes + self.nbr_sq.nbytes)
+                         self.nbr_vecs.nbytes)
 
     def set_deleted(self, deleted: np.ndarray) -> None:
         """Swap only the tombstone mask — mutation path for delete-only
@@ -1376,7 +1422,7 @@ class GraphSearchEngine:
             score_itemsize=self.score_itemsize(),
             merge_bins=self.merge_bins_for(L, B) if L else 0, L=L,
             N=self.n, score_scale=self.score_scale,
-            packed=self.nbr_vecs is not None)
+            packed=self.nbr_vecs is not None, l2=walk_takes_norm(self.metric))
 
     def seed_state(self, queries: jax.Array, L: int,
                    seeds: Optional[jax.Array] = None) -> dict:
@@ -1428,7 +1474,6 @@ class GraphSearchEngine:
             inject=inject if spare_ids is not None else 0,
             spare_ids=spare_ids, spare_d=state["spare_d"],
             data_score=self.data_score, nbr_vecs=self.nbr_vecs,
-            nbr_sq=self.nbr_sq,
             merge_bins=self.merge_bins_for(L, B),
             score_scale=self.score_scale)
         if sample:
@@ -1563,7 +1608,8 @@ class GraphSearchEngine:
         program returned with the answers (the caller has counted which
         driver ran it: `beam.monolithic` / `.chunked` / `.segmented`;
         counted here: which visited / de-duplicate ensemble its program
-        was traced with, `beam.dedup_sorted` / `.dedup_positional`):
+        was traced with, `beam.dedup_sorted` / `.dedup_positional`, and
+        `beam.norm_from_rows` where it scored L2):
         its trips (`beam.trips`, `beam.trips_total`: the `live.max()` of
         each while loop, so a chunked or segmented batch reads the sum
         over the chunks it walked one after another), its pool, pivot
@@ -1581,6 +1627,10 @@ class GraphSearchEngine:
             metrics.inc("beam.dedup_sorted")
         else:
             metrics.inc("beam.dedup_positional")
+        if walk_takes_norm(self.metric):
+            # the body scored against the square sum of the rows its
+            # gather brought, not a norm fetched by id (cosine has none)
+            metrics.inc("beam.norm_from_rows")
         metrics.inc("beam.trips_total", trips)
         metrics.inc("beam.rows_scored_total", scored)
         metrics.inc("beam.queries_total", nq)
@@ -1648,7 +1698,7 @@ class GraphSearchEngine:
                     jnp.asarray(q), t_limit,
                     k_eff, L, B, int(self.metric), self.base, limit,
                     inject=dynamic_pivots, data_score=self.data_score,
-                    nbr_vecs=self.nbr_vecs, nbr_sq=self.nbr_sq,
+                    nbr_vecs=self.nbr_vecs,
                     merge_bins=mb, finalize_bins=fb, seed_keep=sk,
                     score_scale=self.score_scale)
             else:
@@ -1662,7 +1712,7 @@ class GraphSearchEngine:
                     jnp.asarray(s), jnp.asarray(q), t_limit,
                     k_eff, L, B, int(self.metric), self.base, limit,
                     data_score=self.data_score,
-                    nbr_vecs=self.nbr_vecs, nbr_sq=self.nbr_sq,
+                    nbr_vecs=self.nbr_vecs,
                     merge_bins=mb, finalize_bins=fb,
                     score_scale=self.score_scale)
             with trace.span("index.readback"):
@@ -1690,7 +1740,7 @@ class GraphSearchEngine:
                 jnp.asarray(q.reshape(m, chunk, D)), t_limit,
                 k_eff, L, B, int(self.metric), self.base, limit,
                 inject=dynamic_pivots, data_score=self.data_score,
-                nbr_vecs=self.nbr_vecs, nbr_sq=self.nbr_sq,
+                nbr_vecs=self.nbr_vecs,
                 merge_bins=mb, finalize_bins=fb, seed_keep=sk,
                 score_scale=self.score_scale)
         else:
@@ -1705,7 +1755,7 @@ class GraphSearchEngine:
                 jnp.asarray(q.reshape(m, chunk, D)), t_limit,
                 k_eff, L, B, int(self.metric), self.base, limit,
                 data_score=self.data_score,
-                nbr_vecs=self.nbr_vecs, nbr_sq=self.nbr_sq,
+                nbr_vecs=self.nbr_vecs,
                 merge_bins=mb, finalize_bins=fb,
                 score_scale=self.score_scale)
         with trace.span("index.readback"):

@@ -271,8 +271,15 @@ def batched_gathered_distance(q: jax.Array, cand: jax.Array,
     """(Q, D) queries x (Q, C, D) per-query gathered candidates -> (Q, C)
     distances, float32.  The adjacency-gather scoring step of the beam-search
     engine (the reference computes these one at a time in its frontier loop,
-    BKTIndex.cpp:145-152); `cand_sqnorm` (Q, C) skips re-reducing corpus rows
-    whose norms are cached on the index."""
+    BKTIndex.cpp:145-152).  L2 needs the candidates' squared norms:
+    without `cand_sqnorm` they are the float32 square sum of `cand`
+    itself, so the result is |q - cand|^2 of the operands as given (the
+    walk's body: the block is on the chip and the compiler sums it in
+    the pass that contracts it, where fetching a cached norm by id is a
+    second gather); with `cand_sqnorm` (Q, C) the caller states them —
+    the exact re-rank passes the float32 rows' cached norms, and seeds
+    scored against a quantised `cand` pass the norms of the space they
+    are merged in."""
     metric = int(metric)
     if _is_int(q.dtype):
         if not exact_int_dot(q.dtype):

@@ -53,6 +53,7 @@ from sptag_tpu.algo.engine import (
     _walk_machine,
     beam_pool_size,
     beam_width_for,
+    walk_takes_norm,
 )
 from sptag_tpu.ops import topk_bins
 from sptag_tpu.utils import costmodel, recompile_guard, roofline
@@ -219,10 +220,10 @@ def _mesh_seed_cost(Q, P, D, L, W, n_dev, **_):
 
 
 def _mesh_segment_cost(Q, X, D, W, n_dev, score_itemsize=4,
-                       merge_bins=0, L=0, N=0, score_scale=0, **_):
+                       merge_bins=0, L=0, N=0, score_scale=0, l2=True, **_):
     f, b = _walk_iter_cost(Q, X, D, W, score_itemsize,
                            merge_bins=merge_bins, L=L, N=N,
-                           score_scale=score_scale)
+                           score_scale=score_scale, l2=l2)
     return n_dev * f, n_dev * b
 
 
@@ -362,7 +363,8 @@ class MeshGraphEngine:
             D=self.data.shape[1], W=_num_words(self.n_local),
             n_dev=self.n_shards, score_itemsize=self.score_itemsize(),
             merge_bins=self.merge_bins_for(L, B) if L else 0, L=L,
-            N=self.n_local, score_scale=self.score_scale)
+            N=self.n_local, score_scale=self.score_scale,
+            l2=walk_takes_norm(self.metric))
 
     def seed_state(self, queries: jax.Array, L: int,
                    seeds: Optional[jax.Array] = None) -> dict:

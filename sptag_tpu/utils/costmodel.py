@@ -199,7 +199,16 @@ WALK_SORT_TRAFFIC = 130.0
 #: shapes (flops 158-185 an element, words 93-112 where the graph's
 #: gather operand is small beside Q*X)
 WALK_SORTED_FLOPS = 170.0
-WALK_SORTED_TRAFFIC = 100.0
+WALK_SORTED_TRAFFIC = 105.0
+
+#: float32 words an element of the scored (Q, X, D) block that cost
+#: analysis charges the candidates' own square sum (the walk's L2 norm
+#: since ISSUE 44): XLA:CPU rewrites a reduce over D >= 64 into a tree
+#: (reduce-window) that does not fuse with the squares, so the block is
+#: read, its squares written and read again (fitted 3.0 at D = 64 and
+#: 128; a narrower reduce fuses and reads 1.0, the chip's compiler makes
+#: it one pass with the scoring contraction)
+WALK_ROW_NORM_TRAFFIC = 3.0
 
 #: per-merged-row-element flops of the BINNED walk body's selection
 #: ensemble (bin min/argmin reductions + shortlist top-L + the

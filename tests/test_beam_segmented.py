@@ -52,10 +52,32 @@ def bkt_setup():
 _CONFIGS = [(32, 4, 1, 0), (32, 4, 3, 4), (64, 8, 3, 4), (128, 4, 2, 0)]
 
 
-@pytest.mark.parametrize("mc,bw,nbp,dp", _CONFIGS)
-def test_segmented_parity_pivot_seeded(bkt_setup, mc, bw, nbp, dp):
+# what the body scores against, and with it whose norm an in-loop
+# distance carries (PR 44: the scored row's own): the float32 rows, their
+# bfloat16 shadow, the int8 cascade tier's dequantised rows
+# name -> (parameter, value, its default, the shadow's dtype)
+_SCORING = {"f32": ("BeamScoreDtype", "f32", "auto", None),
+            "bf16": ("BeamScoreDtype", "bf16", "auto", "bfloat16"),
+            "int8": ("CascadeSearch", "1", "0", "int8")}
+
+
+@pytest.fixture(params=list(_SCORING))
+def scored_engine(bkt_setup, request):
     idx, _, queries = bkt_setup
+    name, value, default, shadow = _SCORING[request.param]
+    assert idx.set_parameter(name, value)
     eng = idx._get_engine()
+    assert (eng.data_score is None if shadow is None
+            else str(eng.data_score.dtype) == shadow)
+    assert bool(eng.score_scale) == (request.param == "int8")
+    yield eng, queries
+    assert idx.set_parameter(name, default)
+
+
+@pytest.mark.parametrize("mc,bw,nbp,dp", _CONFIGS)
+def test_segmented_parity_pivot_seeded(scored_engine, mc, bw, nbp, dp,
+                                       monkeypatch):
+    eng, queries = scored_engine
     d0, i0 = eng.search(queries, 5, max_check=mc, beam_width=bw,
                         nbp_limit=nbp, dynamic_pivots=dp)
     for s in (1, 3):
@@ -64,6 +86,12 @@ def test_segmented_parity_pivot_seeded(bkt_setup, mc, bw, nbp, dp):
                             segment_iters=s)
         assert np.array_equal(i0, i1), (mc, bw, nbp, dp, s)
         assert np.array_equal(d0, d1), (mc, bw, nbp, dp, s)
+    # the chunked driver (`lax.map` over chunks of 8) is the same twin
+    monkeypatch.setattr(type(eng), "chunk_size", lambda self: 8)
+    d2, i2 = eng.search(queries, 5, max_check=mc, beam_width=bw,
+                        nbp_limit=nbp, dynamic_pivots=dp)
+    assert np.array_equal(i0, i2), (mc, bw, nbp, dp, "chunked")
+    assert np.array_equal(d0, d2), (mc, bw, nbp, dp, "chunked")
 
 
 def test_segmented_parity_seeded_path(bkt_setup):

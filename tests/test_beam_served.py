@@ -167,6 +167,8 @@ def test_each_walk_driver_is_counted_where_it_runs(saved, driver,
     # its visited / de-duplicate ensemble runs in sorted-id order (PR 33)
     assert metrics.counter_value("beam.dedup_sorted") == 1
     assert metrics.counter_value("beam.dedup_positional") == 0
+    # and took its candidates' norms from the rows it gathered (PR 44)
+    assert metrics.counter_value("beam.norm_from_rows") == 1
     assert np.array_equal(ids, want_ids) and np.array_equal(d, want_d)
     # three chunks of 16 walk one after the other: their trips (each
     # chunk's `live.max()`) add up, and no chunk walks longer than the

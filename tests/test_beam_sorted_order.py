@@ -150,8 +150,8 @@ def test_the_traced_body_holds_one_visited_gather(packed):
     X-wide gather from a `pred` operand (the duplicate mask's way back)
     and no X-wide scatter (the inverse permutation).  The
     packed-neighbour layout keeps all three: the guard sees what it
-    guards against.  (The merge's L-wide gather of the `expanded` flags
-    is from a `pred` operand in both, as it was.)"""
+    guards against.  (The merge gathers nothing from a `pred` operand
+    since PR 44: a column's `expanded` flag rides in its id's word.)"""
     import jax.numpy as jnp
 
     Q, L, B, N, D, m, S = 8, 64, 16, 2048, 64, 32, 4
@@ -164,8 +164,7 @@ def test_the_traced_body_holds_one_visited_gather(packed):
         jnp.zeros((Q,), jnp.int32), jnp.zeros((Q,), jnp.int32),
         jnp.zeros((Q,), jnp.int32), 10, L, B, S, int(DistCalcMethod.L2),
         1, 3, 0, None, None, None,
-        jnp.zeros((N, m, D)) if packed else None,
-        jnp.zeros((N, m)) if packed else None)
+        jnp.zeros((N, m, D)) if packed else None)
     visited_gathers = mask_gathers = wide_scatters = sorts = 0
     for eqn, scope in _equations(traced.jaxpr.jaxpr):
         operand = eqn.invars[0].aval if eqn.invars else None

@@ -564,6 +564,14 @@ def test_beam_cell_rungs_compile_at_100k(one_chip, Q):
         # back from a pred one)
         assert re.findall(rf"= (s32|pred)\[{Q},2048\]\S* gather\(",
                           text) == ["s32"]
+        # PR 44: no float a candidate is fetched by id beside its row (the
+        # parent gathered f32[128,2048] from the (N,) norms), and the
+        # merge's pool-wide gathers by position are ONE, of the id's word
+        # with the `expanded` flag in it (the parent: an s32 and a pred
+        # one; the pred one left is the re-rank's tombstones)
+        assert not re.findall(rf"= f32\[{Q},2048\]\S* gather\(", text)
+        assert sorted(re.findall(rf"= (s32|pred)\[{Q},{L}\]\S* gather\(",
+                                 text)) == ["pred", "s32"]
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2 << 30
 

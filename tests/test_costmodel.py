@@ -146,7 +146,7 @@ def test_crosscheck_beam_segment(Q, L, B, N, D, m, S):
         jnp.zeros((Q, W), jnp.int32), jnp.zeros((Q,), jnp.int32),
         jnp.zeros((Q,), jnp.int32), jnp.zeros((Q,), jnp.int32),
         10, L, B, S, int(DistCalcMethod.L2), 1, 3, 0,
-        None, None, None, None, None).compile()
+        None, None, None, None).compile()
     _assert_close("beam.segment", compiled, Q=Q, X=B * m, D=D, W=W, N=N)
 
 
@@ -174,7 +174,7 @@ def test_crosscheck_beam_segment_binned(Q, L, B, N, D, m, S):
         jnp.zeros((Q, W), jnp.int32), jnp.zeros((Q,), jnp.int32),
         jnp.zeros((Q,), jnp.int32), jnp.zeros((Q,), jnp.int32),
         10, L, B, S, int(DistCalcMethod.L2), 1, 3, 0,
-        None, None, None, None, None, mb).compile()
+        None, None, None, None, mb).compile()
     _assert_close("beam.segment", compiled, Q=Q, X=B * m, D=D, W=W,
                   merge_bins=mb, L=L, N=N)
 
