@@ -37,7 +37,7 @@ from typing import Optional, Tuple
 import numpy as np
 
 from sptag_tpu.algo.dense import DenseTreeSearcher, partition_from_tree
-from sptag_tpu.algo.engine import GraphSearchEngine
+from sptag_tpu.algo.engine import GraphSearchEngine, packed_param
 from sptag_tpu.core.index import MAX_DIST, VectorIndex, register_algo
 from sptag_tpu.core.params import BKTParams
 from sptag_tpu.core.types import (DistCalcMethod, IndexAlgoType,
@@ -254,11 +254,14 @@ class BKTIndex(VectorIndex):
             if self._dense is not None:
                 self._dense.register_devmem()
 
-    def _make_engine(self, graph: np.ndarray,
-                     rows: Optional[int] = None) -> GraphSearchEngine:
+    def _make_engine(self, graph: np.ndarray, rows: Optional[int] = None,
+                     serving: bool = True) -> GraphSearchEngine:
         """Materialize an engine snapshot over `rows` corpus rows
         (default: the main-tier coverage — rows in the delta shard are
-        served by the delta scan, never by the engine)."""
+        served by the delta scan, never by the engine).  `serving=False`
+        is an engine made to link or refine a few rows and be dropped:
+        it walks the row layout whatever `BeamPackedNeighbors` says (an
+        N x m x D table gathered for one search is all cost)."""
         p = self.params
         rows = self._main_rows() if rows is None else rows
         if int(getattr(p, "flight_recorder", 0)):
@@ -280,9 +283,9 @@ class BKTIndex(VectorIndex):
                                  self.dist_calc_method, self.base,
                                  score_dtype=getattr(
                                      self.params, "beam_score_dtype", "auto"),
-                                 packed_neighbors=bool(int(getattr(
+                                 packed_neighbors=packed_param(getattr(
                                      self.params, "beam_packed_neighbors",
-                                     0))),
+                                     "auto")) if serving else False,
                                  device_sample_rate=float(getattr(
                                      self.params,
                                      "flight_device_sample_rate", 0.0)),
@@ -579,7 +582,7 @@ class BKTIndex(VectorIndex):
                     group=group, union_factor=union)
             return search
 
-        engine = self._make_engine(graph)
+        engine = self._make_engine(graph, serving=False)
 
         def search(queries: np.ndarray, k: int):
             return engine.search(
@@ -1098,7 +1101,8 @@ class BKTIndex(VectorIndex):
         if getattr(self.params, "build_graph", 1):
             engine = self._engine
             if engine is None or engine.n != begin:
-                engine = self._make_engine(self._graph.graph, rows=begin)
+                engine = self._make_engine(self._graph.graph, rows=begin,
+                                           serving=False)
             # the graph holds exactly `begin` rows while the delta is
             # live (_append_rows_unlinked defers growth); linking
             # appends the tail and refreshes the prefix reverse edges
@@ -1173,7 +1177,8 @@ class BKTIndex(VectorIndex):
                                  payload={"rows": n0 - b0, "base": b0})
             if engine is None:
                 # off-lock materialization over the stable prefix
-                engine = self._make_engine(self._graph.graph, rows=b0)
+                engine = self._make_engine(self._graph.graph, rows=b0,
+                                           serving=False)
             new_graph = self._linked_graph(engine, graph_base, b0,
                                            n0 - b0, host)
             new_engine = self._make_engine(new_graph, rows=n0)

@@ -59,7 +59,7 @@ from __future__ import annotations
 
 import functools
 import time
-from typing import Any, NamedTuple, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -68,7 +68,7 @@ import numpy as np
 from sptag_tpu.core.types import DistCalcMethod
 from sptag_tpu.ops import distance as dist_ops
 from sptag_tpu.ops import topk_bins
-from sptag_tpu.utils import (costmodel, devmem, flightrec, metrics,
+from sptag_tpu.utils import (costmodel, devmem, flightrec, locksan, metrics,
                              query_bucket, recompile_guard, roofline, trace)
 
 MAX_DIST = np.float32(3.4e38)   # plain scalar: module import must NOT init a backend
@@ -191,12 +191,13 @@ def _sorted_dedup(ids: jax.Array) -> Tuple[jax.Array, jax.Array]:
     an id after the first (in original positions — the inverse permutation
     comes from a SCATTER, not a second sort), and the sorted array feeds
     `_mark_bits_sorted` directly.  Called by the seeded kernel's seed
-    dedupe, the dense epilogue's replica dedupe (`algo/dense.py`), the
-    binned walk body's L-wide pool dedupe and the packed-neighbour
-    layout's walk, whose vectors arrive in graph order.  The walk's
-    exact body with the row-gather layout no longer calls it: a trip's
-    candidates stay in sorted order there (`_sorted_fresh`), and the
-    two gathers and the scatter that carry the mask back fall away."""
+    dedupe, the dense epilogue's replica dedupe (`algo/dense.py`) and
+    the binned walk body's L-wide pool dedupe.  The walk's exact body
+    no longer calls it, whichever way it fetches its vectors: a trip's
+    candidates stay in sorted order there (`_sorted_fresh`; the
+    packed-neighbour layout's scores ride the sort as its payload),
+    and the two gathers and the scatter that carry the mask back fall
+    away."""
     Q = ids.shape[0]
     order = jnp.argsort(ids, axis=1, stable=True)
     sorted_ids = jnp.take_along_axis(ids, order, axis=1)
@@ -213,15 +214,72 @@ def _sorted_dup_mask(ids: jax.Array):
     return _sorted_dedup(ids)[1]
 
 
-def dedup_in_sorted_order(merge_bins: int, packed: bool) -> bool:
+def dedup_in_sorted_order(merge_bins: int) -> bool:
     """Whether a walk body keeps a trip's candidates in ascending-id
     order from the de-duplication to the merge (`_sorted_fresh`): the
-    exact body (`merge_bins == 0`) that gathers its rows by id.  The
-    packed-neighbour layout reads vectors in graph order, so its mask has
-    to come back to original positions (`_sorted_dedup`); the binned
-    body has no X-wide sort.  `_walk_machine` traces by this rule and
+    exact body (`merge_bins == 0`), whether it gathers its rows by id
+    after the sort or scores the packed-neighbour blocks before it and
+    carries the scores through the sort.  The binned body has no X-wide
+    sort.  `_walk_machine` traces by this rule and
     `GraphSearchEngine._publish_walk` counts by it."""
-    return not merge_bins and not packed
+    return not merge_bins
+
+
+def packed_layout_fits(n: int, m: int, dim: int, itemsize: int,
+                       device_bytes: Optional[int],
+                       in_use: int = 0) -> bool:
+    """`BeamPackedNeighbors=auto`: whether an engine of `n` rows with `m`
+    neighbours a row lays every node's neighbour vectors side by side
+    (`(n, m, dim)` in the scoring dtype) so that a trip fetches B blocks
+    a query instead of B x m rows.  It does where the table is at most
+    an eighth of the device's memory (`device_bytes`: a TPU's
+    `memory_stats()["bytes_limit"]`; the two engines of a snapshot swap
+    hold a quarter between them) AND at most half of what is free when
+    the first walk asks (`device_bytes - in_use`: the other half is the
+    walk's own and whatever loads next; dense blocks, other indexes and
+    a superseded engine's table are all `in_use`).  It keeps the row
+    layout where the device states no limit (off a TPU: `None`).  100k x
+    32 x 128 bf16 is 0.82 GB of a v5e's 16: packed; 1M rows, 8.2 GB:
+    rows."""
+    if not device_bytes:
+        return False
+    return n * m * dim * itemsize <= min(device_bytes // 8,
+                                         (device_bytes - in_use) // 2)
+
+
+def packed_param(value) -> Union[str, bool]:
+    """What a `BeamPackedNeighbors` line asks of `GraphSearchEngine`:
+    `auto` leaves it to `packed_layout_fits`, `1` insists on the table
+    (the only way to it off a TPU).  `0` was the default until PR 45 and
+    `save_config` writes every value, so every folder saved before says
+    `0` without anybody having chosen it: read as `auto`.  Raises on
+    anything else."""
+    v = str(value).strip().lower()
+    if v in ("auto", "0"):
+        return "auto"
+    if v == "1":
+        return True
+    raise ValueError(f"BeamPackedNeighbors must be auto or 1, got {value!r}")
+
+
+def _device_memory() -> Tuple[Optional[int], int]:
+    """The default device's memory as `packed_layout_fits` takes it: a
+    TPU's `bytes_limit` and `bytes_in_use`, (None, 0) on any other
+    platform."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return None, 0
+    stats = dev.memory_stats() or {}
+    return stats.get("bytes_limit"), int(stats.get("bytes_in_use", 0))
+
+
+@jax.jit
+def _pack_neighbors(src: jax.Array, graph: jax.Array) -> jax.Array:
+    """`src[graph]` as ONE program: the gather writes the (N, m, D)
+    table and nothing beside it (dispatched op by op the same
+    expression held a second copy while it reshaped: 0.82 GB more at
+    the beam cell's size, PERF.md PR 45).  -1 slots point at row 0."""
+    return src[jnp.maximum(graph, 0)]
 
 
 #: the largest corpus whose ids leave a bit free in an int32 word
@@ -250,23 +308,33 @@ def walk_takes_norm(metric) -> bool:
     return int(metric) == int(DistCalcMethod.L2)
 
 
-def _sorted_fresh(visited: jax.Array, flat_safe: jax.Array, n: int):
+def _sorted_fresh(visited: jax.Array, flat_safe: jax.Array, n: int,
+                  payload: Optional[jax.Array] = None):
     """The walk's visited / de-duplicate ensemble in sorted-id order.
     `flat_safe` (Q, X): a trip's candidate ids, `n` in the holes.  ->
     (ids (Q, X) ascending with -1 after them, fresh (Q, X) bool: first
     occurrence of an id not in `visited`, visited with every valid id
-    marked).  One sort, ONE gather of `visited` words: it serves the
-    test and the marker's `existing`, and nothing is carried back to
-    the order the candidates came in."""
+    marked, `payload` in the ids' order).  One sort, ONE gather of
+    `visited` words: it serves the test and the marker's `existing`, and
+    nothing is carried back to the order the candidates came in.
+    `payload` (Q, X), what the caller holds per candidate in the order
+    it came (the packed-neighbour layout's scores), rides the sort
+    beside the ids.  It has to be equal among the copies of one id: the
+    sort is not asked to keep them in order (a stable sort of two
+    operands carries a third, the positions)."""
     Q = flat_safe.shape[0]
-    s = jnp.sort(flat_safe, axis=1)
+    if payload is None:
+        s = jnp.sort(flat_safe, axis=1)
+    else:
+        s, payload = jax.lax.sort((flat_safe, payload), dimension=1,
+                                  is_stable=False, num_keys=1)
     got = jnp.take_along_axis(visited, jnp.right_shift(s, 5), axis=1)
     seen = (jnp.right_shift(got, s & 31) & 1).astype(bool)
     dup = jnp.concatenate(
         [jnp.zeros((Q, 1), bool), s[:, 1:] == s[:, :-1]], axis=1)
     valid = s < n
     return (jnp.where(valid, s, -1), valid & ~seen & ~dup,
-            _mark_bits_sorted(visited, s, existing=got))
+            _mark_bits_sorted(visited, s, existing=got), payload)
 
 
 @jax.named_scope("beam.seed")
@@ -565,21 +633,34 @@ def _walk_machine(data, sqnorm, graph, queries, t_limit, k: int, L: int,
     boundary at k) are computed at full precision.
 
     `nbr_vecs` (N, m, D): optional packed per-node neighbor vectors
-    (BeamPackedNeighbors) — the in-loop gather becomes B block reads per
-    query instead of B*m scattered row reads.
+    (BeamPackedNeighbors) in the scoring dtype, `src[graph]` — the
+    in-loop fetch becomes B block reads per query instead of B*m
+    scattered row reads.
 
-    WHAT A TRIP FETCHES (PR 44).  By candidate id, once: the rows it
-    scores.  A candidate's norm is the float32 square sum of the row the
-    gather just brought — the shadow's, the dequantised tier's, the
-    packed block's — so an in-loop L2 distance is |q~ - x~|^2 of the
-    pair that was contracted, up to accumulation, whatever the scoring
-    source; `sqnorm` (the float32 rows' norms) is read by the seeding
-    from seeds and by `_finalize` only.  By pool position, once: the
-    merge's `_flag_ids` word, a column's id with its `expanded` flag in
-    bit 0.  Beside them: the pops' graph rows and the `visited` words
-    (`_sorted_fresh`).  An element gather is priced on the chip by the
-    element fetched (a float a candidate cost almost half of what its
-    256-byte row costs), so what a fetch already carries is computed."""
+    WHAT A TRIP FETCHES (PRs 44, 45).  The vectors it scores, once, one
+    of two ways.  Row-gather layout: by candidate id, the Q*B*m rows of
+    the FRESH candidates, after the sort.  Packed-neighbour layout: by
+    popped node, Q*B blocks of (m, D) — every neighbour's vector, fresh
+    or not — before the sort, scored in the graph's order, the scores
+    then riding the ONE sort by id as its payload (`_sorted_fresh`), so
+    that nothing is carried back to the graph's order.  The same rows
+    are scored against the same queries either way (the table holds the
+    scoring source's very values), so the two layouts walk the same
+    trajectories.  A candidate's norm is the float32 square sum of the
+    row the fetch just brought — the shadow's, the dequantised tier's,
+    the packed block's — so an in-loop L2 distance is |q~ - x~|^2 of
+    the pair that was contracted, up to accumulation, whatever the
+    scoring source; `sqnorm` (the float32 rows' norms) is read by the
+    seeding from seeds and by `_finalize` only.  By pool position,
+    once: the merge's `_flag_ids` word, a column's id with its
+    `expanded` flag in bit 0.  Beside them: the pops' graph rows and the
+    `visited` words (`_sorted_fresh`: one X-wide element gather).  An
+    element gather is priced on the chip by the element fetched and by
+    the distinct rows, not by the bytes (a float a candidate cost almost
+    half of what its 256-byte row costs; an 8 KB block costs far less
+    than its 32 rows), so what a fetch already carries is computed, and
+    the same bytes are asked for in fewer, larger pieces where the
+    device has room for the table (`packed_layout_fits`)."""
     if merge_bins:
         # the strided binning maps the sorted beam prefix (cols 0..L-1)
         # onto distinct bins ONLY when bins >= L — a narrower reduction
@@ -592,7 +673,7 @@ def _walk_machine(data, sqnorm, graph, queries, t_limit, k: int, L: int,
         raise ValueError(
             "the walk's merge keeps an id and its expanded flag in one "
             "int32 word: more than MAX_FLAGGED_ROWS (2^30) rows", N)
-    sorted_order = dedup_in_sorted_order(merge_bins, nbr_vecs is not None)
+    sorted_order = dedup_in_sorted_order(merge_bins)
     score_src = data_score if data_score is not None else data
     # the bf16-shadow cast only applies between FLOAT dtypes: an int8
     # scoring corpus (score_scale below) keeps f32 queries — the
@@ -633,6 +714,21 @@ def _walk_machine(data, sqnorm, graph, queries, t_limit, k: int, L: int,
             # the next injection may open an unreached graph component
             has_work = has_work | (ptr < n_spare)
         return (it < t_limit) & active & has_work
+
+    @jax.named_scope("beam.score")
+    def score(cvecs):
+        # ---- score a trip's candidates (one batched contraction)
+        if score_scale:
+            # int8 cascade tier (CascadeSearch, ops/cascade.py): the
+            # gathered rows are the int8 quantization of the corpus —
+            # dequantize so in-loop distances stay in (approximately)
+            # the true-distance space the f32-scored seeds live in; the
+            # finalize re-rank restores exact fp distances
+            cvecs = cvecs.astype(jnp.float32) * jnp.float32(score_scale)
+        # no norm operand: a candidate's norm is the square sum of
+        # the row just gathered, not a float fetched again by id
+        return dist_ops.batched_gathered_distance(
+            queries_s, cvecs, DistCalcMethod(metric), base)
 
     def body(state):
         cand_ids, cand_d, expanded, visited, no_better, ptr, it = state
@@ -689,14 +785,29 @@ def _walk_machine(data, sqnorm, graph, queries, t_limit, k: int, L: int,
             nbrs = jnp.where(sel_ok[..., None], nbrs, -1)
             flat = nbrs.reshape(Q, -1)                               # (Q, B*m)
             flat_safe = jnp.where(flat >= 0, flat, N)
+        nd = None
+        if nbr_vecs is not None:
+            # packed-neighbour layout: the trip's vectors are fetched by
+            # POPPED NODE, Q*B blocks of (m, D) instead of Q*B*m
+            # scattered rows, and scored in the graph's order, which is
+            # `flat`'s; the fetch waits for no `fresh` mask (a masked
+            # pop's block is node 0's: scored and discarded)
+            with jax.named_scope("beam.gather"):
+                cvecs = nbr_vecs[jnp.maximum(sel_ids, 0)].reshape(
+                    Q, flat.shape[1], -1)
+            nd = score(cvecs)
         with jax.named_scope("beam.merge"):
             if sorted_order:
                 # the candidates go into ascending-id order ONCE and stay
                 # there: nothing downstream needs the graph's order (the
-                # merge is a top_k over distances, the row and norm
-                # gathers take any order, the spares are appended after)
-                flat, fresh, visited = _sorted_fresh(visited, flat_safe, N)
-            elif merge_bins:
+                # merge is a top_k over distances, the row gather takes
+                # any order, the spares are appended after).  Scores the
+                # blocks brought ride the sort as its payload: copies of
+                # one id carry bit-identical scores (the same row against
+                # the same query), so which copy the mask keeps is all one
+                flat, fresh, visited, nd = _sorted_fresh(
+                    visited, flat_safe, N, payload=nd)
+            else:
                 seen = _test_bits(visited, flat_safe)
                 # binned body: NO X-wide sort.  Same-iteration duplicates are
                 # collapsed after the merge's exact top-L (identical ids carry
@@ -705,53 +816,12 @@ def _walk_machine(data, sqnorm, graph, queries, t_limit, k: int, L: int,
                 # the merge below.  `seen` still excludes everything already
                 # in the beam or ever admitted to it (beam ⊆ visited).
                 fresh = (flat >= 0) & ~seen
-            else:
-                # packed-neighbour layout: the vectors arrive in graph
-                # order, so the mask is needed in original positions
-                seen = _test_bits(visited, flat_safe)
-                # ONE argsort serves both the intra-batch duplicate mask and
-                # the bit marking (the loop previously paid three sorts per
-                # iteration: dup-mask argsort + inverse argsort + mark sort).
-                # Sorting flat_safe keeps invalid ids (-> N) at the END so the
-                # array stays ascending for the segmented-OR marker; the
-                # inverse permutation comes from a scatter, not a second sort.
-                sorted_safe, dup = _sorted_dedup(flat_safe)
-                # a node reached from two popped parents in the SAME iteration
-                # is not yet in `visited` for either copy — dedupe within the
-                # batch or the beam accumulates duplicate entries
-                fresh = (flat >= 0) & ~seen & ~dup
-                # mark ALL valid candidates (OR is idempotent — re-marking
-                # seen ids changes nothing), so the pre-sorted array is
-                # reusable as-is
-                visited = _mark_bits_sorted(visited, sorted_safe)
-
-        with jax.named_scope("beam.gather"):
-            # ---- score fresh candidates (one batched contraction)
-            if nbr_vecs is not None:
-                # packed-neighbor layout (BeamPackedNeighbors): each popped
-                # node's m neighbor VECTORS live contiguously, so the gather
-                # is Q*B block reads of (m, D) instead of Q*B*m scattered
-                # rows — block-granular DMA, the same trick that won in the
-                # dense path, at m x corpus HBM.  Ordering matches `flat`
-                # (both derive from graph-row order); masked slots score
-                # garbage and are discarded by the `fresh` mask exactly like
-                # the row-gather path's index-0 placeholders.
-                sel_safe = jnp.maximum(sel_ids, 0)                   # (Q, B)
-                cvecs = nbr_vecs[sel_safe].reshape(Q, flat.shape[1], -1)
-            else:
+        if nd is None:
+            # row-gather layout: the fresh candidates' rows by id, once
+            with jax.named_scope("beam.gather"):
                 cvecs = score_src[jnp.where(fresh, flat, 0)]  # (Q, C, D)
+            nd = score(cvecs)
         with jax.named_scope("beam.score"):
-            if score_scale:
-                # int8 cascade tier (CascadeSearch, ops/cascade.py): the
-                # gathered rows are the int8 quantization of the corpus —
-                # dequantize so in-loop distances stay in (approximately)
-                # the true-distance space the f32-scored seeds live in; the
-                # finalize re-rank restores exact fp distances
-                cvecs = cvecs.astype(jnp.float32) * jnp.float32(score_scale)
-            # no norm operand: a candidate's norm is the square sum of
-            # the row just gathered, not a float fetched again by id
-            nd = dist_ops.batched_gathered_distance(
-                queries_s, cvecs, DistCalcMethod(metric), base)
             nd = jnp.where(fresh, nd, MAX_DIST)
 
         with jax.named_scope("beam.merge"):
@@ -992,14 +1062,21 @@ def _beam_finalize_gathered_kernel(rows, dead, queries, cand_ids,
 # by their own iteration counts.
 
 def _walk_iter_cost(Q, X, D, W, score_itemsize=4, merge_bins=0, L=0, N=0,
-                    score_scale=0, packed=False, l2=True, **_):
+                    score_scale=0, l2=True, **_):
     """One _walk_machine body application at batch Q: the B*m = X
-    candidate gather + scoring contraction dominates; the fitted
+    candidate fetch + scoring contraction dominates; the fitted
     WALK_SORTED_* constants carry the sort/segmented-scan/top-k
     ensemble in sorted-id order (calibrated against HloCostAnalysis;
-    tests pin ±15%).  `packed` (BeamPackedNeighbors) prices the
-    positional ensemble that layout keeps (WALK_SORT_*; its bytes leave
-    out the m-fold vector table, as they always have).
+    tests pin ±15%).  The exact body costs the same under both layouts
+    (BeamPackedNeighbors): the same X rows a query are fetched and
+    scored, and the score that rides the packed body's sort moves the
+    fitted constants by 2-3 % (flops 152-175 an element, words 102-121,
+    at the three shapes the test holds).  The packed TABLE's bytes are
+    still left out: cost analysis charges a gather its whole operand,
+    here (N, m, D), of which the formula keeps the corpus's N*D below;
+    a trip reads Q*X rows of it, which the first term counts, and the
+    whole table a trip would be six times everything else at the beam
+    cell's size (the calibration test adds the other (m-1)*N*D itself).
 
     `l2`: an L2 body takes each candidate's norm from the block it just
     gathered (PR 44; the `sqnorm` gather went): 2*Q*X*D flops more, and
@@ -1010,8 +1087,9 @@ def _walk_iter_cost(Q, X, D, W, score_itemsize=4, merge_bins=0, L=0, N=0,
     `merge_bins` > 0 prices the BINNED body instead: the X-wide sort
     ensemble is gone — what remains is the (L + X)-wide bin reduction +
     shortlist top-L (WALK_BINNED_* constants, per merged-row element)
-    and the L-wide lazy-mark sort ensemble (the WALK_SORT_* constants at
-    width L).
+    and the L-wide lazy-mark sort ensemble (the WALK_SORT_* constants,
+    the positional `_sorted_dedup` ensemble's, at width L: theirs alone
+    since the exact body left it).
 
     Both bodies carry the corpus gather operand, N*D: cost analysis
     charges a gather its whole operand, and at a small batch that is no
@@ -1036,14 +1114,11 @@ def _walk_iter_cost(Q, X, D, W, score_itemsize=4, merge_bins=0, L=0, N=0,
                   + costmodel.WALK_SORT_TRAFFIC * Q * max(L, 1) * 4
                   + 2.0 * Q * W * 4)
         return flops, nbytes
-    sort_f, sort_b = (
-        (costmodel.WALK_SORTED_FLOPS, costmodel.WALK_SORTED_TRAFFIC)
-        if dedup_in_sorted_order(merge_bins, packed)
-        else (costmodel.WALK_SORT_FLOPS, costmodel.WALK_SORT_TRAFFIC))
-    flops = 2.0 * Q * X * D + deq_f + norm_f + sort_f * Q * X
+    flops = (2.0 * Q * X * D + deq_f + norm_f
+             + costmodel.WALK_SORTED_FLOPS * Q * X)
     nbytes = (2.0 * Q * X * D * score_itemsize + deq_b + norm_b
               + N * D * score_itemsize           # corpus gather operand
-              + sort_b * Q * X * 4
+              + costmodel.WALK_SORTED_TRAFFIC * Q * X * 4
               + 2.0 * Q * W * 4)
     return flops, nbytes
 
@@ -1071,10 +1146,10 @@ def _finalize_cost(Q, L, D, N, rerank=True, itemsize=4, **_):
 
 
 def _segment_cost(Q, X, D, W, score_itemsize=4, merge_bins=0, L=0, N=0,
-                  score_scale=0, packed=False, l2=True, **_):
+                  score_scale=0, l2=True, **_):
     return _walk_iter_cost(Q, X, D, W, score_itemsize,
                            merge_bins=merge_bins, L=L, N=N,
-                           score_scale=score_scale, packed=packed, l2=l2)
+                           score_scale=score_scale, l2=l2)
 
 
 def _walk_full_cost(Q, P, X, D, L, W, N, score_itemsize=4, merge_bins=0,
@@ -1112,6 +1187,15 @@ def _finalize_gathered_cost(Q, L, D, itemsize=4, **_):
     return flops, nbytes
 
 
+def _pack_neighbors_cost(N, m, D, score_itemsize=4, **_):
+    """The packed-neighbour table's one gather: the scoring source and
+    the graph read once, the (N, m, D) table written; a max an id."""
+    return float(N * m), float((N * D + N * m * D) * score_itemsize
+                               + N * m * 4)
+
+
+costmodel.register("beam.pack_neighbors", _pack_neighbors,
+                   _pack_neighbors_cost)
 costmodel.register("beam.finalize_gathered", _beam_finalize_gathered_kernel,
                    _finalize_gathered_cost)
 costmodel.register("beam.seed", _beam_seed_kernel, _seed_pivot_cost)
@@ -1138,7 +1222,7 @@ class GraphSearchEngine:
                  pivot_ids: np.ndarray, deleted: Optional[np.ndarray],
                  metric: DistCalcMethod, base: int,
                  score_dtype: str = "auto",
-                 packed_neighbors: bool = False,
+                 packed_neighbors="auto",
                  device_sample_rate: float = 0.0,
                  roofline_probe: bool = False,
                  binned_topk: str = "off",
@@ -1249,18 +1333,24 @@ class GraphSearchEngine:
         np.bitwise_or.at(mask, pivot_ids >> 5,
                          np.uint32(1) << (pivot_ids.astype(np.uint32) & 31))
         self.pivot_mask = jnp.asarray(mask.view(np.int32))
-        # packed-neighbor layout (BeamPackedNeighbors): materialize each
-        # node's m neighbor VECTORS contiguously so the walk's in-loop
-        # gather is B block reads per query instead of B*m scattered rows
-        # — block-granular DMA at m x corpus HBM (bf16 shadow halves it).
-        # -1 graph slots point at row 0; the walk's `fresh` mask discards
-        # their scores exactly like the row-gather path's placeholders.
+        # packed-neighbor layout (BeamPackedNeighbors): each node's m
+        # neighbor VECTORS side by side, so the walk's in-loop fetch is B
+        # block reads per query instead of B*m scattered rows, at m x
+        # the scoring corpus in HBM.  True / False are obeyed; "auto" is
+        # None here and settled, like the table is built, by the engine's
+        # first walk (`walk_table`: `packed_layout_fits` over what the
+        # device holds THEN; never off a TPU), so an engine that serves
+        # `exact_scan`, a dense-mode index's refresh or a refine pass
+        # through the dense searcher never pays for it.
+        if packed_neighbors not in ("auto", True, False):
+            raise ValueError(
+                f"packed_neighbors is auto / True / False, got "
+                f"{packed_neighbors!r}")
+        self.packed: Optional[bool] = (
+            None if packed_neighbors == "auto" else bool(packed_neighbors))
         self.nbr_vecs = None
-        if packed_neighbors:
-            src = (self.data_score if self.data_score is not None
-                   else self.data)
-            g = jnp.maximum(self.graph, 0)
-            self.nbr_vecs = src[g]
+        self._table_lock = locksan.make_lock(
+            "GraphSearchEngine._table_lock")
         # device-time attribution (FlightDeviceSampleRate): every Nth
         # segment dispatch is timed to completion (block_until_ready) and
         # fed to the flight recorder + the engine.segment_device_ns
@@ -1314,6 +1404,29 @@ class GraphSearchEngine:
         if self.nbr_vecs is not None:
             devmem.track("packed_neighbors", self,
                          self.nbr_vecs.nbytes)
+
+    def walk_table(self) -> Optional[jax.Array]:
+        """The packed-neighbour table a walk of this engine fetches its
+        blocks from, None under the row-gather layout.  The first walk
+        that asks settles an `auto` layout (`packed_layout_fits` over
+        the device's memory as it stands now) and builds the table (one
+        gather of N x m rows of the scoring source; -1 graph slots point
+        at row 0, whose scores the walk's `fresh` mask discards like the
+        row-gather path's placeholders), resident from then on."""
+        if self.nbr_vecs is None and self.packed is not False:
+            with self._table_lock:
+                if self.packed is None:
+                    self.packed = packed_layout_fits(
+                        self.n, int(self.graph.shape[1]),
+                        int(self.data.shape[1]), self.score_itemsize(),
+                        *_device_memory())
+                if self.packed and self.nbr_vecs is None:
+                    src = (self.data_score if self.data_score is not None
+                           else self.data)
+                    self.nbr_vecs = _pack_neighbors(src, self.graph)
+                    devmem.track("packed_neighbors", self,
+                                 self.nbr_vecs.nbytes)
+        return self.nbr_vecs
 
     def set_deleted(self, deleted: np.ndarray) -> None:
         """Swap only the tombstone mask — mutation path for delete-only
@@ -1422,7 +1535,7 @@ class GraphSearchEngine:
             score_itemsize=self.score_itemsize(),
             merge_bins=self.merge_bins_for(L, B) if L else 0, L=L,
             N=self.n, score_scale=self.score_scale,
-            packed=self.nbr_vecs is not None, l2=walk_takes_norm(self.metric))
+            l2=walk_takes_norm(self.metric))
 
     def seed_state(self, queries: jax.Array, L: int,
                    seeds: Optional[jax.Array] = None) -> dict:
@@ -1473,7 +1586,7 @@ class GraphSearchEngine:
             k_eff, L, B, S, int(self.metric), self.base, nbp_limit,
             inject=inject if spare_ids is not None else 0,
             spare_ids=spare_ids, spare_d=state["spare_d"],
-            data_score=self.data_score, nbr_vecs=self.nbr_vecs,
+            data_score=self.data_score, nbr_vecs=self.walk_table(),
             merge_bins=self.merge_bins_for(L, B),
             score_scale=self.score_scale)
         if sample:
@@ -1599,17 +1712,22 @@ class GraphSearchEngine:
             out_d[start:start + nqc] = d[:nqc]
             out_i[start:start + nqc] = ids[:nqc]
         metrics.inc("beam.segmented")
-        self._publish_walk(trips, live, nq, B, L)
+        self._publish_walk(trips, live, nq, B, L,
+                           query_bucket(min(nq, chunk), chunk))
         return out_d, out_i
 
     def _publish_walk(self, trips: int, live, nq: int, B: int,
-                      L: int) -> None:
+                      L: int, rung: int) -> None:
         """What the batch just read back walked, from the count its
         program returned with the answers (the caller has counted which
         driver ran it: `beam.monolithic` / `.chunked` / `.segmented`;
         counted here: which visited / de-duplicate ensemble its program
-        was traced with, `beam.dedup_sorted` / `.dedup_positional`, and
-        `beam.norm_from_rows` where it scored L2):
+        was traced with, `beam.dedup_sorted` / `.dedup_positional`,
+        which way it fetched its vectors, `beam.fetch_blocks` /
+        `.fetch_rows` with the gauge `beam.fetches_per_trip` — a
+        program run of `rung` query rows asks for rung x B blocks or
+        rung x B x m rows a trip —, and `beam.norm_from_rows` where it
+        scored L2):
         its trips (`beam.trips`, `beam.trips_total`: the `live.max()` of
         each while loop, so a chunked or segmented batch reads the sum
         over the chunks it walked one after another), its pool, pivot
@@ -1620,13 +1738,18 @@ class GraphSearchEngine:
         `beam.rows_scored_total` over `beam.queries_total` for a ratio
         of totals.  docs/TELEMETRY.md; the benchmark's kernel.beam_*
         read them."""
-        scored = int(np.sum(np.reshape(live, -1)[:nq])) * B \
-            * int(self.graph.shape[1])
-        if dedup_in_sorted_order(self.merge_bins_for(L, B),
-                                 self.nbr_vecs is not None):
+        m = int(self.graph.shape[1])
+        scored = int(np.sum(np.reshape(live, -1)[:nq])) * B * m
+        if dedup_in_sorted_order(self.merge_bins_for(L, B)):
             metrics.inc("beam.dedup_sorted")
         else:
             metrics.inc("beam.dedup_positional")
+        if self.packed:
+            metrics.inc("beam.fetch_blocks")
+        else:
+            metrics.inc("beam.fetch_rows")
+        metrics.set_gauge("beam.fetches_per_trip",
+                          rung * B * (1 if self.packed else m))
         if walk_takes_norm(self.metric):
             # the body scored against the square sum of the rows its
             # gather brought, not a norm fetched by id (cosine has none)
@@ -1667,6 +1790,7 @@ class GraphSearchEngine:
         fb = self.finalize_bins_for(k_eff, L)
         sk = self.seed_keep_for(L)
         chunk = self.chunk_size()
+        table = self.walk_table()
         out_d = np.full((nq, k), np.float32(MAX_DIST), np.float32)
         out_i = np.full((nq, k), -1, np.int32)
         D = queries.shape[1]
@@ -1698,7 +1822,7 @@ class GraphSearchEngine:
                     jnp.asarray(q), t_limit,
                     k_eff, L, B, int(self.metric), self.base, limit,
                     inject=dynamic_pivots, data_score=self.data_score,
-                    nbr_vecs=self.nbr_vecs,
+                    nbr_vecs=table,
                     merge_bins=mb, finalize_bins=fb, seed_keep=sk,
                     score_scale=self.score_scale)
             else:
@@ -1712,7 +1836,7 @@ class GraphSearchEngine:
                     jnp.asarray(s), jnp.asarray(q), t_limit,
                     k_eff, L, B, int(self.metric), self.base, limit,
                     data_score=self.data_score,
-                    nbr_vecs=self.nbr_vecs,
+                    nbr_vecs=table,
                     merge_bins=mb, finalize_bins=fb,
                     score_scale=self.score_scale)
             with trace.span("index.readback"):
@@ -1722,7 +1846,7 @@ class GraphSearchEngine:
             out_d[:, :k_eff] = d[:nq]
             out_i[:, :k_eff] = ids[:nq]
             metrics.inc("beam.monolithic")
-            self._publish_walk(int(live.max()), live, nq, B, L)
+            self._publish_walk(int(live.max()), live, nq, B, L, q_pad)
             return out_d, out_i
         # multi-chunk: one lax.map device program (one upload / dispatch /
         # read — a Python chunk loop pays a synced host round trip once
@@ -1740,7 +1864,7 @@ class GraphSearchEngine:
                 jnp.asarray(q.reshape(m, chunk, D)), t_limit,
                 k_eff, L, B, int(self.metric), self.base, limit,
                 inject=dynamic_pivots, data_score=self.data_score,
-                nbr_vecs=self.nbr_vecs,
+                nbr_vecs=table,
                 merge_bins=mb, finalize_bins=fb, seed_keep=sk,
                 score_scale=self.score_scale)
         else:
@@ -1755,7 +1879,7 @@ class GraphSearchEngine:
                 jnp.asarray(q.reshape(m, chunk, D)), t_limit,
                 k_eff, L, B, int(self.metric), self.base, limit,
                 data_score=self.data_score,
-                nbr_vecs=self.nbr_vecs,
+                nbr_vecs=table,
                 merge_bins=mb, finalize_bins=fb,
                 score_scale=self.score_scale)
         with trace.span("index.readback"):
@@ -1764,7 +1888,8 @@ class GraphSearchEngine:
         out_i[:, :k_eff] = ids.reshape(m * chunk, -1)[:nq]
         metrics.inc("beam.chunked")
         # (m, chunk): lax.map walks the chunks one after another
-        self._publish_walk(int(live.max(axis=1).sum()), live, nq, B, L)
+        self._publish_walk(int(live.max(axis=1).sum()), live, nq, B, L,
+                           chunk)
         return out_d, out_i
 
 

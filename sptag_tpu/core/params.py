@@ -311,13 +311,18 @@ class BKTParams(ParamSet):
             # walk with reference walk semantics) and the dense partition's
             # target cluster size
             _spec("search_mode", str, "dense", "SearchMode"),
-            # opt-in packed-neighbor layout for the beam walk: each
-            # node's m neighbor VECTORS are materialized contiguously
-            # (in the BeamScoreDtype shadow when active), so the in-loop
-            # gather is B block reads per query instead of B*m scattered
-            # rows — block-granular DMA at m x corpus HBM (VERDICT r3
-            # item 3; ~1.6 GB extra for 200k x m32 x d128 bf16)
-            _spec("beam_packed_neighbors", int, 0, "BeamPackedNeighbors"),
+            # packed-neighbor layout for the beam walk: each node's m
+            # neighbor VECTORS are materialized contiguously (in the
+            # BeamScoreDtype shadow when active), so the in-loop fetch
+            # is B block reads per query instead of B*m scattered rows —
+            # block-granular DMA at m x the scoring corpus in HBM (0.82
+            # GB for 100k x m32 x d128 bf16).  "auto" takes it where
+            # that table is at most an eighth of a TPU's memory and half
+            # of what is free (algo/engine.py packed_layout_fits; never
+            # off a TPU); 1 insists; 0, the default every folder saved
+            # before PR 45 carries, is read as auto (packed_param)
+            _spec("beam_packed_neighbors", str, "auto",
+                  "BeamPackedNeighbors"),
             # SearchMode=auto: per-request engine pick by budget — beam
             # below this MaxCheck threshold, dense at or above it (the
             # crossover measured on the 200k corpus in round 3 was
@@ -408,7 +413,8 @@ class KDTParams(ParamSet):
             # KDT search; the MXU dense scan is the opt-in fast path
             _spec("search_mode", str, "beam", "SearchMode"),
             # packed-neighbor walk layout; see the BKT spec of this name
-            _spec("beam_packed_neighbors", int, 0, "BeamPackedNeighbors"),
+            _spec("beam_packed_neighbors", str, "auto",
+                  "BeamPackedNeighbors"),
             # SearchMode=auto crossover threshold; see the BKT spec
             _spec("auto_mode_threshold", int, 1024, "AutoModeThreshold"),
             _spec("dense_cluster_size", int, 256, "DenseClusterSize"),

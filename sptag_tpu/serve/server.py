@@ -16,7 +16,7 @@ only where the last window says waiting brings some: not for a lone caller
 whose window closed on its one request, and not for what queued while a
 batch executed once a window has brought such a backlog next to nothing;
 and past the window, for the callers just answered, where the batch they
-were in held the executor so long that the wait is an eighth of it at
+were in held the executor so long that the wait is a quarter of it at
 most (`_batcher`).
 """
 
@@ -73,12 +73,21 @@ TRICKLE_BATCHES = 32
 
 #: how far past its window a gather waits for the callers just answered,
 #: as a share of the time their batch held the executor
-#: (`SearchServer._batcher`): nothing under 16 ms a batch at the default
-#: 2 ms window, 50 ms after a 420 ms graph walk.  128 closed-loop callers
-#: of one generator process are back 15-40 ms after such a batch; where
-#: they are not, the wait is lost once and `trickle` sends the next 32
-#: backlogs on at once
-PATIENCE_SHARE = 1.0 / 8
+#: (`SearchServer._batcher`): 49 ms after a 196 ms graph walk, and
+#: nothing where that share would add less than one more window (under
+#: 16 ms a batch at the default 2 ms window: where an eighth began to
+#: count too, so a batch that earned no wait before PR 45 earns none
+#: now).  The wait ends the moment the callers are all back, so the
+#: share is what a crowd that does NOT return costs, once:  `trickle`
+#: then sends the next 32 backlogs on at once, a quarter of one batch
+#: in 33, under 1 % of a saturated server's time.  Callers that do
+#: return are worth any wait shorter than the batch itself: sent on
+#: without them the batch splits in two that take turns on the device,
+#: each padded to its rung.  An eighth (PR 32, walks of 330-420 ms)
+#: closed the gather on 111 of 128 callers once a walk took 196 ms and
+#: the other 17 waited a whole cycle more: p95 was TWO cycles (PERF.md,
+#: PR 45)
+PATIENCE_SHARE = 1.0 / 4
 
 #: the event loop's heartbeat (`SearchServer._beat`): a timer re-armed
 #: from its own callback records how late the loop ran it
@@ -769,9 +778,10 @@ class SearchServer:
         finished batch's replies, before one waits the window again
         (`trickle` counts them down).
 
-        A window whose last batch held the executor more than
-        window / PATIENCE_SHARE goes on past its end, PATIENCE_SHARE of
-        that time at most, until as many requests are here as were
+        A window whose last batch earned it one more window at least
+        (PATIENCE_SHARE of the time it held the executor, less the
+        window) goes on past its end, that share at most, until as many
+        requests are here as were
         queued when the executor freed plus the callers just answered
         (`server.gather_patient`).  Where a batch is a 420 ms walk and
         its 128 callers take 20 ms to come back, the window's 2 ms split
@@ -821,10 +831,9 @@ class SearchServer:
                 metrics.inc("server.gather_window")
                 found = len(batch)               # before the window
                 deadline = loop.time() + self.batch_window
-                patience = 0.0
-                if self.batch_window > 0:
-                    patience = max(0.0, PATIENCE_SHARE * held
-                                   - self.batch_window)
+                patience = PATIENCE_SHARE * held - self.batch_window
+                if not 0 < self.batch_window <= patience:
+                    patience = 0.0   # no window asked, or not one more
                 patient = False                  # past the window
                 while len(batch) < self.max_batch:
                     if patient and len(batch) >= queued + answered:

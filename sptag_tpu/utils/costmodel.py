@@ -184,9 +184,9 @@ SCAN_MATRIX_TRAFFIC = 3.2
 #: per-element flops XLA attributes to the POSITIONAL sort/scan/top-k
 #: ensemble of one beam-walk iteration (`engine._sorted_dedup`: argsort,
 #: the mask carried back through the inverse permutation, two bitset-word
-#: gathers, segmented OR scan, merges).  Since ISSUE 33 the exact body
-#: runs it X-wide only with the packed-neighbour layout; the binned
-#: body prices its L-wide pool de-duplication with it
+#: gathers, segmented OR scan, merges).  The exact body left it (ISSUE
+#: 33 with the row-gather layout, ISSUE 45 with the packed-neighbour
+#: one); the binned body prices its L-wide pool de-duplication with it
 WALK_SORT_FLOPS = 290.0
 
 #: per-element word traffic of the same ensemble (sorted copies,
@@ -197,7 +197,8 @@ WALK_SORT_TRAFFIC = 130.0
 #: (`engine._sorted_fresh`, ISSUE 33: one one-operand sort, one word
 #: gather, no inverse permutation), fitted like the pair above at six
 #: shapes (flops 158-185 an element, words 93-112 where the graph's
-#: gather operand is small beside Q*X)
+#: gather operand is small beside Q*X); the packed-neighbour layout's
+#: scores ride the sort as a second operand and read within 3 % of it
 WALK_SORTED_FLOPS = 170.0
 WALK_SORTED_TRAFFIC = 105.0
 

@@ -10,9 +10,9 @@ brings back fewer than half as many as were just answered: the next
 TRICKLE_BATCHES backlogs leave at once, each as one batch
 (`server.gather_backlog`), after the finished batch's replies, before
 one waits the window again.  Past its window a gather waits for the
-callers just answered where their batch held the executor more than
-window / PATIENCE_SHARE, that share of its time at most
-(`server.gather_patient`; ISSUE 32).  Under test: a real SearchServer over
+callers just answered where PATIENCE_SHARE of the time their batch held
+the executor, less the window, is one more window at least, that share
+of its time at most (`server.gather_patient`; ISSUE 32).  Under test: a real SearchServer over
 a tiny FLAT index, its executor wrapped so a test can hold it busy and read
 the batches it was handed.
 """
@@ -414,17 +414,19 @@ def test_a_backlog_waits_for_replies_that_take_their_time(served, held_up):
         held_up == "stuck_send")
 
 
-@pytest.mark.parametrize("back_after", ["window", "never", "short"])
+@pytest.mark.parametrize("back_after", ["window", "never", "short", "band"])
 def test_a_long_batch_earns_its_callers_a_wait_past_the_window(
         served, back_after):
     """A batch that held the executor 1.6 s (a graph walk's, scaled up
-    for a sandbox's clock) earns its four callers an eighth of that:
-    back 0.1 s after a 0.04 s window they share ONE batch with what
-    queued behind them, sent on the moment the last is back.  Never
-    back, the wait ends after 0.2 s and the trickle rule takes over;
-    after a batch of 0.1 s no gather goes past its window at all."""
+    for a sandbox's clock) earns its four callers PATIENCE_SHARE (a
+    quarter) of that: back 0.1 s after a 0.04 s window they share ONE
+    batch with what queued behind them, sent on the moment the last is
+    back.  Never back, the wait ends after 0.4 s and the trickle rule
+    takes over; after a batch of 0.1 s no gather goes past its window
+    at all, nor after one of 0.24 s (`band`: a quarter of it less the
+    window is half a window, not one more)."""
     window_s = 0.04
-    pad_s = 0.1 if back_after == "short" else 1.6
+    pad_s = {"short": 0.1, "band": 0.24}.get(back_after, 1.6)
     s = served(batch_window_ms=1e3 * window_s, pad_s=pad_s,
                connections=2)
     s.send(4)                            # one write: one window, one batch
@@ -443,14 +445,15 @@ def test_a_long_batch_earns_its_callers_a_wait_past_the_window(
     if back_after == "window":
         assert s.held.sizes == [4, 6]
         assert patient == 1
-        # all six were there 0.1 s in: the rest of the 0.2 s was not
+        # all six were there 0.1 s in: the rest of the 0.4 s was not
         # waited (the server's clock, less the first batch's window)
         assert 0.08 <= gather.sum - window_s < 0.19
     elif back_after == "never":
         assert s.held.sizes == [4, 2]
         assert patient == 1
-        assert time.perf_counter() - t_read >= pad_s / 8 - 0.01
-        assert 0.19 <= gather.sum - window_s < 0.4
+        earned = pad_s * server_module.PATIENCE_SHARE
+        assert time.perf_counter() - t_read >= earned - 0.01
+        assert earned - 0.01 <= gather.sum - window_s < earned + 0.2
     else:
         assert s.held.sizes == [4, 2, 4]
         assert patient == 0

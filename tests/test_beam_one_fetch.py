@@ -84,7 +84,7 @@ def parent_walk_machine(data, sqnorm, graph, queries, t_limit, k, L, B,
         nbrs = jnp.where(sel_ok[..., None], nbrs, -1)
         flat = nbrs.reshape(Q, -1)
         flat_safe = jnp.where(flat >= 0, flat, N)
-        flat, fresh, visited = engine._sorted_fresh(visited, flat_safe, N)
+        flat, fresh, visited, _ = engine._sorted_fresh(visited, flat_safe, N)
 
         gather_idx = jnp.where(fresh, flat, 0)
         cvecs = score_src[gather_idx]

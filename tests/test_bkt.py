@@ -318,7 +318,7 @@ def test_beam_packed_neighbors_matches_row_gather():
         assert np.array_equal(i_row, i_pack), (vt, sd)
         np.testing.assert_allclose(d_row, d_pack, rtol=1e-6,
                                    err_msg=f"{vt}/{sd}")
-        assert idx_pack._get_engine().nbr_vecs is not None
+        assert idx_pack._get_engine().nbr_vecs is not None      # it walked
         assert idx_row._get_engine().nbr_vecs is None
 
 

@@ -576,6 +576,63 @@ def test_beam_cell_rungs_compile_at_100k(one_chip, Q):
     assert mem.temp_size_in_bytes < 2 << 30
 
 
+@pytest.mark.parametrize("Q", [1, 8, 32, 128])
+def test_beam_cell_rungs_compile_at_100k_packed(one_chip, Q):
+    """`bkt_100k_beam.saturate`'s programs since PR 45: the same plan with
+    the packed-neighbour table (`bf16[100000,32,128]`, 0.82 GB: what
+    `BeamPackedNeighbors=auto` takes on the chip at this size).  A trip
+    fetches Q x B blocks of (32, 128) and no row by candidate id."""
+    from sptag_tpu.algo import engine
+
+    n, D, m = 100_000, 128, 32
+    L = engine.beam_pool_size(K, 2048, n)
+    B = engine.beam_width_for(16, 2048, L)
+    assert engine.packed_layout_fits(n, m, D, 2, 16 << 30)
+    a = _engine_arrays(one_chip, n, D, pivots=n // 24)
+    compiled = engine._beam_search_kernel.lower(
+        a["data"], a["sqnorm"], a["graph"], a["deleted"], a["pivot_ids"],
+        a["pivot_vecs"], a["pivot_mask"], _s(one_chip, (Q, D), jnp.float32),
+        _s(one_chip, (Q,), jnp.int32), k=K, L=L, B=B, metric=L2, base=1,
+        nbp_limit=3, inject=4, data_score=a["data_score"],
+        nbr_vecs=_s(one_chip, (n, m, D), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    # the vectors: one gather of Q x B blocks from the table, none of
+    # Q x B x m rows from the shadow
+    lead = "" if Q == 1 else f"{Q},"        # a lone query's axis is dropped
+    assert len(re.findall(rf"= bf16\[{lead}{B},{m},{D}\]\S* gather\(",
+                          text)) == 1
+    assert not re.findall(rf"= bf16\[{lead}{B * m},{D}\]\S* gather\(", text)
+    # the scores ride the ONE X-wide sort beside the ids, nothing else does
+    assert re.findall(rf"= \((s32\[{Q},2048\]\S*, f32\[{Q},2048\]\S*)\) "
+                      r"sort\(", text)
+    if Q == 128:
+        # the ensemble stays PR 33's: one X-wide element gather, of
+        # `visited` words; the merge's one word gather by position
+        assert re.findall(rf"= (s32|pred|f32)\[{Q},2048\]\S* gather\(",
+                          text) == ["s32"]
+        assert sorted(re.findall(rf"= (s32|pred)\[{Q},{L}\]\S* gather\(",
+                                 text)) == ["pred", "s32"]
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 << 30
+
+
+def test_the_beam_cells_table_is_written_with_no_copy_beside_it(one_chip):
+    """`engine._pack_neighbors` at the beam cell's size: the program's
+    output is the 0.82 GB table and it holds next to nothing else while
+    it runs (dispatched op by op the same gather peaked at twice the
+    table: PERF.md, PR 45), so `packed_layout_fits` budgets the table
+    alone."""
+    from sptag_tpu.algo import engine
+
+    n, D, m = 100_000, 128, 32
+    compiled = engine._pack_neighbors.lower(
+        _s(one_chip, (n, D), jnp.bfloat16),
+        _s(one_chip, (n, m), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == n * m * D * 2
+    assert mem.temp_size_in_bytes < 64 << 20
+
+
 # ---------------------------------------------------------------------------
 # four chips: one program across a (4,) mesh of the described devices
 # ---------------------------------------------------------------------------

@@ -154,6 +154,57 @@ def test_crosscheck_beam_segment(Q, L, B, N, D, m, S):
                          [(8, 64, 16, 2048, 64, 32, 4),
                           (32, 128, 32, 4096, 128, 32, 8),
                           (16, 320, 64, 16384, 128, 32, 4)])
+def test_crosscheck_beam_segment_packed(Q, L, B, N, D, m, S):
+    """ISSUE 45: the exact body under the packed-neighbour layout is
+    priced by the SAME formula as under the row layout (the sorted-id
+    ensemble, WALK_SORTED_*; the scores ride its sort).  Cost analysis
+    charges the block gather its whole operand, the (N, m, D) table,
+    where the formula carries the corpus's N*D and says that it leaves
+    the table out: the other (m - 1) N*D are added here."""
+    from sptag_tpu.algo.engine import _beam_segment_kernel, _num_words
+
+    W = _num_words(N)
+    compiled = _beam_segment_kernel.lower(
+        jnp.zeros((N, D)), jnp.zeros((N,)),
+        jnp.zeros((N, m), jnp.int32), jnp.zeros((Q, D)),
+        jnp.zeros((Q,), jnp.int32), jnp.zeros((Q, L), jnp.int32),
+        jnp.zeros((Q, L)), jnp.zeros((Q, L + 1), bool),
+        jnp.zeros((Q, W), jnp.int32), jnp.zeros((Q,), jnp.int32),
+        jnp.zeros((Q,), jnp.int32), jnp.zeros((Q,), jnp.int32),
+        10, L, B, S, int(DistCalcMethod.L2), 1, 3, 0,
+        None, None, None, jnp.zeros((N, m, D))).compile()
+    est = costmodel.estimate("beam.segment", Q=Q, X=B * m, D=D, W=W, N=N)
+    xla_flops, xla_bytes = costmodel.xla_cost(compiled)
+    table_rest = (m - 1) * N * D * 4
+    assert abs(est.flops - xla_flops) <= TOL * xla_flops
+    assert abs(est.hbm_bytes + table_rest - xla_bytes) \
+        <= TOL * (xla_bytes - table_rest)
+
+
+@pytest.mark.parametrize("N,m,D,dtype", [(2048, 32, 64, jnp.float32),
+                                         (16384, 8, 128, jnp.float32),
+                                         (4096, 32, 128, jnp.bfloat16)])
+def test_crosscheck_beam_pack_neighbors(N, m, D, dtype):
+    """PR 45: the packed-neighbour table's build is one program, priced
+    as what it moves (the scoring source and the graph in, the table
+    out).  The flops are held on float rows only: XLA:CPU widens
+    bfloat16 on its way through and bills that."""
+    from sptag_tpu.algo.engine import _pack_neighbors
+
+    compiled = _pack_neighbors.lower(
+        jnp.zeros((N, D), dtype), jnp.zeros((N, m), jnp.int32)).compile()
+    est = costmodel.estimate("beam.pack_neighbors", N=N, m=m, D=D,
+                             score_itemsize=jnp.dtype(dtype).itemsize)
+    xla_flops, xla_bytes = costmodel.xla_cost(compiled)
+    assert abs(est.hbm_bytes - xla_bytes) <= TOL * xla_bytes
+    if dtype == jnp.float32:
+        assert abs(est.flops - xla_flops) <= TOL * xla_flops
+
+
+@pytest.mark.parametrize("Q,L,B,N,D,m,S",
+                         [(8, 64, 16, 2048, 64, 32, 4),
+                          (32, 128, 32, 4096, 128, 32, 8),
+                          (16, 320, 64, 16384, 128, 32, 4)])
 def test_crosscheck_beam_segment_binned(Q, L, B, N, D, m, S):
     """ISSUE 13: the BINNED walk body's recalibrated formula
     (WALK_BINNED_* constants + the explicit corpus gather-operand term)
