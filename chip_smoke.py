@@ -164,10 +164,16 @@ def no_serve_errors(where: str) -> None:
 # phases
 # ---------------------------------------------------------------------------
 
+def device_peaks(kind: str) -> dict:
+    """The three peaks the benchmark's rooflines divide by, from its own
+    table (benchmark/harness/peaks.json); a kind it lacks is refused."""
+    peaks = serving.peaks_for(kind)
+    return {name: peaks[name] for name in (
+        "bf16_flops_per_s", "int8_ops_per_s", "hbm_bytes_per_s")}
+
+
 def phase_device(rehearse: bool, chips: int) -> dict:
     import jax
-
-    from sptag_tpu.utils import roofline
 
     if rehearse:                 # the harness's check refuses a CPU
         devs = jax.devices()
@@ -177,13 +183,8 @@ def phase_device(rehearse: bool, chips: int) -> dict:
         emit({"phase": "device", **device})
         return device
     device = serving.check_device(chips)
-    cap = roofline.capability()
-    require(cap.source == "table",
-            f"device kind {device['kind']!r} is not in "
-            "utils/roofline.py's table")
-    emit({"phase": "device", **device, "capability": {
-        "source": cap.source, "peak_flops_bf16": cap.peak_flops_bf16,
-        "peak_flops_int8": cap.peak_flops_int8, "hbm_gbps": cap.hbm_gbps}})
+    emit({"phase": "device", **device,
+          "peaks": device_peaks(device["kind"])})
     return device
 
 

@@ -190,9 +190,6 @@ class BKTIndex(VectorIndex):
                                 # invalidation a set_parameter on a warm
                                 # index would be a silent no-op
                                 "flightdevicesamplerate",
-                                # capability (incl. probe permission) is
-                                # resolved at engine materialization
-                                "rooflineprobe",
                                 # bin-reduction top-k mode + its recall
                                 # target are baked into the engine's
                                 # compiled walk programs (ISSUE 13)
@@ -289,8 +286,6 @@ class BKTIndex(VectorIndex):
                                  device_sample_rate=float(getattr(
                                      self.params,
                                      "flight_device_sample_rate", 0.0)),
-                                 roofline_probe=bool(int(getattr(
-                                     self.params, "roofline_probe", 0))),
                                  binned_topk=str(getattr(
                                      self.params, "binned_topk", "off")),
                                  recall_target=float(getattr(

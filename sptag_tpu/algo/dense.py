@@ -37,7 +37,7 @@ from sptag_tpu.core.types import DeviceTopK, DistCalcMethod
 from sptag_tpu.ops import distance as dist_ops
 from sptag_tpu.ops import pallas_kernels
 from sptag_tpu.ops import topk_bins
-from sptag_tpu.utils import (costmodel, devmem, metrics, query_bucket,
+from sptag_tpu.utils import (devmem, metrics, query_bucket,
                              round_up, trace)
 
 MAX_DIST = np.float32(3.4e38)   # plain scalar: module import must NOT init a backend
@@ -524,86 +524,6 @@ def _dense_search_chunked(data_perm, member_ids, member_sq, centroids,
             q, k, nprobe, metric, base, use_pallas, interpret, dedup,
             binned_bins)
     return jax.lax.map(body, queries3)
-
-
-# ---------------------------------------------------------------------------
-# cost-ledger entries (utils/costmodel.py; graftlint GL605)
-# ---------------------------------------------------------------------------
-
-def _dense_scan_cost(Q, C, P, D, nprobe, k, itemsize=4, binned_bins=0,
-                     **_):
-    """Per-query kernel: (Q, C) center matmul, top-nprobe cut, block
-    gather, (Q, nprobe*P) candidate contraction, masked top-k.  Bytes:
-    the gathered (Q, nprobe, P, D) candidate tensor is written then
-    re-read by the scoring einsum (2x), plus the full block-layout
-    operand of the gather and the (Q, nprobe*P) score-matrix traffic.
-    With `binned_bins` the final select is the bin reduction: the
-    top-k ensemble term is replaced by the O(M) reduction + the
-    bins-wide shortlist sort (ops/topk_bins.binned_select_cost)."""
-    M = Q * nprobe * P
-    if binned_bins:
-        sel_f, sel_b = topk_bins.binned_select_cost(Q, nprobe * P, k, binned_bins)
-        sel_f += 6.0 * M                          # mask/where epilogue
-        sel_b += 4.0 * M * 4
-    else:
-        sel_f, sel_b = 10.0 * M, 8.0 * M * 4      # mask/top-k ensemble
-    flops = (costmodel.matmul_flops(Q, C, D)      # center scoring
-             + 2.0 * M * D                        # candidate scoring
-             + sel_f
-             + 2.0 * D * (Q + C))                 # norms
-    nbytes = (2.0 * M * D * itemsize              # gather out + einsum read
-              + C * P * D * itemsize              # gather operand
-              + C * D * 4 + C * 4                 # centroids
-              + Q * D * itemsize
-              + sel_b                             # ids/sq/mask/select traffic
-              + Q * k * 8)
-    return flops, nbytes
-
-
-def _dense_chunked_cost(M_chunks, Q, C, P, D, nprobe, k, itemsize=4,
-                        binned_bins=0, **_):
-    f, b = _dense_scan_cost(Q, C, P, D, nprobe, k, itemsize,
-                            binned_bins=binned_bins)
-    return M_chunks * f, M_chunks * b
-
-
-def _dense_grouped_cost(Q, C, P, D, nprobe, U, G, k, itemsize=4,
-                        binned_bins=0, **_):
-    """Grouped kernel: every query scores its group's U-block union —
-    (Q/G)*U grid steps of (G, D) x (D, P) contractions.  With
-    `binned_bins` the final (Q, U*P)-wide select is the bin reduction
-    (same substitution as _dense_scan_cost)."""
-    NG = max(1, Q // max(G, 1))
-    M = NG * U * P * G                            # scored candidates
-    if binned_bins:
-        sel_f, sel_b = topk_bins.binned_select_cost(Q, U * P, k, binned_bins)
-        sel_f += 8.0 * M                          # union rank/scan/mask
-        sel_b += 4.0 * M * 4
-    else:
-        sel_f, sel_b = 12.0 * M, 8.0 * M * 4      # union rank/scan/top-k
-    flops = (costmodel.matmul_flops(Q, C, D)
-             + 2.0 * M * D
-             + sel_f
-             + 2.0 * D * (Q + C))
-    nbytes = (2.0 * NG * U * P * D * itemsize + C * P * D * itemsize
-              + C * D * 4 + Q * D * itemsize + sel_b + Q * k * 8)
-    return flops, nbytes
-
-
-def _dense_grouped_chunked_cost(M_chunks, Q, C, P, D, nprobe, U, G, k,
-                                itemsize=4, binned_bins=0, **_):
-    f, b = _dense_grouped_cost(Q, C, P, D, nprobe, U, G, k, itemsize,
-                               binned_bins=binned_bins)
-    return M_chunks * f, M_chunks * b
-
-
-costmodel.register("dense.scan", _dense_search_kernel, _dense_scan_cost)
-costmodel.register("dense.scan_chunked", _dense_search_chunked,
-                   _dense_chunked_cost)
-costmodel.register("dense.grouped", _dense_search_grouped_kernel,
-                   _dense_grouped_cost)
-costmodel.register("dense.grouped_chunked", _dense_search_grouped_chunked,
-                   _dense_grouped_chunked_cost)
 
 
 @functools.lru_cache(maxsize=8)

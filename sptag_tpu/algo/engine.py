@@ -68,8 +68,8 @@ import numpy as np
 from sptag_tpu.core.types import DistCalcMethod
 from sptag_tpu.ops import distance as dist_ops
 from sptag_tpu.ops import topk_bins
-from sptag_tpu.utils import (costmodel, devmem, flightrec, locksan, metrics,
-                             query_bucket, recompile_guard, roofline, trace)
+from sptag_tpu.utils import (devmem, flightrec, locksan, metrics,
+                             query_bucket, recompile_guard, trace)
 
 MAX_DIST = np.float32(3.4e38)   # plain scalar: module import must NOT init a backend
 
@@ -1051,167 +1051,6 @@ def _beam_finalize_gathered_kernel(rows, dead, queries, cand_ids,
                                        base)
 
 
-# ---------------------------------------------------------------------------
-# cost-ledger entries (utils/costmodel.py; graftlint GL605)
-# ---------------------------------------------------------------------------
-#
-# The walk kernels wrap `lax.while_loop`s, so every formula follows the
-# ledger's count-body-once convention: `beam.segment`'s cost is ONE
-# iteration of the shared body — runtime consumers (run_segment's
-# sampled roofline gauges, the scheduler's per-query attribution) scale
-# by their own iteration counts.
-
-def _walk_iter_cost(Q, X, D, W, score_itemsize=4, merge_bins=0, L=0, N=0,
-                    score_scale=0, l2=True, **_):
-    """One _walk_machine body application at batch Q: the B*m = X
-    candidate fetch + scoring contraction dominates; the fitted
-    WALK_SORTED_* constants carry the sort/segmented-scan/top-k
-    ensemble in sorted-id order (calibrated against HloCostAnalysis;
-    tests pin ±15%).  The exact body costs the same under both layouts
-    (BeamPackedNeighbors): the same X rows a query are fetched and
-    scored, and the score that rides the packed body's sort moves the
-    fitted constants by 2-3 % (flops 152-175 an element, words 102-121,
-    at the three shapes the test holds).  The packed TABLE's bytes are
-    still left out: cost analysis charges a gather its whole operand,
-    here (N, m, D), of which the formula keeps the corpus's N*D below;
-    a trip reads Q*X rows of it, which the first term counts, and the
-    whole table a trip would be six times everything else at the beam
-    cell's size (the calibration test adds the other (m-1)*N*D itself).
-
-    `l2`: an L2 body takes each candidate's norm from the block it just
-    gathered (PR 44; the `sqnorm` gather went): 2*Q*X*D flops more, and
-    the float32 squares' traffic as XLA:CPU's cost analysis sees it,
-    costmodel.WALK_ROW_NORM_TRAFFIC words an element.  Cosine has no
-    norm.
-
-    `merge_bins` > 0 prices the BINNED body instead: the X-wide sort
-    ensemble is gone — what remains is the (L + X)-wide bin reduction +
-    shortlist top-L (WALK_BINNED_* constants, per merged-row element)
-    and the L-wide lazy-mark sort ensemble (the WALK_SORT_* constants,
-    the positional `_sorted_dedup` ensemble's, at width L: theirs alone
-    since the exact body left it).
-
-    Both bodies carry the corpus gather operand, N*D: cost analysis
-    charges a gather its whole operand, and at a small batch that is no
-    small term (Q=8, N=2048, D=64: 0.52 of 5.0 MB; without it the exact
-    body read 15.5-43 % low at Q=8, N=2048-8192)."""
-    # int8 cascade scoring (score_scale > 0): the dequantize cast +
-    # multiply is another 2·Q·X·D elementwise ops, and the dequantized
-    # f32 copy doubles the post-gather traffic words
-    deq_f = 2.0 * Q * X * D if score_scale else 0.0
-    deq_b = Q * X * D * 4.0 if score_scale else 0.0
-    norm_f = 2.0 * Q * X * D if l2 else 0.0
-    norm_b = (costmodel.WALK_ROW_NORM_TRAFFIC * Q * X * D * 4.0 if l2
-              else 0.0)
-    if merge_bins:
-        wall = X + max(L, 1)
-        flops = (2.0 * Q * X * D + deq_f + norm_f
-                 + costmodel.WALK_BINNED_FLOPS * Q * wall
-                 + costmodel.WALK_SORT_FLOPS * Q * max(L, 1))
-        nbytes = (2.0 * Q * X * D * score_itemsize + deq_b + norm_b
-                  + N * D * score_itemsize       # corpus gather operand
-                  + costmodel.WALK_BINNED_TRAFFIC * Q * wall * 4
-                  + costmodel.WALK_SORT_TRAFFIC * Q * max(L, 1) * 4
-                  + 2.0 * Q * W * 4)
-        return flops, nbytes
-    flops = (2.0 * Q * X * D + deq_f + norm_f
-             + costmodel.WALK_SORTED_FLOPS * Q * X)
-    nbytes = (2.0 * Q * X * D * score_itemsize + deq_b + norm_b
-              + N * D * score_itemsize           # corpus gather operand
-              + costmodel.WALK_SORTED_TRAFFIC * Q * X * 4
-              + 2.0 * Q * W * 4)
-    return flops, nbytes
-
-
-def _seed_pivot_cost(Q, P, D, L, W, **_):
-    flops = (costmodel.matmul_flops(Q, P, D) + 32.0 * Q * P
-             + 2.0 * D * (Q + P))
-    nbytes = (P * D * 4 + Q * D * 4 + 8.0 * Q * P * 4 + Q * W * 4
-              + Q * L * 8)
-    return flops, nbytes
-
-
-def _seed_seeded_cost(Q, S, D, N, L, W, itemsize=4, **_):
-    flops = 2.0 * Q * S * D + 64.0 * Q * S + 2.0 * D * Q
-    nbytes = (2.0 * Q * S * D * itemsize + N * D * itemsize
-              + 16.0 * Q * S * 4 + Q * W * 4 + Q * L * 8)
-    return flops, nbytes
-
-
-def _finalize_cost(Q, L, D, N, rerank=True, itemsize=4, **_):
-    flops = (2.0 * Q * L * D if rerank else 0.0) + 4.0 * Q * L
-    nbytes = ((2.0 * Q * L * D * itemsize + N * D * itemsize) * rerank
-              + 6.0 * Q * L * 4 + N)
-    return flops, nbytes
-
-
-def _segment_cost(Q, X, D, W, score_itemsize=4, merge_bins=0, L=0, N=0,
-                  score_scale=0, l2=True, **_):
-    return _walk_iter_cost(Q, X, D, W, score_itemsize,
-                           merge_bins=merge_bins, L=L, N=N,
-                           score_scale=score_scale, l2=l2)
-
-
-def _walk_full_cost(Q, P, X, D, L, W, N, score_itemsize=4, merge_bins=0,
-                    **_):
-    """Monolithic seed + walk + finalize, body counted once."""
-    fs, bs = _seed_pivot_cost(Q, P, D, L, W)
-    fi, bi = _walk_iter_cost(Q, X, D, W, score_itemsize,
-                             merge_bins=merge_bins, L=L, N=N)
-    ff, bf = _finalize_cost(Q, L, D, N, rerank=False)
-    return fs + fi + ff, bs + bi + bf
-
-
-def _walk_seeded_cost(Q, S, X, D, L, W, N, score_itemsize=4, itemsize=4,
-                      merge_bins=0, **_):
-    fs, bs = _seed_seeded_cost(Q, S, D, N, L, W, itemsize)
-    fi, bi = _walk_iter_cost(Q, X, D, W, score_itemsize,
-                             merge_bins=merge_bins, L=L, N=N)
-    ff, bf = _finalize_cost(Q, L, D, N, rerank=False)
-    return fs + fi + ff, bs + bi + bf
-
-
-def _walk_chunked_cost(M_chunks, **shape):
-    f, b = _walk_full_cost(**shape)
-    return M_chunks * f, M_chunks * b
-
-
-def _walk_seeded_chunked_cost(M_chunks, **shape):
-    f, b = _walk_seeded_cost(**shape)
-    return M_chunks * f, M_chunks * b
-
-
-def _finalize_gathered_cost(Q, L, D, itemsize=4, **_):
-    flops = 2.0 * Q * L * D + 3.0 * Q * L * D / 2.0 + 4.0 * Q * L
-    nbytes = 2.0 * Q * L * D * itemsize + 6.0 * Q * L * 4
-    return flops, nbytes
-
-
-def _pack_neighbors_cost(N, m, D, score_itemsize=4, **_):
-    """The packed-neighbour table's one gather: the scoring source and
-    the graph read once, the (N, m, D) table written; a max an id."""
-    return float(N * m), float((N * D + N * m * D) * score_itemsize
-                               + N * m * 4)
-
-
-costmodel.register("beam.pack_neighbors", _pack_neighbors,
-                   _pack_neighbors_cost)
-costmodel.register("beam.finalize_gathered", _beam_finalize_gathered_kernel,
-                   _finalize_gathered_cost)
-costmodel.register("beam.seed", _beam_seed_kernel, _seed_pivot_cost)
-costmodel.register("beam.seed_seeded", _beam_seed_seeded_kernel,
-                   _seed_seeded_cost)
-costmodel.register("beam.segment", _beam_segment_kernel, _segment_cost)
-costmodel.register("beam.finalize", _beam_finalize_kernel, _finalize_cost)
-costmodel.register("beam.walk", _beam_search_kernel, _walk_full_cost)
-costmodel.register("beam.walk_seeded", _beam_search_seeded_kernel,
-                   _walk_seeded_cost)
-costmodel.register("beam.walk_chunked", _beam_search_chunked,
-                   _walk_chunked_cost)
-costmodel.register("beam.walk_seeded_chunked", _beam_search_seeded_chunked,
-                   _walk_seeded_chunked_cost)
-
-
 class GraphSearchEngine:
     """Immutable device snapshot of {vectors, graph, tombstones, pivots}
     plus the compiled beam-search program (the single-writer snapshot design
@@ -1224,7 +1063,6 @@ class GraphSearchEngine:
                  score_dtype: str = "auto",
                  packed_neighbors="auto",
                  device_sample_rate: float = 0.0,
-                 roofline_probe: bool = False,
                  binned_topk: str = "off",
                  recall_target: float = topk_bins.DEFAULT_RECALL_TARGET,
                  cascade_search: bool = False,
@@ -1359,19 +1197,6 @@ class GraphSearchEngine:
         # reproducible traces); 0 disables.
         self.device_sample_rate = max(0.0, float(device_sample_rate))
         self._seg_dispatches = 0
-        # roofline wiring (ISSUE 6): sampled segment timings multiply the
-        # cost ledger into achieved-GFLOP/s gauges; peaks come from the
-        # capability registry (static table, or — with RooflineProbe —
-        # the disk-cached measured micro-probe on cpu/gpu/unknown).
-        # Resolved UNCONDITIONALLY at engine build (a table lookup /
-        # cached-probe read; never on the dispatch path), so the
-        # scheduler's slow-query pct_peak classification works even with
-        # device-time sampling off — only the gauges need the sampler.
-        try:
-            self._capability = roofline.capability(
-                probe=bool(roofline_probe))
-        except Exception:                               # noqa: BLE001
-            self._capability = None
         # device-memory ledger: every resident array of this snapshot,
         # owned by the engine (a snapshot swap retires the entry when
         # the superseded engine is collected)
@@ -1442,8 +1267,7 @@ class GraphSearchEngine:
         (utils/qualmon.py shadow path, via VectorIndex
         .exact_search_batch).  Reuses the engine's already-resident
         data/sqnorm/deleted arrays, so the shadow path costs zero extra
-        HBM, and rides the registered `flat.scan` kernel family — its
-        device work is ledger-attributed like every other dispatch.
+        HBM, and rides the `flat.scan` kernel family.
         A host-tier cascade engine has no resident fp corpus: the
         oracle streams the scan through fixed fp blocks instead
         (cascade.host_exact_scan — re-uploading the corpus would break
@@ -1510,32 +1334,10 @@ class GraphSearchEngine:
 
     def score_itemsize(self) -> int:
         """Bytes per element of the in-loop scoring corpus (bf16 shadow
-        halves the walk's gather bytes) — the cost ledger's byte scale."""
+        halves the walk's gather bytes) — gauge `beam.score_itemsize`,
+        the byte scale of the benchmark's kernel.beam_walk_roofline."""
         src = self.data_score if self.data_score is not None else self.data
         return int(jnp.dtype(src.dtype).itemsize)
-
-    def score_dtype_name(self) -> str:
-        """Peak-selection dtype for the roofline: the matmul dtype the
-        in-loop scoring actually contracts in."""
-        if self.data_score is not None:
-            return "bf16"
-        return ("int8" if jnp.issubdtype(self.data.dtype, jnp.integer)
-                else "f32")
-
-    def walk_iter_cost(self, rows: int, B: int, L: int = 0):
-        """Ledger estimate of ONE walk-body iteration at batch `rows`
-        (the beam.segment family's unit) — shared by the sampled
-        roofline gauges and the scheduler's per-query slow-query
-        attribution.  Pass the pool size `L` so a binned-merge engine
-        prices the binned body; L=0 prices the exact body (the
-        attribution paths that don't know L keep their old estimate)."""
-        return costmodel.estimate(
-            "beam.segment", Q=rows, X=B * self.graph.shape[1],
-            D=self.data.shape[1], W=_num_words(self.n),
-            score_itemsize=self.score_itemsize(),
-            merge_bins=self.merge_bins_for(L, B) if L else 0, L=L,
-            N=self.n, score_scale=self.score_scale,
-            l2=walk_takes_norm(self.metric))
 
     def seed_state(self, queries: jax.Array, L: int,
                    seeds: Optional[jax.Array] = None) -> dict:
@@ -1599,28 +1401,8 @@ class GraphSearchEngine:
             dev_ns = time.monotonic_ns() - t0
             metrics.observe("engine.segment_device_ns", dev_ns)
             rows = int(state["queries"].shape[0])
-            # roofline gauges (ISSUE 6): ledger work x sampled device
-            # time.  S is the segment's iteration CAP, so the estimate
-            # is an upper bound when rows converge mid-segment — the
-            # gauges can overstate achieved rates near a drain tail,
-            # never understate headroom at steady state.
-            est = self.walk_iter_cost(rows, B, L)
-            flops = est.flops * S
-            nbytes = est.hbm_bytes * S
-            dev_s = max(dev_ns, 1) / 1e9
-            metrics.set_gauge("engine.achieved_gflops",
-                              flops / dev_s / 1e9)
-            metrics.set_gauge("engine.achieved_gbps",
-                              nbytes / dev_s / 1e9)
-            pct = (self._capability.pct_of_peak(
-                flops / dev_s, nbytes / dev_s, self.score_dtype_name())
-                if self._capability is not None else None)
-            if pct is not None:
-                metrics.set_gauge("engine.roofline_pct_peak", pct)
             flightrec.record("engine", "segment_device", dur_ns=dev_ns,
-                             payload={"rows": rows, "iters": S,
-                                      "flops": int(flops),
-                                      "bytes": int(nbytes)})
+                             payload={"rows": rows, "iters": S})
         new = dict(state)
         (new["cand_ids"], new["cand_d"], new["expanded"], new["visited"],
          new["no_better"], new["ptr"], new["it"], alive,

@@ -44,7 +44,7 @@ from sptag_tpu.io import format as fmt
 from sptag_tpu.ops import cascade
 from sptag_tpu.ops import distance as dist_ops
 from sptag_tpu.ops import pallas_kernels, topk_bins
-from sptag_tpu.utils import costmodel, devmem, metrics, round_up, trace
+from sptag_tpu.utils import devmem, metrics, round_up, trace
 
 _ROW_PAD = 128      # pad corpus rows to multiples of this (TPU lane width)
 _QUERY_BUCKETS = (1, 8, 32, 128, 512)
@@ -521,7 +521,7 @@ def exact_device_scan(data_d, sqnorm_d, invalid_d, queries: np.ndarray,
     SketchPrefilter never apply here, whatever the index is configured
     to serve with — an oracle that inherited the approximations it is
     supposed to measure would be no oracle at all.  Rides the
-    registered `flat.scan` cost-ledger family (no new jit site)."""
+    `flat.scan` kernel family (no jit site of its own)."""
     q = queries.shape[0]
     k_eff = min(k, data_d.shape[0])
     queries = pad_to_bucket(queries)
@@ -636,147 +636,6 @@ def _flat_sketch_kernel(data, sqnorm, invalid, sketches, mean, queries,
     ids = jnp.take_along_axis(short, pos, axis=1)
     ids = jnp.where(dists >= jnp.float32(MAX_DIST), -1, ids)
     return dists, ids.astype(jnp.int32)
-
-
-# ---------------------------------------------------------------------------
-# cost-ledger entries (utils/costmodel.py; graftlint GL605)
-# ---------------------------------------------------------------------------
-
-def _flat_scan_cost(Q, N, D, k, itemsize=4, binned_bins=0, fused=False,
-                    **_):
-    """Exact scan: one (Q, D) x (N, D) contraction + norms + masked
-    top-k.  Bytes: corpus + queries + norms/tombstones in, results out,
-    plus the materialized (Q, N) score matrix's mask/neg/top-k traffic
-    (the SCAN_MATRIX_TRAFFIC calibration).  With `binned_bins` the
-    selection is the bin reduction, fused into the scan: the contraction
-    writes the (Q, N) matrix and ONE fusion reads it back, masks it and
-    takes min and argmin in a single variadic reduce
-    (_BINNED_MATRIX_TRAFFIC), then the (Q, bins) winner rows go through
-    the shortlist top-k (_BINNED_WINNER_TRAFFIC; the flops are
-    ops/topk_bins.binned_select_cost's).  A row wide enough
-    for the exact branch's two stages (`select_stages`) is costed by
-    `_two_stage_select_cost` instead of the N-wide top-k.  On the `fused`
-    route (`fused_minima`) there is no score matrix to bill: the kernel's
-    own entry (`pallas.scan_group_minima`: the rows once, at their item
-    size) and the select over its minima with the re-scored slabs; the
-    materialised body a float program keeps for an unproved run is not
-    billed (it does not run)."""
-    if fused:
-        # float rows (4 bytes) take the proved form: k + _SPARE_GROUPS
-        # groups a query re-scored, their norms gathered beside them
-        scan = costmodel.estimate("pallas.scan_group_minima", Q=Q, N=N, D=D,
-                                  itemsize=itemsize)
-        sel_f, sel_b = _two_stage_select_cost(
-            Q, N, k + (_SPARE_GROUPS if itemsize == 4 else 0), fused=True,
-            D=D, itemsize=itemsize)
-        return scan.flops + sel_f, scan.hbm_bytes + sel_b + Q * k * 8
-    flops = (costmodel.matmul_flops(Q, N, D) + 2.0 * D * (Q + N)
-             + 2.0 * Q * N)
-    nbytes = N * D * itemsize + Q * D * itemsize + N * 4 + N + Q * k * 8
-    if binned_bins:
-        sel_f, _ = topk_bins.binned_select_cost(Q, N, k, binned_bins)
-        return (flops + sel_f,
-                nbytes + (_BINNED_MATRIX_TRAFFIC * Q * N
-                          + _BINNED_WINNER_TRAFFIC * Q * binned_bins) * 4)
-    if select_stages(Q, N, k) == 2:
-        sel_f, sel_b = _two_stage_select_cost(Q, N, k)
-        return flops + sel_f, nbytes + sel_b
-    return flops, nbytes + costmodel.SCAN_MATRIX_TRAFFIC * Q * N * 4
-
-
-#: cost-analysis traversals of the (Q, N) score matrix under the binned
-#: select (one write by the contraction, one read by the fused mask +
-#: min/argmin reduce) and of the (Q, bins) winner rows (values and columns
-#: written, negated, read by the top-k, columns gathered) — fitted 2.0 and
-#: 5.4 against this container's XLA at bins/N = 1/32 .. 1/8 (the old
-#: 3.2 + 2 traversals read 30-120 % high: the reduce was two passes then)
-_BINNED_MATRIX_TRAFFIC = 2.0
-_BINNED_WINNER_TRAFFIC = 5.4
-
-#: cost-analysis traversals of the (Q, N) score matrix when the exact
-#: selection takes two stages (written and masked through its transpose,
-#: read by the group minima) — fitted 5.1-5.3 against this container's XLA
-_TWO_STAGE_MATRIX_TRAFFIC = 5.2
-
-
-def _two_stage_select_cost(Q, N, k, fused=False, D=0, itemsize=4):
-    """`exact_topk`'s two stages over (Q, N) scores: the group-minimum
-    pass (with the transpose's elementwise ops, fitted 3 an element), the
-    top-k over the (Q, N/_GROUP) minima, the slabs — k groups a query,
-    each fetched for all Q queries, written and read back by the
-    select-reduce that keeps the query's own lane — and the top-k
-    over the k*_GROUP (+ tail) candidates.  A tail costs one more
-    traversal (the whole groups are sliced off it).
-
-    `fused`: stages 2 and 3 alone (`_select_from_groups` over minima the
-    scan wrote; no tail) - the minima read by the top-k, the chosen
-    groups' rows (`D` wide, `itemsize` bytes) gathered and read back by
-    the re-score, the candidates' top-k."""
-    groups, tail = divmod(N, _GROUP)
-    if fused:
-        cand = Q * k * _GROUP
-        flops = (costmodel.topk_flops(Q, groups)
-                 + costmodel.matmul_flops(1, cand, D)
-                 + costmodel.topk_flops(Q, k * _GROUP))
-        return flops, (3.0 * groups * Q * 4 + 2.0 * cand * D * itemsize
-                       + 3.0 * cand * 4)
-    slab = Q * k * _GROUP * Q
-    flops = (3.0 * Q * N + costmodel.topk_flops(Q, groups) + 3.0 * slab
-             + costmodel.topk_flops(Q, k * _GROUP + tail))
-    nbytes = ((_TWO_STAGE_MATRIX_TRAFFIC + (1.0 if tail else 0.0))
-              * Q * N * 4 + 2.0 * slab * 4)
-    return flops, nbytes
-
-
-def _flat_sketch_cost(Q, N, W, R, D, k, itemsize=4, **_):
-    """Sketch prefilter: XOR+popcount Hamming scan over (N, W) packed
-    words, top-R shortlist, exact re-rank of the gathered R rows."""
-    flops = (3.0 * Q * N * W                    # xor + popcount + add
-             + costmodel.topk_flops(Q, N)       # shortlist top-R
-             + costmodel.matmul_flops(Q, R, D)  # exact re-rank
-             + costmodel.topk_flops(Q, R))
-    nbytes = (N * W * 4 + Q * W * 4
-              + costmodel.SCAN_MATRIX_TRAFFIC * Q * N * 4
-              + 2.0 * Q * R * D * itemsize      # gather out + re-read
-              + N * D * itemsize                # gather operand
-              + Q * k * 8)
-    return flops, nbytes
-
-
-def _sketch_cal_cost(S, N, W, D, k, itemsize=4, **_):
-    """Calibration = one exact scan + one Hamming scan over S samples."""
-    f1, b1 = _flat_scan_cost(S, N, D, k, itemsize)
-    flops = f1 + 3.0 * S * N * W
-    nbytes = b1 + N * W * 4 + costmodel.SCAN_MATRIX_TRAFFIC * S * N * 4
-    return flops, nbytes
-
-
-def _pack_bits_cost(R, D, **_):
-    return 3.0 * R * D, R * D * 4 + R * ((D + 31) // 32) * 4
-
-
-def _block_write_cost(R, D, itemsize=4, **_):
-    """In-place write of R rows: the rows in, rows + norms + mask out."""
-    return 2.0 * R * D, 2.0 * R * D * itemsize + R * 5
-
-
-def _block_mask_cost(R, **_):
-    return 0.0, R * 5
-
-
-def _block_grow_cost(N, D, itemsize=4, **_):
-    """A device copy of the whole block: read once, written once."""
-    return 0.0, 2.0 * N * (D * itemsize + 5)
-
-
-costmodel.register("flat.scan", _flat_search_kernel, _flat_scan_cost)
-costmodel.register("flat.block_write", _block_write_rows, _block_write_cost)
-costmodel.register("flat.block_mask", _block_mask_rows, _block_mask_cost)
-costmodel.register("flat.block_grow", _block_grown, _block_grow_cost)
-costmodel.register("flat.sketch_scan", _flat_sketch_kernel,
-                   _flat_sketch_cost)
-costmodel.register("flat.sketch_cal", _sketch_cal_kernel, _sketch_cal_cost)
-costmodel.register("flat.pack_bits", _pack_sign_bits, _pack_bits_cost)
 
 
 @register_algo

@@ -192,33 +192,6 @@ class _SlotPool:
         self.entries: List[Optional[_Item]] = []
         self.state: Dict[str, np.ndarray] = {}
         self.t_limit = np.zeros((0,), np.int32)
-        self._iter_cost1 = None      # lazy one-row walk-iteration cost
-
-    def iter_cost1(self):
-        """Ledger cost of ONE walk iteration for ONE query in this pool
-        (slow-query roofline attribution); None when the engine predates
-        the cost ledger or the family is unregistered.  Estimated at the
-        pool's slot count and divided down: the binned body's byte
-        formula carries a per-DISPATCH corpus-operand term (N*D) that a
-        Q=1 estimate would charge in full to every query."""
-        if self._iter_cost1 is None:
-            try:
-                # max_slots, not capacity: the amortization base must be
-                # stable across grow/compact cycles (the cost is cached
-                # once).  A `self.slots` typo here once raised
-                # AttributeError into the broad except below, silently
-                # disabling gflops= attribution forever (ISSUE 15
-                # satellite root-cause; regression-pinned in
-                # tests/test_roofline.py)
-                rows = max(int(self.max_slots), 1)
-                est = self.engine.walk_iter_cost(rows, self.B, self.L)
-                from sptag_tpu.utils.costmodel import CostEstimate
-
-                self._iter_cost1 = CostEstimate(
-                    est.family, est.flops / rows, est.hbm_bytes / rows)
-            except Exception:                             # noqa: BLE001
-                self._iter_cost1 = False
-        return self._iter_cost1 or None
 
     # ---- state plumbing ---------------------------------------------------
 
@@ -618,15 +591,9 @@ class BeamSlotScheduler:
                 d, ids = engine.finalize(sub, pool.k_eff)
             t_done = time.perf_counter()
             items = [pool.entries[i] for i in done]
-            # per-query roofline attribution (ISSUE 6 satellite): the
-            # row's own iteration count x the one-row ledger cost over
-            # its RESIDENT time classifies a slow query as compute-,
-            # bandwidth- or scheduling-bound right in the log line.
             # np.max covers the mesh layout ((cap, n_shards) counters —
             # device residency tracks the slowest shard's walk)
             iters_done = [int(np.max(pool.state["it"][i])) for i in done]
-            cost1 = pool.iter_cost1()
-            cap = getattr(engine, "_capability", None)
             for i in done:
                 pool.entries[i] = None
             # publish EVERY observation for the retiring queries BEFORE
@@ -653,12 +620,11 @@ class BeamSlotScheduler:
                     # input (utils/qualmon.py classify_low_recall):
                     # iters == budget means the walk was CUT OFF by
                     # MaxCheck ("beam terminated early"), so both ride
-                    # the stats unconditionally, not only when the cost
-                    # ledger resolves
+                    # the stats.
                     # _replace=True: retire OWNS the query lifecycle —
                     # a client-reused rid must not inherit the previous
-                    # query's verdict/roofline keys (flightrec merge
-                    # semantics; later annotators like qualmon merge)
+                    # query's verdict keys (flightrec merge semantics;
+                    # later annotators like qualmon merge)
                     stats = dict(
                         _replace=True,
                         slot_wait_ms=round(item.slot_wait * 1000.0, 3),
@@ -677,19 +643,6 @@ class BeamSlotScheduler:
                             stats["shard_imbalance"] = round(
                                 float(row_it.max()) / row_mean, 3)
                             stats["slow_shard"] = int(row_it.argmax())
-                    if cost1 is not None:
-                        it_n = iters_done[j]
-                        exec_s = max(t_done - item.t_enq - item.slot_wait,
-                                     1e-9)
-                        q_flops = cost1.flops * it_n
-                        q_bytes = cost1.hbm_bytes * it_n
-                        stats["gflops"] = round(q_flops / exec_s / 1e9, 3)
-                        if cap is not None:
-                            pct = cap.pct_of_peak(
-                                q_flops / exec_s, q_bytes / exec_s,
-                                engine.score_dtype_name())
-                            if pct is not None:
-                                stats["pct_peak"] = round(pct, 4)
                     flightrec.note_query_stats(item.rid, **stats)
             for j, item in enumerate(items):
                 if not item.future.done():

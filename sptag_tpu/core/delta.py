@@ -22,10 +22,8 @@ freshest rows is cheap enough to merge into EVERY query:
   off-thread and atomically swaps a new engine in, advancing
   ``base_id`` — the shard never grows past its bound.
 
-The scan rides :func:`sptag_tpu.algo.flat.exact_device_scan` — the
-registered ``flat.scan`` cost-ledger family, so delta device work is
-accounted like every other dispatch and GL605 holds with no new jit
-site.
+The scan rides :func:`sptag_tpu.algo.flat.exact_device_scan`: the
+``flat.scan`` kernel family, no jit site of the shard's own.
 """
 
 from __future__ import annotations

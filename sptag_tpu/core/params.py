@@ -180,13 +180,6 @@ _COMMON_TAIL_SPECS = [
     # are reproducible).
     _spec("flight_device_sample_rate", float, 0.0, "FlightDeviceSampleRate"),
     _spec("flight_dump_on_slow_query", str, "", "FlightDumpOnSlowQuery"),
-    # roofline observability (ISSUE 6, utils/roofline.py): permit the
-    # disk-cached measured micro-probe (matmul peak + copy bandwidth) on
-    # cpu/gpu/unknown device kinds, so %-of-peak gauges exist off-TPU.
-    # Known TPU generations resolve from the static capability table
-    # either way; 0 (default) never runs probe device work.  Baked into
-    # the engine snapshot (it resolves capability at materialization).
-    _spec("roofline_probe", int, 0, "RooflineProbe"),
     # device-memory ledger (utils/devmem.py): 0 disables the resident-
     # bytes accounting behind memory.device_bytes / GET /debug/memory.
     # Process-wide, applied at set_parameter time; the ledger never
@@ -201,7 +194,7 @@ _COMMON_TAIL_SPECS = [
     # QualityRecallFloor: a sampled recall below this triggers triage
     # (verdict in the slow-query stats + flight dump);
     # QualityShadowBudget: GFLOP/s ceiling on shadow-scan device work
-    # (cost-ledger estimated; 0 = unbudgeted); QualityWindow: sliding-
+    # (2 x rows x dim flops a replay; 0 = unbudgeted); QualityWindow: sliding-
     # window length in samples for the recall gauges (0 = default 256)
     _spec("quality_sample_rate", float, 0.0, "QualitySampleRate"),
     _spec("quality_recall_floor", float, 0.0, "QualityRecallFloor"),
@@ -503,9 +496,7 @@ class FlatParams(ParamSet):
         _spec("tier_budget_sketch", int, 0, "TierBudgetSketch"),
         _spec("tier_budget_int8", int, 0, "TierBudgetInt8"),
         _spec("corpus_tier", str, "device", "CorpusTier"),
-        # roofline/memory/quality observability knobs; see
-        # _COMMON_TAIL_SPECS
-        _spec("roofline_probe", int, 0, "RooflineProbe"),
+        # memory/quality observability knobs; see _COMMON_TAIL_SPECS
         _spec("device_bytes_ledger", int, 1, "DeviceBytesLedger"),
         _spec("quality_sample_rate", float, 0.0, "QualitySampleRate"),
         _spec("quality_recall_floor", float, 0.0, "QualityRecallFloor"),

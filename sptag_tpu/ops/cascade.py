@@ -53,7 +53,7 @@ import numpy as np
 from sptag_tpu.core.types import DistCalcMethod
 from sptag_tpu.ops import distance as dist_ops
 from sptag_tpu.ops.topk_bins import pow2ceil
-from sptag_tpu.utils import costmodel, devmem, metrics
+from sptag_tpu.utils import devmem, metrics
 
 MAX_DIST = np.float32(3.4e38)   # plain scalar: import must NOT init a backend
 
@@ -259,7 +259,7 @@ def rerank_gathered(queries, rows, ids, k: int, metric: int, base: int):
 
 
 # ---------------------------------------------------------------------------
-# jitted kernels (costmodel-registered; GL605)
+# jitted kernels
 # ---------------------------------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("k", "b1", "b2", "metric",
@@ -400,151 +400,6 @@ def _host_scan_block_kernel(rows, dead, queries, k: int, metric: int,
     d = jnp.where(dead[None, :], jnp.float32(MAX_DIST), d)
     neg, idx = jax.lax.top_k(-d, k)
     return -neg, idx.astype(jnp.int32)
-
-
-# ---------------------------------------------------------------------------
-# cost-ledger entries (utils/costmodel.py; graftlint GL605)
-# ---------------------------------------------------------------------------
-
-# Calibration note (the ledger's contract, utils/costmodel.py): the
-# constants below were FITTED against this container's HloCostAnalysis
-# at three shapes each (the same procedure as WALK_SORT_* / the
-# SCAN_MATRIX_TRAFFIC constants) and are pinned ±15% by
-# tests/test_cascade.py.  The int8/fp re-rank byte constants carry the
-# int32/f32 cast materializations XLA counts around the s8 contraction
-# (a (Q, b1, D) int8 gather is re-read as int32 twice and squared once
-# — the cast copies, not the int8 bytes, dominate).  Fit domain D >= 64
-# (at D = 32 XLA fuses the small contractions differently; the 15%
-# tolerance does not hold there and real corpora sit well above it).
-
-#: per-(Q·N·W) flops of one Hamming word pass (xor+popcount+add) plus
-#: the per-(Q·N) sort/top-k ensemble of the sketch shortlist
-SKETCH_WORD_FLOPS = 5.0
-SKETCH_SELECT_FLOPS = 12.75
-#: per-(Q·N) bytes of the Hamming scan + shortlist sort (re-fitted
-#: 24.3-25.3 at eight shapes of the standalone kernel; the 18.0 it
-#: replaces read 26-28 % low there and 6-11 % low in the fused programs)
-SKETCH_TRAFFIC = 25.0
-#: per-element flops/bytes of the gathered s8×s8→s32 re-rank (cast
-#: copies included)
-INT8_RERANK_FLOPS = 6.25
-INT8_RERANK_TRAFFIC = 18.5
-#: per-element flops/bytes of the gathered exact fp re-rank (bytes
-#: re-fitted 16.5-17.0 at five shapes of the standalone kernel; 20.7 read
-#: 22-25 % high there)
-FP_RERANK_FLOPS = 4.2
-FP_RERANK_TRAFFIC = 16.8
-
-
-def _sketch_stage_cost(Q, N, W, b1):
-    flops = Q * N * (SKETCH_WORD_FLOPS * W + SKETCH_SELECT_FLOPS)
-    nbytes = SKETCH_TRAFFIC * Q * N + N * W * 4 + Q * b1 * 4
-    return flops, nbytes
-
-
-def _int8_gather_stage_cost(Q, D, b1, b2):
-    flops = INT8_RERANK_FLOPS * Q * b1 * D
-    nbytes = INT8_RERANK_TRAFFIC * Q * b1 * D + Q * b2 * 4
-    return flops, nbytes
-
-
-def _int8_full_stage_cost(Q, N, D, b2):
-    flops = costmodel.matmul_flops(Q, N, D) + 16.0 * Q * N
-    nbytes = 13.0 * Q * N + 19.0 * N * D + Q * b2 * 4
-    return flops, nbytes
-
-
-def _fp_stage_cost(Q, D, b2, k):
-    flops = FP_RERANK_FLOPS * Q * b2 * D
-    nbytes = FP_RERANK_TRAFFIC * Q * b2 * D + Q * k * 8
-    return flops, nbytes
-
-
-def _cascade_search_cost(Q, N, W, D, b1, b2, k, use_sketch=True,
-                         use_int8=True, **_):
-    """Fused device-tier cascade: sum of the composed stage costs plus
-    the in-program gather OPERANDS (int8 corpus once, fp corpus once —
-    the stage constants price the gathered-rows traffic, the operand
-    arrays are what the fused program additionally touches)."""
-    flops = nbytes = 0.0
-    if use_sketch:
-        f, b = _sketch_stage_cost(Q, N, W, b1)
-        flops, nbytes = flops + f, nbytes + b
-        if use_int8:
-            f, b = _int8_gather_stage_cost(Q, D, b1, b2)
-            flops, nbytes = flops + f, nbytes + b + N * D
-    elif use_int8:
-        f, b = _int8_full_stage_cost(Q, N, D, b2)
-        flops, nbytes = flops + f, nbytes + b
-    else:
-        # degenerate both-tiers-off config: the exact masked fp scan
-        return _host_scan_block_cost(Q, N, D, k)
-    r = b2 if use_int8 else b1
-    f, b = _fp_stage_cost(Q, D, r, k)
-    return flops + f, nbytes + b + 4.0 * N * D
-
-
-def _cascade_shortlist_cost(Q, N, W, D, b1, b2, use_sketch=True, **_):
-    if use_sketch:
-        f1, n1 = _sketch_stage_cost(Q, N, W, b1)
-        f2, n2 = _int8_gather_stage_cost(Q, D, b1, b2)
-        return f1 + f2, n1 + n2 + N * D
-    return _int8_full_stage_cost(Q, N, D, b2)
-
-
-def _sketch_shortlist_cost(Q, N, W, b1, **_):
-    return _sketch_stage_cost(Q, N, W, b1)
-
-
-def _int8_rerank_cost(Q, D, b1, b2, **_):
-    return _int8_gather_stage_cost(Q, D, b1, b2)
-
-
-def _fp_rerank_cost(Q, D, b2, k, **_):
-    return _fp_stage_cost(Q, D, b2, k)
-
-
-def _cascade_tiers_cost(Q, N, W, D, b1, b2, use_sketch=True,
-                        use_int8=True, **_):
-    return _cascade_shortlist_cost(Q, N, W, D, b1, b2,
-                                   use_sketch=use_sketch)
-
-
-def _host_scan_block_cost(Q, R, D, k, **_):
-    """Exact masked scan of one (R, D) block: the (Q, R) scores are
-    traversed three times (12 bytes an element), the rows about four
-    (contraction + sqnorms), the queries four — fitted at eight shapes,
-    Q 1-128, R 1024-4096, D 64-128, within 0.7 % (16 / 19 / 0 read
-    15-21 % high)."""
-    flops = costmodel.matmul_flops(Q, R, D) + 10.0 * Q * R
-    nbytes = 12.0 * Q * R + 16.4 * R * D + 16.0 * Q * D + Q * k * 8
-    return flops, nbytes
-
-
-costmodel.register("cascade.search", _cascade_search_kernel,
-                   _cascade_search_cost)
-costmodel.register("cascade.shortlist", _cascade_shortlist_kernel,
-                   _cascade_shortlist_cost)
-costmodel.register("cascade.sketch_shortlist", _sketch_shortlist_kernel,
-                   _sketch_shortlist_cost)
-costmodel.register("cascade.int8_rerank", _int8_rerank_kernel,
-                   _int8_rerank_cost)
-costmodel.register("cascade.rerank", _fp_rerank_kernel, _fp_rerank_cost)
-
-
-def _fp_rerank_resident_cost(Q, N, D, b2, k, **_):
-    f, b = _fp_stage_cost(Q, D, b2, k)
-    # in-program gather: corpus operand + the materialized (Q, b2, D)
-    # gather output (the operand-fed kernel receives it pre-gathered)
-    return f, b + 4.0 * N * D + 4.0 * Q * b2 * D
-
-
-costmodel.register("cascade.rerank_resident", _fp_rerank_resident_kernel,
-                   _fp_rerank_resident_cost)
-costmodel.register("cascade.tiers", _cascade_tiers_kernel,
-                   _cascade_tiers_cost)
-costmodel.register("cascade.host_scan", _host_scan_block_kernel,
-                   _host_scan_block_cost)
 
 
 def gather_host_rows(fp_host: np.ndarray, ids: np.ndarray):
@@ -815,14 +670,6 @@ def _pack_sketches_jit(int8_data, scale, mean):
     program at build; the fp corpus itself never has to be resident."""
     return pack_sign_bits(int8_data.astype(jnp.float32) * scale
                           - mean[None, :])
-
-
-def _pack_sketches_cost(N, D, **_):
-    return 5.0 * N * D, N * D + N * ((D + 31) // 32) * 4 + D * 4
-
-
-costmodel.register("cascade.pack_sketches", _pack_sketches_jit,
-                   _pack_sketches_cost)
 
 
 # ---------------------------------------------------------------------------

@@ -44,7 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from sptag_tpu.core.types import DistCalcMethod, VectorValueType, base_of
-from sptag_tpu.utils import costmodel, host_cores
+from sptag_tpu.utils import host_cores
 
 # Values considered "integer typed" for the base^2 - dot convention.
 _INT_DTYPES = (jnp.int8, jnp.uint8, jnp.int16)
@@ -410,21 +410,3 @@ def batch_topk(dists: jax.Array, k: int):
     """(Q, N) distances -> ((Q, k) dists ascending, (Q, k) int32 indices)."""
     neg, idx = jax.lax.top_k(-dists, k)
     return -neg, idx.astype(jnp.int32)
-
-
-# ---------------------------------------------------------------------------
-# cost-ledger entries (utils/costmodel.py; graftlint GL605)
-# ---------------------------------------------------------------------------
-
-def _batch_topk_cost(Q, N, k, **_):
-    flops = costmodel.topk_flops(Q, N) + 2.0 * Q * N     # two negations
-    nbytes = 3.0 * Q * N * 4 + Q * k * 8
-    return flops, nbytes
-
-
-def _row_sqnorms_cost(N, D, itemsize=4, **_):
-    return 2.0 * N * D, N * D * itemsize + N * 4
-
-
-costmodel.register("distance.batch_topk", batch_topk, _batch_topk_cost)
-costmodel.register("distance.row_sqnorms", row_sqnorms, _row_sqnorms_cost)

@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from sptag_tpu.utils import costmodel
 
 MAX_DIST = np.float32(3.4e38)   # plain scalar: module import must NOT init a backend
 
@@ -209,44 +208,3 @@ def rng_select(node_vecs: jax.Array, cand_vecs: jax.Array,
         out = jnp.concatenate(
             [out, jnp.full((B, m - k), -1, jnp.int32)], axis=1)
     return out
-
-
-# ---------------------------------------------------------------------------
-# cost-ledger entries (utils/costmodel.py; graftlint GL605).  Build-time
-# kernels: the formulas carry the dominant contraction terms so build
-# phases appear in perf reports with honest magnitudes; the XLA
-# cross-check acceptance bar applies to the SERVING families
-# (flat/dense/beam) — see DESIGN.md §12.
-# ---------------------------------------------------------------------------
-
-def _leaf_allpairs_cost(B, P, D, num_candidates, **_):
-    flops = 2.0 * B * P * P * D + costmodel.topk_flops(B * P, P)
-    nbytes = 2.0 * B * P * D * 4 + 3.0 * B * P * P * 4 \
-        + 2.0 * B * P * num_candidates * 4
-    return flops, nbytes
-
-
-def _merge_candidates_cost(N, C, **_):
-    flops = 64.0 * N * C          # three sorts + dedupe + top-k
-    nbytes = 12.0 * N * C * 4
-    return flops, nbytes
-
-
-def _node_candidate_dists_cost(U, C, D, **_):
-    return 2.0 * U * C * D, 2.0 * U * C * D * 4 + U * C * 4
-
-
-def _rng_select_cost(B, C, D, m, **_):
-    steps = min(m, C)
-    flops = 2.0 * B * C * D * steps + 8.0 * B * C * steps
-    nbytes = B * C * D * 4 + 8.0 * B * C * 4 * steps
-    return flops, nbytes
-
-
-costmodel.register("graph.leaf_allpairs", leaf_allpairs_topk,
-                   _leaf_allpairs_cost)
-costmodel.register("graph.merge_candidates", merge_candidates,
-                   _merge_candidates_cost)
-costmodel.register("graph.node_candidate_dists", node_candidate_dists,
-                   _node_candidate_dists_cost)
-costmodel.register("graph.rng_select", rng_select, _rng_select_cost)

@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from sptag_tpu.utils import costmodel
 
 MAX_DIST = np.float32(3.4e38)   # plain scalar: module import must NOT init a backend
 
@@ -159,27 +158,3 @@ def kmeans_final_assign(data: jax.Array, valid: jax.Array,
     medoid_pos = jnp.where(counts > 0, medoid_pos, -1)
     labels = jnp.where(valid, labels, -1)
     return labels, counts, medoid_pos
-
-
-# ---------------------------------------------------------------------------
-# cost-ledger entries (utils/costmodel.py; graftlint GL605) — build-time
-# kernels, count-body-once convention for the Lloyd loop (DESIGN.md §12)
-# ---------------------------------------------------------------------------
-
-def _kmeans_fit_cost(B, P, D, K, restarts, **_):
-    assign = 2.0 * B * P * K * D + 4.0 * B * P * K
-    flops = (restarts + 1.0) * assign + 2.0 * B * K * D
-    nbytes = (restarts + 2.0) * (B * P * D * 4 + B * P * K * 4) \
-        + 2.0 * B * K * D * 4
-    return flops, nbytes
-
-
-def _kmeans_assign_cost(B, P, D, K, **_):
-    flops = 2.0 * B * P * K * D + 6.0 * B * P * K
-    nbytes = B * P * D * 4 + B * K * D * 4 + 5.0 * B * P * K * 4
-    return flops, nbytes
-
-
-costmodel.register("kmeans.fit", kmeans_fit, _kmeans_fit_cost)
-costmodel.register("kmeans.final_assign", kmeans_final_assign,
-                   _kmeans_assign_cost)

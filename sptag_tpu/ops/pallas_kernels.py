@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 
 from sptag_tpu.core.index import MAX_DIST
-from sptag_tpu.utils import costmodel
 
 _INTERPRET = False        # tests may flip this to run on CPU
 
@@ -423,46 +422,3 @@ def l2_minima_eps(d: int, qnorm: jax.Array, sqnorm: jax.Array) -> jax.Array:
     the comparison `m - eps > v` its own rounding."""
     bound = jnp.float32((4 * d + 32) * 2.0 ** -24)
     return bound * (qnorm + jnp.max(sqnorm))
-
-
-# ---------------------------------------------------------------------------
-# cost-ledger entries (utils/costmodel.py; graftlint GL605).  The Pallas
-# kernels stream blocks through VMEM, so bytes here are the TRUE block
-# traffic (no materialized intermediate) — the whole point of the DMA
-# formulation (DESIGN.md §12).
-# ---------------------------------------------------------------------------
-
-def _probe_block_cost(Q, nprobe, P, D, itemsize=4, **_):
-    flops = 2.0 * Q * nprobe * P * D
-    nbytes = (Q * nprobe * P * D * itemsize + Q * D * itemsize
-              + Q * nprobe * P * 4)
-    return flops, nbytes
-
-
-def _group_block_cost(NG, U, G, P, D, itemsize=4, **_):
-    flops = 2.0 * NG * U * G * P * D
-    nbytes = (NG * U * P * D * itemsize + NG * G * D * itemsize
-              + NG * U * G * P * 4)
-    return flops, nbytes
-
-
-def _scan_group_minima_cost(Q, N, D, itemsize=1, **_):
-    """The rows once at their item size, the mask (read as bytes, written
-    and read again as int32; float rows: the norms read beside it), the
-    queries, the (N/128, Q) minima (float rows: read and written once
-    more for `|q|^2`); the contraction, and a cap and a running maximum a
-    score.  No score matrix."""
-    floats = itemsize == 4
-    flops = 2.0 * Q * N * D + 2.0 * Q * N
-    nbytes = (N * D * itemsize + (13 if floats else 9) * N
-              + Q * D * itemsize
-              + N // SCAN_GROUP * Q * 4 * (3 if floats else 1))
-    return flops, nbytes
-
-
-costmodel.register("pallas.scan_group_minima", scan_group_minima,
-                   _scan_group_minima_cost)
-costmodel.register("pallas.probe_block_dots", probe_block_dots,
-                   _probe_block_cost)
-costmodel.register("pallas.group_block_dots", group_block_dots,
-                   _group_block_cost)

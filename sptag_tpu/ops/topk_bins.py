@@ -45,7 +45,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from sptag_tpu.utils import costmodel
 
 MAX_DIST = np.float32(3.4e38)   # plain scalar: module import must NOT init a backend
 
@@ -209,28 +208,3 @@ def binned_topk_kernel(d: jax.Array, k: int, bins: int
     perf probe); the scan/walk kernels compose the traceable helpers
     inline instead."""
     return binned_topk(d, k, bins)
-
-
-# ---------------------------------------------------------------------------
-# cost-ledger entry (utils/costmodel.py; graftlint GL605)
-# ---------------------------------------------------------------------------
-
-def binned_select_cost(Q, W, k, bins, **_):
-    """One bin reduction + the bins-wide exact top-k: the O(W) min/argmin
-    pass (2 compare-ops per element under HloCostAnalysis — min and
-    argmin are separate reductions), the winner-column arithmetic, and
-    `topk_flops` over the shortlist.  Bytes: the padded row read twice
-    (min + argmin), the (Q, bins) winner row's write/read traffic, and
-    the (Q, k) result."""
-    W_pad = (-(-W // bins)) * bins
-    flops = (2.0 * Q * W_pad                    # min + argmin reductions
-             + 2.0 * Q * bins                   # column arithmetic
-             + costmodel.topk_flops(Q, bins))
-    nbytes = (2.0 * Q * W_pad * 4               # row read by both reductions
-              + 6.0 * Q * bins * 4              # winners written + re-read
-              + Q * k * 8)
-    return flops, nbytes
-
-
-costmodel.register("ops.binned_topk", binned_topk_kernel,
-                   binned_select_cost)

@@ -44,19 +44,14 @@ from jax.sharding import Mesh, PartitionSpec as P
 from sptag_tpu.algo.engine import (
     _VISITED_BUDGET,
     _finalize,
-    _finalize_cost,
     _init_walk_state,
-    _num_words,
     _seed_from_pivots,
-    _seed_pivot_cost,
-    _walk_iter_cost,
     _walk_machine,
     beam_pool_size,
     beam_width_for,
-    walk_takes_norm,
 )
 from sptag_tpu.ops import topk_bins
-from sptag_tpu.utils import costmodel, recompile_guard, roofline
+from sptag_tpu.utils import recompile_guard
 
 SHARD_AXIS = "shard"
 
@@ -205,46 +200,6 @@ def _mesh_finalize_kernel(data, sqnorm, deleted, queries, cand_ids,
     )(data, sqnorm, deleted, queries, cand_ids, cand_d)
 
 
-# ---------------------------------------------------------------------------
-# cost-ledger entries (utils/costmodel.py; graftlint GL605 covers parallel/)
-# ---------------------------------------------------------------------------
-#
-# Shard-parallel kernels: per-shard work happens on every shard at once,
-# so the LEDGER cost (total device work per dispatch) is n_dev x the
-# single-chip formula at the per-shard shapes; the finalize adds the
-# merge collective's all-gather traffic + replicated global top-k.
-
-def _mesh_seed_cost(Q, P, D, L, W, n_dev, **_):
-    f, b = _seed_pivot_cost(Q, P, D, L, W)
-    return n_dev * f, n_dev * b
-
-
-def _mesh_segment_cost(Q, X, D, W, n_dev, score_itemsize=4,
-                       merge_bins=0, L=0, N=0, score_scale=0, l2=True, **_):
-    f, b = _walk_iter_cost(Q, X, D, W, score_itemsize,
-                           merge_bins=merge_bins, L=L, N=N,
-                           score_scale=score_scale, l2=l2)
-    return n_dev * f, n_dev * b
-
-
-def _mesh_finalize_cost(Q, L, D, N, k_local, k_final, n_dev,
-                        rerank=False, **_):
-    # THE one merge-cost formula lives in sharded.py (the monolithic
-    # kernels share the same all-gather + replicated-top-k collective)
-    from sptag_tpu.parallel.sharded import _sharded_merge_cost
-
-    f, b = _finalize_cost(Q, L, D, N, rerank=rerank)
-    mf, mb = _sharded_merge_cost(Q, k_local, k_final, n_dev)
-    return n_dev * f + mf, n_dev * b + mb
-
-
-costmodel.register("sharded.seed", _mesh_seed_kernel, _mesh_seed_cost)
-costmodel.register("sharded.segment", _mesh_segment_kernel,
-                   _mesh_segment_cost)
-costmodel.register("sharded.finalize", _mesh_finalize_kernel,
-                   _mesh_finalize_cost)
-
-
 class MeshGraphEngine:
     """`BeamSlotScheduler`-drivable engine over a `ShardedBKTIndex`.
 
@@ -259,7 +214,7 @@ class MeshGraphEngine:
     the monolithic mesh search instead.
     """
 
-    def __init__(self, sharded, roofline_probe: bool = False):
+    def __init__(self, sharded):
         self._sharded = sharded
         # BinnedTopK rides the shard params (the same engine-baked knob
         # the single-chip engine resolves); one shared rule per site —
@@ -290,11 +245,6 @@ class MeshGraphEngine:
         self.pivot_ids = sharded.pivot_ids
         self.pivot_vecs = sharded.pivot_vecs
         self.pivot_mask = sharded.pivot_mask
-        try:
-            self._capability = roofline.capability(
-                probe=bool(roofline_probe))
-        except Exception:                               # noqa: BLE001
-            self._capability = None
 
     # ---- scheduler surface (GraphSearchEngine contract) -------------------
 
@@ -342,29 +292,6 @@ class MeshGraphEngine:
     def finalize_bins_for(self, k_local: int, L: int) -> int:
         return topk_bins.resolve_bins(self.binned_mode, k_local, L,
                                       self.recall_target)
-
-    def score_itemsize(self) -> int:
-        src = self.data_score if self.data_score is not None else self.data
-        return int(jnp.dtype(src.dtype).itemsize)
-
-    def score_dtype_name(self) -> str:
-        src = self.data_score if self.data_score is not None else self.data
-        return ("int8" if jnp.issubdtype(src.dtype, jnp.integer)
-                else "f32")
-
-    def walk_iter_cost(self, rows: int, B: int, L: int = 0):
-        """Total mesh device work of ONE walk iteration at batch `rows`
-        (every shard walks simultaneously) — the scheduler's per-query
-        roofline attribution unit.  `L` prices the binned body when the
-        engine runs BinnedTopK (same contract as the single-chip
-        engine's walk_iter_cost)."""
-        return costmodel.estimate(
-            "sharded.segment", Q=rows, X=B * self.graph.shape[1],
-            D=self.data.shape[1], W=_num_words(self.n_local),
-            n_dev=self.n_shards, score_itemsize=self.score_itemsize(),
-            merge_bins=self.merge_bins_for(L, B) if L else 0, L=L,
-            N=self.n_local, score_scale=self.score_scale,
-            l2=walk_takes_norm(self.metric))
 
     def seed_state(self, queries: jax.Array, L: int,
                    seeds: Optional[jax.Array] = None) -> dict:

@@ -38,7 +38,7 @@ from sptag_tpu.core.index import MAX_DIST
 from sptag_tpu.core.types import DistCalcMethod, value_type_of
 from sptag_tpu.ops import distance as dist_ops
 from sptag_tpu.ops import topk_bins
-from sptag_tpu.utils import (costmodel, devmem, locksan, metrics, round_up,
+from sptag_tpu.utils import (devmem, locksan, metrics, round_up,
                              trace)
 
 SHARD_AXIS = "shard"
@@ -814,58 +814,6 @@ def _sharded_dense_kernel(data_perm, member_ids, member_sq, centroids,
         check_vma=False,
     )(data_perm, member_ids, member_sq, centroids, cent_sq, cent_valid,
       deleted, queries)
-
-
-# ---------------------------------------------------------------------------
-# cost-ledger entries (utils/costmodel.py; graftlint GL605 covers parallel/)
-# ---------------------------------------------------------------------------
-#
-# Shard-parallel dispatch: every shard runs the per-shard formula at the
-# SHARD shapes simultaneously, so total device work per dispatch is
-# n_dev x the single-chip cost, plus the ICI merge (all-gather of every
-# shard's (dist, gid) top-k_local + the replicated global top-k_final).
-
-def _sharded_merge_cost(Q, k_local, k_final, n_dev):
-    gathered = Q * n_dev * k_local
-    flops = n_dev * (costmodel.topk_flops(Q, gathered)
-                     + 2.0 * Q * k_final)
-    nbytes = n_dev * (2.0 * gathered * 8 + Q * k_final * 8)
-    return flops, nbytes
-
-
-def _sharded_flat_cost(Q, N_local, D, k_local, k_final, n_dev,
-                       itemsize=4, **_):
-    from sptag_tpu.algo.flat import _flat_scan_cost
-
-    f, b = _flat_scan_cost(Q, N_local, D, k_local, itemsize)
-    mf, mb = _sharded_merge_cost(Q, k_local, k_final, n_dev)
-    return n_dev * f + mf, n_dev * b + mb
-
-
-def _sharded_beam_cost(Q, P, X, D, L, W, N_local, k_local, k_final,
-                       n_dev, **_):
-    from sptag_tpu.algo.engine import _walk_full_cost
-
-    f, b = _walk_full_cost(Q, P, X, D, L, W, N_local)
-    mf, mb = _sharded_merge_cost(Q, k_local, k_final, n_dev)
-    return n_dev * f + mf, n_dev * b + mb
-
-
-def _sharded_dense_cost(Q, C, Pb, D, nprobe, k_local, k_final, n_dev,
-                        itemsize=4, **_):
-    from sptag_tpu.algo.dense import _dense_scan_cost
-
-    f, b = _dense_scan_cost(Q, C, Pb, D, nprobe, k_local, itemsize)
-    mf, mb = _sharded_merge_cost(Q, k_local, k_final, n_dev)
-    return n_dev * f + mf, n_dev * b + mb
-
-
-costmodel.register("sharded.flat_scan", _sharded_search_kernel,
-                   _sharded_flat_cost)
-costmodel.register("sharded.beam_walk", _sharded_beam_kernel,
-                   _sharded_beam_cost)
-costmodel.register("sharded.dense_scan", _sharded_dense_kernel,
-                   _sharded_dense_cost)
 
 
 @locksan.race_track

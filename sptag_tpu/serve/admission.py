@@ -10,11 +10,9 @@ This controller closes that gap with the classic three-state ladder:
 * ``degrade`` — admit, but clamp the query's device budget: per-query
   MaxCheck is clamped down toward ``DegradeMaxCheckFloor`` and oversized
   k toward the service default, so each admitted query costs a bounded,
-  PREDICTABLE amount of device time (the cost ledger prices a MaxCheck
-  step in GFLOPs — the TPU-KNN framing is what makes "shed compute, not
-  queries" a principled knob).  Degraded responses carry the
-  ``degraded`` marker trailer (serve/wire.py) so clients KNOW recall was
-  traded for survival;
+  PREDICTABLE amount of device time ("shed compute, not queries").
+  Degraded responses carry the ``degraded`` marker trailer
+  (serve/wire.py) so clients KNOW recall was traded for survival;
 * ``shed`` — reject at the socket edge with a distinct status
   (``ResultStatus.Overloaded``) BEFORE the request body is decoded —
   under real overload, decode cost is the attack surface.

@@ -1224,15 +1224,6 @@ class SearchServer:
             sched = ("slot_wait=%.2fms segments=%d refills=%d" % (
                 st.get("slot_wait_ms", 0.0), st.get("segments", 0),
                 st.get("refills", 0))) if st else "sched=-"
-            if st and "gflops" in st:
-                # roofline attribution (ISSUE 6 satellite): achieved
-                # GFLOP/s and %-of-peak over the query's own segments
-                # classify the slowness — low pct at high gflops means
-                # bandwidth-bound, low both with high slot_wait means
-                # scheduling-bound, high pct means genuinely compute-big
-                sched += " gflops=%.2f" % st["gflops"]
-                if "pct_peak" in st:
-                    sched += " pct_peak=%.3f" % st["pct_peak"]
             if self._controller is not None:
                 # ISSUE 17: which controller state served this query —
                 # lines up a slow query against the actuation history
@@ -1277,27 +1268,17 @@ class SearchServer:
         (bounded, drop-on-overflow — never blocks the loop).  The job
         captures only host data (query text + served ids/dists); the
         exact-scan device work is charged against QualityShadowBudget
-        via the cost ledger's flat.scan estimate at the real shapes."""
+        as 2 x num_samples x feature_dim flops a served index: the
+        dots of one exact scan of one query."""
         served = [(r.index_name, [int(v) for v in r.ids],
                    [float(d) for d in r.dists]) for r in result.results]
         if not served:
             return
         est = 0.0
-        for name, ids, _d in served:
+        for name, _ids, _d in served:
             index = self.context.indexes.get(name)
-            if index is None:
-                continue
-            try:
-                from sptag_tpu.utils import costmodel
-
-                est += costmodel.estimate(
-                    "flat.scan", Q=1, N=index.num_samples,
-                    D=index.feature_dim, k=max(1, len(ids))).flops
-            except Exception:                            # noqa: BLE001
-                # estimate failure degrades to an unbudgeted (but still
-                # queue-bounded) submit — visible, never fatal
-                log.debug("quality shadow cost estimate failed for %s",
-                          name, exc_info=True)
+            if index is not None:
+                est += 2.0 * index.num_samples * index.feature_dim
         qualmon.submit(
             functools.partial(_shadow_replay, self.context, rid, text,
                               served),

@@ -1,9 +1,8 @@
 """Device-memory ledger — who is holding the HBM.
 
-Third pillar of the roofline-observability subsystem (ISSUE 6): every
-long-lived device allocation the index stack makes — corpus snapshots,
-graphs, pivot/tree arrays, sketches, dense block layouts (f32 or int8),
-scheduler slot pools — registers its resident bytes under a COMPONENT
+Every long-lived device allocation the index stack makes — corpus
+snapshots, graphs, pivot/tree arrays, sketches, dense block layouts (f32
+or int8), scheduler slot pools — registers its resident bytes under a COMPONENT
 name, so ``/debug/memory`` and the ``memory.device_bytes{component=…}``
 gauges answer "what would I free by dropping X" without a heap dump.
 The HBM-tiering work (compressed in-HBM corpus, ROADMAP) needs exactly

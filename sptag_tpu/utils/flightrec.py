@@ -432,9 +432,8 @@ def dump_to_file(reason: str, rid: str = "") -> Optional[str]:
     path = os.path.join(_dump_dir, name)
     other: dict = {"reason": reason, "rid": rid}
     if rid:
-        # per-query roofline attribution rides the dump (ISSUE 6): the
-        # scheduler's note_query_stats carries achieved GFLOP/s and
-        # %-of-peak, so the payload classifies the slow query without
+        # the scheduler's per-query stats (slot wait, segments, iters
+        # against budget) ride the dump, so the payload reads without
         # cross-referencing the log
         st = query_stats(rid)
         if st:
@@ -496,7 +495,7 @@ def note_query_stats(rid: str, **stats) -> None:
     attribution.  The per-QUERY lifecycle owner (the scheduler's retire
     path) passes `_replace=True` to start the rid's dict fresh: request
     ids are client-supplied and REUSABLE, and without the reset point a
-    reused rid would carry the previous query's verdict/roofline keys
+    reused rid would carry the previous query's verdict keys
     into the next query's slow-query log and flight dump."""
     if not rid:
         return

@@ -1,10 +1,10 @@
 """Host-side sampling profiler — "where is the host CPU going" (ISSUE 10).
 
 The flight recorder (utils/flightrec.py) answers "where did THIS query's
-wall-clock go" and the cost ledger (utils/costmodel.py) answers "how close
-to roofline is the DEVICE" — this module answers the remaining question:
-what the HOST threads are doing while all of that happens.  TPU-KNN
-(arxiv 2206.14286) reaches peak FLOP/s only when host-side dispatch,
+wall-clock go" and the benchmark's rooflines (benchmark/harness/) answer
+"how close to its peak is the DEVICE" — this module answers the remaining
+question: what the HOST threads are doing while all of that happens.
+TPU-KNN (arxiv 2206.14286) reaches peak FLOP/s only when host-side dispatch,
 encode/decode and lock waits are driven out of the serving loop; this is
 the instrument that makes those visible.
 

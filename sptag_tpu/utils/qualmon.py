@@ -1,10 +1,10 @@
 """Search-quality observatory — online recall, index health, triage (ISSUE 7).
 
 The observability stack answers "where did the time go" (utils/flightrec.py)
-and "how well is the chip used" (utils/costmodel.py / utils/roofline.py);
-this module answers the third axis of every ANN tradeoff: **how good are
-the answers**.  Until now recall was measured only offline (the
-IndexSearcher CLI); no live query ever learned its own recall, yet every
+and "how well is the chip used" (the benchmark's rooflines,
+benchmark/harness/); this module answers the third axis of every ANN
+tradeoff: **how good are the answers**.  Until now recall was measured
+only offline (the IndexSearcher CLI); no live query ever learned its own recall, yet every
 planned tradeoff — the tiered sketch→int8→exact pipeline, partial-
 reduction approximate top-k, live mutation's "bounded staleness" — spends
 recall to buy speed.  This module is the measurement substrate:
@@ -21,8 +21,8 @@ recall to buy speed.  This module is the measurement substrate:
   background SHADOW path through the index's exact FLAT/MXU scan
   (`VectorIndex.exact_search_batch`).  The shadow queue is bounded and
   never blocks serving (overflow drops are counted); shadow device work
-  is budgeted in estimated FLOP/s via the cost ledger
-  (`QualityShadowBudget`) so the overhead is explicit, not incidental.
+  is budgeted in estimated FLOP/s (`QualityShadowBudget`) so the
+  overhead is explicit, not incidental.
   Results feed sliding windows per (searchmode, shard) published as
   `quality.recall_at_k` gauges with Wilson confidence bounds.
 * **index health**: mutation paths publish graph degree histograms,
@@ -319,7 +319,7 @@ def submit(job, est_flops: float = 0.0) -> bool:
     """Queue one shadow-replay job (a zero-arg callable) for the worker
     thread.  NEVER blocks the caller: a full queue drops the sample
     (counted), and when `QualityShadowBudget` is set the job's estimated
-    device FLOPs (from the cost ledger at the caller's shapes) are
+    device FLOPs (the caller's count at its shapes) are
     charged against a leaky token bucket first — shadow work is bounded
     in GFLOP/s, not just in queue depth.  Returns False when dropped."""
     global _submitted, _queue_drops, _budget_drops

@@ -334,67 +334,6 @@ def test_cascade_triage_counts_tier_drops():
 
 
 # ---------------------------------------------------------------------------
-# cost ledger crosscheck (the ops.cascade family; ±15%)
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("Q,N,D,b1,b2,k", [(32, 2048, 64, 256, 64, 10)])
-def test_crosscheck_cascade_kernels(Q, N, D, b1, b2, k):
-    from sptag_tpu.utils import costmodel
-
-    W = (D + 31) // 32
-    metric, base = int(DistCalcMethod.L2), 1
-    fp = jnp.zeros((N, D))
-    i8 = jnp.zeros((N, D), jnp.int8)
-    sk = jnp.zeros((N, W), jnp.int32)
-    mean = jnp.zeros((D,))
-    inv = jnp.zeros((N,), bool)
-    scale = jnp.float32(0.01)
-    q = jnp.zeros((Q, D))
-
-    def close(family, compiled, **shape):
-        rel = costmodel.crosscheck(family, compiled, **shape)
-        assert abs(rel["flops_rel"]) <= 0.15, (family, rel)
-        assert abs(rel["bytes_rel"]) <= 0.15, (family, rel)
-
-    c = cascade._cascade_search_kernel.lower(
-        fp, i8, sk, mean, inv, scale, q, k, b1, b2, metric, base,
-        True, True).compile()
-    close("cascade.search", c, Q=Q, N=N, W=W, D=D, b1=b1, b2=b2, k=k)
-    c = cascade._cascade_search_kernel.lower(
-        fp, i8, sk, mean, inv, scale, q, k, b1, b2, metric, base,
-        False, True).compile()
-    close("cascade.search", c, Q=Q, N=N, W=W, D=D, b1=b1, b2=b2, k=k,
-          use_sketch=False)
-    c = cascade._cascade_search_kernel.lower(
-        fp, i8, sk, mean, inv, scale, q, k, b1, b2, metric, base,
-        False, False).compile()
-    close("cascade.search", c, Q=Q, N=N, W=W, D=D, b1=b1, b2=b2, k=k,
-          use_sketch=False, use_int8=False)
-    c = cascade._cascade_shortlist_kernel.lower(
-        i8, sk, mean, inv, scale, q, b1, b2, metric, base, True).compile()
-    close("cascade.shortlist", c, Q=Q, N=N, W=W, D=D, b1=b1, b2=b2)
-    c = cascade._sketch_shortlist_kernel.lower(sk, mean, inv, q,
-                                               b1).compile()
-    close("cascade.sketch_shortlist", c, Q=Q, N=N, W=W, b1=b1)
-    c = cascade._int8_rerank_kernel.lower(
-        q, jnp.zeros((Q, b1, D), jnp.int8),
-        jnp.zeros((Q, b1), jnp.int32), scale, b2, metric, base).compile()
-    close("cascade.int8_rerank", c, Q=Q, D=D, b1=b1, b2=b2)
-    c = cascade._fp_rerank_kernel.lower(
-        q, jnp.zeros((Q, b2, D)), jnp.zeros((Q, b2), jnp.int32), k,
-        metric, base).compile()
-    close("cascade.rerank", c, Q=Q, D=D, b2=b2, k=k)
-    c = cascade._fp_rerank_resident_kernel.lower(
-        fp, q, jnp.zeros((Q, b2), jnp.int32), k, metric, base).compile()
-    close("cascade.rerank_resident", c, Q=Q, N=N, D=D, b2=b2, k=k)
-    R = 1024
-    c = cascade._host_scan_block_kernel.lower(
-        jnp.zeros((R, D)), jnp.zeros((R,), bool), q, k, metric,
-        base).compile()
-    close("cascade.host_scan", c, Q=Q, R=R, D=D, k=k)
-
-
-# ---------------------------------------------------------------------------
 # SketchRerank calibration persistence (save/load satellite)
 # ---------------------------------------------------------------------------
 

@@ -14,6 +14,7 @@ import subprocess
 import sys
 
 import numpy as np
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -116,6 +117,28 @@ def test_int8_reference_follows_the_integer_cosine_convention():
     # integer ties may order differently; the distances must be the same
     np.testing.assert_array_equal(np.asarray(dists, np.float64), ref_scores)
     assert reference.recall_at_k(got, ref_ids, 10) >= 0.9
+
+
+def test_device_phase_takes_its_peaks_from_the_benchmarks_table():
+    """`phase_device` prints what the benchmark's rooflines divide by:
+    benchmark/harness/peaks.json, read and never edited."""
+    import chip_smoke
+
+    with open(os.path.join(REPO, "benchmark", "harness", "peaks.json")) as f:
+        table = json.load(f)["TPU v5 lite"]
+    peaks = chip_smoke.device_peaks("TPU v5 lite")
+    assert peaks == {name: table[name] for name in (
+        "bf16_flops_per_s", "int8_ops_per_s", "hbm_bytes_per_s")}
+    assert all(v > 0 for v in peaks.values())
+
+
+@pytest.mark.parametrize("kind", ["TPU v9 imagined", "cpu", "source"])
+def test_device_phase_refuses_a_kind_the_table_lacks(kind):
+    import chip_smoke
+    from benchmark.harness.serving import HarnessError
+
+    with pytest.raises(HarnessError, match="peaks.json"):
+        chip_smoke.device_peaks(kind)
 
 
 def test_refuses_on_cpu_without_the_rehearsal_switch():

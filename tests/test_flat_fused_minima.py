@@ -637,24 +637,3 @@ def test_float_rows_on_the_route_hold_the_scores_in_the_fallback_alone(
     if dim != q:            # (the rows themselves are n x 128)
         assert f"{n}x{q}x" not in main
     assert f"tensor<{n // 128}x{q}xf32>" in main    # the minima
-
-
-# ---- what the cost ledger bills --------------------------------------------
-
-def test_the_ledger_bills_the_fused_scan_no_score_matrix():
-    from sptag_tpu.utils import costmodel
-
-    shape = dict(Q=128, N=N_CELL, D=384, k=10, itemsize=1)
-    rows, scores = N_CELL * 384, 128 * N_CELL * 4
-    before = costmodel.estimate("flat.scan", **shape)
-    fused = costmodel.estimate("flat.scan", fused=True, **shape)
-    kernel = costmodel.estimate("pallas.scan_group_minima", Q=128, N=N_CELL,
-                                D=384)
-    assert before.hbm_bytes > rows + 4 * scores
-    assert rows < kernel.hbm_bytes < fused.hbm_bytes < 1.15 * rows
-    assert fused.hbm_bytes < rows + scores / 8
-    assert fused.flops >= kernel.flops >= 2.0 * 128 * N_CELL * 384
-    sel_f, sel_b = flat._two_stage_select_cost(128, N_CELL, 10)
-    fsel_f, fsel_b = flat._two_stage_select_cost(128, N_CELL, 10, fused=True,
-                                                 D=384, itemsize=1)
-    assert fsel_b < sel_b / 20 and fsel_f < sel_f

@@ -312,6 +312,34 @@ def test_scheduler_flight_events_and_rid_stats(beam_index):
     assert h is not None and h.count >= 1 and h.max > 0
 
 
+def test_sampled_segment_keeps_its_time_and_event_without_rate_gauges(
+        beam_index):
+    """FlightDeviceSampleRate=1 on the segmented driver (no scheduler):
+    every segment is timed to completion into engine.segment_device_ns
+    and one `segment_device` event with the rows and the iteration cap;
+    no achieved-rate or share-of-peak gauge is derived from it (the
+    kernels' rooflines are the benchmark's, from traced device time)."""
+    idx, data = beam_index
+    assert idx.set_parameter("ContinuousBatching", "0")
+    try:
+        flightrec.configure(enabled=True)
+        idx.search_batch(data[:4], 3)
+        assert metrics.counter_value("beam.segmented") >= 1
+        h = metrics.histogram_or_none("engine.segment_device_ns")
+        assert h is not None and h.count >= 1 and h.max > 0
+        events = [e for e in flightrec.collect()
+                  if (e["tier"], e["kind"]) == ("engine", "segment_device")]
+        assert len(events) == h.count
+        for e in events:
+            assert e["dur_ns"] > 0
+            assert set(e["payload"]) == {"rows", "iters"}
+            assert e["payload"]["iters"] == 2       # BeamSegmentIters
+        gauges = metrics.snapshot()["gauges"]
+        assert not [n for n in gauges if n.startswith("engine.")], gauges
+    finally:
+        assert idx.set_parameter("ContinuousBatching", "1")
+
+
 def test_flight_params_apply_on_warm_index(beam_index):
     """set_parameter on a WARM index must not be a silent no-op: the
     recorder knobs apply directly to the process recorder (both ways —

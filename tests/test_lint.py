@@ -816,113 +816,6 @@ def test_issue17_controller_rule_names_are_literals():
 
 
 # ---------------------------------------------------------------------------
-# GL605 cost-ledger coverage (ISSUE 6)
-# ---------------------------------------------------------------------------
-
-def test_gl605_unregistered_jit_kernel_flagged():
-    src = (
-        "import jax\n"
-        "@jax.jit\n"
-        "def _my_kernel(x):\n"
-        "    return x\n"
-    )
-    found = lint_one(src, select=["GL605"])
-    assert rules_of(found) == ["GL605"]
-    assert "cost-ledger" in found[0].message
-
-
-def test_gl605_registered_kernel_clean():
-    src = (
-        "import functools\n"
-        "import jax\n"
-        "from sptag_tpu.utils import costmodel\n"
-        "@functools.partial(jax.jit, static_argnames=('k',))\n"
-        "def _my_kernel(x, k):\n"
-        "    return x\n"
-        "def _cost(Q, k, **_):\n"
-        "    return 2.0 * Q, 4.0 * Q\n"
-        "costmodel.register('my.kernel', _my_kernel, _cost)\n"
-    )
-    assert lint_one(src, select=["GL605"]) == []
-
-
-def test_gl605_out_of_scope_module_not_flagged():
-    """The rule scopes to algo//ops — a jit helper in serve/ or utils/
-    is not a device kernel family."""
-    src = (
-        "import jax\n"
-        "@jax.jit\n"
-        "def helper(x):\n"
-        "    return x\n"
-    )
-    assert lint_one(src, path="sptag_tpu/serve/snippet.py",
-                    select=["GL605"]) == []
-    assert lint_one(src, path="sptag_tpu/utils/snippet.py",
-                    select=["GL605"]) == []
-
-
-def test_gl605_cross_module_registration_satisfies_dispatch():
-    """jax.jit(other_module.fn) is satisfied by fn's registration in its
-    DEFINING module — the ledger is project-wide."""
-    sources = {
-        "sptag_tpu/ops/distance2.py": (
-            "from sptag_tpu.utils import costmodel\n"
-            "def row_fn(x):\n"
-            "    return x\n"
-            "def _cost(N, **_):\n"
-            "    return N, N\n"
-            "costmodel.register('d.row', row_fn, _cost)\n"),
-        "sptag_tpu/algo/engine2.py": (
-            "import jax\n"
-            "from sptag_tpu.ops import distance2 as dist_ops\n"
-            "sq = jax.jit(dist_ops.row_fn)\n"),
-    }
-    from tools.graftlint.runner import lint_sources as ls
-
-    assert ls(sources, select=["GL605"]) == []
-
-
-def test_gl605_jit_dispatch_of_unregistered_import_flagged():
-    src = (
-        "import jax\n"
-        "from sptag_tpu.ops import distance as dist_ops\n"
-        "_J = jax.jit(dist_ops.mystery_fn)\n"
-    )
-    found = lint_one(src, select=["GL605"])
-    assert rules_of(found) == ["GL605"]
-    assert "mystery_fn" in found[0].message
-
-
-def test_gl605_dynamic_family_name_flagged():
-    """A registered kernel with a NON-LITERAL family name still fails:
-    the ledger never expires a family (GL6xx cardinality)."""
-    src = (
-        "import jax\n"
-        "from sptag_tpu.utils import costmodel\n"
-        "@jax.jit\n"
-        "def _k(x):\n"
-        "    return x\n"
-        "name = 'fam'\n"
-        "costmodel.register(name, _k, lambda **s: (1.0, 1.0))\n"
-    )
-    found = lint_one(src, select=["GL605"])
-    assert rules_of(found) == ["GL605"]
-    assert "string literal" in found[0].message
-    # the family-literal hygiene applies OUTSIDE algo//ops too — the
-    # ledger is project-wide and never expires a family name
-    serve_src = (
-        "from sptag_tpu.utils import costmodel\n"
-        "def _k(x):\n"
-        "    return x\n"
-        "name = 'fam'\n"
-        "costmodel.register(name, _k, lambda **s: (1.0, 1.0))\n"
-    )
-    found = lint_one(serve_src, path="sptag_tpu/serve/snippet.py",
-                     select=["GL605"])
-    assert rules_of(found) == ["GL605"]
-
-
-# ---------------------------------------------------------------------------
 # baseline machinery + the tier-1 repo gate
 # ---------------------------------------------------------------------------
 
@@ -971,6 +864,50 @@ def test_every_rule_has_an_id_and_description():
         "GL701", "GL702", "GL703", "GL704",
     }
     assert all(ALL_RULES[r] for r in ALL_RULES)
+
+
+def test_every_baseline_entry_names_a_live_rule_and_an_existing_path():
+    """A suppression whose rule left the suite, or whose file left the
+    tree, waives nothing and would silently rot in baseline.toml."""
+    from tools.graftlint.baseline import load_baseline
+
+    entries = load_baseline(DEFAULT_BASELINE)
+    assert entries
+    dead = [(s.lineno, s.rule, s.path) for s in entries
+            if s.rule not in ALL_RULES
+            or not os.path.isfile(os.path.join(REPO, s.path))]
+    assert dead == []
+
+
+def test_ci_check_names_only_test_files_that_exist():
+    """A standalone gate of tools/ci_check.sh that runs a deleted test
+    file exits 4 ("file not found") and stops every gate after it."""
+    import re
+
+    with open(os.path.join(REPO, "tools", "ci_check.sh")) as fh:
+        named = set(re.findall(r"tests/test_\w+\.py", fh.read()))
+    assert named
+    assert sorted(n for n in named
+                  if not os.path.isfile(os.path.join(REPO, n))) == []
+
+
+def test_the_program_cites_no_module_that_left_the_tree():
+    """A docstring or comment that sends the reader to `utils/x.py` is
+    held to the tree: the file is there."""
+    import glob
+    import re
+
+    cited = re.compile(r"\b((?:sptag_tpu/)?(?:algo|core|graph|io|ops|parallel"
+                       r"|serve|tools|utils)/\w+\.py)\b")
+    gone = set()
+    for path in glob.glob(os.path.join(REPO, "sptag_tpu", "**", "*.py"),
+                          recursive=True):
+        with open(path, encoding="utf-8") as fh:
+            for rel in cited.findall(fh.read()):
+                if not any(os.path.isfile(os.path.join(REPO, root, rel))
+                           for root in ("", "sptag_tpu")):
+                    gone.add((os.path.relpath(path, REPO), rel))
+    assert sorted(gone) == []
 
 
 # ---------------------------------------------------------------------------

@@ -533,9 +533,20 @@ GOLDEN_FLAT = {
 
 
 def folder_hashes(folder):
-    return {name: hashlib.sha256(
-        open(os.path.join(folder, name), "rb").read()).hexdigest()
-        for name in sorted(os.listdir(folder))}
+    """b2c8c6d wrote `RooflineProbe=0` before `DeviceBytesLedger=`; the
+    parameter left the registry since (PR 46) and `save_config` writes
+    the line no more: the ini is held to the golden one with that line
+    put back, every other file as it is."""
+    hashes = {}
+    for name in sorted(os.listdir(folder)):
+        with open(os.path.join(folder, name), "rb") as f:
+            blob = f.read()
+        if name == "indexloader.ini":
+            assert b"RooflineProbe" not in blob
+            blob = blob.replace(b"DeviceBytesLedger=",
+                                b"RooflineProbe=0\nDeviceBytesLedger=", 1)
+        hashes[name] = hashlib.sha256(blob).hexdigest()
+    return hashes
 
 
 @pytest.mark.parametrize("params", [(), ("Index.MeshShardAxis=0",)])

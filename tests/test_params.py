@@ -1,6 +1,12 @@
 """Parameter registry parity tests (reference X-macro registry,
 inc/Core/BKT/ParameterDefinitionList.h + BKTIndex.cpp:537-573)."""
 
+import os
+
+import numpy as np
+import pytest
+
+import sptag_tpu as sp
 from sptag_tpu.core.params import BKTParams, KDTParams
 from sptag_tpu.core.types import DistCalcMethod
 
@@ -106,3 +112,47 @@ def test_refine_accuracy_floor_parameter():
     g = idx._new_graph()
     assert g.refine_accuracy_floor == 0.15
     assert g.refine_accuracy_guard
+
+
+# ---------------------------------------------------------------------------
+# a name that left the registry (RooflineProbe)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("algo", ["BKT", "KDT", "FLAT"])
+def test_folder_saved_with_rooflineprobe_still_loads(algo, tmp_path):
+    """Every folder `save_config` wrote before the parameter left carries
+    a `RooflineProbe=0` line (the benchmark's cached indexes among them):
+    `load_config` skips a name it does not know, the index answers as it
+    did, the name is refused like any unknown one and is not written
+    again."""
+    rng = np.random.default_rng(7)
+    data = rng.standard_normal((300, 16)).astype(np.float32)
+    index = sp.create_instance(algo, "Float")
+    for name, value in (("DistCalcMethod", "L2"), ("TPTNumber", "2"),
+                        ("CEF", "32"), ("MaxCheckForRefineGraph", "64"),
+                        ("NeighborhoodSize", "8"),
+                        ("FinalRefineSearchMode", "same")):
+        index.set_parameter(name, value)
+    index.build(data)
+    assert index.set_parameter("RooflineProbe", "1") is False
+    assert index.get_parameter("RooflineProbe") is None
+    before = index.search_batch(data[:8], 5)
+
+    folder = str(tmp_path / "saved")
+    index.save_index(folder)
+    ini = os.path.join(folder, "indexloader.ini")
+    with open(ini) as f:
+        lines = f.read().splitlines()
+    assert not [ln for ln in lines if ln.startswith("RooflineProbe")]
+    at = next(i for i, ln in enumerate(lines)
+              if ln.startswith("DeviceBytesLedger="))
+    lines.insert(at, "RooflineProbe=0")         # where the parent wrote it
+    with open(ini, "w") as f:
+        f.write("\n".join(lines) + "\n")
+
+    loaded = sp.load_index(folder)
+    assert loaded.get_parameter("RooflineProbe") is None
+    assert loaded.get_parameter("DeviceBytesLedger") == "1"
+    after = loaded.search_batch(data[:8], 5)
+    np.testing.assert_array_equal(after[1], before[1])
+    np.testing.assert_allclose(after[0], before[0], rtol=1e-6)

@@ -236,6 +236,49 @@ def test_shadow_budget_drops_counted():
     assert qualmon.drain(5)
 
 
+@pytest.mark.parametrize("shapes,fits", [
+    (((50, 8),), True),
+    (((50, 8), (30, 8)), True),
+    (((400, 16),), False),
+])
+def test_server_charges_a_sample_two_n_d_flops_a_served_index(shapes, fits):
+    """What `_queue_quality_sample` charges against QualityShadowBudget:
+    2 x num_samples x feature_dim flops a served index, the dots of one
+    exact scan of one query.  The bucket holds two seconds of budget and
+    never more: one half a flop wider than the charge admits the sample
+    (and the shadow gauge reads the charge), one half a flop narrower
+    drops it and counts the drop."""
+    rng = np.random.default_rng(len(shapes))
+    ctx = ServiceContext(ServiceSettings(default_max_result=3))
+    names = []
+    for i, (n, d) in enumerate(shapes):
+        index = sp.create_instance("FLAT", "Float")
+        index.set_parameter("DistCalcMethod", "L2")
+        index.build(rng.standard_normal((n, d)).astype(np.float32))
+        names.append("idx%d" % i)
+        ctx.add_index(names[-1], index)
+    charge = sum(2.0 * n * d for n, d in shapes)
+    capacity = charge + (0.5 if fits else -0.5)
+    qualmon.configure(sample_rate=1.0,
+                      shadow_budget_gflops=capacity / 2.0 / 1e9)
+    server = SearchServer(ctx, batch_window_ms=1.0)
+    text = ("$indexname:%s " % ",".join(names)
+            + "|".join(["0.5"] * shapes[0][1]))
+    result = SearchExecutor(ctx).execute(text)
+    assert [r.index_name for r in result.results] == names
+    server._queue_quality_sample("budget-rid", text, result)
+    c = qualmon.counters()
+    if fits:
+        assert (c["submitted"], c["budget_drops"]) == (1, 0)
+        assert metrics.gauge_value("quality.shadow_gflops") == \
+            pytest.approx(charge / 1e9, rel=1e-9)
+        assert metrics.counter_value("quality.shadow_budget_drops") == 0
+    else:
+        assert (c["submitted"], c["budget_drops"]) == (0, 1)
+        assert metrics.counter_value("quality.shadow_budget_drops") == 1
+    assert qualmon.drain(10)
+
+
 def test_shadow_worker_error_is_counted_not_fatal():
     qualmon.configure(sample_rate=1.0)
 

@@ -465,30 +465,6 @@ def test_mesh_scheduler_publishes_shard_skew_series(host_mesh):
 
 
 # ---------------------------------------------------------------------------
-# scheduler iter_cost1 regression (the gflops= root cause)
-# ---------------------------------------------------------------------------
-
-def test_slot_pool_iter_cost1_resolves():
-    """Regression: _SlotPool.iter_cost1 referenced a nonexistent
-    attribute and the swallowed AttributeError silently disabled the
-    slow-query log's gflops= attribution (ISSUE 15 satellite)."""
-    from sptag_tpu.algo.scheduler import _SlotPool
-    from sptag_tpu.utils.costmodel import CostEstimate
-
-    class _Engine:
-        def walk_iter_cost(self, rows, B, L):
-            return CostEstimate("beam.walk_iter", 100.0 * rows,
-                                50.0 * rows)
-
-    pool = _SlotPool((5, 32, 16, 3, None, 0), _Engine(),
-                     seg_iters=4, slots=64)
-    est = pool.iter_cost1()
-    assert est is not None
-    assert est.flops == pytest.approx(100.0)
-    assert est.hbm_bytes == pytest.approx(50.0)
-
-
-# ---------------------------------------------------------------------------
 # off-parity: everything default == byte-identical + zero work
 # ---------------------------------------------------------------------------
 

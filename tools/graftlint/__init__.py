@@ -7,8 +7,7 @@ Checker families, each its own module with documented rule ids:
 * GL3xx  concurrency    unlocked shared mutation, late-binding captures
 * GL4xx  errorpath      swallowed exceptions at the ErrorCode boundaries
 * GL5xx  dtype_parity   integer distance paths upcasting before the dot
-* GL6xx  obsnames/cost  literal metric/span/stage names, cost-ledger
-                        registration for jitted kernels
+* GL6xx  obsnames       literal metric/span/stage names
 * GL7xx  lockgraph      lock-order cycles, blocking under a held lock,
                         leaked thread/task handles (+ GL41x persistence
                         writes outside the atomic/WAL funnel)

@@ -14,7 +14,7 @@ import sys
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from tools.graftlint import (asyncrules, attrmodel, concurrency, costrules,
+from tools.graftlint import (asyncrules, attrmodel, concurrency,
                              dtype_parity, errorpath, guardedby, hostsync,
                              lockgraph, obsgraph, obsnames, persistrules,
                              retrace, tracecontract)
@@ -23,7 +23,7 @@ from tools.graftlint.baseline import (BaselineError, Suppression,
 from tools.graftlint.core import Finding, Project
 
 CHECKERS = (hostsync, retrace, concurrency, errorpath, dtype_parity,
-            obsnames, lockgraph, asyncrules, costrules, persistrules,
+            obsnames, lockgraph, asyncrules, persistrules,
             guardedby, tracecontract, attrmodel, obsgraph)
 
 #: rule id -> one-line description, collected from every checker module
