@@ -36,7 +36,8 @@ from typing import Optional, Tuple
 
 import numpy as np
 
-from sptag_tpu.algo.dense import DenseTreeSearcher, partition_from_tree
+from sptag_tpu.algo.dense import (DenseTreeSearcher, partition_from_tree,
+                                  place_rows)
 from sptag_tpu.algo.engine import GraphSearchEngine, packed_param
 from sptag_tpu.core.index import MAX_DIST, VectorIndex, register_algo
 from sptag_tpu.core.params import BKTParams
@@ -341,52 +342,45 @@ class BKTIndex(VectorIndex):
         """
         if replicas is None:
             replicas = getattr(self.params, "dense_replicas", 1)
-        n = self._main_rows()
-        data = self._host[:n]
-        centers, clusters = self._dense_clusters()
-        cascade_cfg = None
-        if cascade_ok and int(getattr(self.params, "cascade_search", 0)) \
-                and np.issubdtype(data.dtype, np.floating):
-            # tiered cascade (ISSUE 14): int8-quantized dense blocks
-            # with a TierBudgetInt8-budgeted exact fp re-rank; the
-            # dense partition's nprobe prefilter plays the coarse-tier
-            # role the sketch scan plays on FLAT
-            cascade_cfg = {
-                "tier": str(getattr(self.params, "corpus_tier",
-                                    "device")),
-                "rerank_budget": int(getattr(self.params,
-                                             "tier_budget_int8", 0)),
-            }
-        return DenseTreeSearcher(
-            data, centers, clusters, self._deleted[:n],
-            self.dist_calc_method, self.base,
-            replicas=replicas, cascade_cfg=cascade_cfg)
+        with trace.span("build.dense_pack"):
+            n = self._main_rows()
+            data = self._host[:n]
+            centers, clusters = self._dense_clusters()
+            cascade_cfg = None
+            if cascade_ok \
+                    and int(getattr(self.params, "cascade_search", 0)) \
+                    and np.issubdtype(data.dtype, np.floating):
+                # tiered cascade (ISSUE 14): int8-quantized dense blocks
+                # with a TierBudgetInt8-budgeted exact fp re-rank; the
+                # dense partition's nprobe prefilter plays the coarse-tier
+                # role the sketch scan plays on FLAT
+                cascade_cfg = {
+                    "tier": str(getattr(self.params, "corpus_tier",
+                                        "device")),
+                    "rerank_budget": int(getattr(self.params,
+                                                 "tier_budget_int8", 0)),
+                }
+            return DenseTreeSearcher(
+                data, centers, clusters, self._deleted[:n],
+                self.dist_calc_method, self.base,
+                replicas=replicas, cascade_cfg=cascade_cfg)
 
     def _dense_clusters(self):
-        """Tree partition plus nearest-center assignment of rows appended
-        after the last rebuild (host numpy throughout — the mesh packer
-        calls this without touching the device).  Coverage stops at the
-        delta base like every main-tier snapshot."""
+        """Tree partition plus nearest-center assignment of the rows it
+        leaves out: those appended after the last rebuild, and the center
+        samples of the tree's nodes above the cut (host numpy throughout —
+        the mesh packer calls this without touching the device).  Coverage
+        stops at the delta base like every main-tier snapshot."""
         n = self._main_rows()
-        data = self._host[:n]
         centers, clusters = self._partition_tree(n)
         covered = np.zeros(n, bool)
-        for c in clusters:
-            covered[c] = True
+        if clusters:
+            covered[np.concatenate(clusters)] = True
         missing = np.flatnonzero(~covered)
         if len(missing):
-            q = data[missing].astype(np.float32)
-            c = data[centers].astype(np.float32)
-            dot = q @ c.T
-            if self.dist_calc_method == DistCalcMethod.Cosine:
-                owner = dot.argmax(axis=1)          # max dot = min distance
-            else:
-                owner = ((c ** 2).sum(1)[None, :] - 2.0 * dot).argmin(axis=1)
-            for ci in range(len(clusters)):
-                extra = missing[owner == ci]
-                if len(extra):
-                    clusters[ci] = np.concatenate(
-                        [clusters[ci], extra])
+            clusters = place_rows(self._host[:n], centers, clusters, missing,
+                                  self.params.dense_cluster_size,
+                                  self.dist_calc_method)
         return centers, clusters
 
     def _partition_tree(self, rows: Optional[int] = None):
@@ -396,7 +390,8 @@ class BKTIndex(VectorIndex):
         return partition_from_tree(self._tree,
                                    self._main_rows() if rows is None
                                    else rows,
-                                   self.params.dense_cluster_size)
+                                   self.params.dense_cluster_size,
+                                   place_loose=False)
 
     def _get_dense(self) -> DenseTreeSearcher:
         """Lazy dense snapshot for the dense search mode (pinned by

@@ -26,7 +26,9 @@ masked in the final top-k exactly like the other TPU paths.
 
 from __future__ import annotations
 
+import concurrent.futures
 import functools
+import heapq
 from typing import List, Optional, Tuple
 
 import jax
@@ -37,7 +39,7 @@ from sptag_tpu.core.types import DeviceTopK, DistCalcMethod
 from sptag_tpu.ops import distance as dist_ops
 from sptag_tpu.ops import pallas_kernels
 from sptag_tpu.ops import topk_bins
-from sptag_tpu.utils import (devmem, metrics, query_bucket,
+from sptag_tpu.utils import (devmem, host_cores, metrics, query_bucket,
                              round_up, trace)
 
 MAX_DIST = np.float32(3.4e38)   # plain scalar: module import must NOT init a backend
@@ -46,92 +48,161 @@ MAX_DIST = np.float32(3.4e38)   # plain scalar: module import must NOT init a ba
 _GATHER_BUDGET = 1 << 30
 
 
-def partition_from_tree(tree, n: int, target_size: int
+def gather_budget() -> int:
+    """Bytes the gathered candidates of ONE kernel call may take:
+    `_GATHER_BUDGET`, or a quarter of the device's memory where the
+    device says how much it has (a TPU's `bytes_limit`: 4 GB on a v5e) —
+    what is free beside the resident blocks is the device's to give, and
+    a batch that fits runs as ONE program (`_dense_search_kernel`) where
+    a fixed 1 GB split the 128 callers of a 10M-row index at MaxCheck
+    32,768 into two chunks of 85 (PERF.md section 6, PR 48)."""
+    from sptag_tpu.algo.engine import _device_memory
+
+    limit, _ = _device_memory()
+    return max(_GATHER_BUDGET, limit // 4) if limit else _GATHER_BUDGET
+
+
+# rows a worker of `build_layout` packs a step (bytes): its temporaries
+# are this large, times the workers
+_PACK_BYTES = 1 << 24
+
+
+def _child_ranges(cs: np.ndarray, ce: np.ndarray
+                  ) -> Tuple[np.ndarray, np.ndarray]:
+    """[first child, one past the last) of every node, (0, 0) for a leaf.
+    leaf: cs == -1 and ce <= 0; a degenerate duplicate node stores a
+    negated childStart (bktree.py loader disambiguation)."""
+    degenerate = (cs < -1) | ((cs == -1) & (ce > 0))
+    has = (cs >= 0) | degenerate
+    return (np.where(cs >= 0, cs, np.where(degenerate, -cs, 0)),
+            np.where(has, ce, 0))
+
+
+def _expand_ranges(lo: np.ndarray, hi: np.ndarray) -> np.ndarray:
+    """arange(lo[0], hi[0]), arange(lo[1], hi[1]), ... as one array."""
+    lens = hi - lo
+    total = int(lens.sum())
+    if not total:
+        return np.zeros(0, np.int64)
+    first = np.cumsum(lens) - lens
+    return np.repeat(lo - first, lens) + np.arange(total, dtype=np.int64)
+
+
+def _segment_sums(values: np.ndarray, lens: np.ndarray) -> np.ndarray:
+    """Sums of consecutive runs of `values`, `lens[i]` long (0 allowed)."""
+    total = np.concatenate([[0], np.cumsum(values)])
+    stop = np.cumsum(lens)
+    return total[stop] - total[stop - lens]
+
+
+def partition_from_tree(tree, n: int, target_size: int,
+                        place_loose: bool = True
                         ) -> Tuple[np.ndarray, List[np.ndarray]]:
     """Cut the first BKT tree into subtrees of <= target_size samples.
 
     Returns (cut-node center sample ids (C,), list of C member id arrays —
     every sample id in [0, n) appears in exactly one cluster; each cluster's
     center sample is a member of that cluster).
-    """
+
+    The center samples of the nodes ABOVE the cut are in no subtree at the
+    cut.  They are the medoids of whole regions — the rows most queries of
+    their region have among their nearest (a Gaussian cluster's hubs) —
+    so where they land decides a recall ceiling no budget lifts.  With
+    `place_loose` each joins the smallest cluster, wherever that lies
+    (sizes stay balanced; all a caller without the rows can ask for): on
+    500k-2M x 96 rows 2 % of all true neighbours were then out of reach
+    at any MaxCheck (recall@10 0.977-0.981 from MaxCheck 32,768 to
+    131,072; PERF.md section 6, PR 48).  A caller that holds the rows
+    passes False, gets them in no cluster and places them by distance
+    (`place_rows`: 0.998 on the same rows).
+
+    Level by level over the node arrays (a 10M-row tree has 10M nodes: one
+    Python step a node was minutes)."""
     nodes = tree.nodes
-    cid = nodes["centerid"].astype(np.int64)
-    cs = nodes["childStart"].astype(np.int64)
-    ce = nodes["childEnd"].astype(np.int64)
     start = int(tree.tree_starts[0])
     end = int(tree.tree_starts[1]) if len(tree.tree_starts) > 1 \
         else len(nodes)
+    lo, hi = _child_ranges(nodes["childStart"][start:end].astype(np.int64),
+                           nodes["childEnd"][start:end].astype(np.int64))
+    # node indexes below are relative to `start`
+    leaf = hi <= lo
+    lo, hi = lo - start, hi - start
+    lo[leaf] = 0
+    hi[leaf] = 0
+    sample = nodes["centerid"][start:end].astype(np.int64)
+    # the ROOT's centerid is the build-time sample count, not a sample
+    # (reference BKTree.h:168); after online adds grow n past it, that
+    # sentinel would masquerade as a real id without this check
+    sample[0] = -1
+    sample[(sample < 0) | (sample >= n)] = -1
 
-    def children(ni: int) -> range:
-        # leaf: cs == -1 and ce <= 0; degenerate duplicate node stores a
-        # negated childStart (bktree.py loader disambiguation)
-        if cs[ni] >= 0:
-            return range(int(cs[ni]), int(ce[ni]))
-        if cs[ni] < -1 or (cs[ni] == -1 and ce[ni] > 0):
-            return range(int(-cs[ni]), int(ce[ni]))
-        return range(0)
+    # the tree by level, each level in the order its parents list it
+    levels = [np.zeros(1, np.int64)]
+    while len(levels[-1]):
+        f = levels[-1]
+        levels.append(_expand_ranges(lo[f], hi[f]))
+    levels.pop()
+    # bottom-up subtree sample counts
+    counts = (sample >= 0).astype(np.int64)
+    for f, kids in zip(levels[-2::-1], levels[:0:-1]):
+        counts[f] += _segment_sums(counts[kids], hi[f] - lo[f])
 
-    def sample_of(ni: int) -> int:
-        # the ROOT's centerid is the build-time sample count, not a sample
-        # (reference BKTree.h:168); after online adds grow n past it, that
-        # sentinel would masquerade as a real id without this check
-        if ni == start:
-            return -1
-        c = int(cid[ni])
-        return c if 0 <= c < n else -1
+    # top-down: a node is a cluster root once its subtree fits
+    rank = np.full(end - start, -1, np.int64)     # node -> its cluster
+    splits = []                                   # per level: nodes split
+    roots = 0
+    f = levels[0]
+    while len(f):
+        c = counts[f]
+        is_root = (c > 0) & ((c <= target_size) | (hi[f] <= lo[f]))
+        rank[f[is_root]] = roots + np.arange(int(is_root.sum()))
+        roots += int(is_root.sum())
+        split = f[(c > 0) & ~is_root]
+        splits.append(split)
+        f = _expand_ranges(lo[split], hi[split])
+    root_nodes = np.flatnonzero(rank >= 0)
+    root_nodes = root_nodes[np.argsort(rank[root_nodes])]
 
-    # bottom-up subtree sample counts (children are appended after parents,
-    # so a reverse scan sees children before parents)
-    counts = np.zeros(end - start, np.int64)
-    for ni in range(end - 1, start - 1, -1):
-        c = 1 if sample_of(ni) >= 0 else 0
-        for ch in children(ni):
-            c += counts[ch - start]
-        counts[ni - start] = c
-
-    # top-down BFS: emit a node as a cluster root once its subtree fits
-    roots: List[int] = []
-    loose: List[int] = []          # interior-node center samples above cuts
-    frontier = [start]
-    while frontier:
-        nxt: List[int] = []
-        for ni in frontier:
-            if counts[ni - start] == 0:
-                continue
-            kids = children(ni)
-            if counts[ni - start] <= target_size or len(kids) == 0:
-                roots.append(ni)
-            else:
-                nxt.extend(kids)
-                if sample_of(ni) >= 0:
-                    loose.append(sample_of(ni))
-        frontier = nxt
-
-    clusters: List[np.ndarray] = []
-    centers: List[int] = []
-    for r in roots:
-        members: List[int] = []
-        stack = [r]
-        while stack:
-            ni = stack.pop()
-            if sample_of(ni) >= 0:
-                members.append(sample_of(ni))
-            stack.extend(children(ni))
-        if members:
-            clusters.append(np.asarray(members, np.int64))
-            centers.append(sample_of(r) if sample_of(r) >= 0 else members[0])
-    # center samples of nodes above the cut join the smallest cluster (keeps
-    # sizes balanced; they are close to several clusters by construction)
-    for s in loose:
-        smallest = min(range(len(clusters)), key=lambda i: len(clusters[i]))
-        clusters[smallest] = np.append(clusters[smallest], s)
-
-    return _pack_clusters(clusters, centers, target_size)
+    # every sample under a root, by root
+    got_s, got_r = [], []
+    f, r = root_nodes, rank[root_nodes]
+    while len(f):
+        keep = sample[f] >= 0
+        got_s.append(sample[f][keep])
+        got_r.append(r[keep])
+        r = np.repeat(r, hi[f] - lo[f])
+        f = _expand_ranges(lo[f], hi[f])
+    if place_loose and roots:
+        # each joins the cluster that is smallest when its turn comes
+        # (ties: the first) — keeps sizes balanced
+        loose = np.concatenate([sample[s][sample[s] >= 0] for s in splits])
+        sizes = np.bincount(np.concatenate(got_r), minlength=roots)
+        heap = list(zip(sizes.tolist(), range(roots)))
+        heapq.heapify(heap)
+        owners = []
+        for _ in range(len(loose)):
+            size, ci = heap[0]
+            heapq.heapreplace(heap, (size + 1, ci))
+            owners.append(ci)
+        got_s.append(loose)
+        got_r.append(np.asarray(owners, np.int64))
+    members = np.concatenate(got_s) if got_s else np.zeros(0, np.int64)
+    owner = np.concatenate(got_r) if got_r else np.zeros(0, np.int64)
+    members = members[np.argsort(owner, kind="stable")]
+    sizes = np.bincount(owner, minlength=roots)
+    first = np.cumsum(sizes) - sizes
+    # a root with no sample of its own (the tree's root alone) is stood
+    # for by its first member
+    centers = np.where(sample[root_nodes] >= 0, sample[root_nodes],
+                       members[np.minimum(first, max(len(members) - 1, 0))])
+    cut, packed_centers = _pack_plan(sizes, centers, target_size)
+    stops = np.concatenate([first, [len(members)]])[cut]
+    return packed_centers, [members[a:b]
+                            for a, b in zip(stops[:-1], stops[1:])]
 
 
-def _pack_clusters(clusters: List[np.ndarray], centers: List[int],
-                   target_size: int
-                   ) -> Tuple[np.ndarray, List[np.ndarray]]:
+def _pack_plan(sizes, centers, target_size: int
+               ) -> Tuple[np.ndarray, np.ndarray]:
     """Greedily merge adjacent small clusters into near-full blocks.
 
     A tree cut yields MANY subtrees far below target_size (k=32 fan-out:
@@ -142,25 +213,83 @@ def _pack_clusters(clusters: List[np.ndarray], centers: List[int],
     Merging BFS-adjacent clusters (tree siblings == spatially close by
     construction) makes blocks ~full, so a probe scores ~target_size REAL
     candidates.  The merged block keeps the center of its largest
-    constituent."""
-    packed_c: List[np.ndarray] = []
+    constituent.
+
+    Returns (the index of each block's first cluster, and len(sizes) at
+    the end: B + 1 cuts; the B blocks' centers)."""
+    cuts: List[int] = []
     packed_id: List[int] = []
-    cur: List[np.ndarray] = []
     cur_center, cur_best, cur_n = -1, -1, 0
-    for ci in range(len(clusters)):
-        sz = len(clusters[ci])
+    for ci, (sz, center) in enumerate(zip(np.asarray(sizes).tolist(),
+                                          np.asarray(centers).tolist())):
         if cur_n and cur_n + sz > target_size:
-            packed_c.append(np.concatenate(cur))
             packed_id.append(cur_center)
-            cur, cur_center, cur_best, cur_n = [], -1, -1, 0
-        cur.append(clusters[ci])
+            cur_center, cur_best, cur_n = -1, -1, 0
+        if not cur_n:
+            cuts.append(ci)
         if sz > cur_best:
-            cur_best, cur_center = sz, centers[ci]
+            cur_best, cur_center = sz, center
         cur_n += sz
     if cur_n:
-        packed_c.append(np.concatenate(cur))
         packed_id.append(cur_center)
-    return np.asarray(packed_id, np.int64), packed_c
+    cuts.append(len(sizes))
+    return np.asarray(cuts, np.int64), np.asarray(packed_id, np.int64)
+
+
+def _pack_clusters(clusters: List[np.ndarray], centers: List[int],
+                   target_size: int
+                   ) -> Tuple[np.ndarray, List[np.ndarray]]:
+    """`_pack_plan` over clusters held as a list of id arrays."""
+    cut, packed_centers = _pack_plan([len(c) for c in clusters], centers,
+                                     target_size)
+    return packed_centers, [np.concatenate(clusters[a:b])
+                            for a, b in zip(cut[:-1], cut[1:])]
+
+
+def place_rows(data: np.ndarray, centers: np.ndarray,
+               clusters: List[np.ndarray], rows: np.ndarray, capacity: int,
+               metric: DistCalcMethod, chunk: int = 1024
+               ) -> List[np.ndarray]:
+    """Append each of `rows` (ids in no cluster yet: rows added since the
+    tree was built, center samples of the nodes above the cut) to the
+    cluster whose center sample is nearest among those that still have
+    room; `capacity` (or the largest cluster, if larger) is what a
+    cluster may hold, so placing rows never grows the padded block size
+    every block pays for.  Only where every cluster is full does a row
+    join its nearest one regardless.
+
+    Host numpy (the mesh packer calls this without touching the device),
+    `chunk` rows against all centers at a time: 34k rows x 45k centers at
+    10M rows is one (1024, 96) x (96, 45k) product a step, not a 6 GB
+    score matrix."""
+    sizes = np.fromiter((len(c) for c in clusters), np.int64, len(clusters))
+    room = max(int(capacity), int(sizes.max())) - sizes
+    c = data[centers].astype(np.float32)
+    c_sq = (c ** 2).sum(1)
+    near = min(8, len(clusters))
+    owner = np.empty(len(rows), np.int64)
+    for lo in range(0, len(rows), chunk):
+        q = data[rows[lo:lo + chunk]].astype(np.float32)
+        score = -(q @ c.T)                 # max dot = min distance
+        if metric != DistCalcMethod.Cosine:
+            score = c_sq[None, :] + 2.0 * score
+        cand = np.argpartition(score, near - 1, axis=1)[:, :near]
+        cand = np.take_along_axis(
+            cand, np.argsort(np.take_along_axis(score, cand, axis=1),
+                             axis=1, kind="stable"), axis=1)
+        for i, row_cand in enumerate(cand.tolist()):
+            pick = next((b for b in row_cand if room[b] > 0), -1)
+            if pick < 0:
+                order = np.argsort(score[i], kind="stable")
+                free = order[room[order] > 0]
+                pick = int(free[0]) if len(free) else row_cand[0]
+            room[pick] -= 1
+            owner[lo + i] = pick
+    order = np.argsort(owner, kind="stable")
+    stops = np.searchsorted(owner[order], np.arange(len(clusters) + 1))
+    placed = np.asarray(rows, np.int64)[order]
+    return [np.concatenate([c, placed[a:b]]) if b > a else c
+            for c, a, b in zip(clusters, stops[:-1], stops[1:])]
 
 
 def partition_from_kdtree(tree, n: int, target_size: int
@@ -644,33 +773,58 @@ class DenseTreeSearcher:
         clusters = replicate_clusters(data, clusters, max(1, replicas),
                                       DistCalcMethod(metric))
         C = len(clusters)
+        sizes = np.fromiter((len(c) for c in clusters), np.int64, C)
         # int8 VMEM tiles are (32, 128): pad P so the Pallas probe kernel's
         # block shape is legal for integer corpora too
         p_align = 32 if np.dtype(data.dtype) == np.int8 else 8
-        P = round_up(max(len(c) for c in clusters), p_align)
+        P = round_up(int(sizes.max()), p_align)
         D = data.shape[1]
-        perm = np.zeros((C, P, D), data.dtype)
         mids = np.full((C, P), -1, np.int32)
-        for i, members in enumerate(clusters):
-            perm[i, :len(members)] = data[members]
-            mids[i, :len(members)] = members
-        # numpy mirror of ops/distance.row_sqnorms (f32 accumulation;
-        # int8/uint8 exact via int64 host sums).  Padding rows get sqnorm
-        # 0 == a real-looking vector; the id mask excludes them anyway
-        flat = perm.reshape(C * P, D)
-        if np.issubdtype(perm.dtype, np.integer):
-            sq = (flat.astype(np.int64) ** 2).sum(1).astype(np.float32)
+        mids.reshape(-1)[_expand_ranges(np.arange(C) * P,
+                                        np.arange(C) * P + sizes)] = \
+            np.concatenate(clusters)
+        perm = np.empty((C, P, D), data.dtype)
+        sq = np.empty((C, P), np.float32)
+        means = np.empty((C, D), np.float32)
+        integer = np.issubdtype(perm.dtype, np.integer)
+
+        def pack(lo: int) -> None:
+            """Blocks [lo, lo + span): their rows, square sums and means,
+            from ONE gather into the layout itself.  A 10M x 96 float32
+            corpus is 3.84 GB: a whole-corpus temporary a step (the rows
+            again for the norms, their squares, a second gather for the
+            means) held five copies on a 40 GiB host."""
+            ids = mids[lo:lo + span].reshape(-1)
+            blk = perm[lo:lo + span].reshape(-1, D)
+            np.take(data, np.maximum(ids, 0), axis=0, out=blk, mode="clip")
+            # padding rows get zeros and sqnorm 0 == a real-looking
+            # vector; the id mask excludes them anyway
+            blk[ids < 0] = 0
+            # numpy mirror of ops/distance.row_sqnorms (f32 accumulation;
+            # int8/uint8 exact via int64 host sums)
+            if integer:
+                s = (blk.astype(np.int64) ** 2).sum(1).astype(np.float32)
+            else:
+                s = (blk.astype(np.float32) ** 2).sum(1, dtype=np.float32)
+            sq[lo:lo + span] = s.reshape(-1, P)
+            # probe ranking uses the block MEAN (an IVF-style centroid):
+            # packed blocks hold several tree subtrees, and a single medoid
+            # sample of one constituent ranks the block far worse than its
+            # mean does
+            means[lo:lo + span] = (
+                blk.reshape(-1, P, D).astype(np.float32).sum(1)
+                / sizes[lo:lo + span, None].astype(np.float32))
+
+        span = max(1, _PACK_BYTES // (P * D * perm.itemsize))
+        workers = min(-(-C // span), host_cores())
+        if workers <= 1:
+            for lo in range(0, C, span):
+                pack(lo)
         else:
-            sq = (flat.astype(np.float32) ** 2).sum(
-                1, dtype=np.float32)
-        # probe ranking uses the block MEAN (an IVF-style centroid): packed
-        # blocks hold several tree subtrees, and a single medoid sample of
-        # one constituent ranks the block far worse than its mean does
-        means = np.stack([
-            data[members].astype(np.float32).mean(axis=0)
-            for members in clusters])
+            with concurrent.futures.ThreadPoolExecutor(workers) as pool:
+                list(pool.map(pack, range(0, C, span)))
         cent_sq = (means ** 2).sum(1, dtype=np.float32)
-        return dict(perm=perm, ids=mids, sq=sq.reshape(C, P), cent=means,
+        return dict(perm=perm, ids=mids, sq=sq, cent=means,
                     cent_sq=cent_sq, cluster_size=P, num_clusters=C)
 
     @staticmethod
@@ -760,6 +914,12 @@ class DenseTreeSearcher:
         if deleted is None:
             deleted = np.zeros(self.n, bool)
         self.deleted = jnp.asarray(deleted[:self.n])
+        # what was placed: the padded geometry every probe pays for
+        slots = self.num_clusters * self.cluster_size
+        metrics.set_gauge("dense.blocks", self.num_clusters)
+        metrics.set_gauge("dense.block_rows", self.cluster_size)
+        metrics.set_gauge("dense.pad_share",
+                          1.0 - int((lay["ids"] >= 0).sum()) / slots)
         self.last_effective_group = 0     # set by search(); diagnostic only
         self.last_use_pallas = False      # likewise: the last search's route
         self._demotions = set()
@@ -937,7 +1097,7 @@ class DenseTreeSearcher:
 
         bytes_q = ((U * P * D * 4 + G - 1) // G if G
                    else nprobe * P * D * 4)
-        chunk = max(1, min(_GATHER_BUDGET // bytes_q, 1024))
+        chunk = max(1, min(gather_budget() // bytes_q, 1024))
         if G:
             chunk = max(G, (chunk // G) * G)    # groups must tile the chunk
         # the int8 kernel needs int8 queries too (dot_general forbids mixed

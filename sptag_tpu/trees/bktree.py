@@ -29,6 +29,7 @@ permutations) on host.
 
 from __future__ import annotations
 
+import concurrent.futures
 import heapq
 from typing import Dict, List, Optional, Tuple
 
@@ -41,14 +42,63 @@ from sptag_tpu.ops import kmeans as km
 # device batch budget: rows per (B, P) padded batch (times D floats)
 _MAX_BATCH_ROWS = 1 << 21
 
+# rows a worker of `_gather_f32` copies a step
+_GATHER_ROWS = 1 << 16
 
-from sptag_tpu.utils import shape_bucket as _shape_bucket
+
+from sptag_tpu.utils import host_cores, shape_bucket as _shape_bucket
 
 # Every distinct (B, P) pair compiles a fresh XLA kernel pair — measured
 # 77% of a 20k-corpus tree build was 37 recompiles (compiles, seconds
 # each, dominate a cold build).  The coarse
 # utils.shape_bucket ladder cuts the shape zoo at the cost of ≤4x padding
 # compute, which is cheap on the MXU.
+
+
+class _NodeTable:
+    """The node arrays while a forest is built.  Every sample is exactly
+    one node, and a tree adds a root and a sentinel, so the arrays are
+    made once at their final size; children of one node lie side by
+    side."""
+
+    def __init__(self, nodes: int):
+        self.centerid = np.empty(nodes, np.int64)
+        self.child_start = np.full(nodes, -1, np.int64)
+        self.child_end = np.full(nodes, -1, np.int64)
+        self.n = 0
+
+    def add(self, centerids) -> int:
+        """Append one node a center id; returns the first one's index."""
+        first = self.n
+        self.n += len(centerids)
+        self.centerid[first:self.n] = centerids
+        return first
+
+
+def _gather_f32(data: np.ndarray, jobs) -> None:
+    """out[i] = data[ids[i]] as float32 for every (ids, out) of `jobs`,
+    with no temporary where the rows are float32 already, in spans of
+    `_GATHER_ROWS` over the cores the process may run on: a level of a
+    10M x 96 tree gathers 3.84 GB of scattered rows, and `take` leaves
+    the interpreter lock."""
+    spans = [(ids[lo:lo + _GATHER_ROWS],
+              out[lo:min(lo + _GATHER_ROWS, len(ids))])
+             for ids, out in jobs for lo in range(0, len(ids), _GATHER_ROWS)]
+
+    def gather(span) -> None:
+        ids, out = span
+        if data.dtype == np.float32:
+            np.take(data, ids, axis=0, out=out, mode="clip")
+        else:
+            out[:] = data[ids]
+
+    workers = min(len(spans), host_cores())
+    if workers <= 1 or sum(len(ids) for ids, _ in spans) < _GATHER_ROWS:
+        for span in spans:
+            gather(span)
+    else:
+        with concurrent.futures.ThreadPoolExecutor(workers) as pool:
+            list(pool.map(gather, spans))
 
 
 class BKTree:
@@ -85,44 +135,32 @@ class BKTree:
         ids_all = (np.arange(n, dtype=np.int64) if sample_ids is None
                    else np.asarray(sample_ids, np.int64))
 
-        centerid: List[int] = []
-        child_start: List[int] = []
-        child_end: List[int] = []
+        table = _NodeTable(self.tree_number * (n + 2))
         tree_starts: List[int] = []
         self.sample_center_map = {}
-
-        def new_node(cid: int) -> int:
-            centerid.append(cid)
-            child_start.append(-1)
-            child_end.append(-1)
-            return len(centerid) - 1
 
         key = jax.random.PRNGKey(seed)
 
         for t in range(self.tree_number):
             perm = rng.permutation(ids_all)
-            tree_starts.append(len(centerid))
-            root = new_node(n)
+            tree_starts.append(table.n)
+            root = table.add([n])
             # level items: (node_idx, sample-id array, has_center_sample —
             # False for the root, whose centerid is the count sentinel)
             level: List[Tuple[int, np.ndarray, bool]] = [(root, perm, False)]
             while level:
-                level = self._expand_level(
-                    data, level, centerid, child_start, child_end,
-                    new_node, rng, key)
+                level = self._expand_level(data, level, table, rng, key)
                 key, _ = jax.random.split(key)
-            new_node(-1)     # per-tree sentinel (reference BKTree.h:208)
+            table.add([-1])  # per-tree sentinel (reference BKTree.h:208)
 
         self.tree_starts = np.asarray(tree_starts, np.int32)
-        self.nodes = np.zeros(len(centerid), fmt.BKT_NODE_DTYPE)
-        self.nodes["centerid"] = centerid
-        self.nodes["childStart"] = child_start
-        self.nodes["childEnd"] = child_end
+        self.nodes = np.zeros(table.n, fmt.BKT_NODE_DTYPE)
+        self.nodes["centerid"] = table.centerid[:table.n]
+        self.nodes["childStart"] = table.child_start[:table.n]
+        self.nodes["childEnd"] = table.child_end[:table.n]
 
-    def _expand_level(self, data, level, centerid, child_start, child_end,
-                      new_node, rng, key):
+    def _expand_level(self, data, level, table, rng, key):
         """Expand all items of one level; returns the next level's items."""
-        K = self.kmeans_k
         next_level: List[Tuple[int, np.ndarray, bool]] = []
 
         leaf_items = [(ni, ids) for ni, ids, _ in level
@@ -130,11 +168,17 @@ class BKTree:
         km_items = [(ni, ids, hc) for ni, ids, hc in level
                     if len(ids) > self.leaf_size]
 
-        for ni, ids in leaf_items:
-            child_start[ni] = len(centerid)
-            for s in ids:
-                new_node(int(s))
-            child_end[ni] = len(centerid)
+        if leaf_items:
+            # one node a sample, every item's children side by side (a
+            # 10M-row tree has a million such items: no Python step each)
+            lens = np.fromiter((len(ids) for _, ids in leaf_items), np.int64,
+                               len(leaf_items))
+            owners = np.fromiter((ni for ni, _ in leaf_items), np.int64,
+                                 len(leaf_items))
+            first = table.add(np.concatenate([ids for _, ids in leaf_items]))
+            stops = first + np.cumsum(lens)
+            table.child_start[owners] = stops - lens
+            table.child_end[owners] = stops
 
         # ---- bucket k-means items by padded size, run batched device kmeans
         results: Dict[int, Tuple[np.ndarray, np.ndarray, np.ndarray]] = {}
@@ -162,7 +206,7 @@ class BKTree:
         for idx, (ni, ids, has_center) in enumerate(km_items):
             labels, counts, medoids = results[idx]
             nonzero = np.flatnonzero(counts)
-            child_start[ni] = len(centerid)
+            table.child_start[ni] = table.n
             if len(nonzero) <= 1:
                 # degenerate duplicate cluster (reference BKTree.h:184-195).
                 # The node's own centerid sample was excluded from `ids` by
@@ -172,32 +216,31 @@ class BKTree:
                 # nodes created by a parent's clustering carry such a
                 # sample (`has_center`) — the root's centerid is the count
                 # sentinel and must never be re-included.
-                old_center = int(centerid[ni])
+                old_center = int(table.centerid[ni])
                 if has_center and old_center not in ids:
                     ids = np.concatenate([ids, [old_center]])
                 ids_sorted = np.sort(ids)
                 center = int(ids_sorted[0])
-                centerid[ni] = center
-                child_start[ni] = -child_start[ni]
+                table.centerid[ni] = center
+                table.child_start[ni] = -table.child_start[ni]
+                table.add(ids_sorted[1:])
                 for dup in ids_sorted[1:]:
-                    new_node(int(dup))
                     self.sample_center_map[int(dup)] = center
                 self.sample_center_map[-1 - center] = ni
             else:
                 order = np.argsort(labels, kind="stable")
                 sorted_ids = ids[order]
                 offsets = np.concatenate([[0], np.cumsum(counts)])
-                for k in nonzero:
+                first = table.add(medoids[nonzero])
+                for cni, k in enumerate(nonzero, first):
                     members = sorted_ids[offsets[k]:offsets[k + 1]]
-                    med = medoids[k]
-                    cni = new_node(int(med))
                     # sample ids are unique within a node: removing the
                     # medoid drops exactly one member (reference excludes the
                     # cluster's center from deeper recursion, BKTree.h:201)
-                    rest = members[members != med]
+                    rest = members[members != medoids[k]]
                     if len(rest) > 0:
                         next_level.append((cni, rest, True))
-            child_end[ni] = len(centerid)
+            table.child_end[ni] = table.n
         return next_level
 
     def _run_kmeans_chunk(self, data, km_items, chunk, p_full, p_sub,
@@ -217,22 +260,33 @@ class BKTree:
         D = data.shape[1]
         sub = np.zeros((B, p_sub, D), np.float32)
         sub_valid = np.zeros((B, p_sub), bool)
-        full = np.zeros((B, p_full, D), np.float32)
-        full_valid = np.zeros((B, p_full), bool)
+        jobs = []
         for row, idx in enumerate(chunk):
             ids = km_items[idx][1]
             cnt = len(ids)
             take = min(cnt, self.samples)
             pick = (ids if cnt <= self.samples
                     else rng.choice(ids, self.samples, replace=False))
-            sub[row, :take] = data[pick].astype(np.float32)
+            jobs.append((pick, sub[row, :take]))
             sub_valid[row, :take] = True
-            full[row, :cnt] = data[ids].astype(np.float32)
-            full_valid[row, :cnt] = True
-
+        _gather_f32(data, jobs)
         centers, _ = km.kmeans_fit(
             sub, sub_valid, key, K, self.lloyd_iterations,
             self.restarts, self.metric, self.base)
+
+        if p_full > _MAX_BATCH_ROWS:
+            # a node larger than the device batch (B is 1 here): the final
+            # assignment is row by row, so it runs a batch of rows at a time
+            (idx,) = chunk
+            results[idx] = self._assign_in_spans(data, km_items[idx][1],
+                                                 centers, K)
+            return
+        full = np.zeros((B, p_full, D), np.float32)
+        full_valid = np.zeros((B, p_full), bool)
+        for row, idx in enumerate(chunk):
+            full_valid[row, :len(km_items[idx][1])] = True
+        _gather_f32(data, [(km_items[idx][1], full[row])
+                           for row, idx in enumerate(chunk)])
         labels, counts, medoid_pos = km.kmeans_final_assign(
             full, full_valid, centers, K, self.metric, self.base)
         labels = np.asarray(labels)
@@ -244,6 +298,42 @@ class BKTree:
             med_ids = np.where(medoid_pos[row] >= 0,
                                ids[np.clip(medoid_pos[row], 0, cnt - 1)], -1)
             results[idx] = (labels[row, :cnt], counts[row], med_ids)
+
+    def _assign_in_spans(self, data, ids, centers, K):
+        """`kmeans_final_assign` of ONE node over spans of `_MAX_BATCH_ROWS`
+        of its rows: labels side by side, counts added, and per cluster the
+        medoid of the span whose medoid lies nearest its center.  The root
+        of a 10M x 96 tree is otherwise one (1, 2^24, 96) float32 batch:
+        6.4 GB on the host, as much on the device, and the (rows, K)
+        temporaries beside it."""
+        D = data.shape[1]
+        centers_h = np.asarray(centers)[0].astype(np.float64)
+        labels = np.empty(len(ids), np.int32)
+        counts = np.zeros(K, np.int64)
+        best = np.full(K, np.inf)
+        med_ids = np.full(K, -1, np.int64)
+        span = np.zeros((1, _MAX_BATCH_ROWS, D), np.float32)
+        valid = np.zeros((1, _MAX_BATCH_ROWS), bool)
+        for lo in range(0, len(ids), _MAX_BATCH_ROWS):
+            part = ids[lo:lo + _MAX_BATCH_ROWS]
+            _gather_f32(data, [(part, span[0])])
+            valid[0] = np.arange(_MAX_BATCH_ROWS) < len(part)
+            lab, cnt, pos = km.kmeans_final_assign(
+                span, valid, centers, K, self.metric, self.base)
+            labels[lo:lo + len(part)] = np.asarray(lab)[0, :len(part)]
+            counts += np.asarray(cnt)[0]
+            pos = np.asarray(pos)[0]
+            has = np.flatnonzero(pos >= 0)
+            cand = part[pos[has]]
+            x = data[cand].astype(np.float64)
+            if self.metric == 1:
+                d = float(self.base) ** 2 - (x * centers_h[has]).sum(1)
+            else:
+                d = ((x - centers_h[has]) ** 2).sum(1)
+            nearer = d < best[has]
+            best[has[nearer]] = d[nearer]
+            med_ids[has[nearer]] = cand[nearer]
+        return labels, counts, med_ids
 
     # ---------------------------------------------------------------- queries
 

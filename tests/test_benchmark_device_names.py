@@ -52,11 +52,13 @@ SCOPE_FILES = {
     "beam": "sptag_tpu/algo/engine.py",
     "mesh": "sptag_tpu/parallel/sharded.py",
 }
-#: the scopes by the constant that lists them (13 in all)
+#: the scopes by the constant that lists them (15 in all)
 SCOPES = ([("STAGES", s) for s in (
               "flat.distance", "flat.topk", "dense.centroids",
               "dense.gather", "dense.probe", "dense.mask", "dense.topk")]
           + [("MERGE", "mesh.merge")]
+          # kernel.dense_mask_ms_per_batch / kernel.dense_probe_ms_per_batch
+          + [("SCOPE", s) for s in ("dense.mask", "dense.probe")]
           + [("BEAM_STAGES", s) for s in (
               "beam.seed", "beam.gather", "beam.score", "beam.merge",
               "beam.finalize")])
@@ -79,7 +81,14 @@ def _benchmark_programs() -> frozenset:
 @functools.lru_cache(maxsize=None)
 def _benchmark_scopes() -> dict:
     merge = load_by_name("layer_metrics", "kernel.mesh_merge_ms_per_batch")
+    one_scope = tuple(
+        load_by_name("layer_metrics", name).SCOPE
+        for name in ("kernel.dense_mask_ms_per_batch",
+                     "kernel.dense_probe_ms_per_batch"))
+    # a one-scope reader takes its seconds from scopes.read_stages
+    assert set(one_scope) <= set(scopes.STAGES)
     return {"STAGES": tuple(scopes.STAGES), "MERGE": (merge.MERGE,),
+            "SCOPE": one_scope,
             "BEAM_STAGES": tuple(stage_dump_beam.BEAM_STAGES)}
 
 

@@ -74,9 +74,25 @@ MESH_LIVE_ONLY = [
     m["name"] for m in _BENCH["per_layer"] if m["name"] in LIVE_ONLY
     and all({w["name"]: w for w in _BENCH["workloads"]}[c]["chips"] == 4
             for c in m["workloads"])]
+
+
+def _packs_a_tree(cell: str) -> bool:
+    """Whether the cell's configuration is a dense-only BKT index (the
+    tree, its cut into blocks and no graph)."""
+    cells = {w["name"]: w for w in _BENCH["workloads"]}
+    files = {c["name"]: c["file"] for c in _BENCH["configs"]}
+    with open(os.path.join(REPO, files[cells[cell]["config"]])) as f:
+        return json.load(f)["index_params"].get("BuildGraph") == "0"
+
+
+#: metrics listed for dense-only cells alone read the stages of such an
+#: index's bring-up: held against one that is built and searched, at the end
+DENSE_ONLY = [m["name"] for m in _BENCH["per_layer"]
+              if m.get("workloads")
+              and all(map(_packs_a_tree, m["workloads"]))]
 PER_LAYER = [m["name"] for m in _BENCH["per_layer"]
              if m["source"] in OFF_CHIP_SOURCES
-             and m["name"] not in BEAM_ONLY + LIVE_ONLY]
+             and m["name"] not in BEAM_ONLY + LIVE_ONLY + DENSE_ONLY]
 
 
 def _served_bursts(index, rows, warm: int, bursts: int) -> dict:
@@ -483,3 +499,51 @@ def test_the_living_control_puts_the_plain_scan_through_the_rule(
     if correct:
         assert compared["stale_or_wrong_lists"] == 0
     assert (compared["dist_err_ulps_rms"] <= 5.0) is correct
+
+
+# ---- the dense-only bring-up's readers (PR 48) ------------------------------
+
+DENSE_OFF_CHIP = [
+    m for m in DENSE_ONLY
+    if {x["name"]: x for x in _BENCH["per_layer"]}[m]["source"]
+    in OFF_CHIP_SOURCES]
+
+
+def test_benchmark_lists_the_bring_ups_readers():
+    assert {"build.tree_seconds",
+            "build.dense_pack_seconds"} <= set(DENSE_OFF_CHIP)
+    assert {"kernel.dense_mask_ms_per_batch",
+            "kernel.dense_probe_ms_per_batch"} <= set(DENSE_ONLY)
+
+
+@pytest.mark.parametrize("metric", DENSE_OFF_CHIP)
+def test_reader_returns_a_number_from_a_dense_only_index(metric):
+    """The build lies before the window, so these read the program's own
+    report: a dense-only BKT index built and searched once in this
+    process leaves both spans."""
+    reader = load_by_name("layer_metrics", metric)
+    assert reader.read({"spans": {}}) is None           # nothing built yet
+    data = np.random.default_rng(3).standard_normal((600, 8)).astype(
+        np.float32)
+    index = sp.create_instance("BKT", "Float")
+    index.set_parameter("DistCalcMethod", "L2")
+    for name, value in [("BuildGraph", "0"), ("BKTKmeansK", "8"),
+                        ("BKTLeafSize", "16"), ("DenseClusterSize", "32"),
+                        ("MaxCheck", "128")]:
+        assert index.set_parameter(name, value), name
+    assert index.build(data) == sp.ErrorCode.Success
+    index.search_batch(data[:4], K)
+    value = reader.read({"spans": {}})
+    assert isinstance(value, float) and math.isfinite(value) and value > 0
+    assert value == trace.report()[{
+        "build.tree_seconds": "build.bkt_tree",
+        "build.dense_pack_seconds": "build.dense_pack"}[metric]]["total_s"]
+
+
+def test_the_bring_ups_readers_read_nothing_on_another_index(run):
+    """The parent's program (no `build.dense_pack`), a served FLAT index
+    (no tree), a run with no chip's trace: None, no raise."""
+    for metric in DENSE_ONLY:
+        assert load_by_name("layer_metrics", metric).read(
+            {**run, "trace": None, "workload": "flat_1m.saturate",
+             "config": {"dim": 8}, "peaks": {}}) is None

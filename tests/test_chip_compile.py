@@ -194,6 +194,42 @@ def test_dense_grouped_kernel_compiles_with_pallas(one_chip, dtype, D,
     assert _has_mosaic_kernel(compiled)
 
 
+# the dense-only cell (bkt_deep10m.saturate, PR 48): 10M x 96 f32 in ~45k
+# blocks of 256 rows, MaxCheck 65,536 -> 256 blocks a query.  96-d rows are
+# off the Pallas route (`pallas_kernels.supported`: the kernel's (P, D)
+# block wants whole 128-lane rows; compiled for 96 it re-lays the WHOLE
+# resident layout out, 5.9 GB of temporaries a call), so the XLA gather
+# scores the probed blocks
+DEEP_N, DEEP_C, DEEP_D, DEEP_NPROBE = 10_000_000, 45_056, 96, 256
+
+
+@pytest.mark.parametrize("Q", [1, 8, 32, 128])
+def test_dense_search_kernel_compiles_deep10m_dense_only(one_chip, Q):
+    from sptag_tpu.algo.dense import _dense_search_kernel
+    from sptag_tpu.ops import pallas_kernels
+
+    perm = _s(one_chip, (DEEP_C, P_BLK, DEEP_D), jnp.float32)
+    assert not pallas_kernels.supported(perm)
+    compiled = _dense_search_kernel.lower(
+        perm, _s(one_chip, (DEEP_C, P_BLK), jnp.int32),
+        _s(one_chip, (DEEP_C, P_BLK), jnp.float32),
+        _s(one_chip, (DEEP_C, DEEP_D), jnp.float32),
+        _s(one_chip, (DEEP_C,), jnp.float32),
+        _s(one_chip, (DEEP_N,), jnp.bool_),
+        _s(one_chip, (Q, DEEP_D), jnp.float32), k=K, nprobe=DEEP_NPROBE,
+        metric=L2, base=1, use_pallas=False, interpret=False).compile()
+    assert not _has_mosaic_kernel(compiled)
+    mem = compiled.memory_analysis()
+    # the resident layout is held compact (no 96 -> 128 lane padding: that
+    # would be 5.9 GB): blocks + ids + norms + means + mask
+    resident = DEEP_C * P_BLK * (DEEP_D * 4 + 8) + DEEP_C * (DEEP_D + 1) * 4
+    assert mem.argument_size_in_bytes <= resident + DEEP_N + (1 << 20)
+    # the gathered candidates (Q, nprobe x 256, 96) f32 and their scores,
+    # once: 3.26 GB at the 128 rung, inside `gather_budget()` of a v5e
+    assert mem.temp_size_in_bytes \
+        <= 1.1 * Q * DEEP_NPROBE * P_BLK * (DEEP_D + 4) * 4 + (1 << 22)
+
+
 @pytest.mark.parametrize("n,D,Q", [
     (1_000_064, 128, 128), (1_000_064, 128, 512),       # flat_1m
     (5_312_640, 100, 128), (5_312_640, 100, 512),       # flat_live5m

@@ -4,7 +4,9 @@ RNG graph so the index serves the MXU partition scan alone.
 The reference always builds its graph (BuildIndex, BKTIndex.cpp:279-306);
 BuildGraph=0 exists for dense-mode-only deployments where the graph's
 TPT + refine passes are pure build cost (the partition scan never reads
-it) — it is what makes 10M-row single-chip corpora buildable in minutes.
+it) — it is what lets one chip build and serve 10M rows inside a run
+(benchmark cell `bkt_deep10m.saturate`; the served path of such a folder
+is tests/test_dense_only_served.py).
 """
 
 import numpy as np
@@ -76,8 +78,8 @@ def test_dense_only_save_load_roundtrip(tmp_path):
 def test_dense_only_sharded_mesh():
     """BuildGraph=0 flows through the mesh build: dense search works over
     8 shards, beam refuses — the 8-shard dense-only program is exactly
-    BASELINE config 3's topology (tools/deep1b_single_chip.py measures
-    the single-chip aggregate)."""
+    BASELINE config 3's topology (one chip's dense-only form is measured
+    by the benchmark cell `bkt_deep10m.saturate`)."""
     from sptag_tpu.parallel.sharded import ShardedBKTIndex
 
     data, queries = _corpus(n=4000)
