@@ -373,19 +373,17 @@ def partition_from_kdtree(tree, n: int, target_size: int
     return _pack_clusters(clusters, centers, target_size)
 
 
-def _finalize_topk(nd, ids, deleted, dedup: bool, k: int, extra_dead=None,
-                   binned_bins: int = 0):
+def _finalize_topk(nd, ids, dead, dedup: bool, k: int, binned_bins: int = 0):
     """Shared epilogue of the dense kernels: tombstone/sentinel masking,
     optional replica de-duplication, masked top-k, -1 id sentinel.
+    `dead` holds the candidates' dead bits, (Q, W) like `nd` and `ids`:
+    true for a padding slot (id < 0) and for a tombstoned row.
     `binned_bins` > 0 replaces the full (Q, nprobe*P)-wide `lax.top_k`
     with the bin-reduction select (ops/topk_bins.py) — the peak-FLOP/s
     recipe's answer to the scan's sort bottleneck; callers size bins via
     the recall-target math so returned-set recall meets the configured
     ApproxRecallTarget."""
     with jax.named_scope("dense.mask"):
-        dead = deleted[jnp.maximum(ids, 0)] | (ids < 0)
-        if extra_dead is not None:
-            dead = dead | extra_dead
         nd = jnp.where(dead, MAX_DIST, nd)
         if dedup:
             # closure-assigned replicas: the same row can appear in
@@ -412,12 +410,17 @@ def _finalize_topk(nd, ids, deleted, dedup: bool, k: int, extra_dead=None,
                                     "use_pallas", "interpret", "dedup",
                                     "binned_bins"))
 def _dense_search_kernel(data_perm, member_ids, member_sq, centroids,
-                        cent_sq, deleted, queries, k: int, nprobe: int,
+                        cent_sq, dead_slot, queries, k: int, nprobe: int,
                         metric: int, base: int, use_pallas: bool = False,
                         interpret: bool = False, dedup: bool = False,
                         binned_bins: int = 0):
     """One program: (Q,C) center scores -> top-nprobe block gather ->
     (Q, nprobe*P) candidate scores -> masked top-k.
+
+    `dead_slot` is the (C, P) tombstone table of the layout's slots
+    (`DenseTreeSearcher.set_deleted`): a candidate's dead bit arrives with its block, as its
+    id and its norm do, never by its row id (that was one element fetch
+    for each of the Q x nprobe x P candidates).
 
     With `use_pallas`, the block gather + scoring runs as the Pallas DMA
     kernel (ops/pallas_kernels.py) — the XLA gather materializes the
@@ -462,7 +465,11 @@ def _dense_search_kernel(data_perm, member_ids, member_sq, centroids,
             vecs = data_perm[topc].reshape(Q, nprobe * P, D)
             nd = dist_ops.batched_gathered_distance(
                 queries, vecs, DistCalcMethod(metric), base, sq)
-    return DeviceTopK(*_finalize_topk(nd, ids, deleted, dedup, k,
+    # under dense.mask, not dense.gather: the scope holds everything a
+    # tombstone costs (benchmark kernel.dense_mask_ms_per_batch)
+    with jax.named_scope("dense.mask"):
+        dead = dead_slot[topc].reshape(Q, nprobe * P)
+    return DeviceTopK(*_finalize_topk(nd, ids, dead, dedup, k,
                                       binned_bins=binned_bins))
 
 
@@ -482,7 +489,7 @@ def _segmented_min(vals, first):
                                     "base", "use_pallas", "interpret",
                                     "dedup", "binned_bins"))
 def _dense_search_grouped_kernel(data_perm, member_ids, member_sq, centroids,
-                                 cent_sq, deleted, queries, nq_valid,
+                                 cent_sq, dead_slot, queries, nq_valid,
                                  k: int, nprobe: int, U: int, G: int,
                                  metric: int, base: int,
                                  use_pallas: bool = False,
@@ -600,10 +607,13 @@ def _dense_search_grouped_kernel(data_perm, member_ids, member_sq, centroids,
     ids = jnp.broadcast_to(ids_u[:, None, :, :],
                            (NG, G, U, P)).reshape(Q, U * P)
     nd = nd.reshape(Q, U * P)
-    pad_blocks = jnp.broadcast_to((union < 0)[:, None, :, None],
-                                  (NG, G, U, P)).reshape(Q, U * P)
-    out_d, out_ids = _finalize_topk(nd, ids, deleted, dedup, k,
-                                    extra_dead=pad_blocks,
+    with jax.named_scope("dense.mask"):
+        # the union's dead bits by block, a padding union entry all dead,
+        # one copy for each of the group's queries
+        dead_u = dead_slot[union_safe] | (union < 0)[:, :, None]  # (NG, U, P)
+        dead = jnp.broadcast_to(dead_u[:, None, :, :],
+                                (NG, G, U, P)).reshape(Q, U * P)
+    out_d, out_ids = _finalize_topk(nd, ids, dead, dedup, k,
                                     binned_bins=binned_bins)
     # un-sort back to the caller's query order
     return DeviceTopK(out_d[inv], out_ids[inv])
@@ -614,7 +624,7 @@ def _dense_search_grouped_kernel(data_perm, member_ids, member_sq, centroids,
                                     "base", "use_pallas", "interpret",
                                     "dedup", "binned_bins"))
 def _dense_search_grouped_chunked(data_perm, member_ids, member_sq,
-                                  centroids, cent_sq, deleted, queries3,
+                                  centroids, cent_sq, dead_slot, queries3,
                                   valid3, k: int, nprobe: int, U: int,
                                   G: int, metric: int, base: int,
                                   use_pallas: bool = False,
@@ -624,7 +634,7 @@ def _dense_search_grouped_chunked(data_perm, member_ids, member_sq,
     def body(args):
         q, nv = args
         return _dense_search_grouped_kernel(
-            data_perm, member_ids, member_sq, centroids, cent_sq, deleted,
+            data_perm, member_ids, member_sq, centroids, cent_sq, dead_slot,
             q, nv, k, nprobe, U, G, metric, base, use_pallas, interpret,
             dedup, binned_bins)
     return jax.lax.map(body, (queries3, valid3))
@@ -635,7 +645,7 @@ def _dense_search_grouped_chunked(data_perm, member_ids, member_sq,
                                     "use_pallas", "interpret", "dedup",
                                     "binned_bins"))
 def _dense_search_chunked(data_perm, member_ids, member_sq, centroids,
-                          cent_sq, deleted, queries3, k: int, nprobe: int,
+                          cent_sq, dead_slot, queries3, k: int, nprobe: int,
                           metric: int, base: int, use_pallas: bool = False,
                           interpret: bool = False, dedup: bool = False,
                           binned_bins: int = 0):
@@ -649,7 +659,7 @@ def _dense_search_chunked(data_perm, member_ids, member_sq, centroids,
     per-chunk score buffer is reused rather than multiplied."""
     def body(q):
         return _dense_search_kernel(
-            data_perm, member_ids, member_sq, centroids, cent_sq, deleted,
+            data_perm, member_ids, member_sq, centroids, cent_sq, dead_slot,
             q, k, nprobe, metric, base, use_pallas, interpret, dedup,
             binned_bins)
     return jax.lax.map(body, queries3)
@@ -911,15 +921,15 @@ class DenseTreeSearcher:
         self.member_sq = jnp.asarray(lay["sq"])
         self.centroids = jnp.asarray(lay["cent"])
         self.cent_sq = jnp.asarray(lay["cent_sq"])
-        if deleted is None:
-            deleted = np.zeros(self.n, bool)
-        self.deleted = jnp.asarray(deleted[:self.n])
+        # the ids stay on the host too: `set_deleted`'s pass over the slots
+        self._slot_ids = lay["ids"]
         # what was placed: the padded geometry every probe pays for
         slots = self.num_clusters * self.cluster_size
+        self._pad_slots = int((self._slot_ids < 0).sum())
         metrics.set_gauge("dense.blocks", self.num_clusters)
         metrics.set_gauge("dense.block_rows", self.cluster_size)
-        metrics.set_gauge("dense.pad_share",
-                          1.0 - int((lay["ids"] >= 0).sum()) / slots)
+        metrics.set_gauge("dense.pad_share", self._pad_slots / slots)
+        self.set_deleted(deleted)
         self.last_effective_group = 0     # set by search(); diagnostic only
         self.last_use_pallas = False      # likewise: the last search's route
         self._demotions = set()
@@ -932,7 +942,7 @@ class DenseTreeSearcher:
         and on DeviceBytesLedger re-enable."""
         lay_bytes = (self.data_perm.nbytes + self.member_ids.nbytes
                      + self.member_sq.nbytes + self.centroids.nbytes
-                     + self.cent_sq.nbytes + self.deleted.nbytes)
+                     + self.cent_sq.nbytes + self.dead_slot.nbytes)
         if self.data_perm.dtype == jnp.dtype(jnp.int8):
             devmem.track("int8_blocks", self, lay_bytes)
         else:
@@ -946,9 +956,24 @@ class DenseTreeSearcher:
             devmem.track("host_corpus", self, self.fp_host.nbytes,
                          host=True)
 
-    def set_deleted(self, deleted: np.ndarray) -> None:
-        """Swap only the tombstone mask (delete-only mutation path)."""
-        self.deleted = jnp.asarray(deleted[:self.n])
+    def set_deleted(self, deleted: Optional[np.ndarray]) -> None:
+        """Swap only the tombstones (delete-only mutation path): the
+        (C, P) per-slot dead table the kernels fetch by block, true where
+        a slot is padding (id < 0) or its row is tombstoned, in every
+        block that holds a replica of the row.  ONE pass over the C x P
+        slots on the host, from the FULL row mask (a mask with fewer bits
+        than the last brings its rows back), where the layout is placed
+        and once a swap, so that no search looks a tombstone up by row id;
+        the row mask itself never goes to the device."""
+        dead = self._slot_ids < 0
+        if deleted is not None:
+            dead |= np.asarray(deleted[:self.n])[
+                np.maximum(self._slot_ids, 0)]
+        self.dead_slot = jnp.asarray(dead)
+        metrics.inc("dense.tombstone_rebuilds")
+        # live tombstones x the blocks that hold them (DenseReplicas)
+        metrics.set_gauge("dense.dead_slots",
+                          int(dead.sum()) - self._pad_slots)
 
     def _group_floor(self) -> int:
         """Smallest legal query-group size: the Pallas (G, D) query block's
@@ -1143,14 +1168,14 @@ class DenseTreeSearcher:
             if g_eff > 1:
                 d, ids = _dense_search_grouped_kernel(
                     self.data_perm, self.member_ids, self.member_sq,
-                    self.centroids, self.cent_sq, self.deleted,
+                    self.centroids, self.cent_sq, self.dead_slot,
                     jnp.asarray(q), jnp.int32(nq), k_eff, nprobe, U, g_eff,
                     int(self.metric), self.base, use_pallas=use_pallas,
                     interpret=interp, dedup=dedup, binned_bins=bins)
             else:
                 d, ids = _dense_search_kernel(
                     self.data_perm, self.member_ids, self.member_sq,
-                    self.centroids, self.cent_sq, self.deleted,
+                    self.centroids, self.cent_sq, self.dead_slot,
                     jnp.asarray(q), k_eff, nprobe, int(self.metric),
                     self.base, use_pallas=use_pallas, interpret=interp,
                     dedup=dedup, binned_bins=bins)
@@ -1175,7 +1200,7 @@ class DenseTreeSearcher:
             valid3 = np.clip(nq - chunk * np.arange(m), 0, chunk)
             d, ids = _dense_search_grouped_chunked(
                 self.data_perm, self.member_ids, self.member_sq,
-                self.centroids, self.cent_sq, self.deleted,
+                self.centroids, self.cent_sq, self.dead_slot,
                 jnp.asarray(q.reshape(m, chunk, D)),
                 jnp.asarray(valid3, np.int32),
                 k_eff, nprobe, U, min(G, chunk), int(self.metric),
@@ -1184,7 +1209,7 @@ class DenseTreeSearcher:
         else:
             d, ids = _dense_search_chunked(
                 self.data_perm, self.member_ids, self.member_sq,
-                self.centroids, self.cent_sq, self.deleted,
+                self.centroids, self.cent_sq, self.dead_slot,
                 jnp.asarray(q.reshape(m, chunk, D)),
                 k_eff, nprobe, int(self.metric), self.base,
                 use_pallas=use_pallas, interpret=interp, dedup=dedup,

@@ -798,7 +798,11 @@ def _sharded_dense_kernel(data_perm, member_ids, member_sq, centroids,
         vecs = dp_s[0][topc].reshape(Q, nprobe * Pb, dp_s.shape[3])
         nd = dist_ops.batched_gathered_distance(
             q_s, vecs, DistCalcMethod(metric), base, sq)
-        d, out_ids = _finalize_topk(nd, ids, del_s, dedup, k_local,
+        # the shard's own row mask by candidate id (the single-chip
+        # searcher keeps a per-slot table instead: DenseTreeSearcher.set_deleted)
+        with jax.named_scope("dense.mask"):
+            dead = del_s[jnp.maximum(ids, 0)] | (ids < 0)
+        d, out_ids = _finalize_topk(nd, ids, dead, dedup, k_local,
                                     binned_bins=binned_bins)
         gids = jnp.where(out_ids >= 0, out_ids + shard * n_local, -1)
         return _gather_merge(d, gids, k_final)

@@ -161,7 +161,7 @@ def _dense_args(sh, dtype, D, Q):
             _s(sh, (C_BLK, P_BLK), jnp.float32),       # member_sq
             _s(sh, (C_BLK, D), jnp.float32),           # centroids
             _s(sh, (C_BLK,), jnp.float32),             # cent_sq
-            _s(sh, (N_BKT,), jnp.bool_),               # deleted
+            _s(sh, (C_BLK, P_BLK), jnp.bool_),         # dead_slot
             _s(sh, (Q, D), dt))                        # queries
 
 
@@ -215,15 +215,15 @@ def test_dense_search_kernel_compiles_deep10m_dense_only(one_chip, Q):
         _s(one_chip, (DEEP_C, P_BLK), jnp.float32),
         _s(one_chip, (DEEP_C, DEEP_D), jnp.float32),
         _s(one_chip, (DEEP_C,), jnp.float32),
-        _s(one_chip, (DEEP_N,), jnp.bool_),
+        _s(one_chip, (DEEP_C, P_BLK), jnp.bool_),
         _s(one_chip, (Q, DEEP_D), jnp.float32), k=K, nprobe=DEEP_NPROBE,
         metric=L2, base=1, use_pallas=False, interpret=False).compile()
     assert not _has_mosaic_kernel(compiled)
     mem = compiled.memory_analysis()
     # the resident layout is held compact (no 96 -> 128 lane padding: that
-    # would be 5.9 GB): blocks + ids + norms + means + mask
-    resident = DEEP_C * P_BLK * (DEEP_D * 4 + 8) + DEEP_C * (DEEP_D + 1) * 4
-    assert mem.argument_size_in_bytes <= resident + DEEP_N + (1 << 20)
+    # would be 5.9 GB): blocks + ids + norms + one dead byte a slot + means
+    resident = DEEP_C * P_BLK * (DEEP_D * 4 + 9) + DEEP_C * (DEEP_D + 1) * 4
+    assert mem.argument_size_in_bytes <= resident + (1 << 20)
     # the gathered candidates (Q, nprobe x 256, 96) f32 and their scores,
     # once: 3.26 GB at the 128 rung, inside `gather_budget()` of a v5e
     assert mem.temp_size_in_bytes \

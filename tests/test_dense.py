@@ -1,8 +1,11 @@
 """Dense tree-partition search tests (TPU-first fast path, algo/dense.py)."""
 
+import jax
 import numpy as np
+import pytest
 
 import sptag_tpu as sp
+from sptag_tpu.algo import dense
 from sptag_tpu.algo.dense import DenseTreeSearcher, partition_from_tree
 from sptag_tpu.core.types import DistCalcMethod
 from sptag_tpu.trees.bktree import BKTree
@@ -257,3 +260,223 @@ def test_dense_param_change_after_search_takes_effect():
     # the snapshot (rebuilds are expensive; only baked params pay it)
     assert index.set_parameter("DenseQueryGroup", "8")
     assert index._dense is snap3
+
+
+# ---------------------------------------------------------------------------
+# a candidate's tombstone arrives with its block (PR 49): the per-slot dead
+# table against the per-id formula `deleted[ids] | ids < 0`
+# ---------------------------------------------------------------------------
+
+def _blocks(data, target=64):
+    tree = BKTree(tree_number=1, kmeans_k=8, leaf_size=8, samples=100)
+    tree.build(data)
+    return partition_from_tree(tree, len(data), target)
+
+
+def _rows_of_block(searcher, block):
+    ids = np.asarray(searcher.member_ids)[block]
+    return ids[ids >= 0]
+
+
+def _by_id_formula(plain, deleted, queries, k, **route):
+    """What the per-id formula answers: EVERY candidate the route scores
+    for a query, nearest first (a tombstone-free searcher over the same
+    layout, asked for as many answers as it has candidates), masked in
+    numpy by `deleted[ids] | ids < 0`, the first k kept.  The distances
+    are the program's own float32 values, so the comparison is bit for
+    bit."""
+    width = plain.num_clusters * plain.cluster_size
+    d_all, i_all = plain._scan_topk(queries, width, **route)
+    out_d = np.full((len(queries), k), dense.MAX_DIST, np.float32)
+    out_i = np.full((len(queries), k), -1, np.int32)
+    for q, (d_q, i_q) in enumerate(zip(d_all, i_all)):
+        live = ~(deleted[np.maximum(i_q, 0)] | (i_q < 0))
+        got = min(k, int(live.sum()))
+        out_d[q, :got] = d_q[live][:got]
+        out_i[q, :got] = i_q[live][:got]
+    return out_d, out_i
+
+
+def _f32_case(**kw):
+    def make():
+        data = _corpus(n=2000, d=16, seed=9)
+        rng = np.random.default_rng(1)
+        queries = data[rng.integers(0, len(data), 131)] \
+            + rng.standard_normal((131, 16)).astype(np.float32) * 0.05
+        return data, queries, DistCalcMethod.L2, 1
+    return dict(make=make, **kw)
+
+
+def _int8_case(**kw):
+    def make():
+        rng = np.random.default_rng(4)
+        data = np.clip(_corpus(n=1500, d=16, seed=2) * 12, -127, 127
+                       ).astype(np.int8)
+        return (data, data[rng.integers(0, len(data), 40)],
+                DistCalcMethod.L2, 127)
+    return dict(make=make, **kw)
+
+
+def _random_tenth(searcher, rng):
+    return rng.random(searcher.n) < 0.1
+
+
+def _one_whole_block(searcher, rng):
+    mask = np.zeros(searcher.n, bool)
+    # the block most queries of the corpus rank first is as good as any
+    mask[_rows_of_block(searcher, 3)] = True
+    return mask
+
+
+CASES = {
+    "no_tombstones": _f32_case(mask=lambda s, rng: np.zeros(s.n, bool)),
+    "several_blocks": _f32_case(mask=_random_tenth),
+    "one_block_all_dead": _f32_case(mask=_one_whole_block),
+    "replicas_2_dedup": _f32_case(mask=_random_tenth, replicas=2),
+    "grouped": _f32_case(mask=_random_tenth, route=dict(group=8),
+                         group=8),
+    "chunked": _f32_case(mask=_random_tenth, chunk=16),
+    "grouped_chunked": _f32_case(mask=_random_tenth, route=dict(group=8),
+                                 group=8, chunk=32),
+    "int8_blocks": _int8_case(mask=_random_tenth),
+    "cascade": _f32_case(mask=_random_tenth,
+                         cascade={"tier": "device", "rerank_budget": 0}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_tombstones_by_slot_answer_as_the_per_id_formula(monkeypatch, case):
+    """ids AND float32 distances, bit for bit, through every route of the
+    dense program: the new per-slot table and the parent's per-id gather
+    are the same set of dead candidates."""
+    spec = CASES[case]
+    data, queries, metric, base = spec["make"]()
+    centers, clusters = _blocks(data)
+    kw = dict(replicas=spec.get("replicas", 1),
+              cascade_cfg=spec.get("cascade"))
+    plain = DenseTreeSearcher(data, centers, clusters, None, metric, base,
+                              **kw)
+    deleted = spec["mask"](plain, np.random.default_rng(7))
+    marked = DenseTreeSearcher(data, centers, clusters, deleted, metric,
+                               base, **kw)
+    np.testing.assert_array_equal(np.asarray(marked.member_ids),
+                                  np.asarray(plain.member_ids))
+    # the table itself is the formula over the layout's ids
+    mids = np.asarray(plain.member_ids)
+    np.testing.assert_array_equal(
+        np.asarray(marked.dead_slot),
+        deleted[np.maximum(mids, 0)] | (mids < 0))
+    if spec.get("cascade"):
+        # the cascade's int8 blocks hold x / scale (the scan's queries too)
+        queries = queries / np.float32(plain.scale)
+    route = dict(max_check=256, **spec.get("route", {}))
+    if "chunk" in spec:
+        per_query = 4 * plain.cluster_size * data.shape[1] * 4
+        # grouped: U = 2 x nprobe blocks a group of 8, reckoned per query
+        if spec.get("group"):
+            per_query = 2 * per_query // spec["group"]
+        monkeypatch.setattr(dense, "gather_budget",
+                            lambda: spec["chunk"] * per_query)
+        twin = ("_dense_search_grouped_chunked" if spec.get("group")
+                else "_dense_search_chunked")
+        chunked, runs = getattr(dense, twin), []
+        monkeypatch.setattr(
+            dense, twin,
+            lambda *a, **k: runs.append(a[6].shape) or chunked(*a, **k))
+    want_d, want_i = _by_id_formula(plain, deleted, queries, 10, **route)
+    got_d, got_i = marked._scan_topk(queries, 10, **route)
+    assert marked.last_effective_group == spec.get("group", 0)
+    if "chunk" in spec:         # both searchers took the lax.map twin
+        assert len(runs) == 2 and runs[1][1] == spec["chunk"], runs
+    np.testing.assert_array_equal(got_i, want_i)
+    np.testing.assert_array_equal(got_d.view(np.uint32),
+                                  want_d.view(np.uint32))
+    assert not deleted[got_i[got_i >= 0]].any()
+    assert (got_i[:, 0] >= 0).all()
+    if spec.get("cascade"):
+        # and the public search re-ranks a tombstone-free shortlist
+        _, ids = marked.search(queries * np.float32(plain.scale), 10,
+                               max_check=256)
+        assert (ids[:, 0] >= 0).all()
+        assert not deleted[ids[ids >= 0]].any()
+
+
+@pytest.mark.parametrize("replicas", [1, 2])
+def test_set_deleted_after_placement_and_a_later_smaller_mask(replicas):
+    """`set_deleted` takes the FULL row mask each time: the next search
+    honours it, and a mask with fewer bits (a refine dropped tombstones)
+    brings the rows back, in every block that holds a replica."""
+    data = _corpus(n=1200, d=16, seed=11)
+    centers, clusters = _blocks(data)
+    searcher = DenseTreeSearcher(data, centers, clusters, None,
+                                 DistCalcMethod.L2, 1, replicas=replicas)
+    queries = data[:24]      # every block probed: a row finds itself
+    d0, i0 = searcher.search(queries, 5, max_check=4096)
+    assert (i0[:, 0] == np.arange(24)).all()
+    mask = np.zeros(len(data), bool)
+    mask[:24] = True
+    searcher.set_deleted(mask)
+    mids = np.asarray(searcher.member_ids)
+    np.testing.assert_array_equal(np.asarray(searcher.dead_slot),
+                                  (mids < 0) | ((mids >= 0) & (mids < 24)))
+    _, i1 = searcher.search(queries, 5, max_check=4096)
+    assert not np.isin(i1, np.arange(24)).any()
+    mask[:12] = False                       # fewer bits than the last
+    searcher.set_deleted(mask)
+    _, i2 = searcher.search(queries, 5, max_check=4096)
+    assert (i2[:12, 0] == np.arange(12)).all()
+    assert not np.isin(i2, np.arange(12, 24)).any()
+    searcher.set_deleted(np.zeros(len(data), bool))
+    d3, i3 = searcher.search(queries, 5, max_check=4096)
+    np.testing.assert_array_equal(i3, i0)
+    np.testing.assert_array_equal(d3.view(np.uint32), d0.view(np.uint32))
+
+
+def _gathers(jaxpr):
+    """Every `gather` equation of a jaxpr, the nested ones (pjit, while,
+    cond, custom calls) included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _gathers(sub)
+
+
+@pytest.mark.parametrize("grouped", [False, True])
+def test_the_dense_programs_fetch_no_tombstone_by_candidate_id(grouped):
+    """Structural guard: the tombstones reach the program as the (C, P)
+    slot table and leave it by whole block rows - no gather reads a
+    rank-1 mask, and none fetches one element for each of the
+    Q x nprobe x P candidates (8.4M a batch in `bkt_deep10m.saturate`)."""
+    C, P, D, Q, nprobe, G = 12, 16, 16, 16, 2, 8
+    s = jax.ShapeDtypeStruct
+    args = (s((C, P, D), np.float32), s((C, P), np.int32),
+            s((C, P), np.float32), s((C, D), np.float32),
+            s((C,), np.float32), s((C, P), np.bool_),
+            s((Q, D), np.float32))
+    if grouped:
+        U = 2 * nprobe
+        closed = jax.make_jaxpr(
+            lambda *a: dense._dense_search_grouped_kernel(
+                *a, k=5, nprobe=nprobe, U=U, G=G,
+                metric=int(DistCalcMethod.L2), base=1))(
+                    *args, s((), np.int32))
+        candidates, fetches = Q * U * P, (Q // G) * U
+    else:
+        closed = jax.make_jaxpr(
+            lambda *a: dense._dense_search_kernel(
+                *a, k=5, nprobe=nprobe, metric=int(DistCalcMethod.L2),
+                base=1))(*args)
+        candidates, fetches = Q * nprobe * P, Q * nprobe
+    gathers = list(_gathers(closed.jaxpr))
+    of_masks = [e for e in gathers
+                if e.invars[0].aval.dtype == np.bool_]
+    assert of_masks, "the slot table is fetched somewhere"
+    for eqn in of_masks:
+        operand = eqn.invars[0].aval
+        assert operand.shape == (C, P)
+        assert tuple(eqn.params["slice_sizes"]) == (1, P)
+        assert int(np.prod(eqn.outvars[0].aval.shape)) == fetches * P
+    for eqn in gathers:
+        if int(np.prod(eqn.params["slice_sizes"])) == 1:
+            assert int(np.prod(eqn.outvars[0].aval.shape)) < candidates

@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 import sptag_tpu as sp
+from sptag_tpu.utils import metrics
 
 
 def _corpus(n=3000, d=32, nq=64, seed=3):
@@ -112,3 +113,26 @@ def test_dense_only_add_delete():
     assert idx.delete(idx.get_sample(victim)[None, :]) == sp.ErrorCode.Success
     _, ids2 = idx.search_batch(extra[:8], 3)
     assert victim not in set(ids2.ravel().tolist())
+
+
+def test_tombstone_table_is_rebuilt_once_a_swap_and_counts_its_dead():
+    """The per-slot dead table (PR 49): computed once where the layout is
+    placed and once a delete-then-search, never a search."""
+    data, queries = _corpus(n=2000)
+    idx = _build(data)
+    metrics.reset()
+    idx.search_batch(queries, 10)                 # places the layout
+    assert metrics.counter_value("dense.tombstone_rebuilds") == 1
+    assert metrics.gauge_value("dense.dead_slots") == 0
+    idx.search_batch(queries, 10)
+    assert metrics.counter_value("dense.tombstone_rebuilds") == 1
+    victims = [5, 700, 1999]
+    # by content, one call: its own search runs before any row is marked
+    assert idx.delete_rows(data[victims]) == (sp.ErrorCode.Success, 3)
+    assert metrics.counter_value("dense.tombstone_rebuilds") == 1
+    _, ids = idx.search_batch(data[victims], 3)   # ONE swap for the three
+    assert not np.isin(ids, victims).any()
+    assert metrics.counter_value("dense.tombstone_rebuilds") == 2
+    assert metrics.gauge_value("dense.dead_slots") == len(victims)
+    idx.search_batch(queries, 10)
+    assert metrics.counter_value("dense.tombstone_rebuilds") == 2

@@ -105,6 +105,9 @@ def test_served_answers_against_the_exact_scan(saved, tmp_path):
     assert metrics.gauge_value("dense.pad_share") \
         == pytest.approx(1 - ROWS / (blocks * 256))
     assert metrics.gauge_value("dense.rows_per_query") == MAX_CHECK
+    # the per-slot tombstone table, computed where the layout was placed
+    assert metrics.counter_value("dense.tombstone_rebuilds") >= 1
+    assert metrics.gauge_value("dense.dead_slots") == 0
     assert metrics.gauge_value("dense.centroids_per_query") == blocks
 
 

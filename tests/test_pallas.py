@@ -79,10 +79,10 @@ def test_dense_kernel_pallas_vs_xla_paths():
     sq = jnp.asarray((data ** 2).sum(1).astype(np.float32).reshape(C, P))
     cents = jnp.asarray(perm.mean(axis=1))
     cent_sq = jnp.asarray((np.asarray(cents) ** 2).sum(1))
-    deleted = jnp.zeros(n, bool)
+    dead_slot = jnp.zeros((C, P), bool)
     queries = jnp.asarray(rng.standard_normal((Q, D)).astype(np.float32))
 
-    args = (jnp.asarray(perm), mids, sq, cents, cent_sq, deleted, queries,
+    args = (jnp.asarray(perm), mids, sq, cents, cent_sq, dead_slot, queries,
             5, nprobe, 0, 1)
     d_x, i_x = _dense_search_kernel(*args, use_pallas=False)
     d_p, i_p = _dense_search_kernel(*args, use_pallas=True, interpret=True)
@@ -140,10 +140,10 @@ def test_dense_grouped_kernel_pallas_vs_xla():
     sq = jnp.asarray((data ** 2).sum(1).astype(np.float32).reshape(C, P))
     cents = jnp.asarray(perm.mean(axis=1))
     cent_sq = jnp.asarray((np.asarray(cents) ** 2).sum(1))
-    deleted = jnp.zeros(n, bool)
+    dead_slot = jnp.zeros((C, P), bool)
     queries = jnp.asarray(rng.standard_normal((Q, D)).astype(np.float32))
 
-    args = (jnp.asarray(perm), mids, sq, cents, cent_sq, deleted, queries,
+    args = (jnp.asarray(perm), mids, sq, cents, cent_sq, dead_slot, queries,
             jnp.int32(Q), 5, nprobe, 4, G, 0, 1)
     d_x, i_x = _dense_search_grouped_kernel(*args, use_pallas=False)
     d_p, i_p = _dense_search_grouped_kernel(*args, use_pallas=True,
@@ -169,10 +169,10 @@ def test_dense_kernel_int8_pallas_vs_xla(metric, base):
         (data.astype(np.float32) ** 2).sum(1).reshape(C, P))
     cents = jnp.asarray(perm.astype(np.float32).mean(axis=1))
     cent_sq = jnp.asarray((np.asarray(cents) ** 2).sum(1))
-    deleted = jnp.zeros(n, bool)
+    dead_slot = jnp.zeros((C, P), bool)
     queries = jnp.asarray(rng.integers(-127, 128, (Q, D)).astype(np.int8))
 
-    args = (jnp.asarray(perm), mids, sq, cents, cent_sq, deleted, queries,
+    args = (jnp.asarray(perm), mids, sq, cents, cent_sq, dead_slot, queries,
             5, nprobe, metric, base)
     d_x, i_x = _dense_search_kernel(*args, use_pallas=False)
     d_p, i_p = _dense_search_kernel(*args, use_pallas=True, interpret=True)

@@ -320,7 +320,7 @@ def test_dense_programs_name_their_stages_and_compute_the_same(
             jnp.asarray((perm * perm).sum(-1)),
             jnp.asarray(perm.mean(1)),
             jnp.asarray((perm.mean(1) ** 2).sum(-1)),
-            jnp.asarray(rng.random(n) < 0.1),
+            jnp.asarray((ids < 0) | (rng.random((C, P)) < 0.1)),  # dead_slot
             jnp.asarray(rng.standard_normal((Q, D)).astype(np.float32)))
     if grouped:
         kernel, shape = dense._dense_search_grouped_kernel, (8, 6)
